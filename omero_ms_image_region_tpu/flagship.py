@@ -15,7 +15,10 @@ import numpy as np
 from .models.pixels import Pixels
 from .models.rendering import (RenderingDef, RenderingModel,
                                default_rendering_def)
-from .ops.render import pack_settings
+
+# NOTE: ``ops.render`` (JAX) is imported inside flagship_settings only:
+# the content generator below is numpy, and JAX-free parents
+# (chip_smoke.py) import this module for it.
 
 FLAGSHIP_COLORS = ((255, 0, 0), (0, 255, 0), (0, 0, 255), (255, 255, 0))
 FLAGSHIP_WINDOW = (100.0, 40000.0)
@@ -38,6 +41,7 @@ def flagship_rdef(n_channels: int = 4,
 
 
 def flagship_settings(n_channels: int = 4) -> Tuple[RenderingDef, dict]:
+    from .ops.render import pack_settings
     rdef = flagship_rdef(n_channels)
     return rdef, pack_settings(rdef)
 

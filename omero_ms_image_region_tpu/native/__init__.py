@@ -13,6 +13,7 @@ runs concurrently across shards — the point of having this tier in C++.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -33,19 +34,45 @@ _WIREPACK_LIB_PATH = os.path.join(_BUILD_DIR, "libwirepack.so")
 _BUILD_LOCK = threading.Lock()
 
 
+_CXX_FLAGS = ("-O3", "-std=c++17", "-shared", "-fPIC", "-fopenmp")
+
+
+def _source_stamp(source: str) -> str:
+    """What a built library is valid for: the source bytes and the
+    flags.  (Not mtimes: a copy of the tree can invert them either
+    way, and ``_build`` is git-ignored so it travels with copies.)"""
+    h = hashlib.sha256(" ".join(_CXX_FLAGS).encode())
+    with open(source, "rb") as f:
+        h.update(f.read())
+    return h.hexdigest()
+
+
 def _compile_lib(source: str, lib_path: str) -> None:
     os.makedirs(_BUILD_DIR, exist_ok=True)
-    cmd = [
-        "g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-fopenmp",
-        "-o", lib_path + ".tmp", source,
-    ]
+    # Per-process temp names: several processes of one checkout (test
+    # workers, a fleet's sidecars) may build at the same moment.
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
+    cmd = ["g++", *_CXX_FLAGS, "-o", tmp, source]
     subprocess.run(cmd, check=True, capture_output=True)
-    os.replace(lib_path + ".tmp", lib_path)
+    os.replace(tmp, lib_path)
+    with open(tmp, "w") as f:
+        f.write(_source_stamp(source))
+    os.replace(tmp, lib_path + ".stamp")
+
+
+def _is_stale(source: str, lib_path: str) -> bool:
+    try:
+        with open(lib_path + ".stamp") as f:
+            built_from = f.read().strip()
+    except OSError:
+        return True
+    return (not os.path.exists(lib_path)
+            or built_from != _source_stamp(source))
 
 
 class _NativeLib:
     """Build-on-first-use loader for one shared library: double-checked
-    lock, mtime-based staleness rebuild, cached first failure (so hot
+    lock, source-hash staleness rebuild, cached first failure (so hot
     paths probing availability per batch don't re-spawn a doomed g++
     attempt every call), and per-lib ctypes prototype setup."""
 
@@ -68,9 +95,7 @@ class _NativeLib:
                 return self.lib
             if self.error is not None:
                 raise ImportError(self.error)
-            if (not os.path.exists(self.lib_path)
-                    or os.path.getmtime(self.lib_path)
-                    < os.path.getmtime(self.source)):
+            if _is_stale(self.source, self.lib_path):
                 try:
                     _compile_lib(self.source, self.lib_path)
                 except (OSError, subprocess.CalledProcessError) as e:
@@ -343,6 +368,22 @@ def mask_overlay_u8(base_rgba, mask_grids, fills):
 
 class SparseOverflowError(ValueError):
     """The device wire buffer dropped entries (content denser than cap)."""
+
+
+def status() -> dict:
+    """Which implementation of each native piece this process got
+    (building what is missing).  The device-owning server logs this at
+    start-up: a failed g++ is an ImportError here and a pure-Python
+    coder there, and an operator should not have to infer which from
+    throughput."""
+    def got(lib: _NativeLib) -> str:
+        try:
+            lib.load()
+            return "native"
+        except ImportError:
+            return "python"
+    return {"entropy_coder": got(_JPEGENC),
+            "tile_cache": got(_TILECACHE)}
 
 
 def jpeg_native_available() -> bool:

@@ -30,28 +30,6 @@ import numpy as np
 from .mesh import Mesh, make_mesh, resolve_devices
 
 
-def _distributed_initialized() -> bool:
-    """Has this process already joined ``jax.distributed``?
-
-    ``jax.distributed.is_initialized()`` only exists on newer jax
-    releases; older ones (this image ships 0.4.x without it) expose the
-    same fact through the private runtime state's client handle.  Both
-    probes are backend-free — neither touches XLA, which is the whole
-    point of checking before ``initialize()``.
-    """
-    probe = getattr(jax.distributed, "is_initialized", None)
-    if probe is not None:
-        return bool(probe())
-    try:
-        from jax._src.distributed import global_state
-        return global_state.client is not None
-    except Exception:
-        # No known probe surface: let initialize() itself decide (it
-        # raises cleanly when already joined, which the caller treats
-        # as the standalone fallback for auto-discovered setups).
-        return False
-
-
 def initialize(coordinator_address: Optional[str] = None,
                num_processes: Optional[int] = None,
                process_id: Optional[int] = None) -> None:
@@ -67,7 +45,7 @@ def initialize(coordinator_address: Optional[str] = None,
     # jax.distributed.initialize() permanently refuses — i.e. the old
     # process_count() probe made every explicit multi-host join fail.
     # (Caught by the 2-process simulated-pod test.)
-    if _distributed_initialized():
+    if jax.distributed.is_initialized():
         return
     try:
         jax.distributed.initialize(
@@ -87,7 +65,7 @@ def host_identity() -> str:
     cluster is joined (``procN`` — stable across the slice by
     construction), else the OS hostname.  Backend-free unless a
     cluster was already joined (the :func:`initialize` discipline)."""
-    if _distributed_initialized():
+    if jax.distributed.is_initialized():
         try:
             return f"proc{jax.process_index()}"
         except Exception:
@@ -105,10 +83,8 @@ def global_mesh(chan_parallel: int = 1,
     ``render_jpeg_step_sharded``) then execute one program over the whole
     slice, each host feeding its addressable shard of the batch.
 
-    ``n_devices`` requests a minimum mesh width: when the default platform
-    is narrower (e.g. a single local chip during tests) this falls back to
-    the virtual host (CPU) mesh exactly like ``mesh.make_mesh`` does, so
-    mesh-shape-dependent code paths stay exercisable everywhere.
+    ``n_devices`` requests a mesh width; a platform with fewer devices
+    is an error (``mesh.resolve_devices``), never a quiet substitution.
     """
     devices = np.asarray(resolve_devices(n_devices))
     return make_mesh(len(devices), chan_parallel=chan_parallel,

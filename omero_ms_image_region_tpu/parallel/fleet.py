@@ -2499,7 +2499,8 @@ def build_local_members(config, base_services, n: int,
     lanes) but all dispatch to the process's default device — this
     topology does NOT spread compute across a multi-chip host.  Real
     per-member device sets are the ``fleet.sockets`` topology, one
-    ``JAX_VISIBLE_DEVICES``-pinned sidecar process per member
+    sidecar process per member, each restricted to its chip by the
+    per-process TPU environment of deploy/DEPLOY.md "Fleet serving"
     (per-member device pinning here is an open roadmap item).
 
     Member-level single-flight and admission are disabled on the extra

@@ -20,54 +20,33 @@ XLA never has to guess the partitioning of the composite.
 
 from __future__ import annotations
 
-import logging
-
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:
-    from jax import shard_map as _shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _legacy_shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False)
+from jax import shard_map as _shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.quantum import quantize
 
-logger = logging.getLogger(__name__)
+
+def shard_map(f, mesh, in_specs, out_specs):
+    return _shard_map(f, mesh=mesh, in_specs=in_specs,
+                      out_specs=out_specs, check_vma=False)
 
 
 def resolve_devices(n_devices: int | None = None):
-    """Devices for an ``n_devices``-wide mesh, falling back to the host mesh.
+    """The default platform's devices for an ``n_devices``-wide mesh.
 
-    The default platform may be a single real TPU chip while a virtual
-    host-platform mesh (``xla_force_host_platform_device_count``) carries the
-    requested width — e.g. the driver's multi-chip dryrun, or test runs where
-    a TPU plugin wins the default platform slot.  The fallback is logged:
-    a CPU mesh run where a real accelerator mesh was expected should be
-    visible in the logs, not silent.
+    Too few devices is an error: a mesh asked for on an accelerator is
+    never quietly built from some other platform's devices.  A caller
+    that WANTS the virtual host mesh (tests, the compile-check entry)
+    runs with ``JAX_PLATFORMS=cpu`` or hands ``make_mesh`` its devices.
     """
     devices = jax.devices()
     if n_devices is not None and len(devices) < n_devices:
-        try:
-            cpu_devices = jax.devices("cpu")
-        except RuntimeError:
-            cpu_devices = []
-        if len(cpu_devices) >= n_devices:
-            logger.warning(
-                "make_mesh: default platform %r has %d device(s) < %d "
-                "requested; using the %d-device virtual host (CPU) mesh",
-                devices[0].platform if devices else "?", len(devices),
-                n_devices, len(cpu_devices),
-            )
-            devices = cpu_devices
+        raise ValueError(
+            f"requested a {n_devices}-device mesh but platform "
+            f"{devices[0].platform!r} has only {len(devices)} device(s)")
     return devices
 
 

@@ -146,16 +146,14 @@ def build_services(config: AppConfig) -> "ImageRegionServices":
     telemetry.install_compile_listener()
     telemetry.FLIGHT.configure(config.telemetry.flight_recorder_events)
     _install_fault_injection(config)
-    if config.renderer.compilation_cache_dir:
-        # Warm restarts: compiled executables persist across processes
-        # (measured 11 s -> 1.5 s first render after restart).  Set
-        # before anything compiles; harmless if the backend cannot
-        # serialize (jax skips caching then).  With persistence on,
-        # this trace cache is the FALLBACK under the serialized-
-        # executable tier (server.execcache).
-        import jax
-        jax.config.update("jax_compilation_cache_dir",
-                          config.renderer.compilation_cache_dir)
+    # Warm restarts: compiled executables persist across processes.
+    # Placed before anything compiles, and from outside when the
+    # operator says where (utils.jaxenv).  With persistence on, this
+    # cache is the FALLBACK under the serialized-executable tier
+    # (server.execcache).
+    from ..utils import jaxenv
+    cache_dir = jaxenv.place_compilation_cache(
+        config.renderer.compilation_cache_dir)
     if config.persistence.enabled and not config.caches.disk_dir:
         # Durable byte tier: slot the disk cache into every named
         # cache's chain (between memory and Redis) so rendered bytes
@@ -239,16 +237,12 @@ def build_services(config: AppConfig) -> "ImageRegionServices":
             # Startup probe picks the opening engine (sparse above
             # ~12 MB/s device->host, huffman below); the controller
             # then keeps the choice LIVE — per-fetch EWMA of the link
-            # rate, hysteresis flips, re-probe after idle — because
-            # tunnel links swing far past the crossover both ways.
+            # rate, hysteresis flips, re-probe after idle.
             from ..ops.jpegenc import set_fetch_observer
             from ..utils.adaptive import AdaptiveEngine
             from ..utils.linkprobe import measure_fetch_mb_s
-            try:
-                rate = measure_fetch_mb_s()
-            except Exception:
-                rate = None
-            controller = AdaptiveEngine(initial_rate_mb_s=rate)
+            controller = AdaptiveEngine(
+                initial_rate_mb_s=measure_fetch_mb_s())
             set_fetch_observer(controller.observe_fetch)
             engine = controller.engine
             log.info("adaptive jpeg engine enabled (opening: %s)",
@@ -269,6 +263,19 @@ def build_services(config: AppConfig) -> "ImageRegionServices":
             engine = resolve_auto_engine()
         renderer = Renderer(jpeg_engine=engine,
                             kernel=config.renderer.kernel)
+    # Say ONCE what this process serves from, and refuse the CPU
+    # backend unless JAX_PLATFORMS asked for it: a server that found
+    # no chip must not look like one that did.  The same document
+    # rides /readyz and the sidecar's ping.
+    device = jaxenv.device_identity(
+        renderer.mesh.devices.flat if config.parallel.enabled else None)
+    from .. import native
+    native_status = native.status()
+    log.info("device: platform=%s kind=%s count=%d ids=%s; entropy "
+             "coder: %s; tile cache: %s; compile cache: %s",
+             device["platform"], device["kind"], device["count"],
+             device["ids"], native_status["entropy_coder"],
+             native_status["tile_cache"], cache_dir)
     if hasattr(renderer, "first_tile_out"):
         # First-tile-out settlement rides the streaming knob: with
         # wire.streaming off the batcher reverts to barrier
@@ -300,6 +307,8 @@ def build_services(config: AppConfig) -> "ImageRegionServices":
             config.raw_cache.max_bytes,
             digest_index=config.raw_cache.digest_dedup)
             if config.raw_cache.enabled else None),
+        device=device,
+        native=native_status,
     )
     if config.single_flight:
         # In-flight render dedup: concurrent identical requests
@@ -2028,10 +2037,14 @@ def create_app(config: Optional[AppConfig] = None,
             checks["drain"] = f"draining: {','.join(parts)}"
 
     async def _ready_state() -> tuple:
-        """(ok, checks) for /readyz: sidecar reachability (proxy mode),
-        prewarm completion, and batcher backlog below the configured
-        threshold."""
+        """(ok, checks, backends) for /readyz: sidecar reachability
+        (proxy mode), prewarm completion, and batcher backlog below
+        the configured threshold.  ``backends`` is one
+        ``{device, native}`` document per device-owning process behind
+        this one (itself in the combined role; every answering
+        sidecar for a frontend)."""
         checks = {}
+        backends: list = []
         ok = True
         max_depth = config.telemetry.ready_max_queue_depth
         if services is None:
@@ -2084,6 +2097,8 @@ def create_app(config: Optional[AppConfig] = None,
                 else:
                     checks.setdefault("sidecar", "ok")
                 infos.append(info)
+            backends = [{"device": i.get("device"),
+                         "native": i.get("native")} for i in infos]
             if infos:
                 prewarm_pending = any(
                     bool(i.get("prewarm_pending")) for i in infos)
@@ -2113,9 +2128,11 @@ def create_app(config: Optional[AppConfig] = None,
                     # routing here — the probe body carries the
                     # degradation for operators and alerting.
                     checks["degraded-mode"] = "active"
-                    return True, checks
-                return False, checks
+                    return True, checks, backends
+                return False, checks, backends
         else:
+            backends = [{"device": services.device,
+                         "native": services.native}]
             prewarm_pending = telemetry.READINESS.prewarm_pending
             renderer = services.renderer
             if fleet_router is not None:
@@ -2194,7 +2211,7 @@ def create_app(config: Optional[AppConfig] = None,
             # Annotation only, like the pressure line: fleet size is
             # the controller's business, readiness is the instance's.
             checks["autoscaler"] = autoscaler.summary()
-        return ok, checks
+        return ok, checks, backends
 
     def _drain_status() -> dict:
         return {
@@ -2294,10 +2311,17 @@ def create_app(config: Optional[AppConfig] = None,
         """Readiness: 200 only when this process can serve renders NOW
         (sidecar up, prewarm done, backlog sane); 503 carries the
         degradation detail so a probe log reads like a diagnosis."""
-        ok, checks = await _ready_state()
-        return web.json_response(
-            {"status": "ready" if ok else "degraded", "checks": checks},
-            status=200 if ok else 503)
+        ok, checks, backends = await _ready_state()
+        doc = {"status": "ready" if ok else "degraded",
+               "checks": checks}
+        if backends and backends[0]["device"] is not None:
+            # What the render backend runs on, as ITS JAX reports it
+            # (platform / kind / count), and which native pieces it
+            # got.  A fleet frontend lists every answering member.
+            doc.update(backends[0])
+            if len(backends) > 1:
+                doc["members"] = backends
+        return web.json_response(doc, status=200 if ok else 503)
 
     async def details(request: web.Request) -> web.Response:
         doc = {
@@ -2683,9 +2707,14 @@ def main(argv=None) -> None:
             coordinator_address=config.parallel.coordinator_address,
             num_processes=config.parallel.num_processes,
             process_id=config.parallel.process_id)
+        from ..utils import jaxenv
+        jaxenv.place_compilation_cache(
+            config.renderer.compilation_cache_dir)
         mesh = cluster.global_mesh(
             chan_parallel=config.parallel.chan_parallel,
             n_devices=config.parallel.n_devices)
+        log.info("pod-worker device: %s",
+                 jaxenv.device_identity(mesh.devices.flat))
         engine = config.renderer.jpeg_engine
         if engine == "auto":
             from ..utils.linkprobe import resolve_auto_engine

@@ -103,12 +103,8 @@ def _capture_shape_estimate(shape: str, jitted_fn, args) -> None:
         flops = nbytes = None
         try:
             cost = jitted_fn.lower(*args).compile().cost_analysis()
-            # API drift: older JAX returns [dict], newer returns dict.
-            if isinstance(cost, (list, tuple)):
-                cost = cost[0] if cost else {}
-            if isinstance(cost, dict):
-                flops = float(cost.get("flops", 0.0) or 0.0)
-                nbytes = float(cost.get("bytes accessed", 0.0) or 0.0)
+            flops = float(cost.get("flops", 0.0) or 0.0)
+            nbytes = float(cost.get("bytes accessed", 0.0) or 0.0)
         except Exception:
             pass
         telemetry.SHAPE_COSTS.set_estimate(shape, flops, nbytes)
@@ -187,10 +183,7 @@ class BatchingRenderer:
             raise ValueError("device_lanes must be >= 1")
         self.max_batch = max_batch
         # Queue-pressure growth ceiling: default 2x the configured
-        # size.  Measured on-chip (1024d 4-ch, v5e): both wire engines
-        # hold their per-tile exec rate at batch 16 but LOSE 20-30% at
-        # 32 (huffman 56->55->44 t/s, sparse 106->109->77), so growth
-        # past 2x trades wire-RTT amortization for worse exec.
+        # size (not measured on the current chip).
         self.max_batch_limit = max(max_batch, max_batch_limit
                                    or max_batch * 2)
         # Per-bucket-key backlog streaks: one saturated key must not be
@@ -202,7 +195,7 @@ class BatchingRenderer:
         # this when process_count > 1).
         self._growth_enabled = True
         # One host-local retry of a group whose dispatch died on a
-        # transient transport error (tunnel relay drop).  Also cleared
+        # transient transport error (utils.transient).  Also cleared
         # on multi-host meshes: a lone host re-launching would diverge
         # the pod's SPMD launch sequence.
         self._transient_retry_enabled = True
@@ -213,9 +206,9 @@ class BatchingRenderer:
         self._deadline_drop_enabled = True
         self.linger_ms = linger_ms
         # Preferred concurrent group count under backlog (see
-        # BatcherConfig.target_inflight: default 1 = max_batch convoys,
-        # the measured winner on the tunnel; >1 splits bursts across
-        # streams for low-RTT links).  Capped by pipeline_depth.
+        # BatcherConfig.target_inflight: default 1 = max_batch
+        # convoys; >1 splits bursts across streams).  Capped by
+        # pipeline_depth.
         self.target_inflight = max(1, min(target_inflight,
                                           pipeline_depth))
         self.jpeg_engine = jpeg_engine

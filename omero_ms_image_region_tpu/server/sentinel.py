@@ -228,7 +228,7 @@ class SentinelEngine:
         baseline says (absolute floor against over-sensitive
         baselines learned during an unusually fast era)."""
         mark = (self.watermarks.get("bench") or {}).get(
-            "p50_service_tile_ms_ex_rtt")
+            "p50_service_tile_ms")
         if isinstance(mark, dict) and isinstance(
                 mark.get("value"), (int, float)):
             return float(mark["value"])

@@ -582,6 +582,10 @@ class CompileStats:
         self._lock = threading.Lock()
         self.events = 0
         self.total_ms = 0.0
+        # Of those events, how many were answered by JAX's persistent
+        # compilation cache (a short event, not a compile): the proof
+        # that the cache directory is one this process can read.
+        self.cache_hits = 0
 
     def observe(self, duration_s: float) -> None:
         with self._lock:
@@ -591,10 +595,15 @@ class CompileStats:
         # fell over" class the black box exists for.
         FLIGHT.record("xla.compile", ms=round(duration_s * 1000.0, 1))
 
+    def observe_cache_hit(self) -> None:
+        with self._lock:
+            self.cache_hits += 1
+
     def reset(self) -> None:
         with self._lock:
             self.events = 0
             self.total_ms = 0.0
+            self.cache_hits = 0
 
 
 COMPILE = CompileStats()
@@ -1208,10 +1217,12 @@ def install_compile_listener() -> bool:
             if "backend_compile" in event:
                 COMPILE.observe(duration)
 
-        try:
-            monitoring.register_event_duration_secs_listener(_on_event)
-        except Exception:       # pragma: no cover - API drift
-            return False
+        def _on_plain_event(event: str, **kw) -> None:
+            if event == "/jax/compilation_cache/cache_hits":
+                COMPILE.observe_cache_hit()
+
+        monitoring.register_event_duration_secs_listener(_on_event)
+        monitoring.register_event_listener(_on_plain_event)
         _compile_listener_installed = True
         return True
 
@@ -3419,6 +3430,8 @@ METRIC_TYPES: Dict[str, str] = {
     "imageregion_batcher_queue_wait_max_ms": "gauge",
     "imageregion_compile_events_total": "counter",
     "imageregion_compile_ms_total": "counter",
+    "imageregion_compile_cache_hits_total": "counter",
+    "imageregion_device_peak_bytes": "gauge",
     "imageregion_link_mb_s": "gauge",
     "imageregion_link_effective_mb_s": "gauge",
     "imageregion_link_fetches_total": "counter",
@@ -3962,6 +3975,20 @@ def request_metric_lines(exemplars: bool = False) -> List[str]:
     return lines
 
 
+def _device_peak_bytes() -> Optional[int]:
+    """High-water device memory of this process (the largest over its
+    local devices), where the backend reports one — the CPU backend
+    does not.  Never the call that initialises a backend."""
+    from .jaxenv import initialised_jax
+    jax = initialised_jax()
+    if jax is None:
+        return None
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+             for d in jax.local_devices()]
+    peaks = [int(p) for p in peaks if p is not None]
+    return max(peaks) if peaks else None
+
+
 def device_metric_lines(services, extra_labels: str = "") -> List[str]:
     """Series owned by a device-side process (combined app or sidecar):
     caches, raw cache, batcher gauges, compile events, link health.
@@ -4053,9 +4080,14 @@ def device_metric_lines(services, extra_labels: str = "") -> List[str]:
         f"imageregion_compile_events_total{lb} {COMPILE.events}",
         f"imageregion_compile_ms_total{lb} "
         f"{round(COMPILE.total_ms, 3)}",
+        f"imageregion_compile_cache_hits_total{lb} "
+        f"{COMPILE.cache_hits}",
         f"imageregion_link_fetches_total{lb} {LINK.fetches}",
         f"imageregion_link_fetch_bytes_total{lb} {LINK.bytes_total}",
     ]
+    peak = _device_peak_bytes()
+    if peak is not None:
+        lines.append(f"imageregion_device_peak_bytes{lb} {peak}")
     # Per-ladder-shape estimated vs observed device cost (the batcher
     # records both; cardinality is bounded by the bucket/batch ladder).
     lines += SHAPE_COSTS.metric_lines(extra_labels)

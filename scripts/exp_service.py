@@ -44,9 +44,9 @@ def run_combo(tmp, engine, max_batch, depth, linger, n_requests=16):
         await client.start_server()
         try:
             def url(i):
-                # Every request gets a unique window so the relay's
-                # dispatch memoization can never serve a cached reply
-                # (same discipline as bench._service_run).
+                # Every request gets a unique window, so each is a
+                # distinct render (same discipline as
+                # bench._service_run).
                 _SEQ[0] += 1
                 w = 20000 + (_SEQ[0] % 5000) * 9
                 x, y = i % 4, (i // 4) % 4
@@ -76,7 +76,7 @@ def run_combo(tmp, engine, max_batch, depth, linger, n_requests=16):
 
 def main():
     rng = np.random.default_rng(
-        int.from_bytes(os.urandom(8), "little"))
+        0)
     tmp = tempfile.mkdtemp()
     planes = synthetic_wsi_tiles(rng, 4, 1, 4096, 4096).reshape(
         4, 1, 4096, 4096)

@@ -2,9 +2,8 @@
 
 Breaks one batch of the config-3 workload into stages and times each:
 dispatch+device compute, wire fetch (prefetched and cold), host entropy
-encode — plus wire-compressibility probes (zeros vs noise payloads of the
-same shape) to see whether the tunnel collapses the sparse buffers' zero
-tails.  Not part of the bench; a diagnostic for optimization work.
+encode — plus fetch probes (zeros vs noise payloads of the same shape).
+Not part of the bench; a diagnostic for optimization work.
 """
 
 import statistics

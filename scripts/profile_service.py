@@ -75,11 +75,9 @@ def main():
     engine = sys.argv[2] if len(sys.argv) > 2 else "huffman"
     max_batch = int(sys.argv[3]) if len(sys.argv) > 3 else 8
 
-    import jax
-    jax.config.update(
-        "jax_compilation_cache_dir",
-        os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), ".jax_cache"))
+    from omero_ms_image_region_tpu.utils.jaxenv import (
+        place_compilation_cache)
+    place_compilation_cache()
 
     patch()
 
@@ -97,7 +95,7 @@ def main():
 
     import bench
 
-    rng = np.random.default_rng(int.from_bytes(os.urandom(8), "little"))
+    rng = np.random.default_rng(0)
     with tempfile.TemporaryDirectory() as tmp:
         planes = synthetic_wsi_tiles(rng, 4, 1, 4096, 4096).reshape(
             4, 1, 4096, 4096)

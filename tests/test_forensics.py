@@ -883,37 +883,37 @@ class TestBenchGate:
         assert by_key["decision_records"] == "pass"
 
     def test_latency_key_gates_in_the_up_direction(self, tmp_path):
-        """p50_service_tile_ms_ex_rtt is a DEFAULT key and judged
+        """p50_service_tile_ms is a DEFAULT key and judged
         lower-is-better: a >=10% latency INCREASE fails even when
         throughput is flat (the regression class a throughput-only
         gate cannot see)."""
         gate = self._gate()
         old = self._write(tmp_path, "a.json",
                           {"service_tiles_per_sec": 100.0,
-                           "p50_service_tile_ms_ex_rtt": 100.0})
+                           "p50_service_tile_ms": 100.0})
         worse = self._write(tmp_path, "b.json",
                            {"service_tiles_per_sec": 100.0,
-                            "p50_service_tile_ms_ex_rtt": 110.0})
+                            "p50_service_tile_ms": 110.0})
         assert gate.main([old, worse]) == 1
         # A latency DROP (improvement) passes, as does one within
         # threshold.
         better = self._write(tmp_path, "c.json",
                              {"service_tiles_per_sec": 100.0,
-                              "p50_service_tile_ms_ex_rtt": 50.0})
+                              "p50_service_tile_ms": 50.0})
         assert gate.main([old, better]) == 0
         near = self._write(tmp_path, "d.json",
                            {"service_tiles_per_sec": 100.0,
-                            "p50_service_tile_ms_ex_rtt": 109.0})
+                            "p50_service_tile_ms": 109.0})
         assert gate.main([old, near]) == 0
 
     def test_latency_key_skips_on_null_like_throughput(self, tmp_path):
         gate = self._gate()
         old = self._write(tmp_path, "a.json",
                           {"service_tiles_per_sec": 100.0,
-                           "p50_service_tile_ms_ex_rtt": None})
+                           "p50_service_tile_ms": None})
         new = self._write(tmp_path, "b.json",
                           {"service_tiles_per_sec": 100.0,
-                           "p50_service_tile_ms_ex_rtt": 50.0})
+                           "p50_service_tile_ms": 50.0})
         assert gate.main([old, new]) == 0
         assert gate.main(["--strict", old, new]) == 1
 
@@ -971,11 +971,11 @@ class TestBenchGate:
         for i, v in enumerate(lat):
             self._write(tmp_path, f"BENCH_r{i + 1:02d}.json",
                         {"service_tiles_per_sec": 100.0,
-                         "p50_service_tile_ms_ex_rtt": v})
+                         "p50_service_tile_ms": v})
         assert gate.main(["--watermark", "--dir", str(tmp_path)]) == 1
         verdict = json.loads(capsys.readouterr().out)
         rows = {r["key"]: r for r in verdict["keys"]}
-        row = rows["p50_service_tile_ms_ex_rtt"]
+        row = rows["p50_service_tile_ms"]
         assert row["verdict"] == "regression"
         assert row["old"] == 40.0
 

@@ -213,3 +213,37 @@ class TestNativeBitOps:
             tf.close()
         with pytest.raises(ValueError):
             native.tiff_lzw_decode(b"\xff\xff\xff\xff", 10)
+
+
+class TestBuildStaleness:
+    """A built library is valid for its SOURCE (a hash of it and the
+    flags), not for an mtime order a copy of the tree can invert."""
+
+    def test_keyed_on_source_hash_not_mtime(self, tmp_path, monkeypatch):
+        import os
+        monkeypatch.setattr(native, "_BUILD_DIR", str(tmp_path))
+        src = tmp_path / "one.cpp"
+        lib = str(tmp_path / "libone.so")
+        src.write_text('extern "C" int one() { return 1; }\n')
+        assert native._is_stale(str(src), lib)          # never built
+        native._compile_lib(str(src), lib)
+        assert not native._is_stale(str(src), lib)
+        # mtimes either way round change nothing.
+        os.utime(src, (1, 1))
+        assert not native._is_stale(str(src), lib)
+        os.utime(lib, (1, 1))
+        os.utime(src, None)
+        assert not native._is_stale(str(src), lib)
+        # The source changing does, and so does a missing stamp or lib.
+        src.write_text('extern "C" int one() { return 2; }\n')
+        assert native._is_stale(str(src), lib)
+        native._compile_lib(str(src), lib)
+        os.remove(lib + ".stamp")
+        assert native._is_stale(str(src), lib)
+        native._compile_lib(str(src), lib)
+        os.remove(lib)
+        assert native._is_stale(str(src), lib)
+
+    def test_status_names_what_was_built(self):
+        assert native.status() == {"entropy_coder": "native",
+                                   "tile_cache": "native"}

@@ -1,10 +1,10 @@
 """Pallas render kernels: parity with the XLA kernel.
 
-The RAMP kernel (elementwise, no one-hot — the Mosaic reshape blocker
-reformulated away, exactly as the XLA path's own arithmetic composite
-did) is a compile-guarded serving option (renderer.kernel: pallas); the
-one-hot LUT kernel stays an interpret-mode experiment.  These tests
-keep both parity contracts honest and pin the fallback guard.
+The RAMP kernel (elementwise, no one-hot) is a serving option
+(renderer.kernel: pallas) whose failures are loud; the one-hot LUT
+kernel is parity-tested here and compiled for the chip in
+tests/test_chip_compile.py.  Interpret mode is steered from these
+tests, never from the product.
 """
 
 import numpy as np
@@ -99,20 +99,30 @@ def test_pallas_full_lut_tables():
 
 def test_pick_block_h_covers_buckets_and_odd_heights():
     from omero_ms_image_region_tpu.experimental.pallas_render import (
-        pick_block_h)
+        _lut_block, _ramp_block_h, pick_block_h)
 
     # Production buckets take the full block.
     for H in (256, 512, 1024, 2048):
         assert pick_block_h(H) == 256
-    # Odd heights pick their largest divisor <= 256.
+    # Odd heights pick their largest multiple-of-8 divisor <= 256 ...
     assert pick_block_h(16) == 16
     assert pick_block_h(272) == 136
     assert pick_block_h(384) == 192
-    assert pick_block_h(520) == 130
-    assert pick_block_h(509) == 1      # large prime: correct, never fast
+    assert pick_block_h(520) == 104
+    # ... and one whole-height block when there is none (a full-dim
+    # block is always tiling-legal: correct, never fast).
+    assert pick_block_h(509) == 509
+    assert pick_block_h(100) == 100
     for H in (16, 272, 384, 520, 509, 100):
         bh = pick_block_h(H)
-        assert H % bh == 0 and bh <= 256
+        assert H % bh == 0 and (bh % 8 == 0 or bh == H)
+    # The serving shape: 4 x 1024^2 keeps the raw block at 2 MB, and
+    # the one-hot block is 8 rows x 512 lanes (the chip refuses the
+    # 4-row block a rows-only cap used to pick).
+    assert _ramp_block_h(4, 1024, 1024) == 128
+    assert _ramp_block_h(1, 1024, 1024) == 256
+    assert _lut_block(1024, 1024) == (8, 512)
+    assert _lut_block(16, 64) == (16, 64)
 
 
 @pytest.mark.parametrize("family", ["linear", "polynomial",
@@ -128,10 +138,20 @@ def test_pallas_ramp_kernel_shapes(B, H, W):
     _parity(B, 2, H, W, seed=B + H, ramp=True)
 
 
-def test_pallas_is_a_guarded_serving_option():
-    """renderer.kernel: pallas is accepted (compile-guarded promotion,
-    round 6) and the direct Renderer serves ramp renders through it
-    bit-identically to the XLA kernel (interpret mode off-TPU)."""
+def _interpret_on_cpu(monkeypatch):
+    """Steer the product's kernel call into interpret mode FROM THE
+    TEST (Mosaic only compiles for a TPU; the Renderer has no hook for
+    this and must not grow one)."""
+    import omero_ms_image_region_tpu.experimental.pallas_render as pr
+    import functools
+    monkeypatch.setattr(
+        pr, "render_tile_packed_pallas",
+        functools.partial(pr.render_tile_packed_pallas, interpret=True))
+
+
+def test_pallas_is_a_serving_option(monkeypatch):
+    """renderer.kernel: pallas is accepted and the direct Renderer
+    serves ramp renders through the kernel bit-identically to XLA."""
     from omero_ms_image_region_tpu.server.config import AppConfig
     from omero_ms_image_region_tpu.server.handler import Renderer
     from omero_ms_image_region_tpu.ops.render import render_tile_packed
@@ -145,21 +165,18 @@ def test_pallas_is_a_guarded_serving_option():
     rng = np.random.default_rng(5)
     raw = rng.integers(0, 65535, size=(2, 16, 64)).astype(np.float32)
 
-    r = Renderer(kernel="pallas")
-    r._pallas_interpret = True            # off-TPU test hook
-    got = r._render_sync(raw, s)
+    _interpret_on_cpu(monkeypatch)
+    got = Renderer(kernel="pallas")._render_sync(raw, s)
     want = np.asarray(render_tile_packed(
         raw, s["window_start"], s["window_end"], s["family"],
         s["coefficient"], s["reverse"], s["cd_start"], s["cd_end"],
         s["tables"]))
     np.testing.assert_array_equal(got, want)
-    assert r._pallas_ok                   # the guard never tripped
 
 
-def test_pallas_option_falls_back_on_failure():
-    """The compile guard: a pallas failure serves the render on the XLA
-    kernel and disables the option for the process life — the option
-    can only remove work, never fail a request."""
+def test_pallas_option_failure_is_loud(monkeypatch):
+    """A kernel the backend refuses fails the request, every time —
+    the option never quietly turns itself into the XLA kernel."""
     from omero_ms_image_region_tpu.server.handler import Renderer
 
     rdef = _rdef(2)
@@ -168,29 +185,35 @@ def test_pallas_option_falls_back_on_failure():
     raw = rng.integers(0, 65535, size=(2, 16, 64)).astype(np.float32)
 
     r = Renderer(kernel="pallas")
-    r._pallas_interpret = True
     import omero_ms_image_region_tpu.experimental.pallas_render as pr
-    original = pr.render_tile_packed_pallas
-    pr.render_tile_packed_pallas = (
-        lambda *a, **k: (_ for _ in ()).throw(RuntimeError("mosaic")))
-    try:
-        out = r._render_sync(raw, s)      # served by the fallback
-    finally:
-        pr.render_tile_packed_pallas = original
-    assert out.shape == (16, 64)
-    assert not r._pallas_ok               # guard latched off
-    out2 = r._render_sync(raw, s)         # straight to XLA now
-    np.testing.assert_array_equal(out, out2)
+
+    def refuse(*a, **k):
+        raise RuntimeError("mosaic")
+    monkeypatch.setattr(pr, "render_tile_packed_pallas", refuse)
+    for _ in range(2):                    # no latch: still loud later
+        with pytest.raises(RuntimeError, match="mosaic"):
+            r._render_sync(raw, s)
+    # And on this CPU backend the real kernel (no interpret steering)
+    # is refused by JAX itself rather than served by XLA.
+    monkeypatch.undo()
+    with pytest.raises(Exception):
+        r._render_sync(raw, s)
 
 
-def test_pallas_lut_renders_stay_on_xla():
+def test_pallas_lut_renders_stay_on_xla(monkeypatch):
     """LUT-table renders (tables.ndim == 3) never route to pallas —
-    the one-hot formulation is still experimental on hardware."""
+    the serving option covers the ramp kernel only."""
     from omero_ms_image_region_tpu.server.handler import Renderer
+    import omero_ms_image_region_tpu.experimental.pallas_render as pr
 
     rdef = _rdef(2)
     s = dict(pack_settings(rdef))
     s["tables"] = build_channel_tables(rdef)    # force the 3-D tables
-    r = Renderer(kernel="pallas")
-    r._pallas_interpret = True
-    assert not r._pallas_eligible(s)
+    rng = np.random.default_rng(7)
+    raw = rng.integers(0, 65535, size=(2, 16, 64)).astype(np.float32)
+
+    def never(*a, **k):
+        raise AssertionError("LUT render routed to the pallas kernel")
+    monkeypatch.setattr(pr, "render_tile_packed_pallas", never)
+    out = Renderer(kernel="pallas")._render_sync(raw, s)
+    assert out.shape == (16, 64)

@@ -190,7 +190,7 @@ def _make_engine(tmp_path, clk, **over):
         max_bundles=3,
         profile_ms=10,
         watermarks={"bench": {
-            "p50_service_tile_ms_ex_rtt": {"value": 5.0},
+            "p50_service_tile_ms": {"value": 5.0},
             "service_tiles_per_sec": {"value": 0.001}}},
         clock=lambda: clk[0],
         profile_fn=lambda directory, ms: {"skipped": "test"},
@@ -315,7 +315,7 @@ class TestDriftLifecycle:
         # Committed p50 mark of 200ms: a 40ms p99 is under the floor
         # so the baseline-relative breach must not fire.
         eng = _make_engine(tmp_path, clk, watermarks={"bench": {
-            "p50_service_tile_ms_ex_rtt": {"value": 200.0},
+            "p50_service_tile_ms": {"value": 200.0},
             "service_tiles_per_sec": {"value": 0.001}}})
         for _ in range(3):
             _feed(eng, 12.0)

@@ -71,11 +71,15 @@ class TestLinkProbe:
                                 lambda *a, r=rate, **k: r)
             assert linkprobe.resolve_auto_engine() == expect
 
-    def test_resolve_auto_engine_survives_probe_failure(self, monkeypatch):
+    def test_resolve_auto_engine_probe_failure_is_an_error(
+            self, monkeypatch):
+        """A probe that cannot reach the device raises — never an
+        "infinite link rate" that quietly picks an engine."""
         from omero_ms_image_region_tpu.utils import linkprobe
 
         def boom(*a, **k):
             raise RuntimeError("no device")
 
         monkeypatch.setattr(linkprobe, "measure_fetch_mb_s", boom)
-        assert linkprobe.resolve_auto_engine() == "sparse"
+        with pytest.raises(RuntimeError, match="no device"):
+            linkprobe.resolve_auto_engine()

@@ -1,0 +1,9 @@
+"""Share of the traced window in which no operation ran on the chip.
+Nothing without a trace."""
+
+
+def read(ctx: dict):
+    trace = ctx.get("trace")
+    if not trace or trace["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - trace["busy_s"] / trace["window_s"])

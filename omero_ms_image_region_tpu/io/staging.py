@@ -50,6 +50,7 @@ def _pad_words(n: int) -> int:
 
 
 @functools.partial(jax.jit, static_argnames=("shape",))
+@jax.named_scope("stage.unpack16")   # utils.profile_summary.STAGES
 def unpack16_device(words, widths, shape) -> jax.Array:
     """Inverse of ``native.wirepack_pack16`` on device.
 

@@ -31,6 +31,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..utils.stopwatch import stopwatch
+
 # ---------------------------------------------------------------- tables
 
 # Annex K base quantization tables (natural 8x8 order).
@@ -96,6 +98,7 @@ def _blockify(x):
     return x.transpose(0, 1, 3, 2, 4).reshape(Bq, -1, 8, 8)
 
 
+@jax.named_scope("jpeg.dct_quant")
 def _dct_quant_zigzag(planes, qtable, zig, D):
     """[B, H, W] level-shifted samples -> i16[B, nb, 64] zigzag coeffs."""
     blocks = _blockify(planes)
@@ -120,27 +123,30 @@ def packed_to_jpeg_coefficients(packed, qy, qc):
     Returns:
       (y, cb, cr) int16 coefficient arrays in the module-docstring layout.
     """
-    r = (packed & 0xFF).astype(jnp.float32)
-    g = ((packed >> 8) & 0xFF).astype(jnp.float32)
-    b = ((packed >> 16) & 0xFF).astype(jnp.float32)
+    with jax.named_scope("jpeg.ycbcr420"):
+        r = (packed & 0xFF).astype(jnp.float32)
+        g = ((packed >> 8) & 0xFF).astype(jnp.float32)
+        b = ((packed >> 16) & 0xFF).astype(jnp.float32)
 
-    # BT.601 full-range YCbCr; the +128 chroma bias and the JPEG -128 level
-    # shift cancel, so only luma is shifted.
-    y = 0.299 * r + 0.587 * g + 0.114 * b - 128.0
-    cb = -0.168736 * r - 0.331264 * g + 0.5 * b
-    cr = 0.5 * r - 0.418688 * g - 0.081312 * b
+        # BT.601 full-range YCbCr; the +128 chroma bias and the JPEG -128
+        # level shift cancel, so only luma is shifted.
+        y = 0.299 * r + 0.587 * g + 0.114 * b - 128.0
+        cb = -0.168736 * r - 0.331264 * g + 0.5 * b
+        cr = 0.5 * r - 0.418688 * g - 0.081312 * b
 
-    # 4:2:0: 2x2 mean subsample of the chroma planes.
-    def sub(x):
-        Bq, H, W = x.shape
-        return x.reshape(Bq, H // 2, 2, W // 2, 2).mean(axis=(2, 4))
+        # 4:2:0: 2x2 mean subsample of the chroma planes.
+        def sub(x):
+            Bq, H, W = x.shape
+            return x.reshape(Bq, H // 2, 2, W // 2, 2).mean(axis=(2, 4))
+
+        cb, cr = sub(cb), sub(cr)
 
     zig = jnp.asarray(zigzag_order())
     D = jnp.asarray(dct_matrix())
     return (
         _dct_quant_zigzag(y, qy, zig, D),
-        _dct_quant_zigzag(sub(cb), qc, zig, D),
-        _dct_quant_zigzag(sub(cr), qc, zig, D),
+        _dct_quant_zigzag(cb, qc, zig, D),
+        _dct_quant_zigzag(cr, qc, zig, D),
     )
 
 
@@ -188,6 +194,7 @@ def sparse_prefix_bytes(total: int, H: int, W: int) -> int:
     return 4 + nb + (ENTRY_BITS * int(total) + 7) // 8
 
 
+@jax.named_scope("wire.sparse_pack")
 def sparse_pack(y, cb, cr, cap: int):
     """Compact nonzero coefficients into one u8 wire buffer per tile.
 
@@ -238,7 +245,8 @@ def sparse_pack(y, cb, cr, cap: int):
         return jnp.zeros(cap, jnp.int32).at[tgt].set(
             f, mode="drop", unique_indices=True)
 
-    comp = jax.vmap(compact_one)(mask, wi, field)          # [B, cap]
+    with jax.named_scope("wire.sparse_pack.scatter"):
+        comp = jax.vmap(compact_one)(mask, wi, field)      # [B, cap]
 
     # Assemble the 18-bit stream byte-by-byte: byte b covers bits
     # [8b, 8b+8), which intersect entries e0 = (8b)//18 and possibly
@@ -256,7 +264,8 @@ def sparse_pack(y, cb, cr, cap: int):
         part1 = jnp.where(off > 10, f1 >> (28 - off), 0)
         return ((part0 | part1) & 0xFF).astype(jnp.uint8)
 
-    stream = jax.vmap(assemble_one)(compz)                  # [B, nbytes]
+    with jax.named_scope("wire.sparse_pack.bits"):
+        stream = jax.vmap(assemble_one)(compz)              # [B, nbytes]
     tot_u8 = jax.lax.bitcast_convert_type(
         total[:, None], jnp.uint8).reshape(B, -1)
     return jnp.concatenate([tot_u8, counts, stream], axis=1)
@@ -320,18 +329,13 @@ class SparseWireFetcher:
         return (4 + self.nb
                 + (ENTRY_BITS * np.clip(totals, 0, self.cap) + 7) // 8)
 
-    def finish(self, handle) -> np.ndarray:
+    def finish(self, handle, tiles: int = None,
+               timings: dict = None) -> np.ndarray:
         """Complete a fetch: host u8[B, >=prefix] rows, decodable by
-        the matching decoder."""
-        import time as _time
-
+        the matching decoder.  ``tiles`` / ``timings``: see
+        :func:`_fetch_first`."""
         pre, buf, k = handle
-        t0 = _time.perf_counter()
-        host = np.asarray(pre)
-        # Conflated: this wait covers the device render completing, not
-        # just the wire, so its rate is only a lower bound on the link.
-        _observe_fetch(host.nbytes, _time.perf_counter() - t0,
-                       conflated=True)
+        host = _fetch_first(pre, tiles, timings)
         needed = self._needed(host)
         mx = int(needed.max(initial=0))
         self._k = self._round(int(mx * self.headroom))
@@ -339,14 +343,12 @@ class SparseWireFetcher:
             return host
         # Under-predicted: complete ALL rows with one batched slice (a
         # per-row fetch would pay the link's latency floor B times).
-        end = self._round(mx)
-        t0 = _time.perf_counter()
-        rest = np.asarray(buf[:, k:end])
-        _observe_fetch(rest.nbytes, _time.perf_counter() - t0)
+        rest = _fetch_rest(buf[:, k:self._round(mx)])
         return np.concatenate([host, rest], axis=1)
 
-    def fetch(self, buf) -> np.ndarray:
-        return self.finish(self.start(buf))
+    def fetch(self, buf, tiles: int = None,
+              timings: dict = None) -> np.ndarray:
+        return self.finish(self.start(buf), tiles, timings)
 
 
 _FETCHERS: dict = {}
@@ -386,6 +388,37 @@ def _observe_fetch(nbytes: int, seconds: float,
             pass            # break the serving path
 
 
+def _fetch_first(pre, tiles: int = None,
+                 timings: dict = None) -> np.ndarray:
+    """The first host copy of a dispatched program's output, as the
+    span ``wire.fetch`` and its two parts: ``device.wait`` (the program
+    and the slice program running to their end) and ``wire.d2h`` (the
+    copy alone; it begins when ``tiles`` real tiles are rendered: how a
+    profiler capture counts renders).  The wait's milliseconds go into
+    ``timings["device_ms"]`` for the caller's cost ledger."""
+    with stopwatch("wire.fetch") as fetch:
+        with stopwatch("device.wait", tiles=tiles or 0) as wait:
+            if hasattr(pre, "block_until_ready"):
+                pre.block_until_ready()
+        with stopwatch("wire.d2h", tiles=tiles or 0):
+            host = np.asarray(pre)
+    if timings is not None:
+        timings["device_ms"] = timings.get("device_ms", 0.0) + wait.ms
+    # Conflated: this wait covers the device render completing, not
+    # just the wire, so its rate is only a lower bound on the link.
+    _observe_fetch(host.nbytes, fetch.ms / 1000.0, conflated=True)
+    return host
+
+
+def _fetch_rest(rest) -> np.ndarray:
+    """The follow-up copy of an under-predicted prefix
+    (``wire.fetch2``): a slice program and its copy, nothing else."""
+    with stopwatch("wire.fetch2") as fetch:
+        host = np.asarray(rest)
+    _observe_fetch(host.nbytes, fetch.ms / 1000.0)
+    return host
+
+
 def wire_fetcher(H: int, W: int, cap: int) -> SparseWireFetcher:
     """Process-wide fetcher per (tile shape, cap): prediction state is
     shared across requests so the serving path warms up once."""
@@ -397,6 +430,7 @@ def wire_fetcher(H: int, W: int, cap: int) -> SparseWireFetcher:
         return f
 
 
+@jax.named_scope("wire.compact_rows")
 def _compact_rows(bufs, lengths):
     """Device-side wire compaction: pack each row's used prefix
     contiguously so the host fetch carries exactly the needed bytes.
@@ -555,30 +589,19 @@ class CompactWireFetcher:
             pre.copy_to_host_async()
         return pre, buf, k
 
-    def finish(self, handle) -> list:
+    def finish(self, handle, tiles: int = None,
+               timings: dict = None) -> list:
         """Complete a fetch -> per-row u8 arrays (length B; excluded
-        rows come back empty)."""
-        import time as _time
-
-        from ..utils.stopwatch import REGISTRY as _REG
-
+        rows come back empty).  ``tiles`` / ``timings``: see
+        :func:`_fetch_first`."""
         pre, buf, k = handle
-        t0 = _time.perf_counter()
-        host = np.asarray(pre)
-        dt = _time.perf_counter() - t0
-        _REG.record("wire.fetch", dt * 1000.0)
-        _observe_fetch(host.nbytes, dt, conflated=True)
+        host = _fetch_first(pre, tiles, timings)
         lengths = host[:self.hdr].view(np.int32)
         total = self.hdr + int(lengths.sum())
         missed = total > k
         if missed:
-            end = self._round(total)
-            t0 = _time.perf_counter()
-            rest = np.asarray(buf[k:end])
-            dt = _time.perf_counter() - t0
-            _REG.record("wire.fetch2", dt * 1000.0)
-            _observe_fetch(rest.nbytes, dt)
-            host = np.concatenate([host, rest])
+            host = np.concatenate(
+                [host, _fetch_rest(buf[k:self._round(total)])])
         # Atomic prediction update: the fetches themselves run
         # unlocked (concurrent groups overlap on the wire by design);
         # only the read-modify-write of the shared training state is
@@ -595,8 +618,9 @@ class CompactWireFetcher:
             [[0], np.cumsum(lengths, dtype=np.int64)])
         return [host[offs[i]:offs[i + 1]] for i in range(self.B)]
 
-    def fetch(self, buf) -> list:
-        return self.finish(self.start(buf))
+    def fetch(self, buf, tiles: int = None,
+              timings: dict = None) -> list:
+        return self.finish(self.start(buf), tiles, timings)
 
 
 def compact_fetcher(engine: str, H: int, W: int, cap: int,
@@ -987,6 +1011,7 @@ def _scan_order_flat(h16: int, w16: int) -> np.ndarray:
 
 @functools.partial(jax.jit,
                    static_argnames=("cap", "cap_words", "h16", "w16"))
+@jax.named_scope("wire.huffman_pack")
 def huffman_pack(y, cb, cr, cap: int, cap_words: int,
                  dc_code, dc_len, ac_code, ac_len, *, h16: int, w16: int):
     """Entropy-code quantized coefficients on device with fixed tables.
@@ -1448,7 +1473,8 @@ def render_batch_to_jpeg(raw, window_start, window_end, family, coefficient,
                          reverse, cd_start, cd_end, tables, quality: int,
                          dims, cap: int | None = None,
                          engine: str = "sparse",
-                         tune: bool = True, on_tile=None) -> list:
+                         tune: bool = True, on_tile=None,
+                         timings: dict = None) -> list:
     """Serving-path helper: one batched device dispatch -> JFIF per tile.
 
     ``raw`` is [B, C, H, W] with H, W multiples of 16 (callers edge-pad;
@@ -1474,8 +1500,20 @@ def render_batch_to_jpeg(raw, window_start, window_end, family, coefficient,
     coding, instead of every waiter parking behind the batch tail.  The
     bytes passed are EXACTLY the returned list's entry (byte-identity is
     the streaming contract); callback exceptions are the caller's.
+
+    ``timings`` (optional dict): ``device_ms`` gains the milliseconds of
+    the spans ``device.dispatch`` (the jitted call until it returns,
+    and the wire fetcher's slice after it: Python dispatch, argument
+    upload, a trace or compile that falls into them) and
+    ``device.wait`` (the program running to its end), for the batcher's
+    cost ledger.
     """
     B, C, H, W = raw.shape
+
+    def dispatched(span) -> None:
+        if timings is not None:
+            timings["device_ms"] = timings.get("device_ms", 0.0) + span.ms
+
     if cap is None:
         cap = default_sparse_cap(H, W, quality)
     qy, qc = (np.asarray(t, np.int32) for t in quant_tables(quality))
@@ -1504,13 +1542,16 @@ def render_batch_to_jpeg(raw, window_start, window_end, family, coefficient,
             spec_arrays, frame_spec = huffman_spec_arrays(), None
 
         def dispatch_huffman(c, cw):
-            bufs = render_to_jpeg_huffman_compact(
-                raw, window_start, window_end, family, coefficient,
-                reverse, cd_start, cd_end, tables, qy, qc,
-                *spec_arrays, np.int32(n),
-                h16=H // 16, w16=W // 16, cap=c, cap_words=cw)
-            return compact_fetcher("huffman", H, W, c, cw,
-                                   B).fetch(bufs)[:n]
+            fetcher = compact_fetcher("huffman", H, W, c, cw, B)
+            with stopwatch("device.dispatch") as span:
+                bufs = render_to_jpeg_huffman_compact(
+                    raw, window_start, window_end, family, coefficient,
+                    reverse, cd_start, cd_end, tables, qy, qc,
+                    *spec_arrays, np.int32(n),
+                    h16=H // 16, w16=W // 16, cap=c, cap_words=cw)
+                handle = fetcher.start(bufs)
+            dispatched(span)
+            return fetcher.finish(handle, n, timings)[:n]
 
         cap_words = default_words_cap(H, W, quality)
         memo_key = ("huffman", H, W, quality)
@@ -1549,7 +1590,6 @@ def render_batch_to_jpeg(raw, window_start, window_end, family, coefficient,
             # callers (prewarm's all-zero compile probes) must never
             # seed the tables real traffic will be served with.
             _maybe_start_tuning((H, W, quality), dense_coefficients)
-        from ..utils.stopwatch import stopwatch
         with stopwatch("jfif.encodeBatch"):
             return finish_huffman_batch(
                 rows, dims, H, W, quality, cap, cap_words,
@@ -1557,10 +1597,15 @@ def render_batch_to_jpeg(raw, window_start, window_end, family, coefficient,
                 on_tile=on_tile)
 
     def dispatch_sparse(c):
-        bufs = render_to_jpeg_sparse_compact(
-            raw, window_start, window_end, family, coefficient, reverse,
-            cd_start, cd_end, tables, qy, qc, np.int32(n), cap=c)
-        return compact_fetcher("sparse", H, W, c, 0, B).fetch(bufs)[:n]
+        fetcher = compact_fetcher("sparse", H, W, c, 0, B)
+        with stopwatch("device.dispatch") as span:
+            bufs = render_to_jpeg_sparse_compact(
+                raw, window_start, window_end, family, coefficient,
+                reverse, cd_start, cd_end, tables, qy, qc, np.int32(n),
+                cap=c)
+            handle = fetcher.start(bufs)
+        dispatched(span)
+        return fetcher.finish(handle, n, timings)[:n]
 
     memo_key = ("sparse", H, W, quality)
     if _CAP_MEMO.get(memo_key):
@@ -1574,7 +1619,6 @@ def render_batch_to_jpeg(raw, window_start, window_end, family, coefficient,
         cap = cap * 2
         rows = dispatch_sparse(cap)
 
-    from ..utils.stopwatch import stopwatch
     with stopwatch("jfif.encodeBatch"):
         return finish_sparse_to_jpegs(rows, dims, H, W, quality, cap,
                                       dense_coefficients,

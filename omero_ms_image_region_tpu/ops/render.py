@@ -176,6 +176,7 @@ def composite_packed(q, tables):
     return r | (g << 8) | (b << 16) | jnp.uint32(0xFF000000)
 
 
+@jax.named_scope("render")      # a stage of utils.profile_summary.STAGES
 def _render_packed_impl(raw, window_start, window_end, family, coefficient,
                         reverse, cd_start, cd_end, tables):
     """Shared impl over arbitrary leading dims: raw [..., C, H, W]."""

@@ -347,7 +347,7 @@ class MeshRenderer(BatchingRenderer):
         telemetry.add_cost(
             "stage_ms", (time.perf_counter() - t_stage) * 1000.0 / n)
         shape = "mesh:" + _shape_label(raw.shape)
-        with self._device_gate:
+        with self._lane():
             if self._pod is not None:
                 self._pod.announce(_POD_RENDER, raw, stacked)
             t0 = time.perf_counter()
@@ -469,7 +469,7 @@ class MeshRenderer(BatchingRenderer):
         qy, qc = (np.asarray(t, np.int32) for t in quant_tables(quality))
         dims = [(p.w, p.h) for p in group]
         if engine == "huffman":
-            with self._device_gate:
+            with self._lane():
                 if self._pod is not None:
                     self._pod.announce(_POD_JPEG, raw, stacked, quality,
                                        engine_id=1)
@@ -497,7 +497,7 @@ class MeshRenderer(BatchingRenderer):
                 # depends on it).
                 on_tile=self._early_settle_cb(group))
         else:
-            with self._device_gate:
+            with self._lane():
                 if self._pod is not None:
                     self._pod.announce(_POD_JPEG, raw, stacked, quality,
                                        engine_id=0)

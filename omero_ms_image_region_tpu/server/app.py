@@ -144,6 +144,10 @@ def build_services(config: AppConfig) -> "ImageRegionServices":
     # compile event with a seconds-scale duration.  Installed before
     # anything can compile.
     telemetry.install_compile_listener()
+    # Every stopwatch span of this process is also an annotation in a
+    # /debug/profile capture, on the device planes' clock.
+    from ..utils.stopwatch import install_annotations
+    install_annotations()
     telemetry.FLIGHT.configure(config.telemetry.flight_recorder_events)
     _install_fault_injection(config)
     # Warm restarts: compiled executables persist across processes.

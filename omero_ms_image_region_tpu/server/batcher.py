@@ -25,6 +25,8 @@ from __future__ import annotations
 
 import asyncio
 import collections
+import contextlib
+import itertools
 from dataclasses import dataclass, field
 from typing import Deque, Dict, List, Optional, Tuple
 
@@ -238,6 +240,9 @@ class BatchingRenderer:
         # contend for the device itself.
         self.device_lanes = device_lanes
         self._device_gate = threading.BoundedSemaphore(device_lanes)
+        # Identifier of a dispatched group: on its ``batcher.group``
+        # span, beside the members' trace ids (``group_trace``).
+        self._group_ids = itertools.count(1)
         # High-water queue wait (ms) for the /metrics gauge — the
         # stragglers a mean hides and a p50 cannot see.
         self.queue_wait_max_ms = 0.0
@@ -285,6 +290,18 @@ class BatchingRenderer:
         with self._stats_lock:
             self.batches_dispatched += 1
             self.tiles_rendered += tiles
+
+    @contextlib.contextmanager
+    def _lane(self):
+        """Hold one of the ``device_lanes``.  The wait for it is a span
+        of its own: with ``pipeline_depth`` > ``device_lanes`` it is a
+        queue, and no other span sees it."""
+        with stopwatch("batcher.laneWait"):
+            self._device_gate.acquire()
+        try:
+            yield
+        finally:
+            self._device_gate.release()
 
     def queue_depth(self) -> int:
         """Requests waiting across every bucket key (the /metrics
@@ -707,8 +724,14 @@ class BatchingRenderer:
         def run():
             # Worker-thread trace target: the group's device render,
             # wire fetch and encode spans land on EVERY member's
-            # waterfall (each request really did wait on them).
-            with telemetry.group_trace(trace_ids):
+            # waterfall (each request really did wait on them).  The
+            # group's own span is the parent of them all, by nesting
+            # on this thread.
+            with telemetry.group_trace(trace_ids), stopwatch(
+                    "batcher.group", group_id=next(self._group_ids),
+                    tiles=len(group),
+                    padded=_pad_batch_size(len(group), self.max_batch),
+                    key=_key_label(key)):
                 return run_inner()
 
         inner = asyncio.ensure_future(asyncio.to_thread(run))
@@ -816,7 +839,7 @@ class BatchingRenderer:
         packed = np.stack([p.raw for p in padded])
         _, width, height, fh, fv = self._mask_key_of(group)
         from ..io.staging import pin_scope
-        with self._device_gate, pin_scope(self.device):
+        with self._lane(), pin_scope(self.device):
             t0 = time.perf_counter()
             with stopwatch("Renderer.rasterizeMask.batch"):
                 grids = rasterize_packed_batch(packed, width, height,
@@ -853,23 +876,27 @@ class BatchingRenderer:
                                             args)
                      if self.exec_cache is not None else None)
         from ..io.staging import pin_scope
-        with self._device_gate, pin_scope(self.device):
+        with self._lane(), pin_scope(self.device):
             t0 = time.perf_counter()
             with stopwatch("Renderer.renderAsPackedInt.batch"):
-                if loaded_fn is not None:
-                    try:
-                        out = loaded_fn(*args)
-                    except Exception:
-                        # Runtime drift the fingerprint cannot see:
-                        # evict so only THIS group pays the failed
-                        # attempt — every later group goes straight
-                        # to the jit path.
-                        self.exec_cache.invalidate(
-                            "render_tile_batch_packed", args)
+                with stopwatch("device.dispatch"):
+                    if loaded_fn is not None:
+                        try:
+                            out = loaded_fn(*args)
+                        except Exception:
+                            # Runtime drift the fingerprint cannot see:
+                            # evict so only THIS group pays the failed
+                            # attempt — every later group goes straight
+                            # to the jit path.
+                            self.exec_cache.invalidate(
+                                "render_tile_batch_packed", args)
+                            out = render_tile_batch_packed(*args)
+                    else:
                         out = render_tile_batch_packed(*args)
-                else:
-                    out = render_tile_batch_packed(*args)
-                host = np.asarray(out)
+                with stopwatch("device.wait", tiles=n):
+                    out.block_until_ready()
+                with stopwatch("wire.d2h", tiles=n):
+                    host = np.asarray(out)
             exec_ms = (time.perf_counter() - t0) * 1000.0
         if loaded_fn is None and self.exec_cache is not None:
             # First group of this signature in this life: capture the
@@ -929,8 +956,8 @@ class BatchingRenderer:
         s0 = group[0].settings
         shape = _shape_label(raw.shape, jpeg=True)
         from ..io.staging import pin_scope
-        with self._device_gate, pin_scope(self.device):
-            t0 = time.perf_counter()
+        timings: Dict[str, float] = {}
+        with self._lane(), pin_scope(self.device):
             with stopwatch("Renderer.renderAsPackedInt.batch"):
                 jpegs = render_batch_to_jpeg(
                     raw, stack("window_start"), stack("window_end"),
@@ -941,11 +968,13 @@ class BatchingRenderer:
                     dims=[(p.w, p.h) for p in group],  # pads skip encode
                     engine=self._current_engine(),
                     on_tile=self._early_settle_cb(group),
+                    timings=timings,
                 )
-            exec_ms = (time.perf_counter() - t0) * 1000.0
-        # Observed-only for JPEG groups: the wire span conflates device
-        # execute with fetch + host entropy coding, and the host
-        # wrapper has no single compiled program to cost-analyze.
+        # Observed-only for JPEG groups (the host wrapper has no single
+        # compiled program to cost-analyze): the dispatch and the wait
+        # for the program, without the copy out and the host entropy
+        # coding that follow them under the same lane.
+        exec_ms = timings.get("device_ms", 0.0)
         telemetry.add_cost("device_ms", exec_ms / n)
         telemetry.SHAPE_COSTS.observe(shape, exec_ms)
         self._count_batch(n)

@@ -4,7 +4,7 @@ Three small facts every device-owning entry point needs and none may
 guess at:
 
 * the compile cache is placed from OUTSIDE when the operator says so
-  (``JAX_COMPILATION_CACHE_DIR`` — JAX reads it itself, nothing is set
+  (``JAX_COMPILATION_CACHE_DIR`` — JAX reads it itself, no path is set
   in code), else from the config, else at ONE fixed in-checkout path.
   The directory is part of the cache key, so it is never a temp name,
   a pid or a time;
@@ -34,11 +34,20 @@ CHECKOUT_CACHE_DIR = os.path.join(_CHECKOUT, ".jax_cache")
 def place_compilation_cache(configured: Optional[str] = None) -> str:
     """Point JAX's persistent compilation cache somewhere stable and
     return the directory in use.  Call before anything compiles."""
+    import jax
+    # The cache's key leaves an operation's metadata out by default, so
+    # a cache that an older build warmed would hand this one programs
+    # without the scope names the profile summary counts
+    # device time by (utils.profile_summary.STAGES).  With metadata in
+    # the key such an entry is never matched.  The price: source lines
+    # are metadata too, so an edit to a file a program is traced from
+    # makes the next start a cold one (deploy/DEPLOY.md).
+    jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                      True)
     from_env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if from_env:
-        return from_env        # JAX reads the variable; set nothing
+        return from_env        # JAX reads the variable; no path is set
     path = configured or CHECKOUT_CACHE_DIR
-    import jax
     jax.config.update("jax_compilation_cache_dir", path)
     return path
 

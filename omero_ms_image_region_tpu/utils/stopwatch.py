@@ -7,7 +7,9 @@ start/elapsed pairs double as latency metrics in the logs (SURVEY.md §5:
 Java service's logs keep working against this one.
 
 Spans log at debug level and feed an in-process aggregator exposed on
-``/metrics``.  Each span keeps a fixed log-scale bucketed histogram
+``/metrics``; in a device-owning process each is also an annotation in
+the profiler's capture (``install_annotations``).  Each span keeps a
+fixed log-scale bucketed histogram
 (``utils.telemetry.Histogram``) — proper Prometheus
 ``_bucket``/``_sum``/``_count`` series, replacing the old 256-sample
 ring whose p50 hid tail regressions.  Every recorded duration is also
@@ -20,7 +22,7 @@ from __future__ import annotations
 import logging
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Dict
 
 from .telemetry import Histogram, observe_span
@@ -100,34 +102,66 @@ def span_lines(extra_labels: str = "",
     """Prometheus exposition lines for every span — the one formatter
     shared by the app's /metrics and the sidecar's metrics op.
 
-    Per span: the legacy count/mean series plus the full
-    ``imageregion_span_ms`` histogram (``_bucket``/``_sum``/``_count``).
-    ``extra_labels`` is appended inside the label braces (e.g.
-    ``,process="sidecar"``)."""
+    Per span: ``imageregion_span_count`` plus the full
+    ``imageregion_span_ms`` histogram (``_bucket``/``_sum``/``_count``;
+    a mean over a window is the growth of ``_sum`` over the growth of
+    the count).  ``extra_labels`` is appended inside the label braces
+    (e.g. ``,process="sidecar"``)."""
     extra = extra_labels.lstrip(",")
     lines = []
     with registry._lock:
-        items = sorted((name, s.count, s.total_ms, s.hist)
+        items = sorted((name, s.count, s.hist)
                        for name, s in registry._spans.items())
-        for name, count, total_ms, hist in items:
+        for name, count, hist in items:
             body = f'span="{name}"' + (f",{extra}" if extra else "")
-            mean = round(total_ms / count, 3) if count else 0.0
-            lines += [
-                f"imageregion_span_count{{{body}}} {count}",
-                f"imageregion_span_mean_ms{{{body}}} {mean}",
-            ]
+            lines.append(f"imageregion_span_count{{{body}}} {count}")
             lines += hist.series("imageregion_span_ms", body)
     return lines
 
 
-@contextmanager
-def stopwatch(name: str, registry: StopWatchRegistry = REGISTRY):
-    """Time a stage under a reference span name, e.g.
-    ``Renderer.renderAsPackedInt`` or ``ProjectionService.projectStack``."""
-    t0 = time.perf_counter()
+# The profiler's host-side annotation, or None.  This module (like
+# utils.telemetry) must import without JAX, so the device-owning
+# process installs it, the way it installs the compile listener.
+_ANNOTATION = None
+
+
+def install_annotations() -> bool:
+    """Make every span also a ``jax.profiler.TraceAnnotation``: with a
+    profiler session live (``/debug/profile``) it lands on the thread's
+    host line on the clock of the device planes; with none it is one
+    flag test.  Device-owning processes only; returns whether the
+    annotation is active."""
+    global _ANNOTATION
     try:
-        yield
-    finally:
-        ms = (time.perf_counter() - t0) * 1000.0
-        registry.record(name, ms)
-        log.debug("time[%s] = %.3f ms", name, ms)
+        from jax.profiler import TraceAnnotation
+    except Exception:       # pragma: no cover - jax-free frontends
+        return False
+    _ANNOTATION = TraceAnnotation
+    return True
+
+
+class Span:
+    """What ``stopwatch`` yields: ``ms`` is the span's duration once it
+    has closed (0 before)."""
+    __slots__ = ("ms",)
+
+    def __init__(self):
+        self.ms = 0.0
+
+
+@contextmanager
+def stopwatch(name: str, registry: StopWatchRegistry = REGISTRY, **meta):
+    """Time a stage under a reference span name, e.g.
+    ``Renderer.renderAsPackedInt`` or ``ProjectionService.projectStack``.
+    ``meta`` (numbers and short strings: ``group_id``, ``tiles``) goes
+    with the profiler annotation only."""
+    span = Span()
+    with (_ANNOTATION(name, **meta) if _ANNOTATION is not None
+          else nullcontext()):
+        t0 = time.perf_counter()
+        try:
+            yield span
+        finally:
+            span.ms = ms = (time.perf_counter() - t0) * 1000.0
+            registry.record(name, ms)
+            log.debug("time[%s] = %.3f ms", name, ms)

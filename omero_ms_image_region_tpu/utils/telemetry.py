@@ -1153,9 +1153,77 @@ class ProfileInProgressError(Exception):
 _PROFILE_LOCK = threading.Lock()
 
 
+class ProfileStats:
+    """What the captures taken so far add up to (``/metrics``
+    ``imageregion_profile_*``): device milliseconds by named stage,
+    busy and traced milliseconds, idle milliseconds by what the host
+    was doing, renders counted.  They move only when a capture is
+    taken; one with no device plane (the CPU backend) moves
+    ``captures`` alone.  Rules: ``utils.profile_summary``."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.reset()
+
+    def reset(self) -> None:
+        with self._lock:
+            self.captures = 0
+            self.busy_ms = self.traced_ms = 0.0
+            self.renders = 0
+            self.device_ms: Dict[str, float] = {}
+            self.idle_ms: Dict[str, float] = {}
+
+    def observe(self, summary: Optional[dict]) -> None:
+        with self._lock:
+            self.captures += 1
+            if not summary:
+                return
+            self.busy_ms += summary["busy_ms"]
+            self.traced_ms += summary["traced_ms"]
+            self.renders += summary["renders"]
+            for mine, theirs in ((self.device_ms, summary["device_ms"]),
+                                 (self.idle_ms, summary["idle_ms"])):
+                for key, ms in theirs.items():
+                    mine[key] = mine.get(key, 0.0) + ms
+
+    def metric_lines(self, extra_labels: str = "") -> List[str]:
+        extra = extra_labels.lstrip(",")
+        plain = f"{{{extra}}}" if extra else ""
+        with self._lock:
+            lines = [
+                f"imageregion_profile_captures_total{plain} "
+                f"{self.captures}",
+                f"imageregion_profile_busy_ms_total{plain} "
+                f"{round(self.busy_ms, 3)}",
+                f"imageregion_profile_traced_ms_total{plain} "
+                f"{round(self.traced_ms, 3)}",
+                f"imageregion_profile_renders_total{plain} "
+                f"{self.renders}",
+            ]
+            for family, label, values in (
+                    ("device_ms", "stage", self.device_ms),
+                    ("idle_ms", "during", self.idle_ms)):
+                for key, ms in sorted(values.items()):
+                    body = f'{label}="{key}"' + (f",{extra}" if extra
+                                                 else "")
+                    lines.append(f"imageregion_profile_{family}_total"
+                                 f"{{{body}}} {round(ms, 3)}")
+        return lines
+
+
+PROFILE = ProfileStats()
+
+
 def capture_profile(directory: str, ms: float) -> dict:
     """Wrap ``jax.profiler`` around whatever the device is doing for
-    ``ms`` milliseconds; returns the artifact manifest.
+    ``ms`` milliseconds; returns the artifact manifest with the
+    capture's ``summary`` (``utils.profile_summary``), which also
+    accumulates on ``PROFILE``.
+
+    The session runs without the Python tracer (its events, by the
+    hundred thousand, were most of a capture's bytes and of its
+    stop time, and nothing read them) and with the host tracer at the
+    level that keeps the program's own annotations.
 
     Single-flight (`ProfileInProgressError` when one is live —
     concurrent captures would interleave one trace file), blocking
@@ -1167,13 +1235,17 @@ def capture_profile(directory: str, ms: float) -> dict:
                                      "running")
     try:
         import jax
+        from . import profile_summary
         seq = next(_ARTIFACT_SEQ)
         path = os.path.join(
             directory,
             time.strftime(f"profile-%Y%m%d-%H%M%S-{seq:04d}"))
         os.makedirs(path, exist_ok=True)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 2
         t0 = time.perf_counter()
-        jax.profiler.start_trace(path)
+        jax.profiler.start_trace(path, profiler_options=options)
         try:
             time.sleep(max(0.0, ms) / 1000.0)
         finally:
@@ -1188,12 +1260,28 @@ def capture_profile(directory: str, ms: float) -> dict:
                     total += os.path.getsize(full)
                 except OSError:
                     pass
+        doc = {"dir": path, "requested_ms": ms, "files": sorted(files),
+               "bytes": total}
+        summary = None
+        t_summary = time.perf_counter()
+        try:
+            xplane = profile_summary.find_xplane(path)
+            if xplane is not None:
+                summary = profile_summary.summarize(
+                    *profile_summary.read_capture(xplane))
+        except Exception as e:
+            # The artifact stands without its reduction.
+            log.warning("profile summary of %s failed", path,
+                        exc_info=True)
+            doc["summary_error"] = repr(e)
+        PROFILE.observe(summary)
+        doc["summary"] = summary
+        doc["summary_ms"] = round(
+            (time.perf_counter() - t_summary) * 1000.0, 1)
         FLIGHT.record("profile.captured", dir=path,
                       ms=round(ms, 1), files=len(files))
-        return {"dir": path, "ms": round(
-            (time.perf_counter() - t0) * 1000.0, 1),
-            "requested_ms": ms, "files": sorted(files),
-            "bytes": total}
+        doc["ms"] = round((time.perf_counter() - t0) * 1000.0, 1)
+        return doc
     finally:
         _PROFILE_LOCK.release()
 
@@ -3406,7 +3494,6 @@ def dump_slow_trace(trace: Trace, total_ms: float, status: int,
 # derives each line's family and emits the # TYPE header once.
 METRIC_TYPES: Dict[str, str] = {
     "imageregion_span_count": "counter",
-    "imageregion_span_mean_ms": "gauge",
     "imageregion_span_ms": "histogram",
     "imageregion_request_duration_ms": "histogram",
     "imageregion_requests_total": "counter",
@@ -3430,6 +3517,12 @@ METRIC_TYPES: Dict[str, str] = {
     "imageregion_batcher_queue_wait_max_ms": "gauge",
     "imageregion_compile_events_total": "counter",
     "imageregion_compile_ms_total": "counter",
+    "imageregion_profile_captures_total": "counter",
+    "imageregion_profile_busy_ms_total": "counter",
+    "imageregion_profile_traced_ms_total": "counter",
+    "imageregion_profile_renders_total": "counter",
+    "imageregion_profile_device_ms_total": "counter",
+    "imageregion_profile_idle_ms_total": "counter",
     "imageregion_compile_cache_hits_total": "counter",
     "imageregion_device_peak_bytes": "gauge",
     "imageregion_link_mb_s": "gauge",
@@ -3669,6 +3762,23 @@ METRIC_HELP: Dict[str, str] = {
         "Quorum fence/restore transitions by verdict",
     "imageregion_federation_quorum_refusals_total":
         "State-changing actions refused while fenced, by action",
+    "imageregion_profile_captures_total":
+        "/debug/profile captures taken by this process",
+    "imageregion_profile_device_ms_total":
+        "Device milliseconds inside the captures, by named stage of "
+        "the device programs (innermost named scope; unnamed = none)",
+    "imageregion_profile_busy_ms_total":
+        "Milliseconds inside the captures in which an operation ran "
+        "on the chip",
+    "imageregion_profile_traced_ms_total":
+        "Milliseconds inside the captures from the first operation's "
+        "start to the last one's end",
+    "imageregion_profile_idle_ms_total":
+        "Idle device milliseconds inside the captures, by the host "
+        "span they overlap (fixed precedence; utils.profile_summary)",
+    "imageregion_profile_renders_total":
+        "Tiles of the groups whose wire.d2h began (their device.wait "
+        "ended) inside a capture's traced interval",
     "imageregion_partition_rules":
         "Injected link-partition rules active in this process",
     "imageregion_partition_blocked_total":
@@ -4091,6 +4201,8 @@ def device_metric_lines(services, extra_labels: str = "") -> List[str]:
     # Per-ladder-shape estimated vs observed device cost (the batcher
     # records both; cardinality is bounded by the bucket/batch ladder).
     lines += SHAPE_COSTS.metric_lines(extra_labels)
+    # What the /debug/profile captures taken so far add up to.
+    lines += PROFILE.metric_lines(extra_labels)
     # Warm-state persistence tier (disk byte cache, snapshot engine,
     # boot rehydrator) — device-side state, merged like the rest.
     lines += PERSIST.metric_lines(extra_labels)
@@ -4147,6 +4259,7 @@ def reset() -> None:
     FLIGHT.reset()
     SLO.reset()
     SHAPE_COSTS.reset()
+    PROFILE.reset()
     PERSIST.reset()
     WIRE.reset()
     FLEET.reset()

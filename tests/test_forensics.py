@@ -1083,6 +1083,20 @@ class TestDebugEndpoints:
                 assert prof["files"], prof
                 assert os.path.isdir(prof["dir"])
                 assert prof["bytes"] > 0
+                # The capture's reduction rides the same answer; the
+                # CPU backend has no device plane to reduce, so only
+                # the count of captures moves on /metrics.
+                assert prof["summary"] is None
+                assert "summary_error" not in prof
+                r = await client.get("/metrics")
+                text = await r.text()
+                assert "imageregion_profile_captures_total 1" in text
+                assert "imageregion_profile_busy_ms_total 0.0" in text
+                assert "imageregion_profile_device_ms_total{" \
+                    not in text
+                assert "imageregion_span_mean_ms" not in text
+                assert 'imageregion_span_count{span="batcher.laneWait"}' \
+                    in text
             finally:
                 await client.close()
 
@@ -1142,6 +1156,10 @@ class TestDebugEndpoints:
                 prof = await r.json()
                 assert r.status == 200, prof
                 assert prof["files"], prof
+                # The summary crosses the sidecar wire with the
+                # manifest (None: no device plane on the CPU backend).
+                assert "summary" in prof and prof["summary"] is None
+                assert "summary_error" not in prof
                 r = await client.get("/debug/flightrecorder")
                 flight = await r.json()
                 assert r.status == 200

@@ -507,6 +507,10 @@ _ALLOWED_LABEL_KEYS = frozenset({
     # verbatim, ``flag`` is utils.provenance.FLAGS — both closed by
     # construction (ProvenanceStats clamps drifted strings).
     "flag",
+    # The profile summary (PR 26): ``stage`` is
+    # utils.profile_summary.STAGES plus "unnamed", ``during`` its
+    # IDLE_ORDER plus "no_group" / "unattributed" -- both fixed there.
+    "stage", "during",
 })
 
 

@@ -183,12 +183,9 @@ class DeviceRawCache:
                     telemetry.add_cost("staged_bytes_skipped",
                                        loaded.nbytes)
             if arr is None:
-                # Host ndarray miss: packed staging ships ~1.4x fewer
-                # wire bytes for uint16 pixel content (io.staging.stage
-                # falls back to a plain transfer when packing doesn't
-                # pay).
-                from .staging import stage
-                arr = stage(loaded)
+                # Host ndarray miss: the plane goes up as it is, one
+                # asynchronous transfer in its storage dtype.
+                arr = jax.device_put(loaded)
                 from ..utils import telemetry
                 telemetry.add_cost("staged_bytes", loaded.nbytes)
         else:

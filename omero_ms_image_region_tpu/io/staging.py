@@ -1,22 +1,14 @@
-"""Packed host->device staging for raw uint16 pixel data.
+"""Host->device staging of raw pixel planes.
 
-The cold first-touch path moves 8 MB per raw 16-bit WSI tile from host
-to HBM.  Pixel
-content is smooth signal + sensor noise, so block bit-packed zigzag row
-deltas (``native/wirepack.cpp``) carry the same planes in ~1.4x fewer
-bytes — and, unlike general entropy coding, the fixed-width-per-block
-layout decodes VECTORIZED on the device: a gather + shift per sample
-and one row cumsum, no sequential bitstream walk (which a TPU cannot
-express).  This is the H2D mirror of the D2H JPEG wire: ship transforms
-of the pixels sized to the link, compute the inverse where the data
-lands.
-
-``stage(arr)`` is the drop-in for ``jax.device_put`` on storage-dtype
-raw planes: it packs when the packer is available and the content
-actually compresses, and falls back to a plain transfer otherwise
-(including non-uint16 dtypes).  Whether the saved bytes pay for the
-on-device decode on the current chip's host link is not measured
-(ROADMAP S4).
+A raw plane that missed the HBM raw cache goes up as it is: one
+asynchronous ``jax.device_put`` of the storage-dtype array, at the call
+site, and no program runs on the device for an upload.  The link is a
+local bus that carries a 24 MB plane in milliseconds while the chip
+runs the previous group; a transform sized to a slower link costs the
+chip more than it spares the bus (a bit-packed upload's device unpack
+measured 200 ms a plane, twice the JPEG program: PERF.md section 6).
+This module holds what the upload's callers share: the per-member
+device pin and the content-digest skip.
 
 Reference context: the reference's Bio-Formats path materializes raw
 planes host-side and hands byte[] buffers to the renderer in-process
@@ -27,100 +19,8 @@ device link, so this stage has no Java counterpart.
 from __future__ import annotations
 
 import contextlib
-import functools
-import math
 
-import jax
-import jax.numpy as jnp
 import numpy as np
-
-# Words arrays pad up to one of these lengths so the unpack kernel
-# compiles once per (shape, padded-length) instead of once per
-# data-dependent length (each distinct shape costs an XLA compile).
-# Ratio 2^(1/4) = <=19% padding.
-_LADDER_RATIO = 2.0 ** 0.25
-_LADDER_FLOOR = 4096          # words
-
-
-def _pad_words(n: int) -> int:
-    if n <= _LADDER_FLOOR:
-        return _LADDER_FLOOR
-    steps = math.ceil(math.log(n / _LADDER_FLOOR, _LADDER_RATIO))
-    return int(math.ceil(_LADDER_FLOOR * _LADDER_RATIO ** steps))
-
-
-@functools.partial(jax.jit, static_argnames=("shape",))
-@jax.named_scope("stage.unpack16")   # utils.profile_summary.STAGES
-def unpack16_device(words, widths, shape) -> jax.Array:
-    """Inverse of ``native.wirepack_pack16`` on device.
-
-    ``words`` u32[>=n_words] (zero-padded), ``widths``
-    u8[n_rows * ceil(W/32)], ``shape`` the original array shape.
-    Fully vectorized: per-sample gather + shifts, then a per-row
-    cumsum undoes the delta coding.
-    """
-    W = shape[-1]
-    n_rows = 1
-    for s in shape[:-1]:
-        n_rows *= s
-    bpr = (W + 31) // 32
-    w32 = widths.astype(jnp.int32)                      # [n_rows*bpr]
-    block_bits = w32 * 32
-    off = jnp.concatenate(
-        [jnp.zeros(1, jnp.int32), jnp.cumsum(block_bits)])[:-1]
-    col = jnp.broadcast_to(jnp.arange(W, dtype=jnp.int32), (n_rows, W))
-    b = (jnp.arange(n_rows, dtype=jnp.int32)[:, None] * bpr
-         + col // 32)                                   # [n_rows, W]
-    j = col % 32
-    w = w32[b]
-    pos = off[b] + j * w
-    wi = pos >> 5
-    sh = (pos & 31).astype(jnp.uint32)
-    words = words.astype(jnp.uint32)
-    lo = words[wi] >> sh
-    hi_shift = (jnp.uint32(32) - sh) & jnp.uint32(31)
-    hi = jnp.where(sh > 0,
-                   words[jnp.minimum(wi + 1, words.shape[0] - 1)]
-                   << hi_shift,
-                   jnp.uint32(0))
-    mask = (jnp.uint32(1) << w.astype(jnp.uint32)) - jnp.uint32(1)
-    z = ((lo | hi) & mask).astype(jnp.int32)
-    d = (z >> 1) ^ -(z & 1)                             # un-zigzag
-    x = jnp.cumsum(d, axis=1)                           # undo row delta
-    return x.astype(jnp.uint16).reshape(shape)
-
-
-def pack16_host(arr: np.ndarray):
-    """Host-side packing via the native packer; raises ImportError when
-    the toolchain is unavailable (callers fall back to raw staging)."""
-    from ..native import wirepack_pack16
-    return wirepack_pack16(arr)
-
-
-# Skip packing below this size: dispatch + decode overhead beats the
-# saved bytes on small transfers.
-_MIN_STAGE_BYTES = 1 << 20
-# Bit offsets are computed with int32 arithmetic on device (TPUs run
-# x32); past this many samples the packed bit count could exceed 2^31
-# and silently wrap, so bigger arrays take the plain transfer.
-_MAX_STAGE_SAMPLES = (1 << 31) // 18
-
-
-def _regular_shape(shape) -> bool:
-    """Shapes worth compiling an unpack executable for.
-
-    ``unpack16_device`` is shape-jitted and a novel shape costs a
-    seconds-scale compile — far more than the
-    packed bytes save once.  Serving traffic is dominated by bucketed
-    tiles and tile-snapped bands, so packing is restricted to that
-    lattice (rows % 64 == 0, width % 256 == 0); arbitrary client
-    region shapes fall back to the un-compiled plain transfer.
-    """
-    h, w = shape[-2], shape[-1]
-    lead = 1
-    for s in shape[:-2]:
-        lead *= s
-    return h % 64 == 0 and w % 256 == 0 and lead <= 64
 
 
 @contextlib.contextmanager
@@ -139,38 +39,6 @@ def pin_scope(device):
         yield
 
 
-def stage(arr: np.ndarray, min_ratio: float = 1.1):
-    """Packed ``device_put`` for uint16 raw planes.
-
-    Packs on host, ships words + widths, decodes on device; returns the
-    device uint16 array.  Falls back to a plain ``device_put`` when the
-    packer is unavailable, the dtype is not uint16, the array is small,
-    huge (int32 bit-offset budget), off the regular tile/band shape
-    lattice (compile economics), or the content does not compress by at
-    least ``min_ratio`` (noise floors exist: packed-but-incompressible
-    data would ship 17/16 of raw).
-    """
-    if (not isinstance(arr, np.ndarray) or arr.dtype != np.uint16
-            or arr.nbytes < _MIN_STAGE_BYTES or arr.ndim < 2
-            or arr.size > _MAX_STAGE_SAMPLES
-            or not _regular_shape(arr.shape)):
-        return jax.device_put(arr)
-    try:
-        words, widths = pack16_host(arr)
-    except ImportError:
-        return jax.device_put(arr)
-    # Judge the bytes that actually cross the link: the words buffer
-    # ships at its ladder-padded length (up to ~19% over), so a pack
-    # accepted at ~0.91x raw could ship ~1.08x raw after padding.
-    packed_bytes = _pad_words(len(words)) * 4 + widths.nbytes
-    if packed_bytes * min_ratio > arr.nbytes:
-        return jax.device_put(arr)
-    padded = np.zeros(_pad_words(len(words)), np.uint32)
-    padded[:len(words)] = words
-    return unpack16_device(jax.device_put(padded),
-                           jax.device_put(widths), arr.shape)
-
-
 def stage_deduped(arr: np.ndarray, cache, digest: str = None):
     """Digest-first staging: skip the upload when the content is already
     device-resident.
@@ -180,8 +48,8 @@ def stage_deduped(arr: np.ndarray, cache, digest: str = None):
     ``was_resident`` True means zero bytes crossed the host->device link
     (the plane was found under some key — a prior wire push, or the same
     content staged for another region identity).  On a miss the plane
-    stages through :func:`stage` (packed when it pays) and is recorded
-    under its content key, so the NEXT identical push — from any
+    is uploaded (``DeviceRawCache.get_or_load``) and recorded under
+    its content key, so the NEXT identical push — from any
     frontend, for any region identity — skips the wire.
 
     This is the server half of the sidecar's digest-first plane
@@ -200,9 +68,3 @@ def stage_deduped(arr: np.ndarray, cache, digest: str = None):
     staged = cache.get_or_load(plane_key(digest), lambda: arr,
                                digest=digest)
     return staged, digest, False
-
-
-def stage_ratio(arr: np.ndarray) -> float:
-    """Diagnostic: packed/raw byte ratio for ``arr`` (1.0 = raw)."""
-    words, widths = pack16_host(arr)
-    return (words.nbytes + widths.nbytes) / arr.nbytes

@@ -29,8 +29,6 @@ _JPEGDEC_SOURCE = os.path.join(_HERE, "jpegdec.cpp")
 _JPEGDEC_LIB_PATH = os.path.join(_BUILD_DIR, "libjpegdec.so")
 _JP2KT1_SOURCE = os.path.join(_HERE, "jp2kt1.cpp")
 _JP2KT1_LIB_PATH = os.path.join(_BUILD_DIR, "libjp2kt1.so")
-_WIREPACK_SOURCE = os.path.join(_HERE, "wirepack.cpp")
-_WIREPACK_LIB_PATH = os.path.join(_BUILD_DIR, "libwirepack.so")
 _BUILD_LOCK = threading.Lock()
 
 
@@ -181,48 +179,6 @@ _JPEGDEC = _NativeLib(_JPEGDEC_SOURCE, _JPEGDEC_LIB_PATH,
                       "native jpeg decoder", _configure_jpegdec)
 _JP2KT1 = _NativeLib(_JP2KT1_SOURCE, _JP2KT1_LIB_PATH,
                      "native jpeg2000 tier-1", _configure_jp2kt1)
-
-
-def _configure_wirepack(lib: ctypes.CDLL) -> None:
-    lib.wirepack_pack16.restype = ctypes.c_longlong
-    lib.wirepack_pack16.argtypes = [
-        ctypes.c_void_p, ctypes.c_longlong, ctypes.c_int,
-        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_longlong,
-    ]
-
-
-_WIREPACK = _NativeLib(_WIREPACK_SOURCE, _WIREPACK_LIB_PATH,
-                       "native wire packer", _configure_wirepack)
-
-
-def wirepack_available() -> bool:
-    try:
-        _WIREPACK.load()
-        return True
-    except ImportError:
-        return False
-
-
-def wirepack_pack16(arr) -> "tuple":
-    """Pack a C-contiguous uint16 array (rows = all leading dims) into
-    (words u32[n], widths u8[n_rows*ceil(W/32)]).  See wirepack.cpp for
-    the layout; the device inverse is io.staging.unpack16_device."""
-    import numpy as np
-    lib = _WIREPACK.load()
-    arr = np.ascontiguousarray(arr, dtype=np.uint16)
-    width = arr.shape[-1]
-    n_rows = arr.size // max(width, 1)
-    bpr = (width + 31) // 32
-    widths = np.empty(n_rows * bpr, np.uint8)
-    # Worst case: every block at 17 bits/sample x 32 slots (edge blocks
-    # occupy full 32-sample slots), i.e. 17 words per block.
-    cap = n_rows * bpr * 17 + 2
-    words = np.empty(cap, np.uint32)
-    n = lib.wirepack_pack16(arr.ctypes.data, n_rows, width,
-                            widths.ctypes.data, words.ctypes.data, cap)
-    if n < 0:
-        raise RuntimeError("wirepack capacity underestimate (bug)")
-    return words[:n].copy(), widths
 
 
 def _load() -> ctypes.CDLL:

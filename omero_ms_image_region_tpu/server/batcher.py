@@ -790,9 +790,9 @@ class BatchingRenderer:
         """Fetch/stage half of a group render: stack the batch and ship
         it to the device BEFORE a device lane is taken, so group N+1's
         wire upload overlaps group N's device execute instead of
-        running serially behind it.  Host stacks go through the packed
-        stager (uint16 content crosses the link ~1.4x smaller); batches
-        with device-resident members are already staged."""
+        running serially behind it.  Host stacks go up as they are
+        (one asynchronous transfer, storage dtype); batches with
+        device-resident members are already staged."""
         from ..utils import faultinject
         inj = faultinject.active()
         if inj is not None:
@@ -809,8 +809,8 @@ class BatchingRenderer:
             staged_bytes = (raw.nbytes
                             if isinstance(raw, np.ndarray) else 0)
             if isinstance(raw, np.ndarray):
-                from ..io.staging import stage
-                raw = stage(raw)
+                import jax
+                raw = jax.device_put(raw)
         # Cost ledger, pro-rata: the group's one stack+upload spread
         # over its members (runs under group_trace, so each member's
         # ledger receives its share).  Device-resident stacks staged

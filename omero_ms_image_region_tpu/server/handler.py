@@ -45,6 +45,26 @@ DEFAULT_MAX_TILE_LENGTH = 2048  # beanRefContext.xml:63-66
 _STAGE_BAND_ROWS = 256
 
 
+def _stage_band_bounds(height: int, y: int, tile_h: int) -> List[int]:
+    """Row bounds ``[0, ..., height]`` of a region's upload bands: up
+    to four, none under ``_STAGE_BAND_ROWS`` rows.  Interior bounds
+    snap to the source's tile-row grid (absolute rows: the region
+    starts at ``y``) so a boundary never splits a chunk row, which
+    both adjacent bands would otherwise read and decode; a bound that
+    snaps onto its predecessor, or within a band's height of either
+    end, is dropped.  ``[0, height]`` means one band: no banding."""
+    n_bands = min(4, height // _STAGE_BAND_ROWS)
+    bounds = [0]
+    for k in range(1, n_bands):
+        b = ((y + height * k // n_bands + tile_h // 2) // tile_h
+             * tile_h - y)
+        if (b - bounds[-1] >= _STAGE_BAND_ROWS
+                and height - b >= _STAGE_BAND_ROWS):
+            bounds.append(b)
+    bounds.append(height)
+    return bounds
+
+
 from .errors import (NotFoundError,  # noqa: E402,F401  (re-export;
                      OverloadedError)
 # The exceptions live in the device-free errors module so frontend
@@ -667,32 +687,17 @@ class ImageRegionHandler:
 
         def load_staged():
             """Cold staging pipeline: band the region's rows and ship
-            each band as its own async packed upload (``io.staging``),
-            so band k+1's disk read + pack overlaps band k's host->HBM
-            transfer (JAX dispatch returns before the copy lands) and
-            uint16 content crosses the link ~1.4x smaller.  Small
-            regions take the single-shot path — banding only pays when
-            the read itself has substance."""
-            import jax.numpy as jnp
-
-            from ..io.staging import stage
-            n_bands = min(4, region.height // _STAGE_BAND_ROWS)
-            if n_bands < 2:
+            each band as its own async ``device_put``, so band k+1's
+            disk read overlaps band k's host->HBM transfer (the
+            dispatch returns before the copy lands).  A region that
+            yields one band takes the single-shot path — banding only
+            pays when the read itself has substance."""
+            bounds = _stage_band_bounds(region.height, region.y,
+                                        max(1, src.tile_size()[1]))
+            if len(bounds) == 2:
                 return load()
-            # Interior bounds snap to the source's tile-row grid so a
-            # boundary never splits a chunk row (which both adjacent
-            # bands would otherwise read and decode).
-            tile_h = max(1, src.tile_size()[1])
-            bounds = [0]
-            for k in range(1, n_bands):
-                b = region.height * k // n_bands
-                # Snap the absolute row to the nearest tile boundary.
-                snapped = ((region.y + b + tile_h // 2) // tile_h
-                           * tile_h - region.y)
-                b = min(max(snapped, bounds[-1] + 1), region.height - 1)
-                if b > bounds[-1]:
-                    bounds.append(b)
-            bounds.append(region.height)
+            import jax
+            import jax.numpy as jnp
             parts = []
             for y0, y1 in zip(bounds, bounds[1:]):
                 sub = RegionDef(region.x, region.y + y0,
@@ -701,7 +706,7 @@ class ImageRegionHandler:
                     src.get_region(ctx.z, c, ctx.t, sub, level)
                     for c in active
                 ])
-                parts.append(stage(band))
+                parts.append(jax.device_put(band))
             return jnp.concatenate(parts, axis=1)
 
         if self.s.raw_cache is None or not device_cache:

@@ -81,43 +81,6 @@ def parse_spec(spec: str) -> Tuple[int, int, int, "np.dtype"]:
     return channels, edge, q, np.dtype(dt)
 
 
-def _warm_stage_shapes(B: int, C: int, bh: int, bw: int,
-                       raw_dtype) -> None:
-    """Warm the FETCH-STAGE half of the two-stage group dispatch.
-
-    The pipelined batcher ships each group's stacked raw through the
-    packed stager (``io.staging.stage``) before taking a device lane;
-    the on-device unpack is shape-jitted per (array shape, ladder word
-    length), so the first pipelined group of a shape would otherwise
-    eat a seconds-scale XLA compile mid-serving.  Content word counts
-    are data-dependent but ladder-quantized, so compiling the ladder
-    lengths bracketing typical pixel entropy (~0.3-0.8x raw bytes)
-    covers serving traffic; off-lattice or sub-threshold shapes take
-    the uncompiled plain transfer and need no warming.
-    """
-    from ..io import staging
-
-    shape = (B, C, bh, bw)
-    nbytes = int(np.prod(shape)) * np.dtype(raw_dtype).itemsize
-    if (np.dtype(raw_dtype) != np.uint16
-            or nbytes < staging._MIN_STAGE_BYTES
-            or int(np.prod(shape)) > staging._MAX_STAGE_SAMPLES
-            or not staging._regular_shape(shape)):
-        return
-    import jax
-    import jax.numpy as jnp
-
-    n_rows = B * C * bh
-    widths = jax.device_put(
-        np.zeros(n_rows * ((bw + 31) // 32), np.uint8))
-    raw_words = nbytes // 4
-    lengths = sorted({staging._pad_words(int(raw_words * f))
-                      for f in (0.35, 0.55, 0.8)})
-    for n_words in lengths:
-        np.asarray(staging.unpack16_device(
-            jnp.zeros(n_words, jnp.uint32), widths, shape))
-
-
 def _warm_one(C: int, edge: int, quality: int, batch_sizes: Sequence[int],
               engines: Sequence[str], buckets, raw_dtype,
               exec_cache=None) -> None:
@@ -162,9 +125,6 @@ def _warm_one(C: int, edge: int, quality: int, batch_sizes: Sequence[int],
                            else render_tile_batch_packed(*args))
             else:
                 np.asarray(render_tile_batch_packed(*args))
-        # The pipelined dispatch's fetch-stage half (packed-staging
-        # unpack programs for this stacked group shape).
-        _warm_stage_shapes(B, C, bh, bw, raw_dtype)
 
 
 def prewarm_batch_sizes(max_batch: int) -> tuple:

@@ -57,10 +57,10 @@ to the v2 behavior against an older peer:
 
 * **Scatter-gather frame coalescing** — every connection's outbound
   frames queue in a :class:`FrameWriter` and flush as ONE vectored
-  ``writer.writelines`` + ONE ``drain()`` (the ``native/wirepack.cpp``
-  gather-then-write idiom), so N multiplexed frames cost one syscall
-  and one round-trip instead of N.  Sender-local: the byte
-  stream is identical, so no negotiation and no version gate.
+  ``writer.writelines`` + ONE ``drain()`` (gather, then write), so
+  N multiplexed frames cost one syscall and one round-trip instead
+  of N.  Sender-local: the byte stream is identical, so no
+  negotiation and no version gate.
 * **Progressive chunk streaming** — a request carrying ``stream: 1``
   may be answered as ordered chunk frames ``{id, seq}`` + body
   followed by a final ``{id, status, fin: true}`` frame (which still
@@ -203,9 +203,8 @@ class FrameWriter:
     Frames enqueue here and ONE flusher task hands the whole backlog to
     ``writer.writelines`` as a list of buffers with a single ``drain()``
     per flush — N small frames cost one syscall and one round-trip
-    instead of N (``native/wirepack.cpp``'s gather-then-write idiom,
-    lifted to the socket).  This also retires the old ``respond()``
-    hazard: no lock is held across ``drain()`` anymore, so a
+    instead of N (gather, then write).  This also retires the old
+    ``respond()`` hazard: no lock is held across ``drain()`` anymore, so a
     slow-reading peer backpressures only the flusher — concurrent
     responders keep enqueueing and their frames coalesce into the next
     flush instead of serializing behind the stalled drain.

@@ -109,19 +109,6 @@ def test_jpeg_wire_program_compiles(shape, engine):
     _compiled(lowered)
 
 
-def test_packed_staging_unpack_compiles(shape):
-    """The on-device inverse of the packed host->HBM stager, at the
-    cold path's band shape and the tile shape."""
-    from omero_ms_image_region_tpu.io import staging
-    for dims in ((C, 256, W), (1, C, H, W)):
-        samples = int(np.prod(dims))
-        rows = samples // dims[-1]
-        _compiled(staging.unpack16_device.lower(
-            shape((staging._pad_words(samples // 4),), "uint32"),
-            shape((rows * ((dims[-1] + 31) // 32),), "uint8"),
-            shape=dims))
-
-
 def test_mask_pyramid_projection_programs_compile(shape):
     from omero_ms_image_region_tpu.ops import maskops, projection, pyramid
     _compiled(maskops._rasterize_batch_jit.lower(

@@ -40,7 +40,7 @@ def test_interval_arithmetic():
      "wire.sparse_pack.scatter"),
     ("jit(f)/wire.sparse_pack/cumsum", "wire.sparse_pack"),
     ("jit(f)/jit(g)/render/jpeg.dct_quant/dot_general", "jpeg.dct_quant"),
-    ("jit(unpack16_device)/stage.unpack16/gather", "stage.unpack16"),
+    ("jit(f)/wire.compact_rows/gather", "wire.compact_rows"),
     ("jit(f)/add", "unnamed"),
     ("jit(f)/renderer/add", "unnamed"),       # a whole component only
     ("", "unnamed"),
@@ -159,7 +159,7 @@ def test_renders_are_the_tiles_of_the_copies_that_began_inside():
 def test_two_device_planes_are_summed_and_kept_apart():
     device, host = hand_made_capture()
     device += [_dev(TPU1, "jit(p)/render/mul", 100, 50),
-               _dev(TPU1, "jit(p)/stage.unpack16/gather", 160, 40)]
+               _dev(TPU1, "jit(p)/wire.compact_rows/gather", 160, 40)]
     s = ps.summarize(device, host)
     one = ps.summarize(*hand_made_capture())
     assert sorted(s["planes"]) == [TPU0, TPU1]
@@ -168,7 +168,7 @@ def test_two_device_planes_are_summed_and_kept_apart():
     assert other["busy_ms"] == pytest.approx(90)
     assert other["traced_ms"] == pytest.approx(100)
     assert other["device_ms"] == pytest.approx(
-        {"render": 50, "stage.unpack16": 40})
+        {"render": 50, "wire.compact_rows": 40})
     assert sum(other["idle_ms"].values()) == pytest.approx(10)
     assert s["busy_ms"] == pytest.approx(80 + 90)
     assert s["traced_ms"] == pytest.approx(220 + 100)
@@ -326,7 +326,6 @@ def test_every_scope_is_in_the_compiled_text_of_the_served_programs():
     """The compiled text carries ``op_name``; the lowered text carries
     none, which is why JAX's cache key can leave the names out
     (``utils.jaxenv.place_compilation_cache`` puts them in)."""
-    from omero_ms_image_region_tpu.io import staging
     from omero_ms_image_region_tpu.ops import jpegenc
     args = _render_args()
     front = {"render", "jpeg.ycbcr420", "jpeg.dct_quant"}
@@ -342,15 +341,10 @@ def test_every_scope_is_in_the_compiled_text_of_the_served_programs():
         cap_words=64).compile().as_text()
     assert _scopes_in(text) == front | {"wire.huffman_pack",
                                         "wire.compact_rows"}
-    words, widths = staging.pack16_host(
-        np.arange(3 * 16 * 16, dtype=np.uint16).reshape(3, 16, 16))[:2]
-    text = staging.unpack16_device.lower(
-        words, widths, shape=(3, 16, 16)).compile().as_text()
-    assert _scopes_in(text) == {"stage.unpack16"}
     assert set(ps.STAGES) == front | {
         "wire.sparse_pack", "wire.sparse_pack.scatter",
         "wire.sparse_pack.bits", "wire.compact_rows",
-        "wire.huffman_pack", "stage.unpack16"}
+        "wire.huffman_pack"}
 
 
 def test_the_cache_key_takes_the_names_in(monkeypatch):
@@ -514,7 +508,7 @@ print("ok")
     assert proc.stdout.strip() == "ok"
 
 
-def test_the_names_live_in_three_files_and_the_annotation_in_one():
+def test_the_names_live_in_two_files_and_the_annotation_in_one():
     """``named_scope`` only where the device programs are written, the
     profiler's annotation only behind ``utils/stopwatch``'s hook."""
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -529,7 +523,6 @@ def test_the_names_live_in_three_files_and_the_annotation_in_one():
                 found[word].add(os.path.relpath(path, package))
     assert found["named_scope"] == {
         os.path.join("ops", "render.py"),
-        os.path.join("ops", "jpegenc.py"),
-        os.path.join("io", "staging.py")}
+        os.path.join("ops", "jpegenc.py")}
     assert found["TraceAnnotation"] == {
         os.path.join("utils", "stopwatch.py")}

@@ -4046,28 +4046,22 @@ async def _chaos_run(sidecar_cfg, frontend_cfg, sock, chaos,
 # -------------------------------------------------------------- config 1
 
 def bench_config1(rng):
-    """1-ch uint8 256^2 linear tile: single-tile renders/sec.
+    """1-ch uint8 256^2 linear tile on the host reference kernel:
+    single-tile renders/sec, the CPU comparator of BASELINE's first
+    row.
 
-    Measures the path a DEFAULT deployment actually serves: 256^2 is at
-    the tiny-render threshold (``RendererConfig.cpu_fallback_max_px``),
-    so requests take the host reference kernel — the measured winner at
-    this size on any deployment (device dispatch+fetch overhead exceeds
-    the ~2 ms of host math).  The CPU comparator is the same kernel, so
-    the served number equals the reference within noise by construction.
+    There is no served number beside it any more: since PR 28 a
+    DEFAULT deployment renders a full 256^2 tile on the device, in
+    groups of up to 64 (``batcher.group_cap``), and what that gives end
+    to end is the benchmark cell ``stock4-u16-t256.pan`` (PERF.md).
+    The host kernel still serves what is smaller than a stock tile.
     """
     from omero_ms_image_region_tpu.refimpl import render_ref
-    from omero_ms_image_region_tpu.server.config import RendererConfig
 
     rdef, s = _settings_for(1, ptype="uint8", window=(0.0, 255.0),
                             model="greyscale")
     raw = rng.integers(0, 255, size=(1, 256, 256)).astype(np.float32)
-
-    assert 256 * 256 <= RendererConfig().cpu_fallback_max_px, \
-        "default config no longer serves 256^2 via the CPU fallback"
-    # Served path and comparator are the same kernel by construction;
-    # one timing feeds both keys.
-    t_served = _timed(lambda: render_ref(raw, rdef), repeats=10)
-    return 1.0 / t_served, 1.0 / t_served
+    return 1.0 / _timed(lambda: render_ref(raw, rdef), repeats=10)
 
 
 # -------------------------------------------------------------- config 2
@@ -4456,7 +4450,7 @@ def main():
         service_p50_ms = None
         service_fetch_mb_s = None
         service_hot_path = {}
-    c1_tpu, c1_cpu = retry_transient(
+    c1_cpu = retry_transient(
         lambda: bench_config1(rng), "bench_config1", backoff_s=15.0)
     c2_planes, c2_cpu = retry_transient(
         lambda: bench_config2(rng), "bench_config2", backoff_s=15.0)
@@ -4554,7 +4548,6 @@ def main():
         "service_window_fetch_mb_per_sec": _opt_round(
             service_fetch_mb_s, 1),
         "batch": 8,
-        "config1_tile256_u8_per_sec": round(c1_tpu, 2),
         "config1_cpu_ref_per_sec": round(c1_cpu, 2),
         "config2_fullplane_2048_3ch_per_sec": round(c2_planes, 2),
         "config2_cpu_ref_per_sec": round(c2_cpu, 2),

@@ -356,7 +356,7 @@ class MeshRenderer(BatchingRenderer):
             exec_ms = (time.perf_counter() - t0) * 1000.0
         telemetry.add_cost("device_ms", exec_ms / n)
         telemetry.SHAPE_COSTS.observe(shape, exec_ms)
-        self._count_batch(n)
+        self._count_batch(n, raw.shape[0])
         return [host[i, :p.h, :p.w] for i, p in enumerate(group[:n])]
 
     def _render_wire(self, raw, stacked) -> np.ndarray:
@@ -513,7 +513,7 @@ class MeshRenderer(BatchingRenderer):
                 lambda i: self._dense_coefficients(raw, stacked, qy,
                                                    qc, i),
                 on_tile=self._early_settle_cb(group))
-        self._count_batch(n)
+        self._count_batch(n, raw.shape[0])
         return jpegs
 
     async def close(self) -> None:

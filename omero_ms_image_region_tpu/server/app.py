@@ -1144,6 +1144,7 @@ def create_app(config: Optional[AppConfig] = None,
             # never cache a failure under a render identity.
             return web.Response(status=400, text=str(e))
         request["prov_ctx"] = ctx
+        ctx.t_accept = t_req      # span handler.prepare starts here
         headers = {
             "Content-Type": codecs.CONTENT_TYPES.get(
                 ctx.format, "application/octet-stream"),

@@ -13,7 +13,9 @@ XLA compile — so two quantizations bound the executable set:
   * spatial buckets: a tile pads up (zeros) to the smallest configured
     bucket that fits, and the result is cropped back;
   * batch sizes: the collected group pads up (repeating the last tile) to
-    the next power of two <= max_batch.
+    the next entry of ``_BATCH_SHAPES`` <= the bucket's cap
+    (``group_cap``: ``max_batch`` renders of 1024^2, more of a smaller
+    bucket).
 
 Requests with differing per-channel settings still share a batch: window,
 family, reverse and the folded color tables are per-tile *data*, not
@@ -59,11 +61,31 @@ def pick_bucket(h: int, w: int,
 _BATCH_SHAPES = (1, 2, 3, 4, 6, 8, 16, 32, 64)
 
 
-def _pad_batch_size(n: int, max_batch: int) -> int:
+# ``max_batch`` counts renders of a bucket this large (or larger).
+_CAP_BUCKET_PX = 1024 * 1024
+
+
+def group_cap(max_batch: int, bucket_px: int) -> int:
+    """The most renders one group of a ``bucket_px`` bucket takes
+    (``_Pending.bucket_px``, set where a request's bucket is picked; 0
+    = not bucketed).  ``max_batch`` keeps its meaning at buckets of
+    1024^2 and above; a smaller bucket's cap is ``max_batch x (1024^2 /
+    its pixels)``, held to the shape ladder's top: the device's cost of
+    a group follows its pixels (on a v5e a 256^2 tile costs the chip
+    ~1.4 ms and a 1024^2 tile ~19, PERF.md PR 28), so counting renders
+    alone left a 256^2 group a sixteenth of a 1024^2 one.  Never below
+    ``max_batch``."""
+    if bucket_px <= 0 or bucket_px >= _CAP_BUCKET_PX:
+        return max_batch
+    return max(max_batch, min(_BATCH_SHAPES[-1],
+                              max_batch * (_CAP_BUCKET_PX // bucket_px)))
+
+
+def _pad_batch_size(n: int, cap: int) -> int:
     for size in _BATCH_SHAPES:
         if size >= n:
-            return min(size, max_batch)
-    return max_batch
+            return min(size, cap)
+    return cap
 
 
 def _key_label(key: tuple) -> str:
@@ -123,6 +145,10 @@ class _Pending:
     h: int
     w: int
     quality: int = 0              # JPEG groups only
+    # Pixels of the spatial bucket ``raw`` was padded to: what the
+    # group's cap follows (``group_cap``).  0 = not bucketed (a mask,
+    # shape-keyed): the cap stays ``max_batch``.
+    bucket_px: int = 0
     future: asyncio.Future = None  # type: ignore[assignment]
     t_enqueue: float = 0.0        # queue-wait waterfall span
     trace_id: str = None          # type: ignore[assignment]  # requester
@@ -231,6 +257,12 @@ class BatchingRenderer:
         self._stats_lock = threading.Lock()
         self.batches_dispatched = 0
         self.tiles_rendered = 0
+        # Slots of the padded shapes launched, and how many of them
+        # held a repeat of the group's last tile instead of a render
+        # (/metrics imageregion_batcher_{shape,padded}_slots_total):
+        # what the shape ladder wastes of the device.
+        self.shape_slots = 0
+        self.padded_slots = 0
         # Two-stage group pipeline: each group render splits into a
         # fetch/stage half (stacking + host->device upload, run by any
         # of the pipeline_depth worker threads) and a device-execute
@@ -284,12 +316,20 @@ class BatchingRenderer:
         # returned list's entries); settlement is loop-threadsafe.
         self.first_tile_out = True
 
-    def _count_batch(self, tiles: int) -> None:
-        """Metrics update; group renders run concurrently on worker
-        threads, so the increments need the lock."""
+    def _count_batch(self, tiles: int, shape: int) -> None:
+        """Metrics update (``shape``: the padded batch shape launched);
+        group renders run concurrently on worker threads, so the
+        increments need the lock."""
         with self._stats_lock:
             self.batches_dispatched += 1
             self.tiles_rendered += tiles
+            self.shape_slots += shape
+            self.padded_slots += shape - tiles
+
+    def group_cap(self, bucket_px: int) -> int:
+        """This renderer's cap for a bucket, at its current (possibly
+        grown) ``max_batch``."""
+        return group_cap(self.max_batch, bucket_px)
 
     @contextlib.contextmanager
     def _lane(self):
@@ -449,6 +489,7 @@ class BatchingRenderer:
 
         from ..utils.transient import deadline as _deadline
         pending = _Pending(raw=raw, settings=settings, h=h, w=w,
+                           bucket_px=bh * bw,
                            future=asyncio.get_running_loop().create_future(),
                            trace_id=telemetry.current_trace_id(),
                            deadline=_deadline())
@@ -477,7 +518,7 @@ class BatchingRenderer:
                str(raw.dtype))
         from ..utils.transient import deadline as _deadline
         pending = _Pending(raw=raw, settings=settings, h=height, w=width,
-                           quality=quality,
+                           quality=quality, bucket_px=bh * bw,
                            future=asyncio.get_running_loop().create_future(),
                            trace_id=telemetry.current_trace_id(),
                            deadline=_deadline())
@@ -515,7 +556,7 @@ class BatchingRenderer:
             queue = self._queues[key] = collections.deque()
             self._wakeups[key] = asyncio.Event()
             self._dispatchers[key] = asyncio.create_task(
-                self._dispatch_loop(key))
+                self._dispatch_loop(key, pending.bucket_px))
         queue.append(pending)
         self._wakeups[key].set()
         return await pending.future
@@ -548,7 +589,7 @@ class BatchingRenderer:
 
     # --------------------------------------------------------- dispatcher
 
-    async def _dispatch_loop(self, key: tuple) -> None:
+    async def _dispatch_loop(self, key: tuple, bucket_px: int) -> None:
         """Drain the key's queue into group renders.
 
         Up to ``pipeline_depth`` group renders run concurrently (each on
@@ -576,8 +617,8 @@ class BatchingRenderer:
             # renderer (no queue behind it, nothing in flight): lingering
             # there buys no coalescing and only taxes single-tile p50.
             lone_idle = len(queue) == 1 and not self._inflight
-            if (len(queue) < self.max_batch and self.linger_ms > 0
-                    and not lone_idle):
+            if (len(queue) < self.group_cap(bucket_px)
+                    and self.linger_ms > 0 and not lone_idle):
                 await asyncio.sleep(self.linger_ms / 1000.0)
             await slots.acquire()
             if self._lane_cap and len(self._inflight) >= self._lane_cap:
@@ -592,7 +633,8 @@ class BatchingRenderer:
             # task, so a close() cancellation (delivered only at the
             # loop's await points) can never orphan a popped group.
             group: List[_Pending] = []
-            take = self._pop_size(len(queue))
+            cap = self.group_cap(bucket_px)
+            take = self._pop_size(len(queue), cap)
             now_mono = time.monotonic()
             expired: List[_Pending] = []
             dead: List[_Pending] = []
@@ -636,13 +678,16 @@ class BatchingRenderer:
                 continue
             # Sustained backlog: full groups that still leave a queue
             # mean the batch is the bottleneck — grow it (bounded).
+            # Only where growing would enlarge THIS bucket's cap: a
+            # backlog of 256^2 groups already at the ladder's top must
+            # not double what a 1024^2 group takes.
             if self._growth_enabled:
-                if len(group) == self.max_batch and queue:
+                grown = min(self.max_batch * 2, self.max_batch_limit)
+                if (len(group) == cap and queue
+                        and group_cap(grown, bucket_px) > cap):
                     streak = self._full_streaks.get(key, 0) + 1
-                    if (streak >= self.GROW_STREAK
-                            and self.max_batch < self.max_batch_limit):
-                        self.max_batch = min(self.max_batch * 2,
-                                             self.max_batch_limit)
+                    if streak >= self.GROW_STREAK:
+                        self.max_batch = grown
                         streak = 0
                     self._full_streaks[key] = streak
                 else:
@@ -665,25 +710,26 @@ class BatchingRenderer:
             self._inflight.add(task)
             task.add_done_callback(self._inflight.discard)
 
-    def _pop_size(self, qlen: int) -> int:
-        """How many requests this group takes.
+    def _pop_size(self, qlen: int, cap: int) -> int:
+        """How many requests this group takes, of at most ``cap`` (the
+        bucket's: ``group_cap``).
 
         Splits a backlog across the remaining pipeline slots so
         ``target_inflight`` wire streams overlap (each fetch pays the
         link RTT up front; concurrent streams hide it), instead of two
-        max_batch convoys.  Multi-host meshes keep the plain
-        max_batch pop: group sizes there must not depend on host-local
+        ``cap``-sized convoys.  Multi-host meshes keep the plain
+        ``cap`` pop: group sizes there must not depend on host-local
         queue timing (same reason growth is disabled —
         ``parallel/serve.py`` lockstep).
         """
         if (not self._growth_enabled or self.target_inflight <= 1
-                or qlen <= self.max_batch):
+                or qlen <= cap):
             # Small backlogs coalesce into one dispatch — splitting
             # only pays when there is more than a full batch to spread
             # across streams.
-            return self.max_batch
+            return cap
         open_streams = max(1, self.target_inflight - len(self._inflight))
-        return max(1, min(self.max_batch, -(-qlen // open_streams)))
+        return max(1, min(cap, -(-qlen // open_streams)))
 
     async def _run_group(self, render, group: List[_Pending],
                          slots: asyncio.Semaphore,
@@ -730,7 +776,8 @@ class BatchingRenderer:
             with telemetry.group_trace(trace_ids), stopwatch(
                     "batcher.group", group_id=next(self._group_ids),
                     tiles=len(group),
-                    padded=_pad_batch_size(len(group), self.max_batch),
+                    padded=_pad_batch_size(
+                        len(group), self.group_cap(group[0].bucket_px)),
                     key=_key_label(key)):
                 return run_inner()
 
@@ -769,11 +816,13 @@ class BatchingRenderer:
             pass   # waiters already failed by settle()
 
     def _group_arrays(self, group: List[_Pending]):
-        """Pad the batch to a power of two (repeating the last tile;
-        extras are discarded) and build the stacked kernel inputs.  Raw
-        stacking stays on device when any member is already resident
-        there (the HBM raw tile cache)."""
-        B = _pad_batch_size(len(group), self.max_batch)
+        """Pad the batch to the next ladder shape within the bucket's
+        cap (repeating the last tile; extras are discarded) and build
+        the stacked kernel inputs.  Raw stacking stays on device when
+        any member is already resident there (the HBM raw tile
+        cache)."""
+        B = _pad_batch_size(len(group),
+                            self.group_cap(group[0].bucket_px))
         padded = group + [group[-1]] * (B - len(group))
         if all(isinstance(p.raw, np.ndarray) for p in padded):
             raw = np.stack([p.raw for p in padded])
@@ -834,7 +883,7 @@ class BatchingRenderer:
         member."""
         from ..ops.maskops import rasterize_packed_batch
         n = len(group)
-        B = _pad_batch_size(n, self.max_batch)
+        B = _pad_batch_size(n, self.group_cap(group[0].bucket_px))
         padded = group + [group[-1]] * (B - n)
         packed = np.stack([p.raw for p in padded])
         _, width, height, fh, fv = self._mask_key_of(group)
@@ -846,7 +895,7 @@ class BatchingRenderer:
                                                fh, fv)
             exec_ms = (time.perf_counter() - t0) * 1000.0
         telemetry.add_cost("device_ms", exec_ms / max(1, n))
-        self._count_batch(n)
+        self._count_batch(n, B)
         return [grids[i] for i in range(n)]
 
     def _mask_key_of(self, group: List[_Pending]) -> tuple:
@@ -910,7 +959,7 @@ class BatchingRenderer:
         if estimate:
             _capture_shape_estimate(shape, render_tile_batch_packed,
                                     args)
-        self._count_batch(n)
+        self._count_batch(n, raw.shape[0])
         return [host[i, :p.h, :p.w] for i, p in enumerate(group[:n])]
 
     def _current_engine(self) -> str:
@@ -977,5 +1026,5 @@ class BatchingRenderer:
         exec_ms = timings.get("device_ms", 0.0)
         telemetry.add_cost("device_ms", exec_ms / n)
         telemetry.SHAPE_COSTS.observe(shape, exec_ms)
-        self._count_batch(n)
+        self._count_batch(n, raw.shape[0])
         return jpegs

@@ -22,6 +22,11 @@ from ..utils.faultinject import FaultInjectionConfig
 @dataclass
 class BatcherConfig:
     enabled: bool = True
+    # Renders a group, counted at buckets of 1024x1024 and larger.  A
+    # smaller bucket's cap follows its pixels (batcher.group_cap):
+    # max_batch x (1024^2 / bucket pixels), held to 64 - so max_batch 1
+    # still groups 16 tiles of 256x256.  It is a bound on the device's
+    # work a group, not on its count.
     max_batch: int = 8
     # Queue-pressure growth bound; None = 2x max_batch.  Not measured
     # on the current chip.
@@ -69,12 +74,26 @@ class RendererConfig:
     """Render path selection knobs."""
 
     # Renders of at most this many pixels take the CPU reference kernel
-    # (refimpl) instead of a device round trip.  0 disables.  Default is
-    # a break-even estimate: at 256x256 single-channel the host kernel
-    # (~2 ms) is in the class of one device dispatch+fetch; beyond it
-    # batched device renders win.  Not measured on the current chip
-    # (ROADMAP S6 decides it from queue depth and pixels).
-    cpu_fallback_max_px: int = 256 * 256
+    # (refimpl) instead of a device round trip.  0 disables.  Default:
+    # everything smaller than a stock 256x256 tile (edge slivers,
+    # pyramid tops); a full stock tile is a device render.  Measured on
+    # a v5e with 13 host cores, 16 viewers x 6 connections panning a
+    # resident 4-channel uint16 slide in 256x256 JPEG tiles (benchmark
+    # cell stock4-u16-t256.pan; PERF.md, PR 28, six 51 s runs a side):
+    # the host route 110-113 tiles/s, p50 830-852 ms, the chip
+    # untouched (refimpl and the encoder share one GIL: the pool's
+    # threads together finish a tile every 9 ms); the device route
+    # 314-332 tiles/s, p50 285-296 ms, in groups the batcher caps by
+    # the bucket's pixels (batcher.group_cap: 64 of 256x256 where
+    # max-batch is 8), the chip busy 47 % and the host's per-request
+    # Python the limit.
+    # A lone viewer (one connection, a scratch run of the same
+    # deployment, two pairs): the host route 42-45 tiles/s, p50 21.8 ms
+    # (it reads the tile from the store for every request; refimpl and
+    # the encoder alone are 7 ms), the device route 110-112, p50 8.7 ms at
+    # B = 1 from the HBM raw cache.  ROADMAP S6 still wants the choice
+    # made from queue depth and pixels, on other image classes too.
+    cpu_fallback_max_px: int = 256 * 256 - 1
     # Device JPEG wire format: "sparse" (18-bit coefficient entries +
     # host entropy coding — wins on fast links), "huffman" (device
     # fixed-table Huffman stream, ~3x fewer wire bytes — wins on slow or

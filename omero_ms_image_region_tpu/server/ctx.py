@@ -73,6 +73,12 @@ class ImageRegionCtx:
     cache_key: str = ""
     omero_session_key: Optional[str] = None
 
+    # ``time.perf_counter()`` at the request's acceptance, stamped by
+    # the HTTP layer (span ``handler.prepare`` starts there).  Process-
+    # local, so deliberately NOT a dataclass field: it never rides the
+    # JSON round-trip or an equality check.
+    t_accept = None
+
     # ------------------------------------------------------------- parsing
 
     @classmethod

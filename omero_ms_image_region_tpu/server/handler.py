@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import asyncio
 import json
+import time
 from dataclasses import dataclass
 from typing import List, Optional, Sequence, Tuple
 
@@ -34,7 +35,8 @@ from ..services.cache import Caches
 from ..services.metadata import CanReadMemo, MetadataService
 from ..utils import telemetry
 from ..utils.color import split_html_color
-from ..utils.stopwatch import stopwatch
+from ..utils.stopwatch import REGISTRY, stopwatch
+from .config import RendererConfig
 from .ctx import BadRequestError, ImageRegionCtx, ShapeMaskCtx
 from .region import RegionDef, clamp_region_to_plane, get_region_def
 from .settings import render_identity_key, update_settings
@@ -236,11 +238,11 @@ class ImageRegionServices:
     warmstate: object = None
     # Renders at or below this pixel count take the CPU reference kernel
     # (refimpl) instead of a device round trip — the SURVEY north star's
-    # fallback path, and a latency win for tiny tiles anywhere the
-    # dispatch+fetch overhead exceeds host compute.  0 disables.  The
-    # served default comes from server.config.RendererConfig (256x256,
-    # the measured break-even).
-    cpu_fallback_max_px: int = 256 * 256
+    # fallback path, for what is smaller than a stock 256x256 tile
+    # (edge slivers, pyramid tops).  0 disables.  The default is
+    # server.config.RendererConfig's, which says what was measured: a
+    # full stock tile is a device render.
+    cpu_fallback_max_px: int = RendererConfig.cpu_fallback_max_px
     # What this process serves from, as build_services found it:
     # ``{platform, kind, count, ids}`` (utils.jaxenv.device_identity)
     # and ``{entropy_coder, tile_cache}`` (native.status).  Carried on
@@ -345,6 +347,8 @@ class ImageRegionHandler:
         from ..services.cache import get_with_tier
         from ..utils import provenance
         t0 = _time.perf_counter()
+        if ctx.t_accept is None:
+            ctx.t_accept = t0     # no HTTP layer stamped the request
         cached, cache_tier = ((None, None) if skip_byte_cache else
                               await get_with_tier(
                                   self.s.caches.image_region,
@@ -545,6 +549,10 @@ class ImageRegionHandler:
             self.s.cpu_fallback_max_px
             and region.width * region.height <= self.s.cpu_fallback_max_px
             and ctx.projection is None)
+        # The handler's choice, counted where it is made
+        # (/metrics imageregion_renders_routed_total{route=...}).
+        route = "host" if tiny else "device"
+        telemetry.ROUTES.count(route)
 
         if ctx.projection is not None:
             raw, region = await self._project(ctx, pixels, src, active)
@@ -584,10 +592,12 @@ class ImageRegionHandler:
                     session_key=ctx.omero_session_key)
 
         if tiny:
+            self._record_prepare(ctx, route)
             return await asyncio.to_thread(
                 self._render_cpu, np.asarray(raw), active_rdef, ctx)
 
         settings = pack_settings(active_rdef, self.s.lut_provider)
+        self._record_prepare(ctx, route)
 
         if ctx.format == "jpeg":
             # Device JPEG path: flips fold into the raw planes (render is
@@ -613,6 +623,21 @@ class ImageRegionHandler:
                 packed = packed[:, ::-1]
         rgba = unpack_rgba(np.ascontiguousarray(packed))
         return await asyncio.to_thread(self._encode_rgba, rgba, ctx)
+
+    @staticmethod
+    def _record_prepare(ctx: ImageRegionCtx, route: str) -> None:
+        """Span ``handler.prepare``: request accepted (the HTTP layer's
+        ``ctx.t_accept``, else this handler's entry) to the hand-off to
+        the batcher or the host render thread — parse, metadata, ACL,
+        region, raw-cache probe or read, ``pack_settings``.  Wall time
+        on the event loop, so it holds the loop's own queue.  Recorded
+        like ``batcher.queueWait``, not under ``stopwatch``: it spans
+        awaits, where a profiler annotation would interleave with other
+        requests' on the loop's thread.  On the request's trace it
+        carries the route the render took."""
+        REGISTRY.record("handler.prepare",
+                        (time.perf_counter() - ctx.t_accept) * 1000.0,
+                        route=route)
 
     def _encode_rgba(self, rgba: np.ndarray, ctx: ImageRegionCtx) -> bytes:
         """Shared encode tail (format dispatch + 404 on unknown format)."""

@@ -21,10 +21,12 @@ spec the serving-path programs are compiled through the real ops entry
 points with the renderer's own wire engine(s):
 
 - the batched JPEG program at EVERY launchable padded batch shape up
-  to ``max_batch`` (``batcher._BATCH_SHAPES``: batch 1 is the idle
-  lone-tile path single-tile p50 rides, max_batch the loaded steady
-  state, and the intermediate shapes — including the non-power-of-two
-  3 and 6 — are what the inflight-aware group split launches);
+  to the cap of the spec's bucket (``batcher.group_cap``: ``max_batch``
+  at 1024^2 and above, up to 64 below; ``batcher._BATCH_SHAPES``:
+  batch 1 is the idle lone-tile path single-tile p50 rides, the cap the
+  loaded steady state, and the intermediate shapes — including the
+  non-power-of-two 3 and 6 — are what a queue shorter than the cap and
+  the inflight-aware group split launch);
 - the packed-RGBA program at batch 1 (png/tif formats).
 
 Settings use the ramp-weight table form (plain color channels; LUT
@@ -82,14 +84,13 @@ def parse_spec(spec: str) -> Tuple[int, int, int, "np.dtype"]:
 
 
 def _warm_one(C: int, edge: int, quality: int, batch_sizes: Sequence[int],
-              engines: Sequence[str], buckets, raw_dtype,
+              engines: Sequence[str], bucket: Tuple[int, int], raw_dtype,
               exec_cache=None) -> None:
     from ..flagship import flagship_settings
     from ..ops.jpegenc import render_batch_to_jpeg
     from ..ops.render import render_tile_batch_packed
-    from .batcher import pick_bucket
 
-    bh, bw = pick_bucket(edge, edge, buckets)
+    bh, bw = bucket
     _, settings = flagship_settings(C)
     for B in dict.fromkeys(batch_sizes):   # de-dup, keep order
         # Zeros: programs are content-independent.  The dtype must
@@ -127,15 +128,15 @@ def _warm_one(C: int, edge: int, quality: int, batch_sizes: Sequence[int],
                 np.asarray(render_tile_batch_packed(*args))
 
 
-def prewarm_batch_sizes(max_batch: int) -> tuple:
+def prewarm_batch_sizes(cap: int) -> tuple:
     """Every padded batch shape the dispatcher can launch at or below
-    ``max_batch`` — imported from the batcher's own shape table so the
-    two can never drift.  Warming only (1, max_batch) left the
-    intermediate entries (3, 6) to lazy XLA compiles on the first 3-/
-    6-tile group."""
+    ``cap`` (a bucket's ``batcher.group_cap``) — imported from the
+    batcher's own shape table so the two can never drift.  Warming
+    only (1, cap) left the intermediate entries (3, 6) to lazy XLA
+    compiles on the first 3-/6-tile group."""
     from .batcher import _BATCH_SHAPES
-    sizes = tuple(s for s in _BATCH_SHAPES if s <= max_batch)
-    return sizes if max_batch in sizes else sizes + (max_batch,)
+    sizes = tuple(s for s in _BATCH_SHAPES if s <= cap)
+    return sizes if cap in sizes else sizes + (cap,)
 
 
 def prewarm_renderer(specs: List[str], engines: Sequence[str],
@@ -153,13 +154,13 @@ def prewarm_renderer(specs: List[str], engines: Sequence[str],
     while this runs (telemetry.READINESS).
     """
     from ..utils.telemetry import READINESS
+    from .batcher import group_cap, pick_bucket
     # Malformed specs raise HERE, before the readiness flag flips or
     # any compile starts (the loader's contract: config errors are
     # loud, and a caller spawning this on a background thread gets the
     # raise before the thread — never a silently-degraded prewarm or a
     # stuck-pending /readyz).
     parsed = [(spec,) + tuple(parse_spec(spec)) for spec in specs]
-    batch_sizes = prewarm_batch_sizes(max_batch)
     READINESS.prewarm_pending = bool(specs)
     try:
         for spec, C, edge, quality, raw_dtype in parsed:
@@ -171,9 +172,12 @@ def prewarm_renderer(specs: List[str], engines: Sequence[str],
                     cpu_fallback_max_px)
                 continue
             t0 = time.perf_counter()
+            bucket = pick_bucket(edge, edge, buckets)
+            batch_sizes = prewarm_batch_sizes(
+                group_cap(max_batch, bucket[0] * bucket[1]))
             try:
                 _warm_one(C, edge, quality, batch_sizes, engines,
-                          buckets, raw_dtype, exec_cache=exec_cache)
+                          bucket, raw_dtype, exec_cache=exec_cache)
             except Exception:
                 # Per-spec: one shape's dead compile must not strand
                 # the others (serving still works, it compiles lazily).

@@ -70,14 +70,15 @@ class StopWatchRegistry:
         self._lock = threading.Lock()
         self._spans: Dict[str, SpanStats] = {}
 
-    def record(self, name: str, ms: float) -> None:
+    def record(self, name: str, ms: float, **meta) -> None:
+        """``meta`` goes with the span on the request's trace only."""
         with self._lock:
             stats = self._spans.get(name)
             if stats is None:
                 stats = self._spans[name] = SpanStats()
             stats.add(ms)
         # Outside the lock: trace recording takes the trace's own lock.
-        observe_span(name, ms)
+        observe_span(name, ms, **meta)
 
     def snapshot(self) -> Dict[str, dict]:
         with self._lock:

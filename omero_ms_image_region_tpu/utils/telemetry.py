@@ -457,7 +457,7 @@ def record_span(name: str, t_start: float, dur_ms: float,
         trace.add_span(name, t_start, dur_ms, **meta)
 
 
-def observe_span(name: str, dur_ms: float) -> None:
+def observe_span(name: str, dur_ms: float, **meta) -> None:
     """Hook for the stopwatch registry: every recorded stage duration
     becomes a child span on whatever traces the context carries."""
     if name in _NON_SPAN_NAMES:
@@ -466,7 +466,7 @@ def observe_span(name: str, dur_ms: float) -> None:
     if not ids:
         return
     record_span(name, time.perf_counter() - dur_ms / 1000.0, dur_ms,
-                trace_ids=ids)
+                trace_ids=ids, **meta)
 
 
 def add_cost(key: str, value: float,
@@ -1212,6 +1212,40 @@ class ProfileStats:
 
 
 PROFILE = ProfileStats()
+
+
+class RouteStats:
+    """Which way ``ImageRegionHandler`` sent each render it prepared:
+    ``device`` (batcher -> chip) or ``host`` (``refimpl`` on a thread:
+    regions of at most ``renderer.cpu-fallback-max-px`` pixels).
+    ``/metrics imageregion_renders_routed_total{route=...}``; both
+    series always present, so a share of them is never read from a
+    missing one."""
+
+    ROUTES = ("device", "host")
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.reset()
+
+    def reset(self) -> None:
+        with self._lock:
+            self.counts = dict.fromkeys(self.ROUTES, 0)
+
+    def count(self, route: str) -> None:
+        with self._lock:
+            self.counts[route] += 1
+
+    def metric_lines(self, extra_labels: str = "") -> List[str]:
+        extra = extra_labels.lstrip(",")
+        with self._lock:
+            return [
+                f"imageregion_renders_routed_total{{route=\"{route}\""
+                + (f",{extra}" if extra else "") + f"}} {n}"
+                for route, n in self.counts.items()]
+
+
+ROUTES = RouteStats()
 
 
 def capture_profile(directory: str, ms: float) -> dict:
@@ -3514,6 +3548,9 @@ METRIC_TYPES: Dict[str, str] = {
     "imageregion_batcher_queue_depth": "gauge",
     "imageregion_pipeline_inflight": "gauge",
     "imageregion_batcher_max_batch": "gauge",
+    "imageregion_batcher_shape_slots_total": "counter",
+    "imageregion_batcher_padded_slots_total": "counter",
+    "imageregion_renders_routed_total": "counter",
     "imageregion_batcher_queue_wait_max_ms": "gauge",
     "imageregion_compile_events_total": "counter",
     "imageregion_compile_ms_total": "counter",
@@ -4170,6 +4207,12 @@ def device_metric_lines(services, extra_labels: str = "") -> List[str]:
             f"{renderer.batches_dispatched}",
             f"imageregion_tiles_rendered{lb} "
             f"{renderer.tiles_rendered}",
+            # Slots of the padded shapes launched, and those of them
+            # that held a repeat instead of a render.
+            f"imageregion_batcher_shape_slots_total{lb} "
+            f"{renderer.shape_slots}",
+            f"imageregion_batcher_padded_slots_total{lb} "
+            f"{renderer.padded_slots}",
         ]
     if hasattr(renderer, "queue_depth"):
         lb = label()
@@ -4203,6 +4246,8 @@ def device_metric_lines(services, extra_labels: str = "") -> List[str]:
     lines += SHAPE_COSTS.metric_lines(extra_labels)
     # What the /debug/profile captures taken so far add up to.
     lines += PROFILE.metric_lines(extra_labels)
+    # The handler's choice between the device and the host render.
+    lines += ROUTES.metric_lines(extra_labels)
     # Warm-state persistence tier (disk byte cache, snapshot engine,
     # boot rehydrator) — device-side state, merged like the rest.
     lines += PERSIST.metric_lines(extra_labels)
@@ -4260,6 +4305,7 @@ def reset() -> None:
     SLO.reset()
     SHAPE_COSTS.reset()
     PROFILE.reset()
+    ROUTES.reset()
     PERSIST.reset()
     WIRE.reset()
     FLEET.reset()

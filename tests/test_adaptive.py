@@ -173,8 +173,11 @@ class TestBatcherIntegration:
         from omero_ms_image_region_tpu.server.batcher import (
             BatchingRenderer)
 
+        # A 1024^2 bucket, where max_batch counts renders as it is
+        # written (a smaller bucket's cap is a multiple: group_cap).
         r = BatchingRenderer(max_batch=2, linger_ms=1.0,
-                             max_batch_limit=8)
+                             max_batch_limit=8,
+                             buckets=((1024, 1024),))
         rdef = flagship_rdef(1)
         settings = pack_settings(rdef)
         rng = np.random.default_rng(1)

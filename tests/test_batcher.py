@@ -222,7 +222,10 @@ class TestPipelining:
                     concurrent["now"] -= 1
                 return super()._render_group(group)
 
-        r = SlowRenderer(max_batch=1, linger_ms=0.0, pipeline_depth=2)
+        # 1024^2 bucket: max_batch=1 means one render a group there
+        # (a smaller bucket's cap is a multiple: group_cap).
+        r = SlowRenderer(max_batch=1, linger_ms=0.0, pipeline_depth=2,
+                         buckets=((1024, 1024),))
         rng = np.random.default_rng(3)
         from omero_ms_image_region_tpu.flagship import flagship_rdef
         from omero_ms_image_region_tpu.ops.render import pack_settings
@@ -339,7 +342,7 @@ class TestTwoStagePipeline:
                     concurrent["now"] -= 1
 
         r = Probe(max_batch=1, linger_ms=0.0, pipeline_depth=2,
-                  device_lanes=1)
+                  device_lanes=1, buckets=((1024, 1024),))
         rng = np.random.default_rng(12)
         from omero_ms_image_region_tpu.flagship import flagship_rdef
         from omero_ms_image_region_tpu.ops.render import pack_settings

@@ -1,0 +1,64 @@
+"""``benchmark/tests``' users of the ``rehearsal_root`` fixture, run on
+the cells the harness had before PR 28 (``tests/bench_rehearsal.py``
+says why they run from here): ``BENCHMARK.json`` against its files,
+``benchmark/run.py`` end to end and traced through each cell at 64^2 /
+128^2 on the CPU, the two planted faults and the controls, which are
+the proof that ``correct`` can come out false."""
+
+import inspect
+import json
+import os
+
+import pytest
+
+from bench_rehearsal import (FIRST_CELL, ONE_DEVICE, PER_CELL, REPO,
+                             build_rehearsal, load)
+
+rehearsal = load("test_rehearsal")
+file_tests = load("test_benchmark_file")
+
+
+@pytest.fixture(scope="module")
+def rehearsal_root(tmp_path_factory):
+    return build_rehearsal(tmp_path_factory)
+
+
+@pytest.fixture(autouse=True)
+def one_device(monkeypatch):
+    monkeypatch.setenv("XLA_FLAGS", ONE_DEVICE)
+
+
+def test_every_rehearsal_test_of_the_harness_is_run_from_here():
+    """A test added to ``test_rehearsal.py`` has to be listed in
+    ``bench_rehearsal`` too, or it would run nowhere."""
+    theirs = {name for name, fn in inspect.getmembers(
+        rehearsal, inspect.isfunction) if name.startswith("test_")}
+    assert theirs == set(PER_CELL) | set(FIRST_CELL)
+    assert {name for name, fn in inspect.getmembers(
+        file_tests, inspect.isfunction) if name.startswith("test_")
+        and "bench" in inspect.signature(fn).parameters} == {
+        "test_keys_names_and_limits", "test_every_name_finds_its_files"}
+
+
+@pytest.mark.parametrize("which", ["committed", "rehearsal"])
+@pytest.mark.parametrize("check", ["test_keys_names_and_limits",
+                                   "test_every_name_finds_its_files"])
+def test_benchmark_file(rehearsal_root, which, check):
+    root = REPO if which == "committed" else rehearsal_root
+    traffic = os.path.join(
+        REPO, "benchmark", "traffic") if root is REPO else os.path.join(
+        root, "traffic")
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    getattr(file_tests, check)((bench, root, traffic))
+
+
+@pytest.mark.parametrize("cell", rehearsal.CELLS)
+@pytest.mark.parametrize("name", PER_CELL)
+def test_a_cell_the_harness_had(tmp_path, rehearsal_root, name, cell):
+    getattr(rehearsal, name)(tmp_path, rehearsal_root, cell)
+
+
+@pytest.mark.parametrize("name", FIRST_CELL)
+def test_the_harness_first_cell(tmp_path, rehearsal_root, name):
+    getattr(rehearsal, name)(tmp_path, rehearsal_root)

@@ -423,10 +423,12 @@ def test_a_capture_holds_the_group_and_its_spans_nested_on_one_line(
     # No Python-tracer event ("$file.py:line function") anywhere.
     assert not [ev.name for _, events in lines for ev in events
                 if ev.name.startswith("$")]
-    groups = [(name, ev) for name, events in lines for ev in events
+    # The line itself, not its name: a worker that has run other files
+    # holds several threads of one name (``asyncio_0``), a line each.
+    groups = [(events, ev) for _, events in lines for ev in events
               if ev.name == "batcher.group"]
     assert len(groups) == 1
-    line_name, group = groups[0]
+    line_events, group = groups[0]
     stats = dict(group.stats)
     assert stats["tiles"] == 3 and stats["padded"] == 3
     assert stats["group_id"] >= 2 and stats["key"] == "jpeg:3x256x256"
@@ -435,7 +437,7 @@ def test_a_capture_holds_the_group_and_its_spans_nested_on_one_line(
         return (ev.start_ns, ev.start_ns + ev.duration_ns)
 
     mine = {}
-    for ev in dict(lines)[line_name]:
+    for ev in line_events:
         if ev.name in ps.HOST_SPANS and _inside(at(ev), at(group)):
             mine.setdefault(ev.name, []).append(ev)
     assert sorted(mine) == sorted([

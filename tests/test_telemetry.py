@@ -861,6 +861,67 @@ class TestExpositionLint:
         assert "# a comment" in text
 
 
+# ------------------------------------------------- the handler's route
+
+STOCK_IMG = 9
+
+
+@pytest.fixture(scope="module")
+def stock_dir(tmp_path_factory):
+    """One column of stock tiles: a full 256^2 tile over a 256 x 40
+    sliver (an image's bottom edge)."""
+    root = tmp_path_factory.mktemp("stockdata")
+    rng = np.random.default_rng(28)
+    planes = rng.integers(0, 60000, size=(2, 1, 296, 256)).astype(
+        np.uint16)
+    build_pyramid(planes, str(root / str(STOCK_IMG)), chunk=(256, 256),
+                  n_levels=1)
+    return str(root)
+
+
+@pytest.mark.parametrize("tile_y, route, padded", [
+    (0, "device", 0),     # a full stock tile: the batcher, alone in B=1
+    (1, "host", 0),       # the sliver under it: refimpl on a thread
+])
+def test_route_counters_and_prepare_span_on_metrics_and_the_trace(
+        stock_dir, tile_y, route, padded):
+    """The DEFAULT configuration (nothing of the route is set): the
+    handler's choice is counted under its label, the batcher's slot
+    counters move with a device render only, and ``handler.prepare``
+    is on ``/metrics`` and on the request's trace with the route."""
+    cfg = AppConfig(data_dir=stock_dir)
+    cfg.wire.streaming = False
+    url = (f"/webgateway/render_image_region/{STOCK_IMG}/0/0"
+           f"?tile=0,0,{tile_y},256,256&format=jpeg&m=c"
+           "&c=1|0:60000$FF0000,2|0:50000$00FF00")
+    (status, _, body), (_, _, metrics) = _fetch(
+        cfg, ("GET", url), ("GET", "/metrics"))
+    assert status == 200 and body[:2] == b"\xff\xd8"
+    text = metrics.decode()
+    other = "host" if route == "device" else "device"
+    assert f'imageregion_renders_routed_total{{route="{route}"}} 1' \
+        in text
+    assert f'imageregion_renders_routed_total{{route="{other}"}} 0' \
+        in text
+    device = int(route == "device")
+    assert f"imageregion_batcher_shape_slots_total {device}" in text
+    assert f"imageregion_batcher_padded_slots_total {padded}" in text
+    assert 'imageregion_span_count{span="handler.prepare"} 1' in text
+    assert "# TYPE imageregion_renders_routed_total counter" in text
+    assert "# TYPE imageregion_batcher_padded_slots_total counter" \
+        in text
+    (trace,) = _finished_render_traces()
+    (prepare,) = [s for s in trace.spans if s["name"] == "handler.prepare"]
+    assert prepare["route"] == route and prepare["dur_ms"] > 0
+    # From the request's acceptance: nothing on the trace starts
+    # before it but the trace itself.
+    assert prepare["start_ms"] < 5.0
+    names = {s["name"] for s in trace.spans}
+    assert ("Renderer.renderAsPackedInt.cpu" in names) == (
+        route == "host")
+    assert ("batcher.queueWait" in names) == (route == "device")
+
+
 # ----------------------------------------------------------- satellites
 
 class TestSatellites:

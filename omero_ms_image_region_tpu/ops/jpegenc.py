@@ -194,6 +194,127 @@ def sparse_prefix_bytes(total: int, H: int, W: int) -> int:
     return 4 + nb + (ENTRY_BITS * int(total) + 7) // 8
 
 
+def _shift_left(x, s: int):
+    """``x`` moved left along its last axis by a static ``s``, zeros
+    coming in at the end."""
+    n = x.shape[-1]
+    if s >= n:
+        return jnp.zeros_like(x)
+    return jnp.pad(x[..., s:], [(0, 0)] * (x.ndim - 1) + [(0, s)])
+
+
+_ENTRY_MASK = (1 << ENTRY_BITS) - 1
+# What a 32-bit word holds of an entry's distance beside its 18 bits.
+_DIST_BITS = 32 - ENTRY_BITS
+
+
+def _shift_select(w, first: int, nbits: int):
+    """``nbits`` passes of the log-step compaction over words
+    ``entry | dist << 18`` (0 = a hole): in pass ``j`` every element
+    whose distance bit ``j`` is set moves left by ``2**(first + j)``:
+    one static shift and one select over the whole array."""
+    for j in range(nbits):
+        arriving = _shift_left(w, 1 << (first + j))
+        comes = ((arriving >> (ENTRY_BITS + j)) & 1) == 1
+        leaves = ((w >> (ENTRY_BITS + j)) & 1) == 1
+        w = jnp.where(comes, arriving, jnp.where(leaves, 0, w))
+    return w
+
+
+def _compact_entries(field, keep, wi, cap: int):
+    """Order-preserving left compaction: i32[B, cap] holding row b's
+    kept ``field`` values in order, zeros after them; what does not
+    fit in ``cap`` is dropped.  ``wi`` is the inclusive prefix sum of
+    ``keep`` minus one (a kept element's target); a kept ``field`` is
+    nonzero and under ``2**18``.
+
+    A kept element at ``i`` must move left by ``d = i - wi[i]``, the
+    zeros before it.  Taking the bits of ``d`` least significant
+    first, an element whose bit ``k`` is set moves left by ``2**k``;
+    two kept elements never meet (their distances differ by less than
+    their separation, modulo any ``2**k``: the compress network of
+    Hacker's Delight 7-4), so after ``ceil(log2 N)`` passes the
+    entries stand compacted.  Each pass is a static shift and a select
+    over the dense array: no index, no scatter, no gather.  The low 14
+    bits of ``d`` ride in the word above the entry's 18, so a pass
+    moves one array; for the passes past them the distance left is
+    found again as position minus rank (a second prefix sum) and rides
+    in the same bits.
+    """
+    N = field.shape[-1]
+    nbits = max(N - 1, 1).bit_length()
+    low = min(nbits, _DIST_BITS)
+    idx = jnp.arange(N, dtype=jnp.int32)
+    dist = idx - wi
+    w = jnp.where(
+        keep, field | ((dist & ((1 << low) - 1)) << ENTRY_BITS), 0)
+    w = _shift_select(w, 0, low) & _ENTRY_MASK
+    if nbits > low:
+        held = w != 0
+        rank = jnp.cumsum(held, axis=-1, dtype=jnp.int32) - 1
+        w = jnp.where(held, w | (((idx - rank) >> low) << ENTRY_BITS), 0)
+        w = _shift_select(w, low, nbits - low) & _ENTRY_MASK
+    return jnp.pad(
+        w, [(0, 0)] * (w.ndim - 1) + [(0, max(cap - N, 0))])[..., :cap]
+
+
+# Entries a row of the stream-assembly matmul: 512 entries are exactly
+# 1,152 = 9 x 128 bytes.
+_PACK_ROW = 512
+
+
+@functools.lru_cache(maxsize=1)
+def _pack_tables():
+    """How 18-bit entries become bytes, for a row of ``_PACK_ROW``
+    entries: entry ``e`` starts at bit ``18 e`` and so falls into three
+    consecutive bytes; piece ``p`` of it is ``(entry >> shift[p, e]) &
+    mask[p, e]`` and lands in byte ``byte[p, e]`` times ``scale[p, e]``
+    (a power of two)."""
+    shape = (3, _PACK_ROW)
+    shift, mask, byte, scale = (np.zeros(shape, np.int32)
+                                for _ in range(4))
+    for e in range(_PACK_ROW):
+        bit, left = ENTRY_BITS * e, ENTRY_BITS
+        for p in range(3):
+            byte[p, e], used = divmod(bit, 8)
+            take = min(8 - used, left)
+            left -= take
+            shift[p, e] = left
+            mask[p, e] = (1 << take) - 1
+            scale[p, e] = 1 << (8 - used - take)
+            bit += take
+        assert left == 0
+    return shift, mask, byte.reshape(-1), scale.reshape(-1)
+
+
+def _pack_entries(comp, nbytes: int):
+    """i32[B, cap] 18-bit entries -> the MSB-first stream u8[B, nbytes].
+
+    Four entries are exactly nine bytes, so the stream is a fixed
+    linear map of the entries' pieces: every entry is cut (shifts and
+    masks, no index array) into the three pieces that fall into
+    different bytes, and one matmul with a 0 / power-of-two matrix
+    adds each byte's at most two pieces at their places.  Pieces are
+    under 256 and the scales powers of two, so bf16 holds both exactly
+    and the f32 sums (under 256) are exact.
+    """
+    B, cap = comp.shape
+    rows = -(-cap // _PACK_ROW)
+    e = jnp.pad(comp, ((0, 0), (0, rows * _PACK_ROW - cap)))
+    e = e.reshape(B, rows, _PACK_ROW)
+    shift, mask, byte, scale = _pack_tables()
+    pieces = jnp.concatenate(
+        [((e >> shift[p]) & mask[p]).astype(jnp.bfloat16)
+         for p in range(3)], axis=-1)                 # [B, rows, 3*512]
+    out_bytes = ENTRY_BITS * _PACK_ROW // 8
+    place = jnp.where(
+        byte[:, None] == jnp.arange(out_bytes, dtype=jnp.int32)[None, :],
+        scale[:, None], 0).astype(jnp.bfloat16)       # [3*512, 1152]
+    stream = jnp.einsum("brk,kn->brn", pieces, place,
+                        preferred_element_type=jnp.float32)
+    return stream.astype(jnp.uint8).reshape(B, -1)[:, :nbytes]
+
+
 @jax.named_scope("wire.sparse_pack")
 def sparse_pack(y, cb, cr, cap: int):
     """Compact nonzero coefficients into one u8 wire buffer per tile.
@@ -215,17 +336,20 @@ def sparse_pack(y, cb, cr, cap: int):
     dropped (detected host-side via total_entries > cap; the caller then
     falls back to the dense path).
 
-    Layout and algorithm are both wire-aware:
+    At 2.25 bytes/entry the used bytes are one contiguous prefix
+    (``sparse_prefix_bytes``), so the host fetches only that prefix —
+    comparable in size to the final JPEG itself — instead of the full
+    ``cap``-sized buffer (``SparseWireFetcher``).
 
-      * at 2.25 bytes/entry the used bytes are one contiguous prefix
-        (``sparse_prefix_bytes``), so the host fetches only that prefix —
-        comparable in size to the final JPEG itself — instead of the full
-        ``cap``-sized buffer (``SparseWireFetcher``);
-      * compaction is one set-scatter with unique, ascending targets
-        (out-of-bounds-dropped tails), which XLA lowers to plain stores —
-        measured ~3x faster than the equivalent non-unique scatter; the
-        18-bit bitstream is then assembled by a pure gather pass (each
-        output byte reads its ≤2 contributing entries arithmetically).
+    Neither stage holds a scatter or a data-dependent gather.  On a
+    v5e the set-scatter that stood here ran at ~5 ns a source element
+    and the per-byte gather pass at ~1.6 ns a byte, together half of a
+    saturated chip (the traces of PR 25-28; 59 and 8 ms of a B = 8
+    group at 4 x 1024^2, the stages timed alone in PR 29).  The
+    compaction is :func:`_compact_entries`' shift-and-select passes
+    (scope ``wire.sparse_pack.scatter``, 4.4 ms for the same group),
+    the stream :func:`_pack_entries`' matmul
+    (``wire.sparse_pack.bits``, 0.9 ms); the bytes are the same.
     """
     B = y.shape[0]
     flat = jnp.concatenate(
@@ -240,32 +364,10 @@ def sparse_pack(y, cb, cr, cap: int):
     pos = jnp.arange(N, dtype=jnp.int32) % 64
     field = (pos << 12) | (flat & 0xFFF)                   # 18-bit entries
 
-    def compact_one(m, w, f):
-        tgt = jnp.where(m & (w < cap), w, jnp.int32(1) << 30)
-        return jnp.zeros(cap, jnp.int32).at[tgt].set(
-            f, mode="drop", unique_indices=True)
-
     with jax.named_scope("wire.sparse_pack.scatter"):
-        comp = jax.vmap(compact_one)(mask, wi, field)      # [B, cap]
-
-    # Assemble the 18-bit stream byte-by-byte: byte b covers bits
-    # [8b, 8b+8), which intersect entries e0 = (8b)//18 and possibly
-    # e0 + 1 (a field is 18 > 8 bits, so never more than two).
-    nbytes = (ENTRY_BITS * cap + 7) // 8
-    bitpos = jnp.arange(nbytes, dtype=jnp.int32) * 8
-    e0 = bitpos // ENTRY_BITS
-    off = bitpos - e0 * ENTRY_BITS                          # 0..17
-    compz = jnp.pad(comp, ((0, 0), (0, 1)))                 # e0+1 guard
-
-    def assemble_one(c_row):
-        f0 = c_row[e0]
-        f1 = c_row[e0 + 1]
-        part0 = ((f0 << off) & 0x3FFFF) >> 10
-        part1 = jnp.where(off > 10, f1 >> (28 - off), 0)
-        return ((part0 | part1) & 0xFF).astype(jnp.uint8)
-
+        comp = _compact_entries(field, mask, wi, cap)      # [B, cap]
     with jax.named_scope("wire.sparse_pack.bits"):
-        stream = jax.vmap(assemble_one)(compz)              # [B, nbytes]
+        stream = _pack_entries(comp, (ENTRY_BITS * cap + 7) // 8)
     tot_u8 = jax.lax.bitcast_convert_type(
         total[:, None], jnp.uint8).reshape(B, -1)
     return jnp.concatenate([tot_u8, counts, stream], axis=1)
@@ -449,28 +551,28 @@ def _compact_rows(bufs, lengths):
     of row sizes (far lower relative variance), pad rows cost zero, and
     a group's wire bytes equal its entropy bytes.
 
-    Formulated as ONE unique-index set-scatter (source byte (b, i)
-    lands at ``cum[b] + i``; bytes past a row's length route out of
-    bounds and drop): row ranges partition the output and offsets
-    within a row are distinct, so XLA lowers it to plain stores.  The
-    previous formulation ran backwards — per OUTPUT byte, a
-    searchsorted over the row bounds plus a random-access 2-D gather —
-    and that B*width-element gather dominated the packers' device
-    profile (gathers serialize per element on TPU; unique-index stores
-    do not).
+    Rows move whole: row ``b`` goes left by ``b*width - start[b]``,
+    the same distance for all its bytes.  Each row is zeroed past its
+    length and the rows are written in ascending order at
+    ``start[b]`` (``dynamic_update_slice``, one block move a row): a
+    later row overwrites the zero tail of the one before it, and what
+    the last one leaves is zeros to the end.  The unique-index
+    set-scatter that stood here cost a v5e ~7 ns a byte (61 ms for
+    8 rows of 909 KB, two fifths of a saturated chip in the traces of
+    PR 25-28); the moves take 1.1 ms for the same rows (both timed
+    alone in PR 29).
     """
     B, width = bufs.shape
     lengths = lengths.astype(jnp.int32)
-    cum = jnp.concatenate([jnp.zeros(1, jnp.int32),
-                           jnp.cumsum(lengths)])
+    start = jnp.cumsum(lengths) - lengths
     col = jnp.arange(width, dtype=jnp.int32)
-    tgt = jnp.where(col[None, :] < lengths[:, None],
-                    cum[:-1, None] + col[None, :],
-                    jnp.int32(1) << 30)
-    data = jnp.zeros(B * width, jnp.uint8).at[tgt.reshape(-1)].set(
-        bufs.reshape(-1), mode="drop", unique_indices=True)
-    header = jax.lax.bitcast_convert_type(
-        cum[1:] - cum[:-1], jnp.uint8).reshape(-1)
+    rows = jnp.where(col[None, :] < lengths[:, None], bufs, 0)
+    data = jax.lax.fori_loop(
+        0, B,
+        lambda b, out: jax.lax.dynamic_update_slice(
+            out, rows[b], (start[b],)),
+        jnp.zeros(B * width, jnp.uint8))
+    header = jax.lax.bitcast_convert_type(lengths, jnp.uint8).reshape(-1)
     return jnp.concatenate([header, data])
 
 

@@ -1083,3 +1083,111 @@ class TestFusedPathsMatchRefimplGolden:
             want[off:off + ln] = row[:ln]
             off += ln
         np.testing.assert_array_equal(got, want)
+
+
+# ------------- the wire compactions against plain numpy (no scatter)
+
+def _random_coeffs(rng, B, nby, density):
+    """i16 coefficient arrays in the module's layout, nonzero at
+    ``density`` (0 -> none, 1 -> every slot)."""
+    def plane(nb):
+        v = rng.integers(1, 2048, size=(B, nb, 64))
+        v *= rng.choice([-1, 1], size=v.shape)
+        keep = rng.random(v.shape) < density
+        return np.where(keep, v, 0).astype(np.int16)
+    return plane(nby), plane(nby // 4), plane(nby // 4)
+
+
+def _numpy_sparse_wire(y, cb, cr, cap):
+    """The wire layout of ``sparse_pack``'s docstring, written out with
+    boolean indexing and ``np.packbits``: u8[B, 4 + nb + ceil(18 cap / 8)]."""
+    B = y.shape[0]
+    flat = np.concatenate([a.reshape(B, -1) for a in (y, cb, cr)],
+                          axis=1).astype(np.int32)
+    nb = flat.shape[1] // 64
+    field = ((np.arange(flat.shape[1]) % 64) << 12) | (flat & 0xFFF)
+    rows = []
+    for b in range(B):
+        mask = flat[b] != 0
+        kept = field[b][mask][:cap]
+        comp = np.zeros(cap, np.int64)
+        comp[:kept.size] = kept
+        bits = ((comp[:, None] >> np.arange(17, -1, -1)) & 1).astype(np.uint8)
+        rows.append(np.concatenate([
+            np.array([mask.sum()], "<i4").view(np.uint8),
+            mask.reshape(nb, 64).sum(-1).astype(np.uint8),
+            np.packbits(bits.reshape(-1))]))
+    return np.stack(rows)
+
+
+@pytest.mark.parametrize("cap_kind", ["under", "at", "over", "odd"])
+@pytest.mark.parametrize("density", [0.0, 0.03, 0.12, 0.5, 1.0])
+def test_entry_compaction_matches_boolean_indexing(density, cap_kind):
+    """``_compact_entries`` is ``field[keep][:cap]`` zero-filled, whole
+    rows compared: no entry lost, none out of order, zeros past the
+    total, at every density and with the total under, at and over
+    ``cap`` (and a ``cap`` that is no multiple of 4)."""
+    import jax.numpy as jnp
+    from omero_ms_image_region_tpu.ops import jpegenc as je
+
+    rng = np.random.default_rng(int(density * 100) + len(cap_kind))
+    B, N = 3, 6144 + 384                 # not a power of two
+    keep = rng.random((B, N)) < density
+    keep[1, :] &= np.arange(N) > N // 2  # a long leading run of zeros
+    field = np.where(keep, rng.integers(1, 1 << 18, size=(B, N)), 0)
+    total = int(keep.sum(axis=1).max())
+    cap = {"under": max(total - 7, 1), "at": max(total, 1),
+           "over": total + 64, "odd": 4 * (total // 8) + 3}[cap_kind]
+    wi = np.cumsum(keep, axis=1) - 1
+    got = np.asarray(je._compact_entries(
+        jnp.asarray(field, jnp.int32), jnp.asarray(keep),
+        jnp.asarray(wi, jnp.int32), cap))
+    want = np.zeros((B, cap), np.int32)
+    for b in range(B):
+        kept = field[b][keep[b]][:cap]
+        want[b, :kept.size] = kept
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("cap_kind", ["under", "at", "over", "odd"])
+@pytest.mark.parametrize("density", [0.0, 0.03, 0.12, 0.5, 1.0])
+def test_sparse_pack_whole_buffer_matches_numpy(density, cap_kind):
+    """Header, counts and the 18-bit stream, zeros past the used total
+    included, against the numpy wire: the stream assembly (four entries
+    are nine bytes) at caps that are and are not multiples of 4."""
+    rng = np.random.default_rng(7 + int(density * 100))
+    y, cb, cr = _random_coeffs(rng, 2, 16, density)      # a 32x32 tile
+    total = max(int(sum((a[b] != 0).sum() for a in (y, cb, cr)))
+                for b in range(2))
+    cap = {"under": max(total - 5, 1), "at": max(total, 1),
+           "over": total + 32, "odd": 4 * (total // 8) + 1}[cap_kind]
+    got = np.asarray(sparse_pack(y, cb, cr, cap))
+    np.testing.assert_array_equal(got, _numpy_sparse_wire(y, cb, cr, cap))
+
+
+@pytest.mark.parametrize("pattern", ["ragged", "pads", "full", "empty"])
+@pytest.mark.parametrize("B", [1, 5, 8, 64])
+def test_compact_rows_matches_ragged_concat(B, pattern):
+    """``_compact_rows`` is the lengths header and the ragged concat of
+    the rows' prefixes, zeros to the end: zero-length (pad) rows first,
+    last and in the middle, every row whole, every row empty."""
+    import jax.numpy as jnp
+    from omero_ms_image_region_tpu.ops import jpegenc as je
+
+    rng = np.random.default_rng(B * 10 + len(pattern))
+    width = 1531                          # odd, not a multiple of 4
+    bufs = rng.integers(1, 256, size=(B, width), dtype=np.uint8)
+    lengths = rng.integers(0, width + 1, size=B).astype(np.int32)
+    if pattern == "pads":
+        lengths[[0, B // 2, B - 1]] = 0
+    elif pattern == "full":
+        lengths[:] = width
+    elif pattern == "empty":
+        lengths[:] = 0
+    got = np.asarray(je._compact_rows(jnp.asarray(bufs),
+                                      jnp.asarray(lengths)))
+    want = np.zeros(4 * B + B * width, np.uint8)
+    want[:4 * B] = lengths.astype("<i4").view(np.uint8)
+    ragged = np.concatenate([row[:n] for row, n in zip(bufs, lengths)])
+    want[4 * B:4 * B + ragged.size] = ragged
+    np.testing.assert_array_equal(got, want)

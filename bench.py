@@ -4434,21 +4434,11 @@ def main():
         service_waterfall = {
             k: v for k, v in _SPAN_REG.snapshot().items()
             if k in _WATERFALL_SPANS}
-        # Device->host fetch rate measured adjacent to the service
-        # windows (the huffman engine ships ~90 KB/tile).
-        try:
-            from omero_ms_image_region_tpu.utils.linkprobe import \
-                measure_fetch_mb_s
-            service_fetch_mb_s = measure_fetch_mb_s(nbytes=2 << 20,
-                                                    repeats=2)
-        except Exception:
-            service_fetch_mb_s = None
     except Exception:
         # App stack unavailable; library numbers stand.
         service_tps, service_engines = None, {}
         service_windows, service_waterfall = {}, {}
         service_p50_ms = None
-        service_fetch_mb_s = None
         service_hot_path = {}
     c1_cpu = retry_transient(
         lambda: bench_config1(rng), "bench_config1", backoff_s=15.0)
@@ -4544,9 +4534,6 @@ def main():
             telemetry_wire_frames_per_flush(), 3),
         "shm_ring_hit_rate": _opt_round(
             telemetry_wire_ring_hit_rate(), 3),
-        # Device->host rate adjacent to the service windows.
-        "service_window_fetch_mb_per_sec": _opt_round(
-            service_fetch_mb_s, 1),
         "batch": 8,
         "config1_cpu_ref_per_sec": round(c1_cpu, 2),
         "config2_fullplane_2048_3ch_per_sec": round(c2_planes, 2),

@@ -456,16 +456,6 @@ class SparseWireFetcher:
 _FETCHERS: dict = {}
 _FETCHERS_LOCK = threading.Lock()
 
-# Optional wire-fetch observer: fn(nbytes, seconds), fed by the
-# fetchers so an adaptive engine controller (utils.adaptive) can track
-# the live device->host rate.  None = disabled (zero overhead).
-_FETCH_OBSERVER = None
-
-
-def set_fetch_observer(fn) -> None:
-    global _FETCH_OBSERVER
-    _FETCH_OBSERVER = fn
-
 
 def _observe_fetch(nbytes: int, seconds: float,
                    conflated: bool = False) -> None:
@@ -474,20 +464,13 @@ def _observe_fetch(nbytes: int, seconds: float,
     so bytes/seconds is a LOWER BOUND on the link rate, not a
     measurement of it."""
     # The link-health EWMA gauge (/metrics imageregion_link_mb_s) rides
-    # every fetch, independent of whether an adaptive controller is
-    # wired — it is what settles "weather or regression?" when a bench
-    # headline moves.
+    # every fetch — it is what settles "weather or regression?" when a
+    # bench headline moves.
     from ..utils.telemetry import LINK
     try:
         LINK.observe(nbytes, seconds, conflated)
     except Exception:       # pragma: no cover - telemetry must never
         pass                # break the serving path
-    obs = _FETCH_OBSERVER
-    if obs is not None:
-        try:
-            obs(nbytes, seconds, conflated)
-        except Exception:   # pragma: no cover - observer bugs must not
-            pass            # break the serving path
 
 
 def _fetch_first(pre, tiles: int = None,
@@ -752,8 +735,8 @@ def _quality_widen(quality: "int | None") -> int:
     content runs ~5% coefficient density at q80 but ~12% at q90 — past
     the 1/8 default budgets, which would silently drop every tile to
     the per-tile host dense path (~170 ms each).  One shared rule so
-    the direct, batched, mesh and bitpack engines all stay on the
-    device path at high quality."""
+    the direct, batched and mesh renderers all stay on the device
+    path at high quality."""
     return 2 if quality is not None and quality >= 88 else 1
 
 
@@ -926,7 +909,7 @@ def encode_tiles_jpeg(packed, quality: int = 85, width: int | None = None,
     return list(executor.map(one, range(B)))
 
 
-# ------------------------------------------------- device bit packing
+# ------------------------------------ compacted-entry device Huffman
 
 @functools.lru_cache(maxsize=16)
 def _mcu_scan_index(h16: int, w16: int) -> np.ndarray:
@@ -954,147 +937,6 @@ def _amplitude(x, s):
     """Amplitude bits: value as-is if positive, ones'-complement if not."""
     return jnp.where(x >= 0, x, x + jnp.left_shift(1, s) - 1)
 
-
-def _bitpack_fixed(blocks, scan_idx, dc_code, dc_len, ac_code, ac_len,
-                   cap_words: int):
-    """Huffman bit-pack one tile's coefficient blocks on device.
-
-    The serial half of JPEG vectorizes: per-coefficient (code, length)
-    gathers from the fixed tables, a cumsum turns lengths into global bit
-    offsets, and each field scatter-adds into at most two u32 stream words
-    — different fields own disjoint bits, so add IS bitwise-or.  The one
-    remaining serial step (0xFF byte stuffing) runs on the host over the
-    finished ~100 KB stream (:func:`..jfif.finish_fixed_stream`).
-
-    Args: ``blocks`` i16[nb, 64] zigzag coefficients ([Y|Cb|Cr] raster),
-    ``scan_idx`` from :func:`_mcu_scan_index`, code/len arrays from
-    :func:`..jfif.fixed_huffman_spec` (u32/i32), ``cap_words`` stream
-    capacity.  Returns ``(words u32[cap_words], total_bits i32)``; a tile
-    whose stream exceeds the cap is detected host-side via total_bits.
-    """
-    # All bit arithmetic in int32 (field values use at most 27 bits, and
-    # disjoint-bit scatter-adds never carry, so signed adds are bitwise
-    # exact); the stream is bitcast to u32 words at the end.
-    v = blocks[scan_idx].astype(jnp.int32)        # [n_mcu, 6, 64]
-    n_mcu = v.shape[0]
-
-    # DC difference chains, one per component.
-    dc = v[..., 0]
-    def chain(x):
-        flat = x.reshape(-1)
-        return (flat - jnp.pad(flat[:-1], (1, 0))).reshape(x.shape)
-    dcdiff = jnp.concatenate([
-        chain(dc[:, :4]), chain(dc[:, 4:5]), chain(dc[:, 5:6]),
-    ], axis=1)
-    s_dc = _category(dcdiff)
-    dc_f_val = jnp.left_shift(dc_code[s_dc], s_dc) | _amplitude(dcdiff, s_dc)
-    dc_f_len = dc_len[s_dc] + s_dc
-
-    # AC run-lengths from the gap to the previous nonzero position.
-    ac = v[..., 1:]                               # [n_mcu, 6, 63]
-    nz = ac != 0
-    k = jnp.arange(1, 64, dtype=jnp.int32)
-    posk = jnp.where(nz, k, 0)
-    prev_incl = jax.lax.cummax(posk, axis=posk.ndim - 1)
-    prev = jnp.pad(prev_incl[..., :-1], ((0, 0), (0, 0), (1, 0)))
-    run = k - prev - 1
-    z = jnp.where(nz, run >> 4, 0)
-    rem = run & 15
-    s_ac = _category(ac)
-    sym = jnp.left_shift(rem, 4) | s_ac
-    f2_val = jnp.left_shift(ac_code[sym], s_ac) | _amplitude(ac, s_ac)
-    f2_len = jnp.where(nz, ac_len[sym] + s_ac, 0)
-    f2_val = jnp.where(nz, f2_val, 0)
-
-    zc, zl = ac_code[0xF0], ac_len[0xF0]          # ZRL
-    f0_len = jnp.minimum(z, 2) * zl
-    f0_val = jnp.where(
-        z >= 2, jnp.left_shift(zc, zl) | zc, jnp.where(z == 1, zc, 0))
-    f1_len = jnp.where(z >= 3, zl, 0)
-    f1_val = jnp.where(z >= 3, zc, 0)
-
-    has_eob = prev_incl[..., -1] < 63
-    eob_val = jnp.where(has_eob, ac_code[0x00], 0)
-    eob_len = jnp.where(has_eob, ac_len[0x00], 0)
-
-    # Stream offsets, computed arithmetically rather than by materializing
-    # an interleaved [.., 191]-field array (a minor dim of 191 pads to 256
-    # lanes on TPU and multiplies HBM traffic ~6x; this was measured at
-    # 1.2 s/batch vs ~0.1 s for the arithmetic form).  Stream order per
-    # block is [dc | (f0 f1 f2) per coeff | eob]; blocks follow MCU scan
-    # order, which dim order (n_mcu, 6) already is.
-    coeff_len = f0_len + f1_len + f2_len                  # [n_mcu, 6, 63]
-    within = jnp.cumsum(coeff_len, axis=2)
-    block_ac_bits = within[..., -1]                       # [n_mcu, 6]
-    block_bits = dc_f_len + block_ac_bits + eob_len
-    block_end = jnp.cumsum(block_bits.reshape(-1)).reshape(n_mcu, 6)
-    block_start = block_end - block_bits
-    total_bits = block_end[-1, -1]
-
-    dc_start = block_start
-    f0_start = (block_start + dc_f_len)[..., None] + (within - coeff_len)
-    f1_start = f0_start + f0_len
-    f2_start = f1_start + f1_len
-    eob_start = block_start + dc_f_len + block_ac_bits
-
-    # ONE coalesced deposit pass over every field stream (non-unique
-    # scatter-adds serialize on TPU, so the five per-field passes —
-    # ten scatters — collapse to two): disjoint-bit adds commute, so
-    # the packed stream is bit-identical to the per-field form.
-    val = jnp.concatenate([a.reshape(-1) for a in (
-        dc_f_val, f0_val, f1_val, f2_val, eob_val)])
-    length = jnp.concatenate([a.reshape(-1) for a in (
-        dc_f_len, f0_len, f1_len, f2_len, eob_len)])
-    start = jnp.concatenate([a.reshape(-1) for a in (
-        dc_start, f0_start, f1_start, f2_start, eob_start)])
-    words = jnp.zeros(cap_words, jnp.int32)
-    w = start >> 5
-    r = start & 31
-    sh0 = 32 - r - length                      # in [-30, 32]
-    # Field values never set bit 31, so arithmetic >> == logical >>.
-    c0 = jnp.where(
-        sh0 >= 0,
-        jnp.left_shift(val, jnp.minimum(sh0, 31)),
-        jnp.right_shift(val, jnp.minimum(-sh0, 31)),
-    )
-    sh1 = 64 - r - length                      # in [2, 64]
-    c1 = jnp.where(
-        sh1 < 32, jnp.left_shift(val, jnp.maximum(sh1, 0) & 31), 0)
-    live = length > 0
-    c0 = jnp.where(live, c0, 0)
-    c1 = jnp.where(live, c1, 0)
-    words = words.at[w].add(c0, mode="drop")
-    words = words.at[w + 1].add(c1, mode="drop")
-    return (jax.lax.bitcast_convert_type(words, jnp.uint32),
-            total_bits.astype(jnp.int32))
-
-
-@functools.partial(jax.jit, static_argnames=("cap_words",))
-def render_to_jpeg_bits(raw, window_start, window_end, family, coefficient,
-                        reverse, cd_start, cd_end, tables, qy, qc,
-                        scan_idx, dc_code, dc_len, ac_code, ac_len,
-                        cap_words: int):
-    """Fully fused batched render -> entropy-coded JPEG bitstream words.
-
-    Everything from raw pixels to Huffman-packed stream bits runs in one
-    device dispatch; the host only 0xFF-stuffs and frames the result
-    (:func:`..jfif.finish_fixed_stream`).  Returns
-    ``(words u32[B, cap_words], total_bits i32[B])``.
-    """
-    y, cb, cr = render_to_jpeg_coefficients(
-        raw, window_start, window_end, family, coefficient, reverse,
-        cd_start, cd_end, tables, qy, qc)
-    B = y.shape[0]
-    blocks = jnp.concatenate(
-        [y.reshape(B, -1, 64), cb.reshape(B, -1, 64),
-         cr.reshape(B, -1, 64)], axis=1)
-    return jax.vmap(
-        lambda b: _bitpack_fixed(b, scan_idx, dc_code, dc_len, ac_code,
-                                 ac_len, cap_words)
-    )(blocks)
-
-
-# ------------------------------------ compacted-entry device Huffman
 
 def default_words_cap(H: int, W: int, quality: "int | None" = None
                       ) -> int:
@@ -1124,10 +966,9 @@ def huffman_pack(y, cb, cr, cap: int, cap_words: int,
     components), so only ~Huffman-entropy bytes cross the link and the
     host merely 0xFF-stuffs and frames (``jfif.finish_fixed_stream``).
 
-    The legacy full-grid device-Huffman path (``_bitpack_fixed``) paid a
-    deposit scatter for EVERY coefficient slot (~15M updates/tile).
-    Here all per-entry work runs
-    on the ``cap``-sized COMPACTED stream (one unique-index set-scatter,
+    A deposit scatter for EVERY coefficient slot would be ~15M
+    updates/tile.  Here all per-entry work runs on the ``cap``-sized
+    COMPACTED stream (one unique-index set-scatter,
     the same trick as ``sparse_pack``), and the bit deposits touch
     ~1.3M update slots/tile across TWO coalesced scatter passes: the
     dense per-block fields (DC diff + EOB, over ``2*nb``) ride one and
@@ -1428,71 +1269,6 @@ def finish_huffman_batch(bufs, dims, H: int, W: int,
         if on_tile is not None:
             on_tile(i, out[-1])
     return out
-
-
-class TpuJpegEncoder:
-    """Host-side driver for the fully-fused JPEG path at one tile shape.
-
-    Holds the per-shape constants (MCU scan map, fixed Huffman code
-    tables, quant tables, stream capacity) and finishes fetched streams
-    into JFIF files, falling back to the dense coefficient path for tiles
-    whose stream overflows the capacity.
-    """
-
-    def __init__(self, H: int, W: int, quality: int = 85,
-                 cap_bytes: int | None = None):
-        from ..jfif import fixed_huffman_spec
-        if H % 16 or W % 16:
-            raise ValueError("tile shape must be MCU (16) aligned")
-        self.H, self.W, self.quality = H, W, quality
-        self.cap_words = (cap_bytes or
-                          (H * W) // 4 * _quality_widen(quality)) // 4
-        _, _, dc_code, dc_len, _, _, ac_code, ac_len = fixed_huffman_spec()
-        self.consts = (
-            jnp.asarray(_mcu_scan_index(H // 16, W // 16)),
-            jnp.asarray(dc_code.astype(np.int32)),   # codes fit 16 bits
-            jnp.asarray(dc_len.astype(np.int32)),
-            jnp.asarray(ac_code.astype(np.int32)),
-            jnp.asarray(ac_len.astype(np.int32)),
-        )
-        qy, qc = quant_tables(quality)
-        self.qy = jnp.asarray(qy.astype(np.int32))
-        self.qc = jnp.asarray(qc.astype(np.int32))
-
-    def render_batch(self, raw, *settings_args):
-        """Dispatch the fused kernel; returns (words, total_bits) handles."""
-        words, bits = render_to_jpeg_bits(
-            raw, *settings_args, self.qy, self.qc, *self.consts,
-            cap_words=self.cap_words)
-        words.copy_to_host_async()
-        bits.copy_to_host_async()
-        return words, bits
-
-    def finish_batch(self, words, bits, dense_fallback=None,
-                     executor=None) -> list:
-        """Fetched stream words -> JFIF bytes per tile."""
-        from ..jfif import finish_fixed_stream
-        words = np.asarray(words)
-        bits = np.asarray(bits)
-
-        def one(i):
-            if bits[i] > self.cap_words * 32:
-                if dense_fallback is None:
-                    raise ValueError(
-                        f"stream overflow: {bits[i]} bits > cap")
-                return dense_fallback(i)
-            return finish_fixed_stream(words[i], int(bits[i]), self.W,
-                                       self.H, self.quality)
-
-        if executor is None:
-            return [one(i) for i in range(words.shape[0])]
-        return list(executor.map(one, range(words.shape[0])))
-
-    def encode_batch(self, raw, *settings_args, dense_fallback=None,
-                     executor=None) -> list:
-        return self.finish_batch(
-            *self.render_batch(raw, *settings_args),
-            dense_fallback=dense_fallback, executor=executor)
 
 
 def dense_encoder():

@@ -2538,21 +2538,13 @@ def build_local_members(config, base_services, n: int,
                 max_batch=config.batcher.max_batch,
                 max_batch_limit=config.batcher.max_batch_limit,
                 linger_ms=config.batcher.linger_ms,
-                jpeg_engine=(base_services.renderer.jpeg_engine
-                             if getattr(base_services.renderer,
-                                        "jpeg_engine", None)
-                             in ("sparse", "huffman") else "sparse"),
+                jpeg_engine=config.renderer.jpeg_engine,
                 pipeline_depth=config.batcher.pipeline_depth,
                 target_inflight=config.batcher.target_inflight,
                 device_lanes=config.batcher.device_lanes)
             renderer.first_tile_out = config.wire.streaming
         else:
-            engine = config.renderer.jpeg_engine
-            if engine == "auto":
-                engine = getattr(base_services.renderer,
-                                 "jpeg_engine", "sparse")
-            renderer = Renderer(jpeg_engine=engine,
-                                kernel=config.renderer.kernel)
+            renderer = Renderer(jpeg_engine=config.renderer.jpeg_engine)
         if devices_for(i):
             renderer.device = devices_for(i)[0]
         raw_cache = (DeviceRawCache(

@@ -197,8 +197,7 @@ class MeshRenderer(BatchingRenderer):
     def __init__(self, mesh: Mesh, max_batch: int | None = None,
                  linger_ms: float = 2.0, buckets=None,
                  jpeg_engine: str = "sparse", pipeline_depth: int = 4,
-                 max_batch_limit: int = None, engine_controller=None,
-                 device_lanes: int = 2):
+                 max_batch_limit: int = None, device_lanes: int = 2):
         data = mesh.shape["data"]
         if max_batch is None:
             max_batch = max(8, 2 * data)
@@ -263,14 +262,6 @@ class MeshRenderer(BatchingRenderer):
         # (renderer.compilation-cache-dir) and the bring-up dryrun.
         self.exec_cache = None
         self.jpeg_engine = jpeg_engine
-        # Live wire-engine selection (utils.adaptive.AdaptiveEngine).
-        # Pod-safe by construction: ONLY the leader consults it, at a
-        # group boundary, and the chosen engine rides the existing
-        # per-group pod announcement (engine_id) — so every process
-        # launches the same sharded program for the group and SPMD
-        # lockstep holds.  A pod deployed during congestion is no
-        # longer frozen on its startup probe for its whole lifetime.
-        self.engine_controller = engine_controller
         import threading
         # Group renders run on up to pipeline_depth concurrent worker
         # threads; without the lock a cold start would build (and
@@ -439,13 +430,10 @@ class MeshRenderer(BatchingRenderer):
         # The packed Huffman stream covers the full (H, W) grid, so the
         # wire-optimal engine applies only when every tile in the group
         # is grid-exact (same policy as ``render_batch_to_jpeg``);
-        # mixed groups fall back to the sparse engine as a whole.
-        # A live controller (jpeg-engine: auto) decides per group; the
-        # decision propagates to followers via the group announcement.
-        engine = (self.engine_controller.current()
-                  if self.engine_controller is not None
-                  else self.jpeg_engine)
-        return "huffman" if engine == "huffman" and all_exact \
+        # mixed groups fall back to the sparse engine as a whole.  The
+        # group's engine rides the pod announcement (engine_id), so
+        # followers replay the fall-back in lockstep.
+        return "huffman" if self.jpeg_engine == "huffman" and all_exact \
             else "sparse"
 
     def _render_group_jpeg(self, group: List[_Pending]) -> List[bytes]:

@@ -174,9 +174,6 @@ def build_services(config: AppConfig) -> "ImageRegionServices":
         # groups dispatch through the (data, chan) mesh steps.
         from ..parallel import cluster
         from ..parallel.serve import MeshRenderer
-        # config validation rejects bitpack in this posture; anything
-        # else invalid fails loudly in MeshRenderer's own check.
-        engine = config.renderer.jpeg_engine
         cluster.initialize(
             coordinator_address=config.parallel.coordinator_address,
             num_processes=config.parallel.num_processes,
@@ -200,73 +197,26 @@ def build_services(config: AppConfig) -> "ImageRegionServices":
         mesh = cluster.global_mesh(
             chan_parallel=config.parallel.chan_parallel,
             n_devices=config.parallel.n_devices)
-        mesh_controller = None
-        if engine == "auto":
-            # Probe strictly after cluster.initialize():
-            # jax.distributed must come up before anything touches a
-            # backend, or a multi-host pod degrades to per-host
-            # standalone meshes.  resolve_auto_engine is COLLECTIVE on
-            # a pod (every process, leader included, joins its
-            # allgather — pod-worker followers call it too; a
-            # leader-local probe here would strand them in the
-            # collective).  The LIVE controller then keeps the choice
-            # current pod-wide, seeded with the pod-agreed opening:
-            # only the leader consults it, at group boundaries, and
-            # the per-group engine rides the pod announcement so
-            # followers replay the identical launch (parallel/
-            # serve.py) — a pod deployed during congestion recovers
-            # instead of freezing on its startup probe.
-            from ..ops.jpegenc import set_fetch_observer
-            from ..utils.adaptive import AdaptiveEngine
-            from ..utils.linkprobe import resolve_auto_engine
-            engine = resolve_auto_engine()
-            mesh_controller = AdaptiveEngine(initial_engine=engine)
-            set_fetch_observer(mesh_controller.observe_fetch)
-        log.info("mesh serving enabled: %s (jpeg engine %s%s)",
-                 dict(mesh.shape), engine,
-                 ", live" if mesh_controller is not None else "")
+        log.info("mesh serving enabled: %s (jpeg engine %s)",
+                 dict(mesh.shape), config.renderer.jpeg_engine)
         renderer = MeshRenderer(
             mesh, max_batch=config.batcher.max_batch,
             max_batch_limit=config.batcher.max_batch_limit,
             linger_ms=config.batcher.linger_ms,
-            jpeg_engine=engine,
+            jpeg_engine=config.renderer.jpeg_engine,
             pipeline_depth=config.batcher.pipeline_depth,
-            engine_controller=mesh_controller,
             device_lanes=config.batcher.device_lanes)
     elif config.batcher.enabled:
-        # config validation rejects bitpack in this posture.
-        engine = config.renderer.jpeg_engine
-        controller = None
-        if engine == "auto":
-            # Startup probe picks the opening engine (sparse above
-            # ~12 MB/s device->host, huffman below); the controller
-            # then keeps the choice LIVE — per-fetch EWMA of the link
-            # rate, hysteresis flips, re-probe after idle.
-            from ..ops.jpegenc import set_fetch_observer
-            from ..utils.adaptive import AdaptiveEngine
-            from ..utils.linkprobe import measure_fetch_mb_s
-            controller = AdaptiveEngine(
-                initial_rate_mb_s=measure_fetch_mb_s())
-            set_fetch_observer(controller.observe_fetch)
-            engine = controller.engine
-            log.info("adaptive jpeg engine enabled (opening: %s)",
-                     engine)
         renderer = BatchingRenderer(
             max_batch=config.batcher.max_batch,
             max_batch_limit=config.batcher.max_batch_limit,
             linger_ms=config.batcher.linger_ms,
-            jpeg_engine=engine,
+            jpeg_engine=config.renderer.jpeg_engine,
             pipeline_depth=config.batcher.pipeline_depth,
-            engine_controller=controller,
             target_inflight=config.batcher.target_inflight,
             device_lanes=config.batcher.device_lanes)
     else:
-        engine = config.renderer.jpeg_engine
-        if engine == "auto":
-            from ..utils.linkprobe import resolve_auto_engine
-            engine = resolve_auto_engine()
-        renderer = Renderer(jpeg_engine=engine,
-                            kernel=config.renderer.kernel)
+        renderer = Renderer(jpeg_engine=config.renderer.jpeg_engine)
     # Say ONCE what this process serves from, and refuse the CPU
     # backend unless JAX_PLATFORMS asked for it: a server that found
     # no chip must not look like one that did.  The same document
@@ -377,10 +327,9 @@ def build_services(config: AppConfig) -> "ImageRegionServices":
     if (config.renderer.prewarm and config.batcher.enabled
             and not config.parallel.enabled):
         # Compile the listed shapes' serving programs so the first
-        # request of each shape doesn't pay 20-40 s of jit (adaptive
-        # deployments warm BOTH wire engines — the controller may flip
-        # mid-serving).  MeshRenderer is excluded: its sharded steps
-        # are warmed by the pod bring-up dryrun instead.
+        # request of each shape doesn't pay 20-40 s of jit.
+        # MeshRenderer is excluded: its sharded steps are warmed by
+        # the pod bring-up dryrun instead.
         #
         # On a BACKGROUND thread, flagged in telemetry.READINESS: the
         # listener binds immediately and /readyz answers 503 until the
@@ -396,13 +345,10 @@ def build_services(config: AppConfig) -> "ImageRegionServices":
             # never a background thread dying into a silently-unwarmed
             # "ready" service (YAML loads validate too; this covers
             # programmatic AppConfigs).
-        engines = (("sparse", "huffman")
-                   if renderer.engine_controller is not None
-                   else (renderer.jpeg_engine,))
         telemetry.READINESS.prewarm_pending = True
         threading.Thread(
             target=prewarm_renderer,
-            args=(list(config.renderer.prewarm), engines,
+            args=(list(config.renderer.prewarm), renderer.jpeg_engine,
                   renderer.max_batch, renderer.buckets),
             kwargs={"cpu_fallback_max_px":
                     config.renderer.cpu_fallback_max_px,
@@ -2720,11 +2666,7 @@ def main(argv=None) -> None:
             n_devices=config.parallel.n_devices)
         log.info("pod-worker device: %s",
                  jaxenv.device_identity(mesh.devices.flat))
-        engine = config.renderer.jpeg_engine
-        if engine == "auto":
-            from ..utils.linkprobe import resolve_auto_engine
-            engine = resolve_auto_engine()   # pod-agreed (allgathered)
-        run_pod_follower(mesh, jpeg_engine=engine)
+        run_pod_follower(mesh, jpeg_engine=config.renderer.jpeg_engine)
         return
     if args.role is not None:
         config.sidecar.role = args.role

@@ -199,8 +199,7 @@ class BatchingRenderer:
     def __init__(self, max_batch: int = 8, linger_ms: float = 2.0,
                  buckets=DEFAULT_BUCKETS, jpeg_engine: str = "sparse",
                  pipeline_depth: int = 4, max_batch_limit: int = None,
-                 engine_controller=None, target_inflight: int = 1,
-                 device_lanes: int = 2):
+                 target_inflight: int = 1, device_lanes: int = 2):
         if jpeg_engine not in ("sparse", "huffman"):
             raise ValueError(
                 f"batched jpeg engine must be 'sparse' or 'huffman', "
@@ -240,9 +239,6 @@ class BatchingRenderer:
         self.target_inflight = max(1, min(target_inflight,
                                           pipeline_depth))
         self.jpeg_engine = jpeg_engine
-        # Live engine selection (utils.adaptive.AdaptiveEngine); None =
-        # startup-static jpeg_engine.
-        self.engine_controller = engine_controller
         self.pipeline_depth = pipeline_depth
         self.buckets = tuple(buckets)
         self._queues: Dict[tuple, Deque[_Pending]] = {}
@@ -962,13 +958,6 @@ class BatchingRenderer:
         self._count_batch(n, raw.shape[0])
         return [host[i, :p.h, :p.w] for i, p in enumerate(group[:n])]
 
-    def _current_engine(self) -> str:
-        """This group's wire engine: the adaptive controller when one is
-        wired (jpeg-engine: auto), else the startup-static choice."""
-        if self.engine_controller is not None:
-            return self.engine_controller.current()
-        return self.jpeg_engine
-
     def _early_settle_cb(self, group: List[_Pending]):
         """First-tile-out hook for a JPEG group: resolve pending ``i``
         from the encode worker thread the moment its bytes exist.  The
@@ -1015,7 +1004,7 @@ class BatchingRenderer:
                     s0["cd_start"], s0["cd_end"], stack("tables"),
                     quality=group[0].quality,
                     dims=[(p.w, p.h) for p in group],  # pads skip encode
-                    engine=self._current_engine(),
+                    engine=self.jpeg_engine,
                     on_tile=self._early_settle_cb(group),
                     timings=timings,
                 )

@@ -34,15 +34,16 @@ class BatcherConfig:
     linger_ms: float = 2.0
     # Concurrent group renders per bucket key: group k+1's device
     # dispatch overlaps group k's wire fetch + host entropy encode.
-    # Default 4 was chosen where each fetch paid a long device-link
-    # round trip (scripts/exp_pipeline_depth.py is the A/B); not
-    # measured on the current chip.
+    # 4 is the value every benchmark cell runs.  Its A/B was taken
+    # over a device link that is gone; ROADMAP S3 measures it on the
+    # current chip.
     pipeline_depth: int = 4
     # Preferred concurrent group count under backlog: >1 makes the
     # dispatcher split a burst across that many wire streams instead
-    # of popping max_batch-sized convoys.  Default 1 (off);
-    # scripts/exp_inflight.py is the A/B, not measured on the current
-    # chip.  Single-host only; multi-host meshes always pop max_batch.
+    # of popping max_batch-sized convoys.  1 (off) is the value every
+    # benchmark cell runs; its A/B, too, was taken over the link that
+    # is gone, and ROADMAP S3 measures it.  Single-host only;
+    # multi-host meshes always pop max_batch.
     target_inflight: int = 1
     # Bounded device-execute stage of the two-stage group pipeline:
     # each group render splits into fetch/stage (stack + host->device
@@ -94,11 +95,11 @@ class RendererConfig:
     # B = 1 from the HBM raw cache.  ROADMAP S6 still wants the choice
     # made from queue depth and pixels, on other image classes too.
     cpu_fallback_max_px: int = 256 * 256 - 1
-    # Device JPEG wire format: "sparse" (18-bit coefficient entries +
-    # host entropy coding — wins on fast links), "huffman" (device
-    # fixed-table Huffman stream, ~3x fewer wire bytes — wins on slow or
-    # congested links; batcher-compatible), or "bitpack" (the legacy
-    # full-grid device Huffman; direct renderer only).
+    # Device JPEG wire format, a static choice of the deployment:
+    # "sparse" (18-bit coefficient entries + host entropy coding; what
+    # every benchmark cell runs) or "huffman" (device fixed-table
+    # Huffman stream, ~3x fewer wire bytes, less host coding).  The two
+    # have not been compared on the current chip.
     jpeg_engine: str = "sparse"
     # JAX persistent compilation cache directory: restarts reuse
     # compiled executables instead of paying first-compile (~20 s per
@@ -106,13 +107,6 @@ class RendererConfig:
     # (utils.jaxenv): JAX_COMPILATION_CACHE_DIR in the environment,
     # then this, then the fixed <checkout>/.jax_cache.
     compilation_cache_dir: Optional[str] = None
-    # Render kernel for the direct (unbatched) renderer: "xla" (the
-    # portable reference, ops.render) or "pallas" — the experimental
-    # VMEM-resident fused kernel: it serves ramp-weight renders (no
-    # LUT files), and a compile/runtime failure of the kernel FAILS
-    # the request — never a quiet switch to the XLA kernel.  "xla"
-    # stays the default; neither has been timed on the current chip.
-    kernel: str = "xla"
     # Tile shapes ("<channels>x<tile-edge>[@quality][:dtype]", e.g.
     # "4x1024" or "3x1024:uint8" — :dtype is the images' storage dtype,
     # default uint16) whose serving programs compile at STARTUP instead
@@ -1914,7 +1908,6 @@ class AppConfig:
                 "cpu-fallback-max-px", rd_defaults.cpu_fallback_max_px)),
             jpeg_engine=str(rd.get("jpeg-engine",
                                    rd_defaults.jpeg_engine)),
-            kernel=str(rd.get("kernel", rd_defaults.kernel)),
             compilation_cache_dir=(
                 str(rd["compilation-cache-dir"])
                 if rd.get("compilation-cache-dir") is not None
@@ -1924,23 +1917,18 @@ class AppConfig:
         from .prewarm import parse_spec
         for spec in cfg.renderer.prewarm:
             parse_spec(spec)   # malformed specs fail at load, not boot
-        if cfg.renderer.jpeg_engine not in ("sparse", "huffman",
-                                            "bitpack", "auto"):
+        if cfg.renderer.jpeg_engine in ("auto", "bitpack"):
             raise ValueError(
-                f"renderer.jpeg-engine must be 'sparse', 'huffman', "
-                f"'bitpack' or 'auto', got {cfg.renderer.jpeg_engine!r}")
-        if (cfg.renderer.jpeg_engine == "bitpack"
-                and (cfg.batcher.enabled or cfg.parallel.enabled)):
-            # Engine/posture parity: bitpack has no batched group form,
-            # so a config valid for the direct renderer must fail loudly
-            # at load time in the batched/mesh postures instead of
-            # silently serving a different engine.
+                f"renderer.jpeg-engine {cfg.renderer.jpeg_engine!r} was "
+                f"removed in PR 30: the wire form is a static choice, "
+                f"'sparse' or 'huffman'")
+        if rd.get("kernel") == "pallas":
             raise ValueError(
-                "renderer.jpeg-engine 'bitpack' is only supported by "
-                "the direct (unbatched) renderer; with batcher.enabled "
-                "or parallel.enabled use 'sparse', 'huffman' or 'auto'")
-        if cfg.renderer.kernel not in ("xla", "pallas"):
+                "renderer.kernel 'pallas' was removed in PR 30 with the "
+                "option itself: the render kernel is ops.render's; "
+                "delete the key")
+        if cfg.renderer.jpeg_engine not in ("sparse", "huffman"):
             raise ValueError(
-                f"renderer.kernel must be 'xla' or 'pallas', "
-                f"got {cfg.renderer.kernel!r}")
+                f"renderer.jpeg-engine must be 'sparse' or 'huffman', "
+                f"got {cfg.renderer.jpeg_engine!r}")
         return cfg

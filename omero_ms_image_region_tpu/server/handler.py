@@ -85,38 +85,19 @@ class Renderer:
     The micro-batcher (``server.batcher``) exposes the same ``render`` /
     ``render_jpeg`` coroutines and substitutes transparently.
 
-    ``jpeg_engine`` selects the device JPEG wire format: ``"sparse"``
-    (default — sparse coefficients + host entropy coding; wins on
-    slow/compressible links) or ``"bitpack"`` (fully device-packed
-    Huffman bitstream, host only 0xFF-stuffs; wins where device compute
-    is cheap relative to the link — see README "Status and known gaps").
+    ``jpeg_engine`` is the deployment's device JPEG wire format
+    (``renderer.jpeg-engine``); ``ops.jpegenc.render_batch_to_jpeg``
+    acts on it.
     """
 
-    # Bitpack encoders hold device-resident tables and a compiled kernel
-    # per (shape, quality); shapes and quality are client-controlled, so
-    # the cache is a small LRU, not an unbounded dict.
-    _MAX_BITPACK_ENCODERS = 8
-
-    def __init__(self, jpeg_engine: str = "sparse",
-                 kernel: str = "xla"):
-        if jpeg_engine not in ("sparse", "huffman", "bitpack"):
+    def __init__(self, jpeg_engine: str = "sparse"):
+        if jpeg_engine not in ("sparse", "huffman"):
             raise ValueError(f"unknown jpeg engine {jpeg_engine!r}")
-        if kernel not in ("xla", "pallas"):
-            raise ValueError(
-                f"unknown render kernel {kernel!r} ('xla' or 'pallas')")
         self.jpeg_engine = jpeg_engine
-        self.kernel = kernel
         # Per-member device pin (cross-host federation): when a fleet
         # member owns a device set, its renders dispatch there instead
         # of the process default device.  None = default device.
         self.device = None
-        import threading
-        from collections import OrderedDict
-        self._bitpack_encoders: "OrderedDict" = OrderedDict()
-        # render_jpeg runs on asyncio worker threads; concurrent requests
-        # for the same (H, W, quality) must not race the LRU bookkeeping
-        # (duplicate encoders each recompile; popitem can race an insert).
-        self._bitpack_lock = threading.Lock()
 
     async def render(self, raw: np.ndarray, settings: dict) -> np.ndarray:
         """f32[C, H, W] + packed settings -> u32[H, W] packed RGBA."""
@@ -132,17 +113,7 @@ class Renderer:
             return fn(*args)
 
     def _render_sync(self, raw: np.ndarray, settings: dict) -> np.ndarray:
-        # ``kernel: pallas`` routes ramp-weight renders to the Pallas
-        # kernel (LUT tables keep the XLA gather path by design).  The
-        # option means what it says: a kernel the backend refuses to
-        # compile or run FAILS the request — never a quiet switch to
-        # XLA that leaves the operator believing the option is on.
-        if self.kernel == "pallas" and settings["tables"].ndim == 2:
-            from ..experimental.pallas_render import (
-                render_tile_packed_pallas as render)
-        else:
-            render = render_tile_packed
-        out = render(
+        out = render_tile_packed(
             raw, settings["window_start"], settings["window_end"],
             settings["family"], settings["coefficient"],
             settings["reverse"], settings["cd_start"], settings["cd_end"],
@@ -171,41 +142,9 @@ class Renderer:
             raw = np.ascontiguousarray(raw)
         padded = pad_planes_to_mcu(raw)[None]
         args = batched_args(settings, padded)
-        # The bitpack stream covers the full padded grid, so it serves
-        # only MCU-aligned tiles; others take the sparse path (whose SOF0
-        # crop handles padding).
-        if (self.jpeg_engine == "bitpack"
-                and width % 16 == 0 and height % 16 == 0):
-            from ..ops.jpegenc import TpuJpegEncoder
-            H, W = padded.shape[-2:]
-            key = (H, W, quality)
-            with self._bitpack_lock:
-                enc = self._bitpack_encoders.get(key)
-                if enc is not None:
-                    self._bitpack_encoders.move_to_end(key)
-            if enc is None:
-                # Construct outside the lock (builds device tables);
-                # put-if-absent on completion so a racing thread's copy
-                # wins at most once.
-                built = TpuJpegEncoder(H, W, quality=quality)
-                with self._bitpack_lock:
-                    enc = self._bitpack_encoders.setdefault(key, built)
-                    self._bitpack_encoders.move_to_end(key)
-                    while (len(self._bitpack_encoders)
-                           > self._MAX_BITPACK_ENCODERS):
-                        self._bitpack_encoders.popitem(last=False)
-
-            def dense_fallback(i):
-                return render_batch_to_jpeg(
-                    *args, quality=quality, dims=[(width, height)])[0]
-            return enc.encode_batch(
-                *args, dense_fallback=dense_fallback)[0]
-        engine = (self.jpeg_engine
-                  if self.jpeg_engine in ("sparse", "huffman")
-                  else "sparse")
         return render_batch_to_jpeg(
             *args, quality=quality, dims=[(width, height)],
-            engine=engine)[0]
+            engine=self.jpeg_engine)[0]
 
 
 from .singleflight import SingleFlight  # noqa: E402,F401  (re-export;

@@ -18,7 +18,7 @@ defaults to the LocalCompress default; ``:dtype`` names the images'
 storage dtype, default uint16 — serving stages storage dtype in both
 cache postures, and the dtype keys the compiled program).  For every
 spec the serving-path programs are compiled through the real ops entry
-points with the renderer's own wire engine(s):
+points with the renderer's own wire engine:
 
 - the batched JPEG program at EVERY launchable padded batch shape up
   to the cap of the spec's bucket (``batcher.group_cap``: ``max_batch``
@@ -84,7 +84,7 @@ def parse_spec(spec: str) -> Tuple[int, int, int, "np.dtype"]:
 
 
 def _warm_one(C: int, edge: int, quality: int, batch_sizes: Sequence[int],
-              engines: Sequence[str], bucket: Tuple[int, int], raw_dtype,
+              engine: str, bucket: Tuple[int, int], raw_dtype,
               exec_cache=None) -> None:
     from ..flagship import flagship_settings
     from ..ops.jpegenc import render_batch_to_jpeg
@@ -105,14 +105,13 @@ def _warm_one(C: int, edge: int, quality: int, batch_sizes: Sequence[int],
                 stacked["family"], stacked["coefficient"],
                 stacked["reverse"], settings["cd_start"],
                 settings["cd_end"], stacked["tables"])
-        for engine in engines:
-            # tune=False: these all-zero compile probes must never
-            # feed the per-workload Huffman tuning — tables fitted to
-            # a black tile would be published permanently and serve
-            # every real tile of this shape with mismatched codes.
-            render_batch_to_jpeg(*args, quality=quality,
-                                 dims=[(edge, edge)] * B, engine=engine,
-                                 tune=False)
+        # tune=False: these all-zero compile probes must never feed
+        # the per-workload Huffman tuning — tables fitted to a black
+        # tile would be published permanently and serve every real
+        # tile of this shape with mismatched codes.
+        render_batch_to_jpeg(*args, quality=quality,
+                             dims=[(edge, edge)] * B, engine=engine,
+                             tune=False)
         if B == 1:
             if exec_cache is not None:
                 # Persistence posture: the packed program loads from a
@@ -139,7 +138,7 @@ def prewarm_batch_sizes(cap: int) -> tuple:
     return sizes if cap in sizes else sizes + (cap,)
 
 
-def prewarm_renderer(specs: List[str], engines: Sequence[str],
+def prewarm_renderer(specs: List[str], engine: str,
                      max_batch: int, buckets,
                      cpu_fallback_max_px: int = 0,
                      exec_cache=None) -> None:
@@ -176,7 +175,7 @@ def prewarm_renderer(specs: List[str], engines: Sequence[str],
             batch_sizes = prewarm_batch_sizes(
                 group_cap(max_batch, bucket[0] * bucket[1]))
             try:
-                _warm_one(C, edge, quality, batch_sizes, engines,
+                _warm_one(C, edge, quality, batch_sizes, engine,
                           bucket, raw_dtype, exec_cache=exec_cache)
             except Exception:
                 # Per-spec: one shape's dead compile must not strand
@@ -185,8 +184,8 @@ def prewarm_renderer(specs: List[str], engines: Sequence[str],
                                "this shape will compile lazily", spec,
                                exc_info=True)
             else:
-                logger.info("prewarmed %s (engines %s, batches %s, %s) "
-                            "in %.1fs", spec, "/".join(engines),
+                logger.info("prewarmed %s (engine %s, batches %s, %s) "
+                            "in %.1fs", spec, engine,
                             "/".join(map(str, batch_sizes)),
                             np.dtype(raw_dtype).name,
                             time.perf_counter() - t0)

@@ -121,36 +121,32 @@ def reference_overflow_mode() -> dict:
     }
 
 
-def serve_adaptive_mode(pid: int) -> dict:
-    """Pod-coordinated LIVE engine flip: the leader's AdaptiveEngine
-    observes a link-rate collapse between groups and the flip
-    propagates to the follower through the per-group announcement —
-    both processes must launch sparse for group 1 and huffman for
-    group 2."""
+def serve_mixed_mode(pid: int) -> dict:
+    """The mixed-dims fall-back, pod-wide: a ``huffman`` deployment
+    codes a group with a ragged tile ``sparse`` as a whole, and the
+    group's engine rides the per-group announcement — both processes
+    must launch huffman for the exact group and sparse for the mixed
+    one, though both were configured ``huffman``."""
     import hashlib
 
     from omero_ms_image_region_tpu.parallel import cluster
     from omero_ms_image_region_tpu.parallel.serve import (
         MeshRenderer, run_pod_follower)
-    from omero_ms_image_region_tpu.utils.adaptive import AdaptiveEngine
 
     launches = _spy_jpeg_launches()
     mesh = cluster.global_mesh(chan_parallel=2)
     if pid != 0:
-        groups = run_pod_follower(mesh, jpeg_engine="sparse")
+        groups = run_pod_follower(mesh, jpeg_engine="huffman")
         return {"follower_groups": groups, "launches": launches}
-    controller = AdaptiveEngine(initial_rate_mb_s=100.0)  # fast: sparse
-    renderer = MeshRenderer(mesh, jpeg_engine="sparse",
-                            engine_controller=controller)
+    renderer = MeshRenderer(mesh, jpeg_engine="huffman")
     jpegs1 = renderer._render_group_jpeg(_make_group(quality=85))
-    # Simulated link collapse: big fetches now crawl (1 MB in 2 s).
-    for _ in range(8):
-        controller.observe_fetch(1 << 20, 2.0)
-    jpegs2 = renderer._render_group_jpeg(_make_group(quality=85))
+    mixed = _make_group(quality=85)
+    mixed[-1].h, mixed[-1].w = 40, 48    # true dims inside the 64^2 grid
+    jpegs2 = renderer._render_group_jpeg(mixed)
     renderer._pod.announce(0)          # shutdown broadcast
     return {
         "launches": launches,
-        "engine_after": controller.engine,
+        "last_starts_soi": jpegs2[-1][:2] == b"\xff\xd8",
         "jpeg_sha": hashlib.sha256(
             b"".join(jpegs1 + jpegs2)).hexdigest(),
     }
@@ -257,8 +253,8 @@ def main() -> int:
         out.update({"pid": pid, "ok": True})
         print(json.dumps(out))
         return 0
-    if mode == "serve-adaptive":
-        out = serve_adaptive_mode(pid)
+    if mode == "serve-mixed":
+        out = serve_mixed_mode(pid)
         out.update({"pid": pid, "ok": True})
         print(json.dumps(out))
         return 0

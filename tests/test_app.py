@@ -352,21 +352,6 @@ class TestBatchedApp:
         for (w, h), body in zip(sizes, bodies):
             assert codecs.decode_to_rgba(body).shape == (h, w, 4)
 
-    def test_auto_engine_resolves_by_link_probe(self, data_dir,
-                                                monkeypatch):
-        """renderer.jpeg-engine='auto' probes the device->host link and
-        builds the batcher with sparse (fast link) or huffman (slow)."""
-        from omero_ms_image_region_tpu.utils import linkprobe
-
-        for rate, expect in ((500.0, "sparse"), (2.0, "huffman")):
-            monkeypatch.setattr(linkprobe, "measure_fetch_mb_s",
-                                lambda *a, rate=rate, **k: rate)
-            _, _, renderer = _gather_requests(data_dir, [
-                f"/webgateway/render_image_region/{IMG}/0/0"
-                "?tile=0,0,0,16,16&format=jpeg&m=c&c=1|0:60000$FF0000"
-            ], jpeg_engine="auto")
-            assert renderer.jpeg_engine == expect
-
 
 class TestPrewarm:
     def test_app_boots_with_prewarm_and_serves(self, data_dir):
@@ -385,6 +370,44 @@ class TestPrewarm:
         status, headers, body = r
         assert status == 200
         assert body[:2] == b"\xff\xd8"
+
+
+    @pytest.mark.parametrize("engine", ["sparse", "huffman"])
+    def test_prewarm_warms_the_one_configured_engine(
+            self, data_dir, engine, monkeypatch):
+        """build_services hands prewarm ``renderer.jpeg-engine`` as it
+        was configured: every warmed program is that engine's, and the
+        renderer serves it."""
+        import time
+
+        from omero_ms_image_region_tpu.ops import jpegenc
+        from omero_ms_image_region_tpu.server.app import build_services
+        from omero_ms_image_region_tpu.utils import telemetry
+
+        warmed = []
+        real = jpegenc.render_batch_to_jpeg
+
+        def spy(*args, **kw):
+            warmed.append((kw["engine"], args[0].shape))
+            return real(*args, **kw)
+
+        monkeypatch.setattr(jpegenc, "render_batch_to_jpeg", spy)
+        config = AppConfig(data_dir=data_dir)
+        # One 1024^2 bucket at max-batch 1: one batch shape to compile.
+        config.renderer.prewarm = ("1x1024",)
+        config.renderer.jpeg_engine = engine
+        config.batcher.max_batch = 1
+        services = build_services(config)
+        try:
+            t_end = time.monotonic() + 300
+            while (telemetry.READINESS.prewarm_pending
+                   and time.monotonic() < t_end):
+                time.sleep(0.05)
+            assert not telemetry.READINESS.prewarm_pending
+            assert services.renderer.jpeg_engine == engine
+            assert warmed == [(engine, (1, 1, 1024, 1024))]
+        finally:
+            asyncio.run(services.renderer.close())
 
 
 class TestUncachedPosturesMatch:

@@ -193,6 +193,81 @@ class TestBatchingRenderer:
         run(main())
 
 
+def _golden_decoded(raw, rdef, width, height, quality):
+    """PIL's decode of the JFIF that ``refimpl`` + the host coders give
+    for one tile: the reference render of the edge-padded planes, the
+    same coefficient front end, the dense entropy coder."""
+    import io
+
+    from PIL import Image
+
+    from omero_ms_image_region_tpu.ops.jpegenc import (
+        dense_encoder, pad_planes_to_mcu, quant_tables,
+        rgb_to_jpeg_coefficients)
+    from omero_ms_image_region_tpu.refimpl import render_ref
+
+    rgba = render_ref(pad_planes_to_mcu(raw), rdef)
+    qy, qc = (t.astype(np.int32) for t in quant_tables(quality))
+    y, cb, cr = (np.asarray(a)[0] for a in rgb_to_jpeg_coefficients(
+        rgba[None, ..., :3].astype(np.float32), qy, qc))
+    body = dense_encoder()(y, cb, cr, width, height, quality)
+    return np.asarray(Image.open(io.BytesIO(body)).convert("RGB"))
+
+
+@pytest.mark.parametrize("dims", [(64, 64), (56, 40)],
+                         ids=["exact", "ragged"])
+@pytest.mark.parametrize("kind", ["direct", "batched"])
+@pytest.mark.parametrize("engine", ["sparse", "huffman"])
+def test_both_engines_through_both_single_chip_renderers(engine, kind,
+                                                         dims):
+    """The engine string goes from the constructor to
+    ``render_batch_to_jpeg`` untouched: either renderer, under either
+    engine, answers with the coefficients of the reference render (the
+    decoded pixels are the golden's, whatever Huffman tables framed
+    them), and a ``huffman`` batcher codes a tile smaller than its
+    bucket's grid ``sparse``, byte for byte."""
+    import io
+
+    from PIL import Image
+
+    from omero_ms_image_region_tpu.flagship import (
+        flagship_settings, synthetic_wsi_tiles)
+
+    width, height = dims
+    rdef, settings = flagship_settings(2)
+    # Soft content, inside the default wire caps (no dense fall-back).
+    raw = (synthetic_wsi_tiles(np.random.default_rng(42), 1, 2, height,
+                               width)[0].astype(np.float32)
+           / 8.0 + 15000.0)
+
+    def build(eng):
+        if kind == "direct":
+            return Renderer(jpeg_engine=eng)
+        return BatchingRenderer(jpeg_engine=eng, linger_ms=0.0,
+                                buckets=((64, 64),))
+
+    async def serve(eng):
+        renderer = build(eng)
+        assert renderer.jpeg_engine == eng
+        try:
+            return await renderer.render_jpeg(raw, settings, 85, width,
+                                              height)
+        finally:
+            if kind == "batched":
+                await renderer.close()
+
+    body = run(serve(engine))
+    got = np.asarray(Image.open(io.BytesIO(body)).convert("RGB"))
+    assert got.shape == (height, width, 3)
+    np.testing.assert_array_equal(
+        got, _golden_decoded(raw, rdef, width, height, 85))
+    if engine == "huffman":
+        # The Huffman stream covers a group's whole grid: the direct
+        # renderer's grid is the tile's own, the batcher's its bucket's.
+        fell_back = kind == "batched" and dims == (56, 40)
+        assert (body == run(serve("sparse"))) == fell_back
+
+
 class TestPipelining:
     def test_groups_overlap_up_to_depth(self):
         """With pipeline_depth=2, a second group dispatches while the
@@ -517,7 +592,7 @@ class TestPrewarm:
             prewarm_renderer,
         )
 
-        prewarm_renderer(["3x64"], ("sparse",), max_batch=2,
+        prewarm_renderer(["3x64"], "sparse", max_batch=2,
                          buckets=((64, 64),))
 
         settings = _settings()
@@ -550,9 +625,9 @@ class TestPrewarm:
         # malformed specs (the loader's contract) ...
         import pytest as _pytest
         with _pytest.raises(ValueError):
-            prewarm_renderer(["0x64"], ("sparse",), 2, ((64, 64),))
+            prewarm_renderer(["0x64"], "sparse", 2, ((64, 64),))
         # ... but a VALID spec whose compile dies is logged, not fatal.
-        prewarm_renderer(["3x64"], ("no-such-engine",), 2, ((64, 64),))
+        prewarm_renderer(["3x64"], "no-such-engine", 2, ((64, 64),))
 
     def test_prewarm_skips_cpu_fallback_shapes_and_dtype_specs(self):
         """Shapes the CPU fallback serves are skipped (their device
@@ -564,7 +639,80 @@ class TestPrewarm:
 
         # 64*64 = 4096 <= threshold -> skipped (returns instantly even
         # with a bogus engine that would fail compile).
-        prewarm_renderer(["3x64"], ("no-such-engine",), 2, ((64, 64),),
+        prewarm_renderer(["3x64"], "no-such-engine", 2, ((64, 64),),
                          cpu_fallback_max_px=64 * 64)
         # Non-default storage dtype (uint8 sources) compiles fine.
-        prewarm_renderer(["3x64:uint8"], ("sparse",), 2, ((64, 64),))
+        prewarm_renderer(["3x64:uint8"], "sparse", 2, ((64, 64),))
+
+
+class TestQueuePressure:
+    def test_queue_pressure_grows_batch(self):
+        """Sustained full-batch backlog doubles max_batch up to the
+        limit; light load never grows it."""
+        from omero_ms_image_region_tpu.flagship import flagship_rdef
+        from omero_ms_image_region_tpu.ops.render import pack_settings
+        from omero_ms_image_region_tpu.server.batcher import (
+            BatchingRenderer)
+
+        # A 1024^2 bucket, where max_batch counts renders as it is
+        # written (a smaller bucket's cap is a multiple: group_cap).
+        r = BatchingRenderer(max_batch=2, linger_ms=1.0,
+                             max_batch_limit=8,
+                             buckets=((1024, 1024),))
+        rdef = flagship_rdef(1)
+        settings = pack_settings(rdef)
+        rng = np.random.default_rng(1)
+
+        async def flood(n):
+            raws = [rng.uniform(0, 60000, (1, 32, 32)).astype(
+                np.float32) for _ in range(n)]
+            return await asyncio.gather(
+                *[r.render(raw, settings) for raw in raws])
+
+        loop = asyncio.new_event_loop()
+        try:
+            out = loop.run_until_complete(flood(64))
+            assert len(out) == 64
+            assert 2 < r.max_batch <= 8
+        finally:
+            loop.run_until_complete(r.close())
+            loop.close()
+
+
+class TestLingerBypass:
+    def test_lone_idle_request_skips_linger(self, monkeypatch):
+        """A single request on an idle renderer dispatches immediately
+        (single-tile p50 must not pay the coalescing linger)."""
+        from omero_ms_image_region_tpu.flagship import flagship_rdef
+        from omero_ms_image_region_tpu.ops.render import pack_settings
+        from omero_ms_image_region_tpu.server.batcher import (
+            BatchingRenderer)
+
+        sleeps = []
+        real_sleep = asyncio.sleep
+
+        async def spy_sleep(s):
+            if s > 0:
+                sleeps.append(s)
+            await real_sleep(0)
+
+        r = BatchingRenderer(max_batch=8, linger_ms=50.0)
+        rdef = flagship_rdef(1)
+        settings = pack_settings(rdef)
+        raw = np.zeros((1, 32, 32), np.float32)
+
+        async def one():
+            monkeypatch.setattr(asyncio, "sleep", spy_sleep)
+            try:
+                return await r.render(raw, settings)
+            finally:
+                monkeypatch.setattr(asyncio, "sleep", real_sleep)
+
+        loop = asyncio.new_event_loop()
+        try:
+            out = loop.run_until_complete(one())
+            assert out.shape == (32, 32)
+            assert 0.05 not in sleeps    # the linger was bypassed
+        finally:
+            loop.run_until_complete(r.close())
+            loop.close()

@@ -126,22 +126,6 @@ def test_mask_pyramid_projection_programs_compile(shape):
         shape((8, 2048, 2048), "uint16"), alg=0))
 
 
-@pytest.mark.parametrize("tables", [(3,), (256, 3)],
-                         ids=["ramp", "lut"])
-def test_pallas_kernel_compiles(shape, tables):
-    """Both Pallas forms at 1 x 4 x 1024^2.  The ramp kernel's scalar
-    ``powf`` and the LUT kernel's 4-row block were refused by Mosaic
-    for as long as only interpret mode was looking."""
-    from omero_ms_image_region_tpu.experimental.pallas_render import (
-        render_tile_batch_packed_pallas)
-    compiled = _compiled(render_tile_batch_packed_pallas.lower(
-        shape((1, C, H, W), "float32"), shape((C,), "float32"),
-        shape((C,), "float32"), shape((C,), "int32"),
-        shape((C,), "float32"), shape((C,), "int32"), 0, 255,
-        shape((C,) + tables, "float32")))
-    assert "tpu_custom_call" in compiled.as_text()
-
-
 def test_mesh_jpeg_step_compiles_with_all_reduce(topo):
     """The (data=2, chan=2) serving step on the described 2x2 mesh: the
     channel composite must be there as a collective."""

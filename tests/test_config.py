@@ -114,15 +114,29 @@ class TestAppConfig:
         assert cfg.caches.shape_mask is False
 
 
-def test_jpeg_engine_auto_accepted():
-    import pytest
+@pytest.mark.parametrize("renderer, names", [
+    ({"jpeg-engine": "auto"}, "renderer.jpeg-engine 'auto'"),
+    ({"jpeg-engine": "bitpack"}, "renderer.jpeg-engine 'bitpack'"),
+    ({"kernel": "pallas"}, "renderer.kernel 'pallas'"),
+])
+def test_removed_render_options_are_rejected(renderer, names):
+    """A YAML that still asks for what PR 30 removed fails at load, in
+    every posture, with a message that names the key and the removal."""
+    for posture in ({}, {"batcher": {"enabled": False}}):
+        with pytest.raises(ValueError) as e:
+            AppConfig.from_dict({"renderer": renderer, **posture})
+        assert names in str(e.value) and "removed in PR 30" in str(e.value)
 
-    from omero_ms_image_region_tpu.server.config import AppConfig
 
-    cfg = AppConfig.from_dict({"renderer": {"jpeg-engine": "auto"}})
-    assert cfg.renderer.jpeg_engine == "auto"
-    with pytest.raises(ValueError):
+def test_jpeg_engine_is_one_of_two_and_kernel_is_no_field():
+    for engine in ("sparse", "huffman"):
+        cfg = AppConfig.from_dict({"renderer": {"jpeg-engine": engine}})
+        assert cfg.renderer.jpeg_engine == engine
+    with pytest.raises(ValueError, match="'sparse' or 'huffman'"):
         AppConfig.from_dict({"renderer": {"jpeg-engine": "turbo"}})
+    # A bare ``kernel: xla`` is a key the loader does not know.
+    cfg = AppConfig.from_dict({"renderer": {"kernel": "xla"}})
+    assert not hasattr(cfg.renderer, "kernel")
 
 
 def test_pipeline_depth_validated_at_load():
@@ -160,24 +174,6 @@ def test_compilation_cache_dir_config():
         {"renderer": {"compilation-cache-dir": "/tmp/jc"}})
     assert cfg.renderer.compilation_cache_dir == "/tmp/jc"
     assert AppConfig().renderer.compilation_cache_dir is None
-
-
-def test_bitpack_engine_rejected_in_batched_postures():
-    """Engine/posture parity (VERDICT r3 item 8): bitpack is valid only
-    for the direct renderer; batched/mesh configs fail at load time."""
-    import pytest
-
-    from omero_ms_image_region_tpu.server.config import AppConfig
-
-    base = {"renderer": {"jpeg-engine": "bitpack"}}
-    # Direct posture: fine.
-    cfg = AppConfig.from_dict({**base, "batcher": {"enabled": False}})
-    assert cfg.renderer.jpeg_engine == "bitpack"
-    with pytest.raises(ValueError, match="bitpack"):
-        AppConfig.from_dict({**base, "batcher": {"enabled": True}})
-    with pytest.raises(ValueError, match="bitpack"):
-        AppConfig.from_dict({**base, "batcher": {"enabled": False},
-                             "parallel": {"enabled": True}})
 
 
 def test_max_batch_limit_parses():

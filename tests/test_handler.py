@@ -134,19 +134,6 @@ class TestImageRegionHandler:
         assert jpg[..., 1].astype(int).sum() > 5 * jpg[..., 0].astype(
             int).sum()
 
-    def test_bitpack_engine_decodes_identically(self, services):
-        """Both JPEG engines carry the same coefficients, so the decoded
-        pixels are identical; only the Huffman tables differ."""
-        from dataclasses import replace
-        bp = replace(services, renderer=Renderer(jpeg_engine="bitpack"),
-                     caches=Caches.from_config(CacheConfig.enabled_all()))
-        ctx = {"tile": "0,0,0,32,32", "m": "c", "format": "jpeg"}
-        sparse = codecs.decode_to_rgba(run(
-            ImageRegionHandler(services).render_image_region(_ctx(**ctx))))
-        bitpack = codecs.decode_to_rgba(run(
-            ImageRegionHandler(bp).render_image_region(_ctx(**ctx))))
-        np.testing.assert_array_equal(sparse, bitpack)
-
     def test_cpu_fallback_for_tiny_renders(self, services):
         """Renders at or below cpu_fallback_max_px take the refimpl path
         and must match the device path within codec tolerance."""

@@ -402,76 +402,6 @@ def test_fixed_huffman_spec_is_complete_and_valid():
     assert max(dc_len.max(), ac_len.max()) <= 16
 
 
-@pytest.mark.parametrize("seed,H,W,q", [(7, 64, 64, 85), (8, 32, 48, 75),
-                                        (9, 16, 16, 95)])
-def test_device_bitpack_matches_python_fixed(seed, H, W, q):
-    from omero_ms_image_region_tpu.flagship import batched_args
-    from omero_ms_image_region_tpu.models.pixels import Pixels
-    from omero_ms_image_region_tpu.models.rendering import (
-        RenderingModel, default_rendering_def,
-    )
-    from omero_ms_image_region_tpu.ops.jpegenc import TpuJpegEncoder
-    from omero_ms_image_region_tpu.ops.render import pack_settings
-
-    rng = np.random.default_rng(seed)
-    C = 3
-    pixels = Pixels(image_id=1, size_x=W, size_y=H, size_c=C,
-                    pixels_type="uint16")
-    rdef = default_rendering_def(pixels)
-    rdef.model = RenderingModel.RGB
-    for i, cb in enumerate(rdef.channel_bindings):
-        cb.active = True
-        cb.red, cb.green, cb.blue = [(255, 0, 0), (0, 255, 0),
-                                     (0, 0, 255)][i]
-        cb.input_start, cb.input_end = 0.0, 65535.0
-    settings = pack_settings(rdef)
-    raw = rng.integers(0, 65535, size=(2, C, H, W)).astype(np.uint16)
-    args = batched_args(settings, raw)[1:]
-
-    # Uniform-noise tiles exceed the realistic-content default cap.
-    enc = TpuJpegEncoder(H, W, quality=q, cap_bytes=H * W * 8)
-    got = enc.encode_batch(raw, *args)
-
-    from omero_ms_image_region_tpu.ops.render import render_tile_batch_packed
-    packed = np.asarray(render_tile_batch_packed(raw, *args))
-    qy, qc = quant_tables(q)
-    y, cb_, cr = [np.asarray(a) for a in packed_to_jpeg_coefficients(
-        packed, qy.astype(np.int32), qc.astype(np.int32))]
-    want = [encode_jfif(y[i], cb_[i], cr[i], W, H, q, huffman="fixed")
-            for i in range(2)]
-    assert got == want
-    dec = Image.open(io.BytesIO(got[0])).convert("RGB")
-    assert dec.size == (W, H)
-
-
-def test_bitpack_overflow_detected():
-    from omero_ms_image_region_tpu.flagship import batched_args
-    from omero_ms_image_region_tpu.ops.jpegenc import TpuJpegEncoder
-    from omero_ms_image_region_tpu.models.pixels import Pixels
-    from omero_ms_image_region_tpu.models.rendering import (
-        RenderingModel, default_rendering_def,
-    )
-    from omero_ms_image_region_tpu.ops.render import pack_settings
-
-    rng = np.random.default_rng(1)
-    pixels = Pixels(image_id=1, size_x=32, size_y=32, size_c=3,
-                    pixels_type="uint16")
-    rdef = default_rendering_def(pixels)
-    rdef.model = RenderingModel.RGB
-    for i, cb in enumerate(rdef.channel_bindings):
-        cb.active = True
-        cb.red, cb.green, cb.blue = [(255, 0, 0), (0, 255, 0),
-                                     (0, 0, 255)][i]
-        cb.input_start, cb.input_end = 0.0, 65535.0
-    raw = rng.integers(0, 65535, size=(1, 3, 32, 32)).astype(np.uint16)
-    args = batched_args(pack_settings(rdef), raw)[1:]
-    enc = TpuJpegEncoder(32, 32, quality=95, cap_bytes=64)
-    with pytest.raises(ValueError, match="overflow"):
-        enc.encode_batch(raw, *args)
-    fb = enc.encode_batch(raw, *args, dense_fallback=lambda i: b"\xff\xd8x")
-    assert fb == [b"\xff\xd8x"]
-
-
 def test_encode_tiles_jpeg_batch():
     imgs = np.stack([blob_image(32, 32, seed=s) for s in range(3)])
     packed = pack(imgs)
@@ -569,6 +499,84 @@ def test_high_quality_widens_wire_caps():
         je._CAP_MEMO.clear()
 
 
+def test_huffman_rescuable_overflow_widens_once_and_memoizes():
+    """The huffman engine's half of the one-shot widening: a group
+    whose entries or bits land in (cap, 2 x cap] is dispatched once
+    more with BOTH caps doubled and no dense re-render, and the memo
+    starts the next group of that shape and quality at the doubled
+    caps."""
+    import omero_ms_image_region_tpu.ops.jpegenc as je
+
+    rng = np.random.default_rng(40)
+    B, C, H, W, Q = 2, 1, 64, 64, 80
+    noisy = rng.integers(0, 65535, size=(B, C, H, W)).astype(np.float32)
+    ws = np.zeros((B, C), np.float32)
+    we = np.full((B, C), 65535.0, np.float32)
+    fam = np.zeros((B, C), np.int32)
+    coef = np.ones((B, C), np.float32)
+    rev = np.zeros((B, C), np.int32)
+    tables = np.ones((B, C, 3), np.float32)
+    cap, words = je.default_sparse_cap(H, W, Q), je.default_words_cap(
+        H, W, Q)
+    spec = je.huffman_spec_arrays()
+    qy, qc = (np.asarray(t, np.int32) for t in je.quant_tables(Q))
+
+    def probe(raw):
+        bufs = np.asarray(je.render_to_jpeg_huffman(
+            raw, ws, we, fam, coef, rev, 0, 255, tables, qy, qc, *spec,
+            h16=H // 16, w16=W // 16, cap=je.max_sparse_cap(H, W),
+            cap_words=H * W))
+        return je.wire_header_i32(bufs, 0), je.wire_header_i32(bufs, 1)
+
+    mid = None
+    for band in range(2, W + 1, 2):
+        cand = np.zeros((B, C, H, W), np.float32)
+        cand[:, :, :, :band] = noisy[:, :, :, :band]
+        totals, bits = probe(cand)
+        over = (totals > cap) | (bits > words * 32)
+        fits_doubled = (totals <= 2 * cap) & (bits <= 2 * words * 32)
+        if (over & fits_doubled).all():
+            mid = cand
+            break
+    assert mid is not None, "no mid-density band found"
+
+    launches, dense_calls = [], []
+    orig = je.render_to_jpeg_huffman_compact
+    orig_coeff = je.render_to_jpeg_coefficients
+
+    def spy(*args, **kwargs):
+        launches.append((kwargs["cap"], kwargs["cap_words"]))
+        return orig(*args, **kwargs)
+
+    def spy_coeff(*args, **kwargs):
+        if isinstance(args[0], np.ndarray):     # host calls, not traces
+            dense_calls.append(1)
+        return orig_coeff(*args, **kwargs)
+
+    def run():
+        launches.clear()
+        jpegs = je.render_batch_to_jpeg(
+            mid, ws, we, fam, coef, rev, 0, 255, tables, quality=Q,
+            dims=[(W, H)] * B, engine="huffman", tune=False)
+        for j in jpegs:
+            assert Image.open(io.BytesIO(j)).size == (W, H)
+        return list(launches)
+
+    je.render_to_jpeg_huffman_compact = spy
+    je.render_to_jpeg_coefficients = spy_coeff
+    je._CAP_MEMO.clear()
+    try:
+        assert run() == [(cap, words), (2 * cap, 2 * words)]
+        assert run() == [(2 * cap, 2 * words)]
+        assert not dense_calls
+        # The sparse engine's memo is its own key.
+        assert ("sparse", H, W, Q) not in je._CAP_MEMO
+    finally:
+        je.render_to_jpeg_huffman_compact = orig
+        je.render_to_jpeg_coefficients = orig_coeff
+        je._CAP_MEMO.clear()
+
+
 # ---------------------------------------------------- compacted wire
 
 class TestCompactWire:
@@ -622,9 +630,10 @@ class TestCompactWire:
             row = compact[offs[i]:offs[i + 1]]
             np.testing.assert_array_equal(row, full[i, :need])
 
-    def test_huffman_rows_match_uncompacted(self):
+    @pytest.mark.parametrize("B", [1, 2, 3, 4, 6, 8])
+    def test_huffman_rows_match_uncompacted(self, B):
         from omero_ms_image_region_tpu.ops import jpegenc as je
-        B, C, H, W = 3, 1, 32, 32
+        C, H, W = 1, 32, 32
         raw, ws, we, fam, coef, rev, tables = self._args(B, C, H, W, 1)
         qy, qc = (np.asarray(t, np.int32) for t in quant_tables(85))
         cap = je.max_sparse_cap(H, W)
@@ -708,6 +717,74 @@ class TestCompactWire:
         hr = f.headroom
         f.fetch(buf)
         assert f.headroom <= hr
+
+    @pytest.mark.parametrize("engine", ["sparse", "huffman"])
+    def test_under_predicted_fetch_serves_the_same_bytes(self, engine):
+        """A group whose prefix prediction fell short pays one
+        ``wire.fetch2`` and answers with the bytes a well-predicted
+        fetch gives; the miss retrains the shared fetcher."""
+        from omero_ms_image_region_tpu.ops import jpegenc as je
+        from omero_ms_image_region_tpu.utils.stopwatch import REGISTRY
+        # A quality no other test serves at this shape: the cap memo
+        # and the fetcher of this key are this test's own.
+        B, C, H, W, Q = 4, 2, 32, 32, 83
+        raw, ws, we, fam, coef, rev, tables = self._args(B, C, H, W, 6)
+
+        def second_fetches():
+            return REGISTRY.snapshot().get("wire.fetch2",
+                                           {}).get("count", 0)
+
+        def serve():
+            before = second_fetches()
+            jpegs = je.render_batch_to_jpeg(
+                raw, ws, we, fam, coef, rev, 0, 255, tables, quality=Q,
+                dims=[(W, H)] * B, engine=engine, tune=False)
+            return jpegs, second_fetches() - before
+
+        f = je.compact_fetcher(
+            engine, H, W, je.default_sparse_cap(H, W, Q),
+            je.default_words_cap(H, W, Q) if engine == "huffman" else 0,
+            B)
+        f._k = f.hdr                 # the prefix holds the lengths only
+        missed, n_missed = serve()
+        assert n_missed == 1 and f.headroom > f.HEADROOM_FLOOR
+        again, n_again = serve()
+        assert n_again == 0
+        assert missed == again
+        for j in missed:
+            assert Image.open(io.BytesIO(j)).size == (W, H)
+
+    def test_fetches_feed_the_link_gauge(self, monkeypatch):
+        """Every fetch reports to the ``/metrics`` link gauge: the
+        first of a dispatched program as conflated with its execution,
+        the follow-up of an under-predicted prefix as the wire alone;
+        a gauge that raises never breaks the fetch."""
+        from omero_ms_image_region_tpu.ops import jpegenc as je
+        from omero_ms_image_region_tpu.utils import telemetry
+        B, C, H, W = 4, 2, 32, 32
+        raw, ws, we, fam, coef, rev, tables = self._args(B, C, H, W, 7)
+        qy, qc = (np.asarray(t, np.int32) for t in quant_tables(85))
+        cap = je.max_sparse_cap(H, W)
+        buf = je.render_to_jpeg_sparse_compact(
+            raw, ws, we, fam, coef, rev, 0, 255, tables, qy, qc,
+            np.int32(B), cap=cap)
+        seen = []
+        monkeypatch.setattr(
+            telemetry.LINK, "observe",
+            lambda n, s, conflated=False: seen.append((n, conflated)))
+        f = je.CompactWireFetcher(B, je.sparse_wire_width(H, W, cap))
+        f._k = f.hdr
+        rows = f.fetch(buf)
+        total = f.hdr + sum(len(r) for r in rows)
+        assert [c for _, c in seen] == [True, False]
+        assert seen[0][0] == f.hdr and f.hdr + seen[1][0] >= total
+
+        def broken(*a, **k):
+            raise RuntimeError("gauge down")
+
+        monkeypatch.setattr(telemetry.LINK, "observe", broken)
+        for got, want in zip(f.fetch(buf), rows):
+            np.testing.assert_array_equal(got, want)
 
     def test_batch_to_jpeg_end_to_end_decodable(self):
         from omero_ms_image_region_tpu.ops import jpegenc as je

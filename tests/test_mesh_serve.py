@@ -381,3 +381,26 @@ class TestMeshOverflowLockstep:
         assert plain == jpegs1 == jpegs2
         run(renderer.close())
         je._CAP_MEMO.clear()
+
+
+def test_mesh_multihost_disables_batch_growth(monkeypatch):
+    """Host-local max_batch growth would diverge multi-host SPMD
+    launches; the mesh renderer disables it when process_count > 1."""
+    import jax
+
+    from omero_ms_image_region_tpu.parallel.mesh import (
+        make_mesh, resolve_devices)
+    from omero_ms_image_region_tpu.parallel.serve import MeshRenderer
+
+    if len(resolve_devices(8)) < 8:
+        pytest.skip("no 8-wide device pool")
+    monkeypatch.setattr(jax, "process_count", lambda: 2)
+    r = MeshRenderer(make_mesh(8, chan_parallel=1))
+    assert r._growth_enabled is False
+    r2 = BatchingRendererForTest()
+    assert r2._growth_enabled is True
+
+
+def BatchingRendererForTest():
+    from omero_ms_image_region_tpu.server.batcher import BatchingRenderer
+    return BatchingRenderer(max_batch=2, linger_ms=0.0)

@@ -135,16 +135,15 @@ def test_two_process_pod_overflow_rescue_stays_in_lockstep():
     assert ref["jpeg_sha"] == leader["jpeg_sha"]
 
 
-def test_two_process_pod_flips_engine_on_link_change():
-    """Pod-coordinated adaptive wire engine: the leader's controller
-    observes a simulated link-rate collapse between groups and the
-    engine flip rides the per-group pod announcement — both processes
-    launch sparse for group 1 and huffman for group 2, in lockstep
-    (the r4 gap: a pod froze its startup-probed engine for life)."""
-    outs = _run_workers("serve-adaptive", (0, 1))
+def test_two_process_pod_replays_mixed_dims_fallback():
+    """A ``huffman`` pod codes a group of mixed dims ``sparse`` as a
+    whole; the group's engine rides the per-group pod announcement, so
+    leader and follower (both configured ``huffman``) launch huffman
+    for the exact group and sparse for the mixed one, in lockstep."""
+    outs = _run_workers("serve-mixed", (0, 1))
     leader, follower = outs[0], outs[1]
     assert follower["follower_groups"] == 2
-    assert leader["engine_after"] == "huffman"
+    assert leader["last_starts_soi"]
     assert leader["launches"] == follower["launches"]
     engines = [launch[0] for launch in leader["launches"]]
-    assert engines == ["sparse", "huffman"]
+    assert engines == ["huffman", "sparse"]

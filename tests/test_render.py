@@ -2,6 +2,7 @@
 composition, batching."""
 
 import numpy as np
+import pytest
 
 from omero_ms_image_region_tpu.models.pixels import Pixels
 from omero_ms_image_region_tpu.models.rendering import (
@@ -14,9 +15,12 @@ from omero_ms_image_region_tpu.models.rendering import (
 )
 from omero_ms_image_region_tpu.ops.lut import LutProvider
 from omero_ms_image_region_tpu.ops.render import (
+    build_channel_tables,
     pack_settings,
     render_tile,
     render_tile_batch,
+    render_tile_batch_packed,
+    unpack_rgba,
 )
 from omero_ms_image_region_tpu.refimpl import render_ref
 
@@ -204,3 +208,86 @@ def test_default_rendering_def_matches_reference_defaults():
     assert (cb.red, cb.green, cb.blue, cb.alpha) == (255, 0, 0, 255)
     assert rdef.model == RenderingModel.GREYSCALE
     assert rdef.quantum.cd_start == 0 and rdef.quantum.cd_end == 255
+
+
+# ---- the served kernel (render_tile_batch_packed, what the batcher
+# and both JPEG programs call) against the reference, with each tile
+# of the batch under its own window as a coalesced group has them.
+
+_FAMILIES = ["linear", "polynomial", "logarithmic", "exponential"]
+
+
+def _tile_rdef(C, family, b):
+    rdef = _rdef(C=C)
+    for i, cb in enumerate(rdef.channel_bindings):
+        cb.active = True
+        cb.family = Family(family)
+        cb.coefficient = 1.3 if family in ("polynomial",
+                                           "exponential") else 1.0
+        cb.input_start = 200.0 + 150.0 * b
+        cb.input_end = 50000.0 - 4000.0 * b
+        cb.reverse_intensity = i == 2
+    return rdef
+
+
+def _batched_vs_reference(B, C, H, W, family="linear", form="tables",
+                          seed=0, lut_provider=None, lut=None):
+    rng = np.random.default_rng(seed)
+    raw = rng.integers(0, 65535, size=(B, C, H, W)).astype(np.float32)
+    rdefs = [_tile_rdef(C, family, b) for b in range(B)]
+    if lut is not None:
+        for rdef in rdefs:
+            rdef.channel_bindings[0].lut = lut
+    packs = [pack_settings(r, lut_provider) for r in rdefs]
+    if form == "ramp":
+        assert all(s["tables"].ndim == 2 for s in packs)   # f32[C, 3]
+        tables = [s["tables"] for s in packs]
+    else:
+        tables = [build_channel_tables(r, lut_provider) for r in rdefs]
+        assert all(t.shape == (C, 256, 3) for t in tables)
+
+    def stack(k):
+        return np.stack([s[k] for s in packs])
+
+    got = unpack_rgba(render_tile_batch_packed(
+        raw, stack("window_start"), stack("window_end"), stack("family"),
+        stack("coefficient"), stack("reverse"), packs[0]["cd_start"],
+        packs[0]["cd_end"], np.stack(tables)))
+    assert got.shape == (B, H, W, 4)
+    for b in range(B):
+        want = render_ref(raw[b], rdefs[b], lut_provider)
+        assert np.abs(got[b].astype(int) - want.astype(int)).max() <= 2
+
+
+@pytest.mark.parametrize("C", [1, 3, 4])
+@pytest.mark.parametrize("family", _FAMILIES)
+def test_batched_kernel_families_against_reference(C, family):
+    _batched_vs_reference(2, C, 16, 64, family=family, seed=C)
+
+
+@pytest.mark.parametrize("B", [1, 2, 5])
+@pytest.mark.parametrize("H,W", [(16, 64), (40, 32), (96, 128), (272, 64)])
+def test_batched_kernel_shapes_against_reference(B, H, W):
+    _batched_vs_reference(B, 2, H, W, seed=B * H)
+
+
+@pytest.mark.parametrize("C", [3, 4])
+@pytest.mark.parametrize("family", _FAMILIES)
+def test_batched_kernel_ramp_weights_against_reference(C, family):
+    _batched_vs_reference(2, C, 16, 64, family=family, form="ramp",
+                          seed=11 + C)
+
+
+@pytest.mark.parametrize("B,H,W", [(1, 16, 64), (3, 96, 128)])
+def test_batched_kernel_ramp_weights_shapes(B, H, W):
+    _batched_vs_reference(B, 2, H, W, form="ramp", seed=B + H)
+
+
+def test_batched_kernel_lut_file_tables_against_reference():
+    lp = LutProvider()
+    table = np.zeros((256, 3), np.uint8)
+    table[:, 1] = np.arange(256)
+    table[:, 2] = 255 - np.arange(256)
+    lp.add("green_up_blue_down.lut", table)
+    _batched_vs_reference(2, 2, 16, 64, seed=9, lut_provider=lp,
+                          lut="green_up_blue_down.lut")

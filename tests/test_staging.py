@@ -192,7 +192,7 @@ def test_prewarm_compiles_nothing_from_staging(caplog):
     from omero_ms_image_region_tpu.server.prewarm import prewarm_renderer
 
     with jax.log_compiles(), caplog.at_level(logging.WARNING):
-        prewarm_renderer(["1x768@37"], ("sparse",), max_batch=2,
+        prewarm_renderer(["1x768@37"], "sparse", max_batch=2,
                          buckets=((768, 768),))
     compiled = set(re.findall(r"Compiling (?:jit\()?(\w+)", caplog.text))
     assert "render_to_jpeg_sparse_compact" in compiled

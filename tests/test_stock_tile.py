@@ -324,7 +324,7 @@ def test_prewarm_compiles_the_stock_shape_at_every_batch_size(
     monkeypatch.setattr(jpegenc, "render_batch_to_jpeg", spy)
     with caplog.at_level(logging.INFO):
         prewarm_renderer(
-            ["4x256@90"], ("sparse",), max_batch=8,
+            ["4x256@90"], "sparse", max_batch=8,
             buckets=batcher_mod.DEFAULT_BUCKETS,
             cpu_fallback_max_px=RendererConfig().cpu_fallback_max_px)
     assert [s[0][0] for s in seen] == [1, 2, 3, 4, 6, 8, 16, 32, 64]

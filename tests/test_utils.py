@@ -53,33 +53,3 @@ def test_guava_hex_formatting():
 )
 def test_split_html_color(color, expect):
     assert split_html_color(color) == expect
-
-
-class TestLinkProbe:
-    def test_measure_returns_positive_rate(self):
-        from omero_ms_image_region_tpu.utils.linkprobe import (
-            measure_fetch_mb_s)
-
-        rate = measure_fetch_mb_s(nbytes=1 << 16, repeats=2)
-        assert rate > 0
-
-    def test_resolve_auto_engine_thresholds(self, monkeypatch):
-        from omero_ms_image_region_tpu.utils import linkprobe
-
-        for rate, expect in ((500.0, "sparse"), (1.0, "huffman")):
-            monkeypatch.setattr(linkprobe, "measure_fetch_mb_s",
-                                lambda *a, r=rate, **k: r)
-            assert linkprobe.resolve_auto_engine() == expect
-
-    def test_resolve_auto_engine_probe_failure_is_an_error(
-            self, monkeypatch):
-        """A probe that cannot reach the device raises — never an
-        "infinite link rate" that quietly picks an engine."""
-        from omero_ms_image_region_tpu.utils import linkprobe
-
-        def boom(*a, **k):
-            raise RuntimeError("no device")
-
-        monkeypatch.setattr(linkprobe, "measure_fetch_mb_s", boom)
-        with pytest.raises(RuntimeError, match="no device"):
-            linkprobe.resolve_auto_engine()

@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import functools
 import threading
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -1347,45 +1348,36 @@ def huffman_wire_fetcher(H: int, W: int, cap: int,
         return f
 
 
-def render_batch_to_jpeg(raw, window_start, window_end, family, coefficient,
-                         reverse, cd_start, cd_end, tables, quality: int,
-                         dims, cap: int | None = None,
+class GroupWire(NamedTuple):
+    """A group's wire rows in host memory, with what the host half of
+    :func:`render_batch_to_jpeg` needs to frame them: the engine that
+    coded them (a ``huffman`` group with a bucket-padded tile comes back
+    ``sparse``), the caps the rows were packed under (after the one-shot
+    widening), the tuned tables' frame spec (``huffman``; None = the
+    fixed profile) and ``dense_coefficients(i) -> (y, cb, cr)``, the
+    one-tile program a tile that overflowed twice falls back to."""
+    engine: str
+    rows: list
+    cap: int
+    cap_words: int
+    frame_spec: object
+    dims: list
+    H: int
+    W: int
+    quality: int
+    dense_coefficients: object
+
+
+def render_batch_to_wire(raw, window_start, window_end, family,
+                         coefficient, reverse, cd_start, cd_end, tables,
+                         quality: int, dims, cap: int | None = None,
                          engine: str = "sparse",
-                         tune: bool = True, on_tile=None,
-                         timings: dict = None) -> list:
-    """Serving-path helper: one batched device dispatch -> JFIF per tile.
-
-    ``raw`` is [B, C, H, W] with H, W multiples of 16 (callers edge-pad;
-    render is pointwise so padding commutes with it) and per-tile settings
-    stacked along B as in :func:`render_to_jpeg_sparse`.  ``dims`` gives
-    each tile's true ``(width, height)`` written into its SOF0 header —
-    the decoder crops the MCU padding away.  A tile whose own ceil-16
-    grid is smaller than (H, W) (spatial bucketing bounding the compile
-    set) is entropy-coded from the top-left block subgrid on the host.
-    Overflowing tiles re-run through the dense coefficient path.
-
-    ``engine`` selects the device wire format: ``"sparse"`` (18-bit
-    coefficient entries + host entropy coding — wins on fast links) or
-    ``"huffman"`` (device fixed-table Huffman, ~3x fewer wire bytes —
-    wins on slow/congested links).  The packed Huffman stream covers the
-    full (H, W) grid, so a group containing bucket-padded tiles (true
-    grid smaller than (H, W)) falls back to the sparse engine as a
-    whole — one dispatch either way, never per-tile re-renders.
-
-    ``on_tile(i, jpeg_bytes)`` (optional) fires the moment tile ``i``'s
-    encode slice lands — the batcher's first-tile-out settlement hook:
-    tile 0's waiter can be answered while tile N-1 is still entropy
-    coding, instead of every waiter parking behind the batch tail.  The
-    bytes passed are EXACTLY the returned list's entry (byte-identity is
-    the streaming contract); callback exceptions are the caller's.
-
-    ``timings`` (optional dict): ``device_ms`` gains the milliseconds of
-    the spans ``device.dispatch`` (the jitted call until it returns,
-    and the wire fetcher's slice after it: Python dispatch, argument
-    upload, a trace or compile that falls into them) and
-    ``device.wait`` (the program running to its end), for the batcher's
-    cost ledger.
-    """
+                         timings: dict = None) -> GroupWire:
+    """The device half of :func:`render_batch_to_jpeg` (its arguments):
+    one batched dispatch and the fetch of its wire rows, and a second of
+    both where a tile overflowed its cap.  When it returns the device
+    has nothing left to do for the group, which is where the batcher
+    lets go of its device lane."""
     B, C, H, W = raw.shape
 
     def dispatched(span) -> None:
@@ -1411,7 +1403,7 @@ def render_batch_to_jpeg(raw, window_start, window_end, family, coefficient,
                     and (w_ + 15) // 16 * 16 == W for (w_, h_) in dims)
     if engine == "huffman" and all_exact:
         # Tuned per-workload tables when ready (fixed profile until
-        # then, and forever if tuning failed); the framing below must
+        # then, and forever if tuning failed); the host half must
         # declare whichever tables coded the stream.
         tuned = _TUNED_TABLES.get((H, W, quality))
         if tuned is not None:
@@ -1454,25 +1446,8 @@ def render_batch_to_jpeg(raw, window_start, window_end, family, coefficient,
             _CAP_MEMO[memo_key] = True
             cap, cap_words = cap * 2, cap_words * 2
             rows = dispatch_huffman(cap, cap_words)
-
-        _dense_encode = dense_encoder()
-
-        def dense_tile(i):
-            # Still overflowing at 2x: re-encode from dense coefficients.
-            w_, h_ = dims[i]
-            return _dense_encode(*dense_coefficients(i), w_, h_, quality)
-
-        if tuned is None and tune:
-            # One-time background tuning from this workload's first
-            # group (a single dense-coefficient sample).  ``tune=False``
-            # callers (prewarm's all-zero compile probes) must never
-            # seed the tables real traffic will be served with.
-            _maybe_start_tuning((H, W, quality), dense_coefficients)
-        with stopwatch("jfif.encodeBatch"):
-            return finish_huffman_batch(
-                rows, dims, H, W, quality, cap, cap_words,
-                dense_fallback=dense_tile, spec=frame_spec,
-                on_tile=on_tile)
+        return GroupWire("huffman", rows, cap, cap_words, frame_spec,
+                         dims, H, W, quality, dense_coefficients)
 
     def dispatch_sparse(c):
         fetcher = compact_fetcher("sparse", H, W, c, 0, B)
@@ -1496,11 +1471,94 @@ def render_batch_to_jpeg(raw, window_start, window_end, family, coefficient,
         _CAP_MEMO[memo_key] = True
         cap = cap * 2
         rows = dispatch_sparse(cap)
+    return GroupWire("sparse", rows, cap, 0, None, dims, H, W, quality,
+                     dense_coefficients)
 
+
+def finish_wire_to_jpegs(wire: GroupWire, tune: bool = True,
+                         on_tile=None) -> list:
+    """The host half of :func:`render_batch_to_jpeg`: the fetched rows
+    of :func:`render_batch_to_wire` -> JFIF per tile
+    (``jfif.encodeBatch``).  Nothing here runs on the device but the
+    rare ``dense_coefficients(i)`` of a tile that overflowed twice."""
+    rows, cap, dims, H, W, quality = (wire.rows, wire.cap, wire.dims,
+                                      wire.H, wire.W, wire.quality)
+    if wire.engine == "huffman":
+        _dense_encode = dense_encoder()
+
+        def dense_tile(i):
+            # Still overflowing at 2x: re-encode from dense coefficients.
+            w_, h_ = dims[i]
+            return _dense_encode(*wire.dense_coefficients(i), w_, h_,
+                                 quality)
+
+        if wire.frame_spec is None and tune:
+            # One-time background tuning from this workload's first
+            # group (a single dense-coefficient sample).  ``tune=False``
+            # callers (prewarm's all-zero compile probes) must never
+            # seed the tables real traffic will be served with.
+            _maybe_start_tuning((H, W, quality), wire.dense_coefficients)
+        with stopwatch("jfif.encodeBatch"):
+            return finish_huffman_batch(
+                rows, dims, H, W, quality, cap, wire.cap_words,
+                dense_fallback=dense_tile, spec=wire.frame_spec,
+                on_tile=on_tile)
     with stopwatch("jfif.encodeBatch"):
         return finish_sparse_to_jpegs(rows, dims, H, W, quality, cap,
-                                      dense_coefficients,
+                                      wire.dense_coefficients,
                                       on_tile=on_tile)
+
+
+def render_batch_to_jpeg(raw, window_start, window_end, family, coefficient,
+                         reverse, cd_start, cd_end, tables, quality: int,
+                         dims, cap: int | None = None,
+                         engine: str = "sparse",
+                         tune: bool = True, on_tile=None,
+                         timings: dict = None) -> list:
+    """Serving-path helper: one batched device dispatch -> JFIF per tile.
+
+    ``raw`` is [B, C, H, W] with H, W multiples of 16 (callers edge-pad;
+    render is pointwise so padding commutes with it) and per-tile settings
+    stacked along B as in :func:`render_to_jpeg_sparse`.  ``dims`` gives
+    each tile's true ``(width, height)`` written into its SOF0 header —
+    the decoder crops the MCU padding away.  A tile whose own ceil-16
+    grid is smaller than (H, W) (spatial bucketing bounding the compile
+    set) is entropy-coded from the top-left block subgrid on the host.
+    Overflowing tiles re-run through the dense coefficient path.
+
+    ``engine`` selects the device wire format: ``"sparse"`` (18-bit
+    coefficient entries + host entropy coding — wins on fast links) or
+    ``"huffman"`` (device fixed-table Huffman, ~3x fewer wire bytes —
+    wins on slow/congested links).  The packed Huffman stream covers the
+    full (H, W) grid, so a group containing bucket-padded tiles (true
+    grid smaller than (H, W)) falls back to the sparse engine as a
+    whole — one dispatch either way, never per-tile re-renders.
+
+    ``on_tile(i, jpeg_bytes)`` (optional) fires the moment tile ``i``'s
+    encode slice lands — the batcher's first-tile-out settlement hook:
+    tile 0's waiter can be answered while tile N-1 is still entropy
+    coding, instead of every waiter parking behind the batch tail.  The
+    bytes passed are EXACTLY the returned list's entry (byte-identity is
+    the streaming contract); callback exceptions are the caller's.
+
+    ``timings`` (optional dict): ``device_ms`` gains the milliseconds of
+    the spans ``device.dispatch`` (the jitted call until it returns,
+    and the wire fetcher's slice after it: Python dispatch, argument
+    upload, a trace or compile that falls into them) and
+    ``device.wait`` (the program running to its end), for the batcher's
+    cost ledger.
+
+    It is its two halves called in turn: :func:`render_batch_to_wire`
+    (everything the device does) and :func:`finish_wire_to_jpegs` (the
+    host's entropy coding).  The batcher calls them apart, with a device
+    lane around the first only.
+    """
+    return finish_wire_to_jpegs(
+        render_batch_to_wire(
+            raw, window_start, window_end, family, coefficient, reverse,
+            cd_start, cd_end, tables, quality, dims, cap=cap,
+            engine=engine, timings=timings),
+        tune=tune, on_tile=on_tile)
 
 
 def finish_sparse_to_jpegs(bufs, dims, H: int, W: int, quality: int,

@@ -331,11 +331,14 @@ class BatchingRenderer:
     def _lane(self):
         """Hold one of the ``device_lanes``.  The wait for it is a span
         of its own: with ``pipeline_depth`` > ``device_lanes`` it is a
-        queue, and no other span sees it."""
+        queue, and no other span sees it.  So is the hold, acquire to
+        release: lanes x tiles a group / hold is the rate the gate
+        allows."""
         with stopwatch("batcher.laneWait"):
             self._device_gate.acquire()
         try:
-            yield
+            with stopwatch("batcher.laneHold"):
+                yield
         finally:
             self._device_gate.release()
 
@@ -589,13 +592,14 @@ class BatchingRenderer:
         """Drain the key's queue into group renders.
 
         Up to ``pipeline_depth`` group renders run concurrently (each on
-        its own worker thread), and each render is itself two stages —
-        fetch/stage (stack + host->device upload) then device-execute —
-        connected by the bounded ``device_lanes`` gate.  Group k+1's
-        upload and group k's wire fetch / host entropy encode overlap
-        group k's device execute (the render functions release the GIL
-        in those stages), so the device never idles behind host or wire
-        work under sustained load.
+        its own worker thread), and each render is three stages:
+        fetch/stage (stack + host->device upload), device-execute
+        (dispatch, wait, the wire rows' copy to the host) and, for a
+        JPEG group, the host's entropy coding.  Only the middle one
+        holds one of the ``device_lanes``: group k+1's upload and group
+        k-1's entropy coding (native code, off the GIL) overlap group
+        k's device execute.  A slot, unlike a lane, is kept through the
+        group's serial entropy tail.
         """
         # The loop task was created from some request's context; detach
         # so dispatcher-side spans never attach to that one waterfall.
@@ -986,7 +990,12 @@ class BatchingRenderer:
         return on_tile
 
     def _render_group_jpeg(self, group: List[_Pending]) -> List[bytes]:
-        from ..ops.jpegenc import render_batch_to_jpeg
+        """``ops.jpegenc.render_batch_to_jpeg`` in its two halves: the
+        lane is held while the device works for the group (dispatch,
+        wait, the rows' copy to the host) and let go before the host
+        codes them, so the next group's program starts under this
+        group's entropy tail."""
+        from ..ops.jpegenc import finish_wire_to_jpegs, render_batch_to_wire
 
         n = len(group)
         REGISTRY.record("batcher.groupTiles", float(n))
@@ -995,9 +1004,13 @@ class BatchingRenderer:
         shape = _shape_label(raw.shape, jpeg=True)
         from ..io.staging import pin_scope
         timings: Dict[str, float] = {}
-        with self._lane(), pin_scope(self.device):
+        # The pin covers the host half too: a tile that overflowed its
+        # cap twice dispatches a one-tile program from there, with no
+        # lane (as the mesh path's ``_dense_coefficients``).
+        with pin_scope(self.device), contextlib.ExitStack() as lane:
+            lane.enter_context(self._lane())
             with stopwatch("Renderer.renderAsPackedInt.batch"):
-                jpegs = render_batch_to_jpeg(
+                wire = render_batch_to_wire(
                     raw, stack("window_start"), stack("window_end"),
                     stack("family"), stack("coefficient"),
                     stack("reverse"),
@@ -1005,13 +1018,15 @@ class BatchingRenderer:
                     quality=group[0].quality,
                     dims=[(p.w, p.h) for p in group],  # pads skip encode
                     engine=self.jpeg_engine,
-                    on_tile=self._early_settle_cb(group),
                     timings=timings,
                 )
+                lane.close()        # the device's work is over
+                jpegs = finish_wire_to_jpegs(
+                    wire, on_tile=self._early_settle_cb(group))
         # Observed-only for JPEG groups (the host wrapper has no single
         # compiled program to cost-analyze): the dispatch and the wait
-        # for the program, without the copy out and the host entropy
-        # coding that follow them under the same lane.
+        # for the program, without the copy out under the same lane or
+        # the host entropy coding after it.
         exec_ms = timings.get("device_ms", 0.0)
         telemetry.add_cost("device_ms", exec_ms / n)
         telemetry.SHAPE_COSTS.observe(shape, exec_ms)

@@ -32,11 +32,12 @@ class BatcherConfig:
     # on the current chip.
     max_batch_limit: Optional[int] = None
     linger_ms: float = 2.0
-    # Concurrent group renders per bucket key: group k+1's device
-    # dispatch overlaps group k's wire fetch + host entropy encode.
-    # 4 is the value every benchmark cell runs.  Its A/B was taken
-    # over a device link that is gone; ROADMAP S3 measures it on the
-    # current chip.
+    # Concurrent group renders per bucket key, each on a worker thread
+    # from its stacking to the last byte of its host entropy coding
+    # (which holds no device lane since PR 31, so group k+1's device
+    # work overlaps group k's entropy tail).  4 is the value every
+    # benchmark cell runs.  Its A/B was taken over a device link that
+    # is gone; ROADMAP S3 measures it on the current chip.
     pipeline_depth: int = 4
     # Preferred concurrent group count under backlog: >1 makes the
     # dispatcher split a burst across that many wire streams instead
@@ -45,13 +46,15 @@ class BatcherConfig:
     # is gone, and ROADMAP S3 measures it.  Single-host only;
     # multi-host meshes always pop max_batch.
     target_inflight: int = 1
-    # Bounded device-execute stage of the two-stage group pipeline:
-    # each group render splits into fetch/stage (stack + host->device
-    # upload) and device-execute halves, and at most this many groups
-    # occupy the execute stage at once.  Default 2 (double-buffered):
-    # group N+1's upload overlaps group N's execute without letting
-    # every pipeline_depth group pile onto the device.  Multi-host
-    # meshes force 1 (SPMD launch order).
+    # Bounded device-execute stage of the group pipeline: a group
+    # render is fetch/stage (stack + host->device upload), then
+    # device-execute (the jitted call until the group's wire rows are
+    # in host memory), then for JPEG the host's entropy coding, and at
+    # most this many groups occupy the execute stage at once; the
+    # other two hold no lane (span batcher.laneHold is the hold).
+    # Default 2 (double-buffered): group N+1's program is queued
+    # behind group N's without letting every pipeline_depth group pile
+    # onto the device.  Multi-host meshes force 1 (SPMD launch order).
     device_lanes: int = 2
 
 

@@ -478,6 +478,224 @@ class TestTwoStagePipeline:
         assert run(gauge())
 
 
+class TestLaneCoversTheDeviceHalfOnly:
+    """A JPEG group holds its device lane from the jitted call until
+    its wire rows are in host memory (``render_batch_to_wire``) and not
+    through the host's entropy coding (``finish_wire_to_jpegs``)."""
+
+    @staticmethod
+    def _tile(seed):
+        rng = np.random.default_rng(seed)
+        return rng.integers(0, 60000, size=(3, 32, 32)).astype(np.float32)
+
+    @staticmethod
+    def _batcher(**kw):
+        # A 64^2 bucket: small programs.  Each request is sent alone
+        # (linger 0, an idle renderer skips it), so a group is a tile.
+        return BatchingRenderer(linger_ms=0.0, buckets=((64, 64),), **kw)
+
+    def test_next_device_half_enters_under_a_blocked_host_half(
+            self, monkeypatch):
+        """device_lanes=1, pipeline_depth=2: while the first group's
+        host half blocks, the second group's device half is entered
+        (before PR 31 it waited for the lane until the first group's
+        last byte)."""
+        import threading
+
+        from omero_ms_image_region_tpu.ops import jpegenc
+
+        host_entered, release = threading.Event(), threading.Event()
+        second_device_entered = threading.Event()
+        device_calls = []
+        real_wire = jpegenc.render_batch_to_wire
+        real_finish = jpegenc.finish_wire_to_jpegs
+
+        def wire(*args, **kw):
+            device_calls.append(1)
+            if len(device_calls) == 2:
+                second_device_entered.set()
+            return real_wire(*args, **kw)
+
+        def finish(*args, **kw):
+            first = not host_entered.is_set()
+            host_entered.set()
+            if first:
+                release.wait(timeout=60)
+            return real_finish(*args, **kw)
+
+        monkeypatch.setattr(jpegenc, "render_batch_to_wire", wire)
+        monkeypatch.setattr(jpegenc, "finish_wire_to_jpegs", finish)
+        settings = _settings()
+        a, b = self._tile(21), self._tile(22)
+
+        async def main():
+            batcher = self._batcher(pipeline_depth=2, device_lanes=1)
+            try:
+                first = asyncio.ensure_future(
+                    batcher.render_jpeg(a, settings, 85, 32, 32))
+                assert await asyncio.to_thread(host_entered.wait, 60)
+                second = asyncio.ensure_future(
+                    batcher.render_jpeg(b, settings, 85, 32, 32))
+                entered = await asyncio.to_thread(
+                    second_device_entered.wait, 20)
+                blocked = not first.done()
+                release.set()
+                return entered, blocked, await first, await second
+            finally:
+                release.set()
+                await batcher.close()
+
+        entered, blocked, first, second = run(main())
+        assert blocked      # the first host half had not returned
+        assert entered      # and the lane was free all the same
+        direct = Renderer()
+        assert first == run(direct.render_jpeg(a, settings, 85, 32, 32))
+        assert second == run(direct.render_jpeg(b, settings, 85, 32, 32))
+
+    def test_device_halves_never_overlap_under_one_lane(self,
+                                                        monkeypatch):
+        """The gate still bounds what it is for: with device_lanes=1
+        two groups' device halves never run at once, whatever their
+        host halves do."""
+        import threading
+        import time as _t
+
+        from omero_ms_image_region_tpu.ops import jpegenc
+
+        lock = threading.Lock()
+        concurrent = {"now": 0, "peak": 0, "calls": 0}
+        real_wire = jpegenc.render_batch_to_wire
+
+        def wire(*args, **kw):
+            with lock:
+                concurrent["now"] += 1
+                concurrent["calls"] += 1
+                concurrent["peak"] = max(concurrent["peak"],
+                                         concurrent["now"])
+            try:
+                _t.sleep(0.05)      # force overlap if the gate leaked
+                return real_wire(*args, **kw)
+            finally:
+                with lock:
+                    concurrent["now"] -= 1
+
+        monkeypatch.setattr(jpegenc, "render_batch_to_wire", wire)
+        settings = _settings()
+        # Different channel counts never share a group: two groups.
+        _, s4 = flagship_settings(4)
+        raw4 = np.random.default_rng(23).integers(
+            0, 60000, size=(4, 32, 32)).astype(np.float32)
+
+        async def main():
+            batcher = self._batcher(pipeline_depth=2, device_lanes=1)
+            try:
+                return await asyncio.gather(
+                    batcher.render_jpeg(self._tile(24), settings, 85,
+                                        32, 32),
+                    batcher.render_jpeg(raw4, s4, 85, 32, 32))
+            finally:
+                await batcher.close()
+
+        outs = run(main())
+        assert all(o[:2] == b"\xff\xd8" for o in outs)
+        assert concurrent["calls"] == 2 and concurrent["peak"] == 1
+
+    def test_lane_released_when_either_half_raises(self, monkeypatch):
+        """The device half of one group raises, the host half of the
+        next: both fail their waiters, and a third group renders on the
+        only lane."""
+        from omero_ms_image_region_tpu.ops import jpegenc
+
+        calls = {"wire": 0, "finish": 0}
+        real_wire = jpegenc.render_batch_to_wire
+        real_finish = jpegenc.finish_wire_to_jpegs
+
+        def wire(*args, **kw):
+            calls["wire"] += 1
+            if calls["wire"] == 1:
+                raise ValueError("device half down")
+            return real_wire(*args, **kw)
+
+        def finish(*args, **kw):
+            calls["finish"] += 1
+            if calls["finish"] == 1:
+                raise ValueError("host half down")
+            return real_finish(*args, **kw)
+
+        monkeypatch.setattr(jpegenc, "render_batch_to_wire", wire)
+        monkeypatch.setattr(jpegenc, "finish_wire_to_jpegs", finish)
+        settings = _settings()
+        raw = self._tile(25)
+
+        async def main():
+            batcher = self._batcher(pipeline_depth=1, device_lanes=1)
+            try:
+                for half in ("device", "host"):
+                    with pytest.raises(ValueError, match=half):
+                        await batcher.render_jpeg(raw, settings, 85,
+                                                  32, 32)
+                third = await asyncio.wait_for(
+                    batcher.render_jpeg(raw, settings, 85, 32, 32), 60)
+                # The lane is back: the only one can be taken at once.
+                free = batcher._device_gate.acquire(blocking=False)
+                if free:
+                    batcher._device_gate.release()
+                return third, free
+            finally:
+                await batcher.close()
+
+        third, free = run(main())
+        assert free and calls == {"wire": 3, "finish": 2}
+        assert third == run(Renderer().render_jpeg(raw, settings, 85,
+                                                   32, 32))
+
+    def test_group_trace_holds_the_lane_hold_around_the_device_spans(
+            self):
+        """A served JPEG group's trace holds ``batcher.laneHold``;
+        ``device.dispatch``, ``device.wait`` and ``wire.d2h`` begin and
+        end inside it, ``jfif.encodeBatch`` begins after it ends."""
+        from omero_ms_image_region_tpu.utils import telemetry
+
+        settings = _settings()
+        raw = self._tile(26)
+
+        async def main():
+            batcher = self._batcher()
+            with telemetry.trace_scope(telemetry.new_trace_id(),
+                                       "test") as trace:
+                try:
+                    await batcher.render_jpeg(raw, settings, 85, 32, 32)
+                finally:
+                    # close() awaits the group's tail: first-tile-out
+                    # answers before ``jfif.encodeBatch`` has closed.
+                    await batcher.close()
+            return trace
+
+        trace = run(main())
+        spans = {}
+        for s in trace.export_spans():
+            spans.setdefault(s["name"], []).append(
+                (s["start_ms"], s["start_ms"] + s["dur_ms"]))
+        for name in ("batcher.laneWait", "batcher.laneHold",
+                     "device.dispatch", "device.wait", "wire.d2h",
+                     "jfif.encodeBatch",
+                     "Renderer.renderAsPackedInt.batch"):
+            assert len(spans.get(name, [])) == 1, (name, sorted(spans))
+        # A span's start is its end less its duration, each rounded to
+        # the microsecond.
+        eps = 0.01
+        hold = spans["batcher.laneHold"][0]
+        for name in ("device.dispatch", "device.wait", "wire.d2h"):
+            start, end = spans[name][0]
+            assert hold[0] - eps <= start and end <= hold[1] + eps, name
+        assert spans["batcher.laneWait"][0][1] <= hold[0] + eps
+        assert spans["jfif.encodeBatch"][0][0] >= hold[1] - eps
+        # The batch span still covers both halves, and not the wait.
+        batch = spans["Renderer.renderAsPackedInt.batch"][0]
+        assert hold[0] - eps <= batch[0]
+        assert batch[1] + eps >= spans["jfif.encodeBatch"][0][1]
+
+
 class TestTransientRetry:
     """One host-local retry of a group whose dispatch died on a
     transient transport error (utils.transient: a JaxRuntimeError

@@ -53,6 +53,39 @@ def test_benchmark_file(rehearsal_root, which, check):
     getattr(file_tests, check)((bench, root, traffic))
 
 
+def test_lane_hold_ms_is_added_at_the_end_and_reads_the_lanes_span():
+    """PR 31's per-layer metric: a list entry after everything the
+    benchmark had, in all three cells, and a file that hands the span
+    ``batcher.laneHold`` to the reader the other span means use."""
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    entry = bench["per_layer"][-1]
+    assert entry == {
+        "name": "lane_hold_ms", "unit": "ms", "better": "lower",
+        "source": "program_span", "layer": "batcher",
+        "moves": "renders_per_s",
+        "workloads": [w["name"] for w in bench["workloads"]]}
+    with open(os.path.join(REPO, "benchmark", "layer_metrics",
+                           "lane_hold_ms.json")) as f:
+        spec = json.load(f)
+    assert spec == {
+        "name": "lane_hold_ms", "layer": "batcher", "unit": "ms",
+        "moves": "renders_per_s", "source": "program_span",
+        "reader": "span_mean", "args": {"span": "batcher.laneHold"}}
+    # The span is the batcher's own, recorded by the gate itself.
+    from omero_ms_image_region_tpu.server.batcher import BatchingRenderer
+    from omero_ms_image_region_tpu.utils.stopwatch import REGISTRY
+
+    def holds():
+        return REGISTRY.snapshot().get("batcher.laneHold",
+                                       {}).get("count", 0)
+
+    before = holds()
+    with BatchingRenderer()._lane():
+        assert holds() == before        # recorded at release
+    assert holds() == before + 1
+
+
 @pytest.mark.parametrize("cell", rehearsal.CELLS)
 @pytest.mark.parametrize("name", PER_CELL)
 def test_a_cell_the_harness_had(tmp_path, rehearsal_root, name, cell):

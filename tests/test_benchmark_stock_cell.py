@@ -39,8 +39,9 @@ def test_the_new_entries_are_the_issues():
     cell = bench["workloads"][-1]
     assert (cell["name"], cell["config"], cell["traffic"],
             cell["chips"]) == (CELL, "stock4-u16-t256", "pan", 1)
-    assert [m["name"] for m in bench["per_layer"][-3:]] == list(
-        NEW_METRICS)
+    names = [m["name"] for m in bench["per_layer"]]
+    first = names.index(NEW_METRICS[0])
+    assert names[first:first + 3] == list(NEW_METRICS)
     listed = {m["name"] for m in bench["per_layer"]
               if CELL in m["workloads"]}
     assert listed == {m["name"] for m in bench["per_layer"]} - {

@@ -1268,3 +1268,113 @@ def test_compact_rows_matches_ragged_concat(B, pattern):
     ragged = np.concatenate([row[:n] for row, n in zip(bufs, lengths)])
     want[4 * B:4 * B + ragged.size] = ragged
     np.testing.assert_array_equal(got, want)
+
+
+# ------------------------------------ the two halves of the served path
+
+_HALVES_SHAPE = (3, 2, 64, 64)
+_HALVES_Q = 77          # no other test serves this shape at this quality
+
+
+def _halves_batch():
+    """Three tiles of rising density: a smooth field under noise of
+    sigma 0.5, 2 and 4 grey levels (299, 379 and 595 entries, under
+    the default cap of 768 and its 4,096 bits)."""
+    B, C, H, W = _HALVES_SHAPE
+    rng = np.random.default_rng(31)
+    yy, xx = np.mgrid[0:H, 0:W].astype(np.float32)
+    raw = np.broadcast_to(120.0 + 30.0 * np.sin((yy + xx) / 24.0),
+                          (B, C, H, W)).copy()
+    raw += rng.normal(0, 1.0, raw.shape).astype(np.float32) \
+        * np.array([0.5, 2.0, 4.0], np.float32)[:, None, None, None]
+    ws = np.zeros((B, C), np.float32)
+    we = np.full((B, C), 255.0, np.float32)
+    fam = np.zeros((B, C), np.int32)
+    coef = np.ones((B, C), np.float32)
+    rev = np.zeros((B, C), np.bool_)
+    tables = np.tile(np.array([[1.0, 0.8, 0.5]], np.float32),
+                     (B, C, 1)).reshape(B, C, 3)
+    return raw.astype(np.float32), ws, we, fam, coef, rev, 0, 255, tables
+
+
+@pytest.mark.parametrize("case", ["exact", "padded", "retry", "dense"])
+@pytest.mark.parametrize("engine", ["sparse", "huffman"])
+def test_the_two_halves_give_the_bytes_of_the_composed_call(
+        engine, case, monkeypatch):
+    """``render_batch_to_wire`` then ``finish_wire_to_jpegs``, called
+    apart as the batcher calls them, return what
+    ``render_batch_to_jpeg`` returns, byte for byte, and ``on_tile``
+    fires for the same tiles with the same bytes in the same order: on
+    grid-exact and bucket-padded ``dims``, through the one-shot cap
+    widening, and with a tile that overflows the doubled cap too and
+    is coded from its dense coefficients."""
+    import omero_ms_image_region_tpu.ops.jpegenc as je
+
+    B, C, H, W = _HALVES_SHAPE
+    Q = _HALVES_Q
+    args = _halves_batch()
+    dims = [(W, H), (60, 50), (W, H)]       # 50 x 60 rounds up to 64^2
+    if case == "padded":
+        dims = [(W, H), (32, 32), (40, 56)]
+    default_cap = je.default_sparse_cap(H, W, Q)
+
+    def forget():
+        for e in ("sparse", "huffman"):
+            je._CAP_MEMO.pop((e, H, W, Q), None)
+
+    dense_calls = []
+    real_coeff = je.render_to_jpeg_coefficients
+
+    def spy_coeff(*a, **kw):
+        if isinstance(a[0], np.ndarray):        # host calls, not traces
+            dense_calls.append(1)
+        return real_coeff(*a, **kw)
+
+    monkeypatch.setattr(je, "render_to_jpeg_coefficients", spy_coeff)
+    forget()
+    try:
+        # Each tile's entries, from the headers of an uncapped dispatch.
+        probe = je.render_batch_to_wire(
+            *args, quality=Q, dims=dims, engine=engine,
+            cap=je.max_sparse_cap(H, W))
+        totals = sorted(je.row_header_i32(r, 0) for r in probe.rows)
+        assert totals[0] < totals[1] < totals[2] - 1
+        assert totals[2] <= default_cap
+        # ``retry``: the densest tile lands in (cap, 2 x cap].
+        # ``dense``: the middle one does, and the densest is over the
+        # doubled cap as well.
+        cap = {"retry": totals[2] * 2 // 3,
+               "dense": (totals[1] + 1) // 2}.get(case)
+
+        def composed():
+            forget()
+            fired = []
+            jpegs = je.render_batch_to_jpeg(
+                *args, quality=Q, dims=dims, engine=engine, cap=cap,
+                tune=False, on_tile=lambda i, d: fired.append((i, d)))
+            return jpegs, fired
+
+        def apart():
+            forget()
+            fired = []
+            wire = je.render_batch_to_wire(
+                *args, quality=Q, dims=dims, engine=engine, cap=cap)
+            jpegs = je.finish_wire_to_jpegs(
+                wire, tune=False,
+                on_tile=lambda i, d: fired.append((i, d)))
+            return jpegs, fired, wire
+
+        want, want_fired = composed()
+        dense_calls.clear()
+        got, got_fired, wire = apart()
+    finally:
+        forget()
+    assert got == want and got_fired == want_fired
+    assert [i for i, _ in got_fired] == list(range(B))
+    assert [d for _, d in got_fired] == got
+    for j, (w_, h_) in zip(got, dims):
+        assert Image.open(io.BytesIO(j)).size == (w_, h_)
+    # Each case took the path it is named for.
+    assert wire.engine == ("sparse" if case == "padded" else engine)
+    assert wire.cap == (default_cap if cap is None else 2 * cap)
+    assert bool(dense_calls) == (case == "dense")

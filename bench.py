@@ -1019,7 +1019,7 @@ def _fleet_smoke(exec_ms: float = 150.0, grid: int = 4,
         build_pyramid(planes, os.path.join(tmp, "1"), n_levels=1)
         single = asyncio.run(run_fleet(tmp, 1))
         fleet = asyncio.run(run_fleet(tmp, 4))
-    working_set = grid * grid
+    working_set = grid * grid * 2     # one plane a channel of a tile
     return {
         "fleet_members": 4,
         "fleet_virtual_exec_ms": exec_ms,

@@ -8,10 +8,16 @@ LUTs — so after the first read, a settings change costs zero host->device
 bytes (the dominant cost on link-constrained deployments; the encoded
 region cache above this one only covers byte-identical requests).
 
-Keyed by (image, z, t, level, region, channels); bounded by device bytes
-with LRU eviction (dropping the reference frees the HBM buffer).  Raw
-planes stay in their storage dtype (uint16 halves HBM vs float32); the
-render kernels cast on device.
+Keyed by (image, z, t, level, region, channel): ONE channel plane
+``[h, w]`` an entry (:func:`region_key`), so a viewer that switches one
+of its shown channels reads and uploads that one plane, every sample is
+resident at most once however the shown sets overlap, and the
+``[C_active, h, w]`` stack a render takes is put together from the
+planes per request (``ops.render.stack_channel_planes``), never kept
+beside them.  Bounded by device bytes with LRU eviction (dropping the
+reference frees the HBM buffer).  Raw planes stay in their storage
+dtype (uint16 halves HBM vs float32); the render kernels cast on
+device.
 
 Content addressing: with ``digest_index`` on (the default), every host
 plane stack staged through :meth:`DeviceRawCache.get_or_load` is also
@@ -76,9 +82,14 @@ class DeviceRawCache:
         # that will actually SERVE its future requests.
         self._route_of: Dict[Hashable, str] = {}
         self._bytes = 0
+        # Lookups of entries (a region entry is one channel plane).
         self.hits = 0
         self.misses = 0
         self.evictions = 0
+        # Channel planes that were read from the pixel store and
+        # uploaded (a region key's loader ran): /metrics
+        # imageregion_rawcache_channel_loads_total.
+        self.channel_loads = 0
         # Uploads skipped (served) / paid because of the content digest.
         self.plane_hits = 0
         self.plane_misses = 0
@@ -194,6 +205,8 @@ class DeviceRawCache:
             arr = jax.device_put(loaded)
             digest = None
         with self._lock:
+            if _is_region_key(key):
+                self.channel_loads += 1
             old = self._entries.pop(key, None)
             if old is not None:
                 self._release_bytes(key, old)
@@ -241,17 +254,34 @@ class DeviceRawCache:
     def get(self, key: Hashable):
         """Pure hit probe WITH the LRU bump; None on miss (the serving
         fast path — callers fall back to ``get_or_load`` off-loop)."""
+        return self.get_planes((key,))[0]
+
+    def get_planes(self, keys) -> list:
+        """:meth:`get` for each of ``keys`` under one hold of the lock:
+        the resident entries, None where one is missing (the probe of a
+        request's channel planes; only the hits are counted here, a
+        missing plane's miss by the ``get_or_load`` that follows)."""
         with self._lock:
-            arr = self._entries.get(key)
-            if arr is not None:
-                self._entries.move_to_end(key)
-                self.hits += 1
-            return arr
+            out = []
+            for key in keys:
+                arr = self._entries.get(key)
+                if arr is not None:
+                    self._entries.move_to_end(key)
+                    self.hits += 1
+                out.append(arr)
+            return out
 
     def __contains__(self, key: Hashable) -> bool:
         """Residency probe without an LRU bump (prefetch skip check)."""
         with self._lock:
             return key in self._entries
+
+    def absent(self, keys) -> list:
+        """Those of ``keys`` that are not resident, under one hold of
+        the lock and without an LRU bump (the prefetcher's check of a
+        predicted tile's channel planes)."""
+        with self._lock:
+            return [key for key in keys if key not in self._entries]
 
     def resident_digests(self) -> Set[str]:
         """Snapshot of every content digest currently resident (fleet
@@ -309,13 +339,10 @@ class DeviceRawCache:
         with self._lock:
             keys = list(reversed(self._entries.keys()))   # MRU first
             for key in keys:
-                if (not isinstance(key, tuple) or len(key) != 6
-                        or not isinstance(key[0], int)):
+                if not _is_region_key(key):
                     continue
-                image_id, z, t, level, region, channels = key
                 entry = {
-                    "key": [image_id, z, t, level, list(region),
-                            list(channels)],
+                    "key": _manifest_key(key),
                     "digest": self._digests_of.get(key),
                 }
                 route = self._route_of.get(key)
@@ -339,13 +366,10 @@ class DeviceRawCache:
             for key in reversed(self._entries.keys()):   # MRU first
                 if self._route_of.get(key) != route_key:
                     continue
-                if (not isinstance(key, tuple) or len(key) != 6
-                        or not isinstance(key[0], int)):
+                if not _is_region_key(key):
                     continue
-                image_id, z, t, level, region, channels = key
                 out.append({
-                    "key": [image_id, z, t, level, list(region),
-                            list(channels)],
+                    "key": _manifest_key(key),
                     "digest": self._digests_of.get(key),
                     "route": route_key,
                 })
@@ -353,11 +377,39 @@ class DeviceRawCache:
 
 
 def region_key(image_id: int, z: int, t: int, level: int,
-               region: Tuple[int, int, int, int],
-               channels: Tuple[int, ...]) -> tuple:
-    """The raw-read identity: everything the pixel data depends on and
-    nothing the rendering settings touch."""
-    return (image_id, z, t, level, region, channels)
+               region: Tuple[int, int, int, int], channel: int) -> tuple:
+    """The raw-read identity of ONE channel plane: everything its pixel
+    data depends on and nothing the rendering settings touch, the set
+    of channels shown beside it included."""
+    return (image_id, z, t, level, region, channel)
+
+
+def entry_region_key(entry: dict) -> tuple:
+    """The :func:`region_key` a manifest entry names (``"key":
+    [image, z, t, level, [x, y, w, h], channel]``, as
+    :meth:`DeviceRawCache.snapshot_entries` writes it): what the
+    warm-state rehydrator, the sidecar's ``shard_transfer`` and the
+    fleet's hand-off rebuild a key from.  Raises ``KeyError`` /
+    ``TypeError`` / ``ValueError`` on anything else, an entry of the
+    older format (a LIST of channels in the last place) among them:
+    its callers skip such an entry, which is then a cold miss later."""
+    image_id, z, t, level, region, channel = entry["key"]
+    x, y, w, h = (int(v) for v in region)
+    return region_key(int(image_id), int(z), int(t), int(level),
+                      (x, y, w, h), int(channel))
+
+
+def _is_region_key(key) -> bool:
+    """A :func:`region_key` (restageable from source), as against a
+    content-only ``("plane", digest)`` or a ``("proj", ...)`` entry."""
+    return (isinstance(key, tuple) and len(key) == 6
+            and isinstance(key[0], int))
+
+
+def _manifest_key(key: tuple) -> list:
+    """A region key as a manifest entry writes it (JSON)."""
+    image_id, z, t, level, region, channel = key
+    return [image_id, z, t, level, list(region), channel]
 
 
 def plane_key(digest: str) -> tuple:

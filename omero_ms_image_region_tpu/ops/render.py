@@ -302,3 +302,15 @@ def pack_settings(rdef: RenderingDef, lut_provider=None):
         "tables": (weights if weights is not None
                    else build_channel_tables(rdef, lut_provider)),
     }
+
+
+@jax.jit
+def stack_channel_planes(*planes):
+    """The ``[C, h, w]`` stack a render takes, from ``C`` device-resident
+    channel planes ``[h, w]`` of the HBM raw cache (one entry a channel:
+    ``io.devicecache.region_key``).  One program a (count, shape,
+    dtype), one dispatch a request, a copy of the planes on the device;
+    the stack is the request's own and nothing keeps it."""
+    # A stage of utils.profile_summary.STAGES.
+    with jax.named_scope("stage.channel_stack"):
+        return jnp.stack(planes)

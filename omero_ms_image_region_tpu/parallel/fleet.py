@@ -143,11 +143,9 @@ def _entry_key(entry: dict) -> tuple:
     """Canonical identity of a restageable manifest entry (the region
     key as a hashable tuple) — matches exported bytes back to their
     hint entries across JSON round-trips (lists vs tuples)."""
+    from ..io.devicecache import entry_region_key
     try:
-        image_id, z, t, level, region, channels = entry["key"]
-        return (int(image_id), int(z), int(t), int(level),
-                tuple(int(v) for v in region),
-                tuple(int(c) for c in channels))
+        return entry_region_key(entry)
     except (KeyError, TypeError, ValueError):
         return (id(entry),)
 
@@ -437,7 +435,7 @@ class LocalMember:
         like :meth:`shard_manifest`; entries whose buffer is already
         gone (eviction race) are skipped."""
         import numpy as np
-        from ..io.devicecache import region_key
+        from ..io.devicecache import entry_region_key
         cache = getattr(self.services, "raw_cache", None)
         if cache is None or not hasattr(cache, "snapshot_entries"):
             return []
@@ -447,12 +445,7 @@ class LocalMember:
             out = []
             for entry in entries:
                 try:
-                    image_id, z, t, level, region, channels = \
-                        entry["key"]
-                    key = region_key(
-                        int(image_id), int(z), int(t), int(level),
-                        tuple(int(v) for v in region),
-                        tuple(int(c) for c in channels))
+                    key = entry_region_key(entry)
                 except (KeyError, TypeError, ValueError):
                     continue
                 arr = cache.get(key)
@@ -473,7 +466,7 @@ class LocalMember:
         is member-kind-agnostic).  Digest-deduped like every staging
         path: re-handing a resident plane aliases, never duplicates."""
         import numpy as np
-        from ..io.devicecache import region_key
+        from ..io.devicecache import entry_region_key
         cache = getattr(self.services, "raw_cache", None)
         if cache is None:
             return 0
@@ -482,12 +475,7 @@ class LocalMember:
             staged = 0
             for entry in entries:
                 try:
-                    image_id, z, t, level, region, channels = \
-                        entry["key"]
-                    key = region_key(
-                        int(image_id), int(z), int(t), int(level),
-                        tuple(int(v) for v in region),
-                        tuple(int(c) for c in channels))
+                    key = entry_region_key(entry)
                     arr = np.frombuffer(
                         entry["bytes"], dtype=entry["dtype"]).reshape(
                         tuple(entry["shape"]))

@@ -30,7 +30,8 @@ from .. import codecs
 from ..models.pixels import Pixels
 from ..models.rendering import RenderingDef
 from ..ops import projection as projection_ops
-from ..ops.render import pack_settings, render_tile_packed, unpack_rgba
+from ..ops.render import (pack_settings, render_tile_packed,
+                          stack_channel_planes, unpack_rgba)
 from ..services.cache import Caches
 from ..services.metadata import CanReadMemo, MetadataService
 from ..utils import telemetry
@@ -496,28 +497,32 @@ class ImageRegionHandler:
         if ctx.projection is not None:
             raw, region = await self._project(ctx, pixels, src, active)
         else:
-            cached = None
+            planes = None
             if not tiny and self.s.raw_cache is not None:
-                key = self._region_key(ctx, region, level or 0, active)
-                cached = self.s.raw_cache.get(key)
-                if cached is not None and self.s.prefetcher is not None:
+                # One probe a shown channel: a plane is cached under
+                # its own channel, whatever is shown beside it.
+                keys = self._plane_keys(ctx, region, level or 0, active)
+                planes = self.s.raw_cache.get_planes(keys)
+                if self.s.prefetcher is not None:
                     # Predictive-hit accounting: if the prefetcher
-                    # staged this plane, the pan/zoom step just paid
+                    # staged a plane, the pan/zoom step just paid
                     # render + encode only — the number the sessions
                     # bench gates on.
-                    self.s.prefetcher.note_hit(key)
+                    for key, plane in zip(keys, planes):
+                        if plane is not None:
+                            self.s.prefetcher.note_hit(key)
             from ..utils import provenance
-            if cached is not None:
-                # HBM raw-cache hit: a dict lookup — skip the
-                # thread-pool hop (same economics as the open-source
-                # fast path above).
-                raw = cached
+            if planes is not None and all(p is not None for p in planes):
+                # Every shown channel is HBM-resident: dict lookups and
+                # one dispatch — skip the thread-pool hop (same
+                # economics as the open-source fast path above).
+                raw = self._channel_stack(planes, missing=0)
                 provenance.mark(ctx, tier="hbm_warm")
             else:
                 provenance.mark(ctx, tier="render_cold")
                 raw = await asyncio.to_thread(
                     self._read_region, src, ctx, region, level or 0,
-                    active,
+                    active, planes,
                     # Tiny renders stay host-side; stolen fleet work
                     # reads from source without adopting ownership.
                     not tiny and adopt_cache)
@@ -607,24 +612,40 @@ class ImageRegionHandler:
         return self._encode_rgba(rgba, ctx)
 
     @staticmethod
-    def _region_key(ctx: ImageRegionCtx, region: RegionDef, level: int,
-                    active: List[int]):
-        """The raw read's cache identity — ONE construction site shared
-        by the event-loop probe and the loader (a drifted duplicate
-        would silently defeat the fast path)."""
+    def _plane_keys(ctx: ImageRegionCtx, region: RegionDef, level: int,
+                    active: List[int]) -> list:
+        """The raw read's cache identities, one a shown channel — ONE
+        construction site shared by the event-loop probe and the loader
+        (a drifted duplicate would silently defeat the fast path)."""
         from ..io.devicecache import region_key
-        return region_key(ctx.image_id, ctx.z, ctx.t, level,
-                          region.as_tuple(), tuple(active))
+        where = (ctx.image_id, ctx.z, ctx.t, level, region.as_tuple())
+        return [region_key(*where, c) for c in active]
+
+    @staticmethod
+    def _channel_stack(planes: list, missing: int):
+        """Span ``handler.channelStack``: one request's ``[C_active, h,
+        w]`` stack put together from its channel planes, ``missing`` of
+        which this request had to read and upload first.  The stack is
+        the request's own; the cache keeps the planes only."""
+        with stopwatch("handler.channelStack", channels=len(planes),
+                       missing=missing):
+            return stack_channel_planes(*planes)
 
     def _read_region(self, src, ctx: ImageRegionCtx, region: RegionDef,
                      level: int, active: List[int],
+                     planes: Optional[list] = None,
                      device_cache: bool = True):
         """Raw [C_active, h, w] planes (storage dtype) for the region.
 
-        With a device raw cache configured (and ``device_cache`` true) the
-        result is an HBM-resident ``jax.Array``: raw planes are
-        settings-independent, so the interactive re-window/re-color
-        pattern re-renders without moving a byte over the host link.
+        With a device raw cache configured (and ``device_cache`` true)
+        the result is an HBM-resident ``jax.Array`` stacked from the
+        region's channel planes: ``planes`` is the caller's probe of
+        the cache (the resident planes, None where one is missing;
+        probed here when not given), only the missing ones are read and
+        uploaded, each adopted under its own channel.  Raw planes are
+        settings-independent, so the interactive re-window / re-color /
+        channel-toggle pattern re-renders without moving a resident
+        byte over the host link.
 
         Wrapped in the ``PixelsService.readRegion`` span (and the
         ledger's ``read_ms``): the cold disk-read + staging half of a
@@ -633,60 +654,58 @@ class ImageRegionHandler:
         waterfall.
         """
         with stopwatch("PixelsService.readRegion"):
-            return self._read_region_inner(src, ctx, region, level,
-                                           active, device_cache)
+            if self.s.raw_cache is None or not device_cache:
+                # Storage dtype, not float32: the kernels cast on
+                # device (dtype keys the batch group), and a float32
+                # staging copy would double the host->device bytes of
+                # the posture that pays for every upload.
+                return np.stack([
+                    src.get_region(ctx.z, c, ctx.t, region, level)
+                    for c in active])
+            keys = self._plane_keys(ctx, region, level, active)
+            if planes is None:
+                planes = self.s.raw_cache.get_planes(keys)
+            missing = sum(p is None for p in planes)
+            planes = self._load_missing_planes(src, ctx, region, level,
+                                               zip(keys, active, planes))
+        return self._channel_stack(planes, missing)
 
-    def _read_region_inner(self, src, ctx: ImageRegionCtx,
-                           region: RegionDef, level: int,
-                           active: List[int],
-                           device_cache: bool = True):
-        def load() -> np.ndarray:
-            planes = [
-                src.get_region(ctx.z, c, ctx.t, region, level)
-                for c in active
-            ]
-            # Storage dtype, not float32: the kernels cast on device, and
-            # uint16 sources take half the HBM/link bytes.
-            return np.stack(planes)
-
-        def load_staged():
-            """Cold staging pipeline: band the region's rows and ship
-            each band as its own async ``device_put``, so band k+1's
-            disk read overlaps band k's host->HBM transfer (the
-            dispatch returns before the copy lands).  A region that
-            yields one band takes the single-shot path — banding only
-            pays when the read itself has substance."""
+    def _load_missing_planes(self, src, ctx: ImageRegionCtx,
+                             region: RegionDef, level: int,
+                             probed) -> list:
+        """``probed``: (key, channel, resident plane or None) of each
+        shown channel.  The planes of all, the missing ones loaded."""
+        def load_staged(c: int):
+            """Cold staging pipeline of one channel plane: band the
+            region's rows and ship each band as its own async
+            ``device_put``, so band k+1's disk read overlaps band k's
+            host->HBM transfer (the dispatch returns before the copy
+            lands).  A region that yields one band is read in one
+            piece — banding only pays when the read itself has
+            substance."""
             bounds = _stage_band_bounds(region.height, region.y,
                                         max(1, src.tile_size()[1]))
             if len(bounds) == 2:
-                return load()
+                return src.get_region(ctx.z, c, ctx.t, region, level)
             import jax
             import jax.numpy as jnp
             parts = []
             for y0, y1 in zip(bounds, bounds[1:]):
                 sub = RegionDef(region.x, region.y + y0,
                                 region.width, y1 - y0)
-                band = np.stack([
-                    src.get_region(ctx.z, c, ctx.t, sub, level)
-                    for c in active
-                ])
-                parts.append(jax.device_put(band))
-            return jnp.concatenate(parts, axis=1)
+                parts.append(jax.device_put(
+                    src.get_region(ctx.z, c, ctx.t, sub, level)))
+            return jnp.concatenate(parts, axis=0)
 
-        if self.s.raw_cache is None or not device_cache:
-            # Storage dtype here too: the cached branch already feeds
-            # uint16 through the identical downstream kernels (dtype
-            # keys the batch group; quantize casts on device), and a
-            # float32 staging copy would double the host->device bytes
-            # of the posture that pays for every upload.
-            return load()
-        key = self._region_key(ctx, region, level, active)
         # The routing identity rides along so a rolling drain can hand
-        # this plane to the ring member that will serve its future
+        # each plane to the ring member that will serve its future
         # requests (parallel.fleet drain handoff).
         from ..parallel.fleet import plane_route_key
-        return self.s.raw_cache.get_or_load(
-            key, load_staged, route_key=plane_route_key(ctx))
+        route = plane_route_key(ctx)
+        return [
+            plane if plane is not None else self.s.raw_cache.get_or_load(
+                key, lambda c=c: load_staged(c), route_key=route)
+            for key, c, plane in probed]
 
     async def _project(self, ctx: ImageRegionCtx, pixels: Pixels, src,
                        active: List[int]

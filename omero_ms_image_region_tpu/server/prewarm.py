@@ -27,7 +27,10 @@ points with the renderer's own wire engine:
   loaded steady state, and the intermediate shapes — including the
   non-power-of-two 3 and 6 — are what a queue shorter than the cap and
   the inflight-aware group split launch);
-- the packed-RGBA program at batch 1 (png/tif formats).
+- the packed-RGBA program at batch 1 (png/tif formats);
+- the stack of the spec's channel planes
+  (``ops.render.stack_channel_planes``: what a request whose channels
+  are HBM-resident dispatches first).
 
 Settings use the ramp-weight table form (plain color channels; LUT
 renders compile on first use).
@@ -125,6 +128,10 @@ def _warm_one(C: int, edge: int, quality: int, batch_sizes: Sequence[int],
                            else render_tile_batch_packed(*args))
             else:
                 np.asarray(render_tile_batch_packed(*args))
+    import jax
+    from ..ops.render import stack_channel_planes
+    plane = jax.device_put(np.zeros((bh, bw), raw_dtype))
+    stack_channel_planes(*[plane] * C).block_until_ready()
 
 
 def prewarm_batch_sizes(cap: int) -> tuple:

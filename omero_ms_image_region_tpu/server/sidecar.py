@@ -398,7 +398,7 @@ async def _shard_transfer(image_handler, header: dict,
     this member had read it from its own store."""
     import numpy as np
 
-    from ..io.devicecache import plane_digest, region_key
+    from ..io.devicecache import entry_region_key, plane_digest
 
     cache = getattr(getattr(image_handler, "s", None), "raw_cache",
                     None)
@@ -411,10 +411,7 @@ async def _shard_transfer(image_handler, header: dict,
         raise BadRequestError("shard_transfer requires an entry doc")
     digest = str(entry.get("digest") or "")
     try:
-        image_id, z, t, level, region, channels = entry["key"]
-        key = region_key(int(image_id), int(z), int(t), int(level),
-                         tuple(int(v) for v in region),
-                         tuple(int(c) for c in channels))
+        key = entry_region_key(entry)
         dtype = np.dtype(str(entry["dtype"]))
         shape = tuple(int(s) for s in entry["shape"])
         if dtype.kind not in "uif":

@@ -161,6 +161,8 @@ class TilePrefetcher:
         """The foreground path found ``key`` resident: if this
         prefetcher staged it, that is a PREDICTIVE HIT — the pan/zoom
         step paid render + encode only."""
+        if not self._staged_keys:
+            return      # nothing staged: no lock on the serving path
         with self._lock:
             if self._staged_keys.pop(key, None) is None:
                 return
@@ -247,8 +249,10 @@ class TilePrefetcher:
             if region.width <= 0 or region.height <= 0:
                 continue
             level = nres or 0
-            key = region_key(image_id, nz, nt, level,
-                             region.as_tuple(), tuple(active))
+            # One cache identity a channel plane, as the foreground
+            # read builds them.
+            keys = [region_key(image_id, nz, nt, level,
+                               region.as_tuple(), c) for c in active]
             # Fleet routing: the predicted tile stages into the HBM
             # shard of the member that will serve it (route computed
             # from the REQUEST identity, exactly like the router).
@@ -266,11 +270,11 @@ class TilePrefetcher:
                     # (a prestage hint; the owner reads the region
                     # from its own store through the digest-deduped
                     # staging path) and spend nothing here.
-                    entry = {"key": [image_id, nz, nt, level,
-                                     list(region.as_tuple()),
-                                     list(active)],
-                             "route": route}
-                    if self.remote_prestage(route, entry):
+                    sent = [self.remote_prestage(
+                        route, {"key": [image_id, nz, nt, level,
+                                        list(region.as_tuple()), c],
+                                "route": route}) for c in active]
+                    if any(sent):
                         self.predicted += 1
                         continue
             # Hot-route replication: when the router promoted this
@@ -284,11 +288,21 @@ class TilePrefetcher:
                     reps = []
                 targets += [c for c in reps if c is not cache]
             for tcache in targets:
-                # Replica stagings carry a per-cache token so two
-                # shards can hold the same key in flight at once.
-                token = key if tcache is cache else (id(tcache), key)
-                if tcache is None or key in tcache:
+                if tcache is None:
+                    continue
+                # One pool task a predicted tile: the channel planes of
+                # it that this shard lacks (a viewer that toggled one
+                # channel finds the others resident).
+                absent = set(tcache.absent(keys))
+                if not absent:
                     continue   # already resident: no pool churn
+                missing = [(c, key) for c, key in zip(active, keys)
+                           if key in absent]
+                # Replica stagings carry a per-cache token so two
+                # shards can hold the same tile in flight at once.
+                token = tuple(key for _, key in missing)
+                if tcache is not cache:
+                    token = (id(tcache), token)
                 with self._lock:
                     if token in self._pending:
                         # Already in flight: dedupe, not a budget
@@ -302,8 +316,8 @@ class TilePrefetcher:
                     self._pending.add(token)
                 try:
                     future = self._pool.submit(
-                        self._load, src, tcache, key, route, nz, nt,
-                        level, region, active, token)
+                        self._load, src, tcache, missing, route, nz, nt,
+                        level, region, token)
                 except RuntimeError:   # pool shut down mid-request
                     with self._lock:
                         self._pending.discard(token)
@@ -315,10 +329,10 @@ class TilePrefetcher:
                 future.add_done_callback(
                     lambda f: self._futures.discard(f))
 
-    def _load(self, src, cache, key, route, z: int, t: int, level: int,
-              region, active: Sequence[int], token=None) -> None:
-        if token is None:
-            token = key
+    def _load(self, src, cache, missing, route, z: int, t: int,
+              level: int, region, token) -> None:
+        """Stage the ``missing`` (channel, key) planes of one predicted
+        tile.  ``staged`` and the predictive hits count planes."""
         try:
             # Budget changes bind QUEUED work too: an item whose turn
             # comes after the budget hit zero exits without touching
@@ -328,21 +342,20 @@ class TilePrefetcher:
                 telemetry.PREFETCH.count_skipped("paused")
                 return
 
-            loaded = [False]
+            for c, key in missing:
+                loaded = [False]
 
-            def loader() -> np.ndarray:
-                loaded[0] = True
-                planes = [src.get_region(z, c, t, region, level)
-                          for c in active]
-                return np.stack(planes)
+                def loader(c=c, loaded=loaded) -> np.ndarray:
+                    loaded[0] = True
+                    return src.get_region(z, c, t, region, level)
 
-            cache.get_or_load(key, loader, route_key=route)
-            if loaded[0]:
-                self.staged += 1
-                telemetry.PREFETCH.count_staged()
-                self._mark_staged(key)
+                cache.get_or_load(key, loader, route_key=route)
+                if loaded[0]:
+                    self.staged += 1
+                    telemetry.PREFETCH.count_staged()
+                    self._mark_staged(key)
         except Exception as e:  # best-effort: foreground re-reads on miss
-            logger.debug("prefetch failed for %s: %r", key, e)
+            logger.debug("prefetch failed for %s: %r", token, e)
         finally:
             with self._lock:
                 self._pending.discard(token)

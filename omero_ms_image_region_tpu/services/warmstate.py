@@ -59,28 +59,23 @@ def restage_plane_entry(raw_cache, pixels_service, entry: dict) -> bool:
     pre-stager (``parallel.fleet`` hands a draining member's shard
     manifest to its ring successor through this).  Returns False on a
     malformed entry; read errors propagate to the caller's guard."""
-    from ..io.devicecache import region_key
+    from ..io.devicecache import entry_region_key
     from ..server.region import RegionDef
 
     try:
-        image_id, z, t, level, region, channels = entry["key"]
-        key = region_key(int(image_id), int(z), int(t), int(level),
-                         tuple(int(v) for v in region),
-                         tuple(int(c) for c in channels))
+        key = entry_region_key(entry)
     except (KeyError, TypeError, ValueError):
+        # Malformed, or an older manifest's entry (a list of channels
+        # where one channel stands now): a cold miss later.
         return False
     if key in raw_cache:
         return True
 
     def load():
-        import numpy as np
-        src = pixels_service.get_pixel_source(key[0])
-        x, y, w, h = key[4]
-        sub = RegionDef(x, y, w, h)
-        return np.stack([
-            src.get_region(key[1], c, key[2], sub, key[3])
-            for c in key[5]
-        ])
+        image_id, z, t, level, (x, y, w, h), channel = key
+        src = pixels_service.get_pixel_source(image_id)
+        return src.get_region(z, channel, t, RegionDef(x, y, w, h),
+                              level)
 
     # Carry the entry's recorded routing identity onto the receiving
     # cache: a restaged plane that loses its route would fall back to

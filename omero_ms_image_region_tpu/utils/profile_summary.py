@@ -49,12 +49,13 @@ OPS_LINE = "XLA Ops"
 HOST_PLANE = "/host:CPU"
 
 # The named scopes of the device programs (ops/render.py,
-# ops/jpegenc.py).  Fixed here: the reduction has to find them after
-# a refactor, and they label a counter on /metrics.
+# ops/jpegenc.py; ops/render.py's stack of channel planes).  Fixed
+# here: the reduction has to find them after a refactor, and they label
+# a counter on /metrics.
 STAGES = ("render", "jpeg.ycbcr420", "jpeg.dct_quant",
           "wire.sparse_pack", "wire.sparse_pack.scatter",
           "wire.sparse_pack.bits", "wire.huffman_pack",
-          "wire.compact_rows")
+          "wire.compact_rows", "stage.channel_stack")
 UNNAMED = "unnamed"
 
 COMPILE = "xla.compile"

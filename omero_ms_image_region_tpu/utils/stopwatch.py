@@ -154,8 +154,9 @@ class Span:
 def stopwatch(name: str, registry: StopWatchRegistry = REGISTRY, **meta):
     """Time a stage under a reference span name, e.g.
     ``Renderer.renderAsPackedInt`` or ``ProjectionService.projectStack``.
-    ``meta`` (numbers and short strings: ``group_id``, ``tiles``) goes
-    with the profiler annotation only."""
+    ``meta`` (numbers and short strings: ``group_id``, ``tiles``,
+    ``channels``) goes with the profiler annotation and with the span
+    on the request's trace."""
     span = Span()
     with (_ANNOTATION(name, **meta) if _ANNOTATION is not None
           else nullcontext()):
@@ -164,5 +165,5 @@ def stopwatch(name: str, registry: StopWatchRegistry = REGISTRY, **meta):
             yield span
         finally:
             span.ms = ms = (time.perf_counter() - t0) * 1000.0
-            registry.record(name, ms)
+            registry.record(name, ms, **meta)
             log.debug("time[%s] = %.3f ms", name, ms)

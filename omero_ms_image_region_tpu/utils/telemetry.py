@@ -3538,6 +3538,7 @@ METRIC_TYPES: Dict[str, str] = {
     "imageregion_rawcache_misses": "counter",
     "imageregion_rawcache_evictions": "counter",
     "imageregion_rawcache_bytes": "gauge",
+    "imageregion_rawcache_channel_loads_total": "counter",
     "imageregion_planecache_hits": "counter",
     "imageregion_planecache_misses": "counter",
     "imageregion_singleflight_hits": "counter",
@@ -3779,6 +3780,9 @@ METRIC_TYPES: Dict[str, str] = {
 # from the name; every family gets a HELP line (fallback text) so the
 # exposition lint can hold "HELP exactly once per family" everywhere.
 METRIC_HELP: Dict[str, str] = {
+    "imageregion_rawcache_channel_loads_total":
+        "Channel planes read (or handed over) and uploaded to the HBM "
+        "raw cache",
     "imageregion_federation_manifest_version":
         "Shard epoch of the agreed fleet manifest",
     "imageregion_federation_agreements_total":
@@ -4180,6 +4184,12 @@ def device_metric_lines(services, extra_labels: str = "") -> List[str]:
         if hasattr(raw_cache, "evictions"):
             lines.append(f"imageregion_rawcache_evictions{lb} "
                          f"{raw_cache.evictions}")
+        if hasattr(raw_cache, "channel_loads"):
+            # Channel planes read (or handed over) and uploaded: what
+            # a change of the shown channels costs the store and the
+            # link, beside the hits and misses of the planes' lookups.
+            lines.append(f"imageregion_rawcache_channel_loads_total{lb} "
+                         f"{raw_cache.channel_loads}")
         if hasattr(raw_cache, "plane_hits"):
             # Content-digest staging skips: uploads the plane cache
             # saved (hits) vs paid (misses) — wire probes included.

@@ -65,7 +65,7 @@ def shape(topo):
     return make
 
 
-def _render_args(shape, B=1, tables=(3,), raw_dtype="uint16"):
+def _render_args(shape, B=1, tables=(3,), raw_dtype="uint16", C=C):
     """``render_tile_batch_packed`` argument order, per-tile settings."""
     return (shape((B, C, H, W), raw_dtype),
             shape((B, C), "float32"), shape((B, C), "float32"),
@@ -107,6 +107,22 @@ def test_jpeg_wire_program_compiles(shape, engine):
             h16=H // 16, w16=W // 16, cap=cap,
             cap_words=jpegenc.default_words_cap(H, W, QUALITY))
     _compiled(lowered)
+
+
+@pytest.mark.parametrize("shown", [5, 6])
+def test_channel_stack_and_the_multiplexed_counts_compile(shape, shown):
+    """A multiplexed slide's request (5 or 6 shown of 40 stored
+    channels): the stack of its resident channel planes, and the JPEG
+    program at that count."""
+    from omero_ms_image_region_tpu.ops import jpegenc
+    from omero_ms_image_region_tpu.ops.render import stack_channel_planes
+    stacked = _compiled(stack_channel_planes.lower(
+        *[shape((H, W), "uint16")] * shown))
+    assert "stage.channel_stack" in stacked.as_text()
+    q = (shape((8, 8), "int32"), shape((8, 8), "int32"))
+    _compiled(jpegenc.render_to_jpeg_sparse_compact.lower(
+        *_render_args(shape, C=shown), *q, shape((), "int32"),
+        cap=jpegenc.default_sparse_cap(H, W, QUALITY)))
 
 
 def test_mask_pyramid_projection_programs_compile(shape):

@@ -16,14 +16,19 @@ def test_same_key_loads_once_and_counts():
 
     def loader():
         calls.append(1)
-        return np.ones((2, 8, 8), np.float32)
+        return np.ones((8, 8), np.float32)
 
-    key = region_key(1, 0, 0, 0, (0, 0, 8, 8), (0, 1))
+    # One channel plane an entry: the key's last part is one channel.
+    key = region_key(1, 0, 0, 0, (0, 0, 8, 8), 1)
     a = cache.get_or_load(key, loader)
     b = cache.get_or_load(key, loader)
     assert len(calls) == 1
     assert a is b
     assert cache.hits == 1 and cache.misses == 1
+    assert cache.channel_loads == 1
+    assert cache.get_planes([key, region_key(1, 0, 0, 0, (0, 0, 8, 8),
+                                             0)]) == [a, None]
+    assert cache.hits == 2 and cache.misses == 1
     np.testing.assert_array_equal(np.asarray(a), 1.0)
 
 

@@ -328,11 +328,12 @@ class TestWireOps:
             SidecarClient, run_sidecar)
 
         sock = str(tmp_path / "fed2.sock")
-        arr = np.arange(2 * 8 * 8, dtype=np.uint16).reshape(2, 8, 8)
+        arr = np.arange(8 * 8, dtype=np.uint16).reshape(8, 8)
         digest = plane_digest(arr)
-        entry = {"key": [IMG, 0, 0, 0, [0, 0, 8, 8], [1, 2]],
+        # One channel plane an entry: the key ends in ONE channel.
+        entry = {"key": [IMG, 0, 0, 0, [0, 0, 8, 8], 1],
                  "digest": digest, "route": "route-xyz",
-                 "dtype": "uint16", "shape": [2, 8, 8],
+                 "dtype": "uint16", "shape": [8, 8],
                  "bytes": arr.tobytes()}
 
         async def scenario():
@@ -381,11 +382,11 @@ class TestWireOps:
             SidecarClient, run_sidecar)
 
         sock = str(tmp_path / "fed3.sock")
-        arr = np.arange(2 * 8 * 8, dtype=np.uint16).reshape(2, 8, 8)
+        arr = np.arange(8 * 8, dtype=np.uint16).reshape(8, 8)
         digest = plane_digest(arr)
-        entry = {"key": [IMG, 0, 0, 0, [0, 0, 8, 8], [1, 2]],
+        entry = {"key": [IMG, 0, 0, 0, [0, 0, 8, 8], 1],
                  "digest": digest, "route": "route-kill",
-                 "dtype": "uint16", "shape": [2, 8, 8],
+                 "dtype": "uint16", "shape": [8, 8],
                  "bytes": arr.tobytes()}
 
         async def scenario():

@@ -317,6 +317,10 @@ def test_banded_cold_staging_matches_single_shot(tmp_path):
         src.get_region(0, c, 0, region, 0) for c in (0, 1)])
     assert staged.dtype == jnp.uint16        # storage dtype preserved
     np.testing.assert_array_equal(np.asarray(staged), direct)
-    # Cache hit returns the staged array without re-reading.
+    # A hit stacks the two resident channel planes again without
+    # re-reading either.
+    cache = services.raw_cache
+    assert (cache.channel_loads, len(cache)) == (2, 2)
     again = handler._read_region(src, ctx, region, 0, [0, 1])
-    assert again is staged
+    np.testing.assert_array_equal(np.asarray(again), direct)
+    assert (cache.channel_loads, cache.hits) == (2, 2)

@@ -341,10 +341,16 @@ def test_every_scope_is_in_the_compiled_text_of_the_served_programs():
         cap_words=64).compile().as_text()
     assert _scopes_in(text) == front | {"wire.huffman_pack",
                                         "wire.compact_rows"}
+    # The stack of a request's channel planes is a program of its own
+    # (ops.render.stack_channel_planes) with a stage of its own.
+    from omero_ms_image_region_tpu.ops.render import stack_channel_planes
+    plane = np.zeros((16, 16), np.uint16)
+    assert _scopes_in(stack_channel_planes.lower(
+        plane, plane).compile().as_text()) == {"stage.channel_stack"}
     assert set(ps.STAGES) == front | {
         "wire.sparse_pack", "wire.sparse_pack.scatter",
         "wire.sparse_pack.bits", "wire.compact_rows",
-        "wire.huffman_pack"}
+        "wire.huffman_pack", "stage.channel_stack"}
 
 
 def _opcodes(compiled_text: str) -> set:

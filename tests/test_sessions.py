@@ -623,7 +623,7 @@ class TestPredictivePrefetch:
             # resident and reports the hit back.
             from omero_ms_image_region_tpu.io.devicecache import (
                 region_key)
-            key = region_key(1, 0, 0, 0, (32, 0, 16, 16), (0,))
+            key = region_key(1, 0, 0, 0, (32, 0, 16, 16), 0)
             assert cache.get(key) is not None
             prefetcher.note_hit(key)
             assert prefetcher.hits == 1

@@ -660,14 +660,14 @@ def test_wire_pushed_plane_skips_handler_upload(data_dir, tmp_path):
            f"?c=1|0:60000$FF0000&m=g&format=png")
 
     async def body():
-        # Push exactly the plane stack the handler's full-plane read
-        # will produce: channel 0, z 0, t 0, stacked along C.
+        # Push exactly the channel plane the handler's full-plane read
+        # will produce: channel 0, z 0, t 0.
         src = ChunkedPyramidStore(os.path.join(data_dir, str(IMG)))
         from omero_ms_image_region_tpu.server.region import RegionDef
         plane = src.get_region(0, 0, 0, RegionDef(0, 0, W, H), 0)
         pusher = SidecarClient(sock)
         try:
-            _, resident = await pusher.stage_plane(plane[None])
+            _, resident = await pusher.stage_plane(plane)
             assert resident is False
             app = create_app(_frontend_config(data_dir, sock))
             client = TestClient(TestServer(app))

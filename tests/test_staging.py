@@ -145,9 +145,11 @@ def test_without_a_raw_cache_the_read_stays_on_the_host():
 
 def test_a_cold_miss_compiles_and_dispatches_no_program():
     """A shape this process has never seen goes up through
-    ``get_or_load`` and through the handler's single-shot read, and
-    ``imageregion_compile_events_total`` does not move; a jitted call
-    of the same new shape afterwards does move it, so the listener was
+    ``get_or_load`` and ``imageregion_compile_events_total`` does not
+    move; through the handler's single-shot read it moves by one, the
+    stack of the region's channel planes (no program an upload: a
+    second region of the shape compiles nothing); a jitted call of the
+    same new shape afterwards moves it again, so the listener was
     listening."""
     assert telemetry.install_compile_listener()
     shape = (3, 320, 1280)
@@ -165,10 +167,15 @@ def test_a_cold_miss_compiles_and_dispatches_no_program():
         InMemoryPixelSource(planes), ImageRegionCtx.from_params(_CTX),
         RegionDef(0, 0, 1280, 320), 0, [0, 1, 2])
     jax.block_until_ready(staged)
-    assert telemetry.COMPILE.events == before
-    assert np.array_equal(np.asarray(staged), arr)
-    jax.block_until_ready(jax.jit(lambda a: a + 1)(got))
     assert telemetry.COMPILE.events == before + 1
+    assert np.array_equal(np.asarray(staged), arr)
+    again = _handler(cache)._read_region(
+        InMemoryPixelSource(planes + 1), ImageRegionCtx.from_params(_CTX),
+        RegionDef(0, 0, 1280, 320), 0, [0, 1, 2])
+    assert telemetry.COMPILE.events == before + 1
+    assert np.array_equal(np.asarray(again), arr)   # resident: no read
+    jax.block_until_ready(jax.jit(lambda a: a + 1)(got))
+    assert telemetry.COMPILE.events == before + 2
 
 
 def test_staging_defines_no_device_program():

@@ -300,8 +300,10 @@ def test_a_stock_tile_is_adopted_by_the_raw_cache_and_a_sliver_bypasses_it(
             await services.renderer.close()
 
     first, second, after_sliver = run(main())
-    assert first == (0, 1) and second == (1, 1)
+    # Lookups count channel planes: C misses, then C hits.
+    assert first == (0, C) and second == (C, C)
     assert after_sliver == second
+    assert (cache.channel_loads, len(cache)) == (C, C)
 
 
 # --------------------------------------------------------------- prewarm

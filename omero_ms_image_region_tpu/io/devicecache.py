@@ -11,10 +11,13 @@ region cache above this one only covers byte-identical requests).
 Keyed by (image, z, t, level, region, channel): ONE channel plane
 ``[h, w]`` an entry (:func:`region_key`), so a viewer that switches one
 of its shown channels reads and uploads that one plane, every sample is
-resident at most once however the shown sets overlap, and the
-``[C_active, h, w]`` stack a render takes is put together from the
-planes per request (``ops.render.stack_channel_planes``), never kept
-beside them.  Bounded by device bytes with LRU eviction (dropping the
+resident at most once however the shown sets overlap, and the stack
+a render takes is put together from the planes on the device, never
+kept beside them: a group's ``[B, C_active, h, w]`` by one program
+over its members' planes (``ops.render.stack_group_planes``), or one
+request's ``[C_active, h, w]`` where it needs a flip or a pad of its
+own (``ops.render.stack_channel_planes``).  Bounded by device bytes
+with LRU eviction (dropping the
 reference frees the HBM buffer).  Raw planes stay in their storage
 dtype (uint16 halves HBM vs float32); the render kernels cast on
 device.

@@ -306,11 +306,28 @@ def pack_settings(rdef: RenderingDef, lut_provider=None):
 
 @jax.jit
 def stack_channel_planes(*planes):
-    """The ``[C, h, w]`` stack a render takes, from ``C`` device-resident
+    """The ``[C, h, w]`` stack of ONE request, from ``C`` device-resident
     channel planes ``[h, w]`` of the HBM raw cache (one entry a channel:
     ``io.devicecache.region_key``).  One program a (count, shape,
-    dtype), one dispatch a request, a copy of the planes on the device;
-    the stack is the request's own and nothing keeps it."""
+    dtype), a copy of the planes on the device; the stack is the
+    request's own and nothing keeps it.  The fallback of
+    :func:`stack_group_planes`: a request whose planes need a flip or a
+    pad of their own, a projection's planes, a renderer that does not
+    batch."""
     # A stage of utils.profile_summary.STAGES.
     with jax.named_scope("stage.channel_stack"):
         return jnp.stack(planes)
+
+
+@jax.jit
+def stack_group_planes(members):
+    """The ``[B, C, h, w]`` array a group's render takes, from its
+    members' planes: ``members`` is a tuple of ``B`` tuples of ``C``
+    device-resident planes ``[h, w]`` (a padded slot repeats the last
+    member's).  One program a (B, C, shape, dtype) and ONE dispatch a
+    group, where a stack a request and an eager ``jnp.stack`` of the
+    stacks were B + B + 1.  Bit for bit
+    ``jnp.stack([stack_channel_planes(*m) for m in members])``.  The
+    planes are the cache's: nothing is donated."""
+    with jax.named_scope("stage.channel_stack"):
+        return jnp.stack([jnp.stack(planes) for planes in members])

@@ -88,6 +88,24 @@ def _pad_batch_size(n: int, cap: int) -> int:
     return cap
 
 
+def _raw_form(raw) -> tuple:
+    """``(C, h, w, dtype)`` of a request's raw input in either of its
+    forms (``_Pending.raw``)."""
+    if isinstance(raw, tuple):
+        return (len(raw),) + tuple(raw[0].shape) + (raw[0].dtype,)
+    return tuple(raw.shape) + (raw.dtype,)
+
+
+def _request_stack(raw):
+    """A request's ``[C, h, w]`` array, stacked from its planes where
+    it carries them (one dispatch: the fallback of a group that cannot
+    take the planes as they are)."""
+    if isinstance(raw, tuple):
+        from ..ops.render import stack_channel_planes
+        return stack_channel_planes(*raw)
+    return raw
+
+
 def _key_label(key: tuple) -> str:
     """Compact group-key label for flight-recorder events: the shape
     prefix only (channels x bucket), never the settings scalars."""
@@ -140,7 +158,11 @@ def _capture_shape_estimate(shape: str, jitted_fn, args) -> None:
 
 @dataclass
 class _Pending:
-    raw: np.ndarray               # f32[C, bh, bw] padded
+    # What the group stacks: ``[C, bh, bw]`` padded to the bucket, in
+    # the storage dtype (host numpy or device-resident), or a tuple of
+    # ``C`` device-resident planes ``[bh, bw]`` of the HBM raw cache
+    # that needed nothing done to them (``takes_planes``).
+    raw: object
     settings: dict
     h: int
     w: int
@@ -259,6 +281,11 @@ class BatchingRenderer:
         # what the shape ladder wastes of the device.
         self.shape_slots = 0
         self.padded_slots = 0
+        # Groups staged, by how their ``[B, C, bh, bw]`` array came to
+        # be (/metrics imageregion_batcher_group_stacks_total{path=}):
+        # "planes" = one jitted program over the members' resident
+        # planes, "arrays" = a stack of the members' own stacks.
+        self.group_stacks = {"planes": 0, "arrays": 0}
         # Two-stage group pipeline: each group render splits into a
         # fetch/stage half (stacking + host->device upload, run by any
         # of the pipeline_depth worker threads) and a device-execute
@@ -466,11 +493,23 @@ class BatchingRenderer:
 
     # ------------------------------------------------------------- public
 
-    async def render(self, raw: np.ndarray, settings: dict) -> np.ndarray:
-        """f32[C, H, W] + packed settings -> u32[H, W] packed RGBA."""
-        C, h, w = raw.shape
+    def takes_planes(self, h: int, w: int, jpeg: bool) -> bool:
+        """Whether a request's resident planes ``[h, w]`` can ride to
+        their group as they are (``_Pending.raw`` as a tuple): they fill
+        their bucket, so ``render`` / ``render_jpeg`` would pad nothing.
+        What the handler asks before it stacks a request."""
+        if jpeg:
+            return pick_bucket(h + (-h) % 16, w + (-w) % 16,
+                               self.buckets) == (h, w)
+        return pick_bucket(h, w, self.buckets) == (h, w)
+
+    async def render(self, raw, settings: dict) -> np.ndarray:
+        """[C, H, W] raw (or its C resident planes) + packed settings
+        -> u32[H, W] packed RGBA."""
+        C, h, w, dtype = _raw_form(raw)
         bh, bw = pick_bucket(h, w, self.buckets)
         if (h, w) != (bh, bw):
+            raw = _request_stack(raw)
             if isinstance(raw, np.ndarray):
                 padded = np.zeros((C, bh, bw), raw.dtype)
                 padded[:, :h, :w] = raw
@@ -484,7 +523,7 @@ class BatchingRenderer:
         # can raw dtypes (uint16 storage vs float32) mix in one stack.
         key = (C, bh, bw, int(settings["cd_start"]),
                int(settings["cd_end"]), settings["tables"].ndim,
-               str(raw.dtype))
+               str(dtype))
 
         from ..utils.transient import deadline as _deadline
         pending = _Pending(raw=raw, settings=settings, h=h, w=w,
@@ -494,9 +533,10 @@ class BatchingRenderer:
                            deadline=_deadline())
         return await self._enqueue(key, pending)
 
-    async def render_jpeg(self, raw: np.ndarray, settings: dict,
+    async def render_jpeg(self, raw, settings: dict,
                           quality: int, width: int, height: int) -> bytes:
-        """Batched fused render + device JPEG front end -> JFIF bytes.
+        """Batched fused render + device JPEG front end -> JFIF bytes
+        (``raw``: ``[C, h, w]``, or its C resident planes).
 
         JPEG groups use the same spatial buckets as the packed path (all
         16-aligned), bounding the compile set against client-controlled
@@ -508,13 +548,14 @@ class BatchingRenderer:
         """
         from ..ops.jpegenc import pad_planes_to_mcu
 
-        C, h, w = raw.shape
+        C, h, w, dtype = _raw_form(raw)
         gh, gw = h + (-h) % 16, w + (-w) % 16
         bh, bw = pick_bucket(gh, gw, self.buckets)
-        raw = pad_planes_to_mcu(raw, bh, bw)
+        if (bh, bw) != (h, w):
+            raw = pad_planes_to_mcu(_request_stack(raw), bh, bw)
         key = ("jpeg", C, bh, bw, int(settings["cd_start"]),
                int(settings["cd_end"]), settings["tables"].ndim, quality,
-               str(raw.dtype))
+               str(dtype))
         from ..utils.transient import deadline as _deadline
         pending = _Pending(raw=raw, settings=settings, h=height, w=width,
                            quality=quality, bucket_px=bh * bw,
@@ -818,17 +859,27 @@ class BatchingRenderer:
     def _group_arrays(self, group: List[_Pending]):
         """Pad the batch to the next ladder shape within the bucket's
         cap (repeating the last tile; extras are discarded) and build
-        the stacked kernel inputs.  Raw stacking stays on device when
-        any member is already resident there (the HBM raw tile
-        cache)."""
+        the stacked kernel inputs.  Where every member carries its
+        resident planes, ONE jitted program stacks the group's B x C
+        planes (``ops.render.stack_group_planes``); a group with any
+        member of the other form stacks the members' own ``[C, bh,
+        bw]`` arrays, on the device when any is resident there (the HBM
+        raw tile cache)."""
         B = _pad_batch_size(len(group),
                             self.group_cap(group[0].bucket_px))
         padded = group + [group[-1]] * (B - len(group))
-        if all(isinstance(p.raw, np.ndarray) for p in padded):
+        planes = all(isinstance(p.raw, tuple) for p in group)
+        if planes:
+            from ..ops.render import stack_group_planes
+            raw = stack_group_planes(tuple(p.raw for p in padded))
+        elif all(isinstance(p.raw, np.ndarray) for p in group):
             raw = np.stack([p.raw for p in padded])
         else:
             import jax.numpy as jnp
-            raw = jnp.stack([p.raw for p in padded])
+            stacks = [_request_stack(p.raw) for p in group]
+            raw = jnp.stack(stacks + stacks[-1:] * (B - len(group)))
+        with self._stats_lock:
+            self.group_stacks["planes" if planes else "arrays"] += 1
 
         def stack(name):
             return np.stack([p.settings[name] for p in padded])

@@ -513,10 +513,11 @@ class ImageRegionHandler:
                             self.s.prefetcher.note_hit(key)
             from ..utils import provenance
             if planes is not None and all(p is not None for p in planes):
-                # Every shown channel is HBM-resident: dict lookups and
-                # one dispatch — skip the thread-pool hop (same
-                # economics as the open-source fast path above).
-                raw = self._channel_stack(planes, missing=0)
+                # Every shown channel is HBM-resident: dict lookups
+                # (and one dispatch where the request has to be stacked
+                # here) — skip the thread-pool hop (same economics as
+                # the open-source fast path above).
+                raw = self._channel_stack(planes, 0, ctx)
                 provenance.mark(ctx, tier="hbm_warm")
             else:
                 provenance.mark(ctx, tier="render_cold")
@@ -550,7 +551,8 @@ class ImageRegionHandler:
                 raw = raw[:, ::-1, :]
             if ctx.flip_horizontal:
                 raw = raw[:, :, ::-1]
-            h, w = raw.shape[-2:]
+            # Planes handed on as they are have no flip (_channel_stack).
+            h, w = (raw[0] if isinstance(raw, tuple) else raw).shape[-2:]
             quality = codecs.quality_percent(ctx.compression_quality)
             quality = _pressure_quality(quality, ctx)
             with stopwatch("Renderer.renderAsPackedInt"):
@@ -621,14 +623,25 @@ class ImageRegionHandler:
         where = (ctx.image_id, ctx.z, ctx.t, level, region.as_tuple())
         return [region_key(*where, c) for c in active]
 
-    @staticmethod
-    def _channel_stack(planes: list, missing: int):
-        """Span ``handler.channelStack``: one request's ``[C_active, h,
-        w]`` stack put together from its channel planes, ``missing`` of
-        which this request had to read and upload first.  The stack is
-        the request's own; the cache keeps the planes only."""
+    def _channel_stack(self, planes: list, missing: int,
+                       ctx: ImageRegionCtx):
+        """Span ``handler.channelStack``: one request's resident channel
+        planes put together for the renderer, ``missing`` of which this
+        request had to read and upload first.  Where nothing has to be
+        done to them per request (no flip, and the renderer says they
+        fill their bucket: ``takes_planes``) they go on as a tuple and
+        their group stacks them, once for all its members; otherwise
+        one jitted program stacks this request's ``[C_active, h, w]``
+        here.  Either is the request's own; the cache keeps the planes
+        only."""
         with stopwatch("handler.channelStack", channels=len(planes),
                        missing=missing):
+            takes = getattr(self.s.renderer, "takes_planes", None)
+            if (takes is not None
+                    and not (ctx.flip_horizontal or ctx.flip_vertical)
+                    and takes(*planes[0].shape,
+                              jpeg=ctx.format == "jpeg")):
+                return tuple(planes)
             return stack_channel_planes(*planes)
 
     def _read_region(self, src, ctx: ImageRegionCtx, region: RegionDef,
@@ -638,8 +651,9 @@ class ImageRegionHandler:
         """Raw [C_active, h, w] planes (storage dtype) for the region.
 
         With a device raw cache configured (and ``device_cache`` true)
-        the result is an HBM-resident ``jax.Array`` stacked from the
-        region's channel planes: ``planes`` is the caller's probe of
+        the result is HBM-resident, the region's channel planes as
+        ``_channel_stack`` hands them on (a ``jax.Array`` stack, or the
+        planes themselves): ``planes`` is the caller's probe of
         the cache (the resident planes, None where one is missing;
         probed here when not given), only the missing ones are read and
         uploaded, each adopted under its own channel.  Raw planes are
@@ -668,7 +682,7 @@ class ImageRegionHandler:
             missing = sum(p is None for p in planes)
             planes = self._load_missing_planes(src, ctx, region, level,
                                                zip(keys, active, planes))
-        return self._channel_stack(planes, missing)
+        return self._channel_stack(planes, missing, ctx)
 
     def _load_missing_planes(self, src, ctx: ImageRegionCtx,
                              region: RegionDef, level: int,

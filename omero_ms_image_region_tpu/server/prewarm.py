@@ -28,9 +28,12 @@ points with the renderer's own wire engine:
   non-power-of-two 3 and 6 — are what a queue shorter than the cap and
   the inflight-aware group split launch);
 - the packed-RGBA program at batch 1 (png/tif formats);
-- the stack of the spec's channel planes
-  (``ops.render.stack_channel_planes``: what a request whose channels
-  are HBM-resident dispatches first).
+- the stack of a group's resident channel planes at each of those
+  batch shapes (``ops.render.stack_group_planes``: what the batcher
+  dispatches first for a group whose members' channels are
+  HBM-resident, one program a (B, C, shape, dtype)), and the stack of
+  one request's planes (``ops.render.stack_channel_planes``: a flipped
+  request stacks by itself).
 
 Settings use the ramp-weight table form (plain color channels; LUT
 renders compile on first use).
@@ -89,13 +92,20 @@ def parse_spec(spec: str) -> Tuple[int, int, int, "np.dtype"]:
 def _warm_one(C: int, edge: int, quality: int, batch_sizes: Sequence[int],
               engine: str, bucket: Tuple[int, int], raw_dtype,
               exec_cache=None) -> None:
+    import jax
+
     from ..flagship import flagship_settings
     from ..ops.jpegenc import render_batch_to_jpeg
-    from ..ops.render import render_tile_batch_packed
+    from ..ops.render import (render_tile_batch_packed,
+                              stack_channel_planes, stack_group_planes)
 
     bh, bw = bucket
     _, settings = flagship_settings(C)
+    plane = jax.device_put(np.zeros((bh, bw), raw_dtype))
+    # The fallback of a request that is flipped or padded by itself.
+    stack_channel_planes(*[plane] * C).block_until_ready()
     for B in dict.fromkeys(batch_sizes):   # de-dup, keep order
+        stack_group_planes(((plane,) * C,) * B).block_until_ready()
         # Zeros: programs are content-independent.  The dtype must
         # match what serving stacks (it keys the compiled program);
         # both cache postures stage the images' STORAGE dtype.
@@ -128,10 +138,6 @@ def _warm_one(C: int, edge: int, quality: int, batch_sizes: Sequence[int],
                            else render_tile_batch_packed(*args))
             else:
                 np.asarray(render_tile_batch_packed(*args))
-    import jax
-    from ..ops.render import stack_channel_planes
-    plane = jax.device_put(np.zeros((bh, bw), raw_dtype))
-    stack_channel_planes(*[plane] * C).block_until_ready()
 
 
 def prewarm_batch_sizes(cap: int) -> tuple:

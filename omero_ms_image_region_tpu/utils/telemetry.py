@@ -3551,6 +3551,7 @@ METRIC_TYPES: Dict[str, str] = {
     "imageregion_batcher_max_batch": "gauge",
     "imageregion_batcher_shape_slots_total": "counter",
     "imageregion_batcher_padded_slots_total": "counter",
+    "imageregion_batcher_group_stacks_total": "counter",
     "imageregion_renders_routed_total": "counter",
     "imageregion_batcher_queue_wait_max_ms": "gauge",
     "imageregion_compile_events_total": "counter",
@@ -3780,6 +3781,9 @@ METRIC_TYPES: Dict[str, str] = {
 # from the name; every family gets a HELP line (fallback text) so the
 # exposition lint can hold "HELP exactly once per family" everywhere.
 METRIC_HELP: Dict[str, str] = {
+    "imageregion_batcher_group_stacks_total":
+        "Groups staged, by path: one program over the members' "
+        "resident planes, or a stack of the members' own arrays",
     "imageregion_rawcache_channel_loads_total":
         "Channel planes read (or handed over) and uploaded to the HBM "
         "raw cache",
@@ -4224,6 +4228,12 @@ def device_metric_lines(services, extra_labels: str = "") -> List[str]:
             f"imageregion_batcher_padded_slots_total{lb} "
             f"{renderer.padded_slots}",
         ]
+        # Groups staged by one program over their members' resident
+        # planes ("planes"), or from the members' own stacks ("arrays").
+        for path, n in getattr(renderer, "group_stacks", {}).items():
+            body = f'path="{path}"'
+            lines.append("imageregion_batcher_group_stacks_total"
+                         f"{label(body)} {n}")
     if hasattr(renderer, "queue_depth"):
         lb = label()
         lines += [

@@ -6,6 +6,7 @@ says why they run from here): ``BENCHMARK.json`` against its files,
 the proof that ``correct`` can come out false."""
 
 import inspect
+import importlib
 import json
 import os
 
@@ -55,12 +56,12 @@ def test_benchmark_file(rehearsal_root, which, check):
 
 def test_lane_hold_ms_is_added_at_the_end_and_reads_the_lanes_span():
     """PR 31's per-layer metric: a list entry after everything the
-    benchmark had then (PR 32 appended three), in every cell, and a
-    file that hands the span ``batcher.laneHold`` to the reader the
-    other span means use."""
+    benchmark had then (PR 32 appended three, PR 33 one), in every
+    cell, and a file that hands the span ``batcher.laneHold`` to the
+    reader the other span means use."""
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    entry = bench["per_layer"][-4]
+    entry = bench["per_layer"][-5]
     assert entry == {
         "name": "lane_hold_ms", "unit": "ms", "better": "lower",
         "source": "program_span", "layer": "batcher",
@@ -85,6 +86,58 @@ def test_lane_hold_ms_is_added_at_the_end_and_reads_the_lanes_span():
     with BatchingRenderer()._lane():
         assert holds() == before        # recorded at release
     assert holds() == before + 1
+
+
+def test_plane_stack_share_is_added_at_the_end_and_reads_the_counter():
+    """PR 33's per-layer metric: the last list entry, in every cell; a
+    file that hands ``imageregion_batcher_group_stacks_total`` to the
+    reader ``host_route_share`` uses; nothing from a server without
+    the family (the parent), never 0 and never a raise."""
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    assert bench["per_layer"][-1] == {
+        "name": "plane_stack_share", "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "staging",
+        "moves": "renders_per_s",
+        "workloads": [w["name"] for w in bench["workloads"]]}
+    with open(os.path.join(REPO, "benchmark", "layer_metrics",
+                           "plane_stack_share.json")) as f:
+        spec = json.load(f)
+    family = "imageregion_batcher_group_stacks_total"
+    assert spec == {
+        "name": "plane_stack_share", "layer": "staging", "unit": "%",
+        "moves": "renders_per_s", "source": "program_counter",
+        "reader": "labelled_ratio",
+        "args": {"numerator": [{"family": family,
+                                "labels": {"path": "planes"}}],
+                 "denominator": [{"family": family}], "percent": True}}
+    reader = importlib.import_module("benchmark.readers.labelled_ratio")
+
+    def read(m0, m1):
+        return reader.read({"m0": m0, "m1": m1}, **spec["args"])
+
+    parent = {"imageregion_batches_dispatched": 10.0,
+              'imageregion_span_count{span="batcher.stage"}': 10.0}
+    assert read({}, parent) is None
+    assert read(parent, {k: 2 * v for k, v in parent.items()}) is None
+    m0 = {family + '{path="planes"}': 4.0, family + '{path="arrays"}': 1.0}
+    m1 = {family + '{path="planes"}': 34.0,
+          family + '{path="arrays"}': 11.0}
+    assert read(m0, m1) == pytest.approx(75.0)
+    assert read(m0, m0) is None          # no group in the window
+    # The series are the batcher's own, on /metrics from its first
+    # scrape on (both labels, so a share is never read from a missing
+    # one).
+    from omero_ms_image_region_tpu.server.batcher import BatchingRenderer
+    from omero_ms_image_region_tpu.utils.telemetry import (
+        device_metric_lines)
+
+    class Services:
+        renderer = BatchingRenderer()
+
+    text = "\n".join(device_metric_lines(Services()))
+    assert family + '{path="planes"} 0' in text
+    assert family + '{path="arrays"} 0' in text
 
 
 @pytest.mark.parametrize("cell", rehearsal.CELLS)

@@ -179,6 +179,9 @@ def test_rehearsal_traced_line_reads_the_layer_metrics(
     assert set(result["metrics"]) == want
     value = {k: v["value"] for k, v in result["metrics"].items()}
     assert value["host_route_share"] == 0.0
+    # PR 33: read in every cell; 64^2 tiles pad to the 256^2 bucket,
+    # so the rehearsal's groups stack their members' own stacks.
+    assert value["plane_stack_share"] == 0.0
     assert value["rawcache_hit_share"] >= 99.0
     assert value["prepare_ms"] > 0.0
     assert 0.0 <= value["group_pad_share"] < 50.0

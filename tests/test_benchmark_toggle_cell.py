@@ -49,8 +49,8 @@ def test_the_new_entries_are_the_issues():
     assert (single["name"], single["config"], single["traffic"],
             single["chips"]) == (SINGLE_CELL, "stock4-u16-t256",
                                  "single", 1)
-    assert [m["name"] for m in bench["per_layer"]][-3:] == list(
-        NEW_METRICS)
+    assert [m["name"] for m in bench["per_layer"]][-4:-1] == list(
+        NEW_METRICS)       # PR 33 appended one after them
     listed = {cell: {m["name"] for m in bench["per_layer"]
                      if cell in m["workloads"]}
               for cell in (w["name"] for w in bench["workloads"])}
@@ -349,6 +349,11 @@ def test_rehearsal_traced_line_reads_the_layer_metrics(
     assert value["channel_stack_ms"] > 0.0
     assert value["prepare_ms"] > 0.0
     assert value["group_renders"] >= 1.0
+    # PR 33: a number in every cell.  A 64^2 tile is smaller than the
+    # smallest shipped bucket (256^2), so each request is stacked and
+    # padded by itself; a tile that fills its bucket rides to its group
+    # as planes (tests/test_group_stack.py).
+    assert value["plane_stack_share"] == 0.0
 
 
 def test_rehearsal_part_of_a_group_shed_comes_out_not_correct(

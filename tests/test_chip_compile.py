@@ -125,6 +125,28 @@ def test_channel_stack_and_the_multiplexed_counts_compile(shape, shown):
         cap=jpegenc.default_sparse_cap(H, W, QUALITY)))
 
 
+@pytest.mark.parametrize("B, shown, edge", [
+    (64, 4, 256), (8, 4, 1024), (8, 6, 1024), (8, 3, 2048)])
+def test_group_stack_compiles_and_keeps_its_one_scope(shape, B, shown,
+                                                      edge):
+    """The one program that stacks a group's B x C resident planes, at
+    the largest batch shape of each cell's bucket: named
+    ``stage.channel_stack`` (``utils.profile_summary`` charges its
+    device time to that stage) and nothing else."""
+    import re
+
+    from omero_ms_image_region_tpu.ops.render import stack_group_planes
+    from omero_ms_image_region_tpu.utils.profile_summary import STAGES
+    plane = shape((edge, edge), "uint16")
+    text = _compiled(stack_group_planes.lower(
+        ((plane,) * shown,) * B)).as_text()
+    assert f"u16[{B},{shown},{edge},{edge}]" in text
+    scopes = set()
+    for op_name in re.findall(r'op_name="([^"]*)"', text):
+        scopes.update(op_name.split("/"))
+    assert scopes & set(STAGES) == {"stage.channel_stack"}
+
+
 def test_mask_pyramid_projection_programs_compile(shape):
     from omero_ms_image_region_tpu.ops import maskops, projection, pyramid
     _compiled(maskops._rasterize_batch_jit.lower(

@@ -347,6 +347,11 @@ def test_every_scope_is_in_the_compiled_text_of_the_served_programs():
     plane = np.zeros((16, 16), np.uint16)
     assert _scopes_in(stack_channel_planes.lower(
         plane, plane).compile().as_text()) == {"stage.channel_stack"}
+    # So is the stack of a group's planes (PR 33), under the same stage.
+    from omero_ms_image_region_tpu.ops.render import stack_group_planes
+    assert _scopes_in(stack_group_planes.lower(
+        ((plane, plane),) * 3).compile().as_text()) == {
+        "stage.channel_stack"}
     assert set(ps.STAGES) == front | {
         "wire.sparse_pack", "wire.sparse_pack.scatter",
         "wire.sparse_pack.bits", "wire.compact_rows",

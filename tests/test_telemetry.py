@@ -511,6 +511,9 @@ _ALLOWED_LABEL_KEYS = frozenset({
     # utils.profile_summary.STAGES plus "unnamed", ``during`` its
     # IDLE_ORDER plus "no_group" / "unattributed" -- both fixed there.
     "stage", "during",
+    # How a group's raw array came to be (PR 33): "planes" or
+    # "arrays", the two keys of ``BatchingRenderer.group_stacks``.
+    "path",
 })
 
 

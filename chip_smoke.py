@@ -619,7 +619,11 @@ def phase_a(workdir: str, data_dir: str, get_data) -> dict:
         # (Prewarm fetches too, outside the batcher: count from ready.)
         fetches = span_count(m, "wire.fetch") \
             - span_count(m_ready, "wire.fetch")
-        check(fetches == groups,
+        # A (shape, quality, engine) workload whose first dense tile
+        # overflows its cap takes ONE retry, a second fetch, and starts
+        # at the doubled cap ever after (``ops.jpegenc._CAP_MEMO``); the
+        # rehearsal's noise tiles do in their own 64^2 bucket.
+        check(groups <= fetches <= groups + 1,
               f"{fetches} wire fetches for {groups} JPEG groups")
         check(span_count(m, "Renderer.renderAsPackedInt.batch")
               == batches - span_count(m, "Renderer.rasterizeMask.batch")

@@ -31,6 +31,7 @@ Semantics preserved from the reference renderer:
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -319,15 +320,28 @@ def stack_channel_planes(*planes):
         return jnp.stack(planes)
 
 
-@jax.jit
-def stack_group_planes(members):
+@functools.partial(jax.jit, static_argnames=("pad",))
+def stack_group_planes(members, pad=None):
     """The ``[B, C, h, w]`` array a group's render takes, from its
     members' planes: ``members`` is a tuple of ``B`` tuples of ``C``
     device-resident planes ``[h, w]`` (a padded slot repeats the last
-    member's).  One program a (B, C, shape, dtype) and ONE dispatch a
-    group, where a stack a request and an eager ``jnp.stack`` of the
+    member's).  One program a (B, C, shape, pad, dtype) and ONE dispatch
+    a group, where a stack a request and an eager ``jnp.stack`` of the
     stacks were B + B + 1.  Bit for bit
     ``jnp.stack([stack_channel_planes(*m) for m in members])``.  The
-    planes are the cache's: nothing is donated."""
+    planes are the cache's: nothing is donated.
+
+    ``pad``: the bucket ``(bh, bw)`` of a JPEG group whose planes are
+    smaller than it (a 1080^2 field in its 1088^2 MCU grid).  The same
+    program then edge-replicates the stack to ``[B, C, bh, bw]``, bit
+    for bit ``ops.jpegenc.pad_planes_to_mcu`` of each member's stack;
+    the cache keeps the planes as the store holds them."""
     with jax.named_scope("stage.channel_stack"):
-        return jnp.stack([jnp.stack(planes) for planes in members])
+        raw = jnp.stack([jnp.stack(planes) for planes in members])
+    h, w = raw.shape[-2:]
+    if pad is None or tuple(pad) == (h, w):
+        return raw
+    # A stage of its own: what a bucket larger than the plane costs.
+    with jax.named_scope("stage.pad_mcu"):
+        return jnp.pad(raw, ((0, 0), (0, 0), (0, pad[0] - h),
+                             (0, pad[1] - w)), mode="edge")

@@ -2497,6 +2497,7 @@ def build_local_members(config, base_services, n: int,
     from ..server.batcher import BatchingRenderer
     from ..server.handler import (ImageRegionHandler,
                                   ImageRegionServices, Renderer)
+    from ..server.prewarm import stated_planes
 
     def devices_for(i: int) -> tuple:
         if not device_sets or i >= len(device_sets):
@@ -2529,7 +2530,8 @@ def build_local_members(config, base_services, n: int,
                 jpeg_engine=config.renderer.jpeg_engine,
                 pipeline_depth=config.batcher.pipeline_depth,
                 target_inflight=config.batcher.target_inflight,
-                device_lanes=config.batcher.device_lanes)
+                device_lanes=config.batcher.device_lanes,
+                planes=stated_planes(config.renderer.prewarm))
             renderer.first_tile_out = config.wire.streaming
         else:
             renderer = Renderer(jpeg_engine=config.renderer.jpeg_engine)

@@ -197,7 +197,8 @@ class MeshRenderer(BatchingRenderer):
     def __init__(self, mesh: Mesh, max_batch: int | None = None,
                  linger_ms: float = 2.0, buckets=None,
                  jpeg_engine: str = "sparse", pipeline_depth: int = 4,
-                 max_batch_limit: int = None, device_lanes: int = 2):
+                 max_batch_limit: int = None, device_lanes: int = 2,
+                 planes=()):
         data = mesh.shape["data"]
         if max_batch is None:
             max_batch = max(8, 2 * data)
@@ -223,7 +224,8 @@ class MeshRenderer(BatchingRenderer):
         super().__init__(max_batch=max_batch, linger_ms=linger_ms,
                          pipeline_depth=pipeline_depth,
                          max_batch_limit=max_batch_limit,
-                         device_lanes=device_lanes, **kwargs)
+                         device_lanes=device_lanes, planes=planes,
+                         **kwargs)
         if multihost:
             # One launch slot shared across ALL bucket keys: without it,
             # two keys' dispatchers would interleave sharded launches in

@@ -169,6 +169,7 @@ def build_services(config: AppConfig) -> "ImageRegionServices":
             config.persistence.disk_cache_max_bytes
     from .batcher import BatchingRenderer
     from .handler import ImageRegionServices, Renderer
+    from .prewarm import stated_planes
     if config.parallel.enabled:
         # Mesh-sharded serving (≙ the reference's -cluster mode):
         # groups dispatch through the (data, chan) mesh steps.
@@ -205,7 +206,8 @@ def build_services(config: AppConfig) -> "ImageRegionServices":
             linger_ms=config.batcher.linger_ms,
             jpeg_engine=config.renderer.jpeg_engine,
             pipeline_depth=config.batcher.pipeline_depth,
-            device_lanes=config.batcher.device_lanes)
+            device_lanes=config.batcher.device_lanes,
+            planes=stated_planes(config.renderer.prewarm))
     elif config.batcher.enabled:
         renderer = BatchingRenderer(
             max_batch=config.batcher.max_batch,
@@ -214,7 +216,8 @@ def build_services(config: AppConfig) -> "ImageRegionServices":
             jpeg_engine=config.renderer.jpeg_engine,
             pipeline_depth=config.batcher.pipeline_depth,
             target_inflight=config.batcher.target_inflight,
-            device_lanes=config.batcher.device_lanes)
+            device_lanes=config.batcher.device_lanes,
+            planes=stated_planes(config.renderer.prewarm))
     else:
         renderer = Renderer(jpeg_engine=config.renderer.jpeg_engine)
     # Say ONCE what this process serves from, and refuse the CPU

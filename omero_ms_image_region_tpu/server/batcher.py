@@ -53,6 +53,27 @@ def pick_bucket(h: int, w: int,
     return h, w
 
 
+def mcu_grid(h: int, w: int) -> Tuple[int, int]:
+    """``(h, w)`` rounded up to whole 16 x 16 MCUs: the least array a
+    4:2:0 JPEG of that size is coded from."""
+    return h + (-h) % 16, w + (-w) % 16
+
+
+def bucket_lattice(ladder=DEFAULT_BUCKETS, planes=()) -> tuple:
+    """The buckets a renderer picks from: the fixed ``ladder`` plus the
+    MCU grid of every plane shape ``(h, w)`` the site states
+    (``renderer.prewarm``: ``server.prewarm.stated_planes``), smallest
+    first.  A stated 1080^2 field gets a 1088^2 bucket where the ladder
+    alone would render, pack and fetch it as 2048^2, 3.5 x its pixels.
+    A client's ``region=`` adds nothing here, so the compile set stays
+    what the site wrote down.  Nothing stated: the ladder as given."""
+    grids = {mcu_grid(h, w) for h, w in planes} - set(ladder)
+    if not grids:
+        return tuple(ladder)
+    return tuple(sorted(set(ladder) | grids,
+                        key=lambda b: (b[0] * b[1], b)))
+
+
 # Allowed padded batch shapes: powers of two plus 3 and 6, so the
 # inflight-aware group split (see _pop_size) can run ~3 concurrent
 # groups from a 16-request burst without paying 8-shape execution for
@@ -96,14 +117,24 @@ def _raw_form(raw) -> tuple:
     return tuple(raw.shape) + (raw.dtype,)
 
 
-def _request_stack(raw):
+def _request_stack(raw, pad_to=None):
     """A request's ``[C, h, w]`` array, stacked from its planes where
-    it carries them (one dispatch: the fallback of a group that cannot
+    it carries them and edge-replicated to ``pad_to`` where its group's
+    program would have done that (the fallback of a group that cannot
     take the planes as they are)."""
     if isinstance(raw, tuple):
         from ..ops.render import stack_channel_planes
-        return stack_channel_planes(*raw)
+        raw = stack_channel_planes(*raw)
+        if pad_to is not None:
+            from ..ops.jpegenc import pad_planes_to_mcu
+            raw = pad_planes_to_mcu(raw, *pad_to)
     return raw
+
+
+def _plane_shape(raw):
+    """``(h, w)`` of the planes a request carries, None where it
+    carries its own stack."""
+    return raw[0].shape if isinstance(raw, tuple) else None
 
 
 def _key_label(key: tuple) -> str:
@@ -114,6 +145,13 @@ def _key_label(key: tuple) -> str:
     if key and key[0] == "mask":
         return "mask:" + "x".join(str(v) for v in key[1:3])
     return "x".join(str(v) for v in key[:3])
+
+
+def _bucket_label(key: tuple) -> str:
+    """The spatial bucket of a group key ("1088x1088"), as the
+    ``batcher.group`` span carries it; a mask's shape is its own."""
+    at = 2 if key and key[0] == "jpeg" else 1
+    return "x".join(str(v) for v in key[at:at + 2])
 
 
 def _shape_label(raw_shape, jpeg: bool = False) -> str:
@@ -160,8 +198,9 @@ def _capture_shape_estimate(shape: str, jitted_fn, args) -> None:
 class _Pending:
     # What the group stacks: ``[C, bh, bw]`` padded to the bucket, in
     # the storage dtype (host numpy or device-resident), or a tuple of
-    # ``C`` device-resident planes ``[bh, bw]`` of the HBM raw cache
-    # that needed nothing done to them (``takes_planes``).
+    # ``C`` device-resident planes of the HBM raw cache that needed
+    # nothing done to them per request (``takes_planes``): ``[bh, bw]``,
+    # or a stated plane shape whose MCU grid the bucket is (``pad_to``).
     raw: object
     settings: dict
     h: int
@@ -171,6 +210,10 @@ class _Pending:
     # group's cap follows (``group_cap``).  0 = not bucketed (a mask,
     # shape-keyed): the cap stays ``max_batch``.
     bucket_px: int = 0
+    # The bucket ``(bh, bw)`` the group's one program edge-replicates
+    # this request's planes to (a JPEG request that carries planes
+    # smaller than their bucket); None = ``raw`` has the bucket's shape.
+    pad_to: Optional[Tuple[int, int]] = None
     future: asyncio.Future = None  # type: ignore[assignment]
     t_enqueue: float = 0.0        # queue-wait waterfall span
     trace_id: str = None          # type: ignore[assignment]  # requester
@@ -221,7 +264,8 @@ class BatchingRenderer:
     def __init__(self, max_batch: int = 8, linger_ms: float = 2.0,
                  buckets=DEFAULT_BUCKETS, jpeg_engine: str = "sparse",
                  pipeline_depth: int = 4, max_batch_limit: int = None,
-                 target_inflight: int = 1, device_lanes: int = 2):
+                 target_inflight: int = 1, device_lanes: int = 2,
+                 planes=()):
         if jpeg_engine not in ("sparse", "huffman"):
             raise ValueError(
                 f"batched jpeg engine must be 'sparse' or 'huffman', "
@@ -262,7 +306,12 @@ class BatchingRenderer:
                                           pipeline_depth))
         self.jpeg_engine = jpeg_engine
         self.pipeline_depth = pipeline_depth
-        self.buckets = tuple(buckets)
+        # ``planes``: the plane shapes (h, w) the site states
+        # (``renderer.prewarm``).  Each gets a bucket no larger than
+        # its MCU grid (``bucket_lattice``), and resident planes of a
+        # stated shape ride to their group as they are.
+        self.planes = frozenset((int(h), int(w)) for h, w in planes)
+        self.buckets = bucket_lattice(buckets, self.planes)
         self._queues: Dict[tuple, Deque[_Pending]] = {}
         self._dispatchers: Dict[tuple, asyncio.Task] = {}
         self._wakeups: Dict[tuple, asyncio.Event] = {}
@@ -286,6 +335,11 @@ class BatchingRenderer:
         # "planes" = one jitted program over the members' resident
         # planes, "arrays" = a stack of the members' own stacks.
         self.group_stacks = {"planes": 0, "arrays": 0}
+        # Pixels of the groups launched (/metrics
+        # imageregion_batcher_bucket_px_total{part=}): "image" = the
+        # members' own h x w, "pad" = what their buckets hold beyond
+        # them.  Padded batch slots are ``padded_slots``', not these.
+        self.bucket_px = {"image": 0, "pad": 0}
         # Two-stage group pipeline: each group render splits into a
         # fetch/stage half (stacking + host->device upload, run by any
         # of the pipeline_depth worker threads) and a device-execute
@@ -496,11 +550,17 @@ class BatchingRenderer:
     def takes_planes(self, h: int, w: int, jpeg: bool) -> bool:
         """Whether a request's resident planes ``[h, w]`` can ride to
         their group as they are (``_Pending.raw`` as a tuple): they fill
-        their bucket, so ``render`` / ``render_jpeg`` would pad nothing.
-        What the handler asks before it stacks a request."""
+        their bucket, so ``render`` / ``render_jpeg`` would pad nothing;
+        or, for a JPEG, they have a shape the site states, whose MCU
+        grid is a bucket, and the group's one program pads them to it.
+        What the handler asks before it stacks a request.  A shape
+        nobody stated (a client's ``region=``, a WSI edge tile) is
+        stacked and padded by itself: one program a shape there would
+        leave the compile set to the clients."""
         if jpeg:
-            return pick_bucket(h + (-h) % 16, w + (-w) % 16,
-                               self.buckets) == (h, w)
+            return ((h, w) in self.planes
+                    or pick_bucket(*mcu_grid(h, w),
+                                   self.buckets) == (h, w))
         return pick_bucket(h, w, self.buckets) == (h, w)
 
     async def render(self, raw, settings: dict) -> np.ndarray:
@@ -549,16 +609,20 @@ class BatchingRenderer:
         from ..ops.jpegenc import pad_planes_to_mcu
 
         C, h, w, dtype = _raw_form(raw)
-        gh, gw = h + (-h) % 16, w + (-w) % 16
-        bh, bw = pick_bucket(gh, gw, self.buckets)
+        bh, bw = pick_bucket(*mcu_grid(h, w), self.buckets)
+        pad_to = None
         if (bh, bw) != (h, w):
-            raw = pad_planes_to_mcu(_request_stack(raw), bh, bw)
+            if isinstance(raw, tuple) and (h, w) in self.planes:
+                pad_to = (bh, bw)      # the group's program pads them
+            else:
+                raw = pad_planes_to_mcu(_request_stack(raw), bh, bw)
         key = ("jpeg", C, bh, bw, int(settings["cd_start"]),
                int(settings["cd_end"]), settings["tables"].ndim, quality,
                str(dtype))
         from ..utils.transient import deadline as _deadline
         pending = _Pending(raw=raw, settings=settings, h=height, w=width,
                            quality=quality, bucket_px=bh * bw,
+                           pad_to=pad_to,
                            future=asyncio.get_running_loop().create_future(),
                            trace_id=telemetry.current_trace_id(),
                            deadline=_deadline())
@@ -819,7 +883,7 @@ class BatchingRenderer:
                     tiles=len(group),
                     padded=_pad_batch_size(
                         len(group), self.group_cap(group[0].bucket_px)),
-                    key=_key_label(key)):
+                    key=_key_label(key), bucket=_bucket_label(key)):
                 return run_inner()
 
         inner = asyncio.ensure_future(asyncio.to_thread(run))
@@ -868,18 +932,26 @@ class BatchingRenderer:
         B = _pad_batch_size(len(group),
                             self.group_cap(group[0].bucket_px))
         padded = group + [group[-1]] * (B - len(group))
-        planes = all(isinstance(p.raw, tuple) for p in group)
+        shape = _plane_shape(group[0].raw)
+        planes = shape is not None and all(
+            _plane_shape(p.raw) == shape for p in group)
         if planes:
             from ..ops.render import stack_group_planes
-            raw = stack_group_planes(tuple(p.raw for p in padded))
+            raw = stack_group_planes(tuple(p.raw for p in padded),
+                                     pad=group[0].pad_to)
         elif all(isinstance(p.raw, np.ndarray) for p in group):
             raw = np.stack([p.raw for p in padded])
         else:
             import jax.numpy as jnp
-            stacks = [_request_stack(p.raw) for p in group]
+            stacks = [_request_stack(p.raw, p.pad_to) for p in group]
             raw = jnp.stack(stacks + stacks[-1:] * (B - len(group)))
+        image_px = sum(p.h * p.w for p in group)
         with self._stats_lock:
             self.group_stacks["planes" if planes else "arrays"] += 1
+            if group[0].bucket_px:
+                self.bucket_px["image"] += image_px
+                self.bucket_px["pad"] += (
+                    len(group) * group[0].bucket_px - image_px)
 
         def stack(name):
             return np.stack([p.settings[name] for p in padded])

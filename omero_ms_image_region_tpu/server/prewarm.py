@@ -11,14 +11,19 @@ front-loads reader construction cost at startup
 ``renderer.prewarm`` lists the tile shapes a deployment expects, e.g.::
 
     renderer:
-        prewarm: ["4x1024", "3x512@90", "2x1024:uint8"]
+        prewarm: ["4x1024", "3x512@90", "2x1024:uint8", "5x1080"]
 
 Each spec is ``<channels>x<tile-edge>[@quality][:dtype]`` (quality
 defaults to the LocalCompress default; ``:dtype`` names the images'
 storage dtype, default uint16 — serving stages storage dtype in both
-cache postures, and the dtype keys the compiled program).  For every
-spec the serving-path programs are compiled through the real ops entry
-points with the renderer's own wire engine:
+cache postures, and the dtype keys the compiled program).  The edge is
+the edge of a plane as the store holds it, any whole number of pixels:
+it is where a site STATES its plane sizes, and the renderer gives each
+stated size a bucket of its own, its MCU grid (:func:`stated_planes`,
+``batcher.bucket_lattice``: ``5x1080`` is served from a 1088^2 array,
+where a size nobody stated falls to the fixed ladder's next bucket,
+2048^2).  For every spec the serving-path programs are compiled through
+the real ops entry points with the renderer's own wire engine:
 
 - the batched JPEG program at EVERY launchable padded batch shape up
   to the cap of the spec's bucket (``batcher.group_cap``: ``max_batch``
@@ -31,7 +36,8 @@ points with the renderer's own wire engine:
 - the stack of a group's resident channel planes at each of those
   batch shapes (``ops.render.stack_group_planes``: what the batcher
   dispatches first for a group whose members' channels are
-  HBM-resident, one program a (B, C, shape, dtype)), and the stack of
+  HBM-resident, one program a (B, C, shape, bucket, dtype), which also
+  pads a plane smaller than its bucket), and the stack of
   one request's planes (``ops.render.stack_channel_planes``: a flipped
   request stacks by itself).
 
@@ -76,10 +82,9 @@ def parse_spec(spec: str) -> Tuple[int, int, int, "np.dtype"]:
                         else round(DEFAULT_JPEG_QUALITY * 100))
     if not (1 <= channels <= 64):
         raise ValueError(f"prewarm channels out of range: {spec!r}")
-    if not (16 <= edge <= 8192) or edge % 16:
+    if not (16 <= edge <= 8192):
         raise ValueError(
-            f"prewarm tile edge must be a multiple of 16 in "
-            f"[16, 8192]: {spec!r}")
+            f"prewarm tile edge must lie in [16, 8192]: {spec!r}")
     if not (1 <= q <= 100):
         raise ValueError(f"prewarm quality out of range: {spec!r}")
     dt = m.group(4) or "uint16"
@@ -87,6 +92,15 @@ def parse_spec(spec: str) -> Tuple[int, int, int, "np.dtype"]:
         raise ValueError(
             f"prewarm dtype {dt!r} not one of {_SPEC_DTYPES}: {spec!r}")
     return channels, edge, q, np.dtype(dt)
+
+
+def stated_planes(specs: Sequence[str]) -> tuple:
+    """The plane shapes ``(h, w)`` that ``renderer.prewarm`` states, in
+    its order: what every renderer of the process builds its bucket
+    lattice from (``BatchingRenderer(planes=...)``), the one chip's,
+    the mesh's and each fleet member's alike."""
+    return tuple(dict.fromkeys(
+        (edge, edge) for _, edge, _, _ in map(parse_spec, specs)))
 
 
 def _warm_one(C: int, edge: int, quality: int, batch_sizes: Sequence[int],
@@ -98,14 +112,21 @@ def _warm_one(C: int, edge: int, quality: int, batch_sizes: Sequence[int],
     from ..ops.jpegenc import render_batch_to_jpeg
     from ..ops.render import (render_tile_batch_packed,
                               stack_channel_planes, stack_group_planes)
+    from .batcher import mcu_grid
 
     bh, bw = bucket
     _, settings = flagship_settings(C)
-    plane = jax.device_put(np.zeros((bh, bw), raw_dtype))
+    # A plane as the raw cache holds it: the spec's own shape where the
+    # bucket is its MCU grid (the group's program pads it), else the
+    # bucket's.
+    shape = (edge, edge) if mcu_grid(edge, edge) == bucket else bucket
+    pad = None if shape == bucket else bucket   # as _Pending.pad_to
+    plane = jax.device_put(np.zeros(shape, raw_dtype))
     # The fallback of a request that is flipped or padded by itself.
     stack_channel_planes(*[plane] * C).block_until_ready()
     for B in dict.fromkeys(batch_sizes):   # de-dup, keep order
-        stack_group_planes(((plane,) * C,) * B).block_until_ready()
+        stack_group_planes(((plane,) * C,) * B,
+                           pad=pad).block_until_ready()
         # Zeros: programs are content-independent.  The dtype must
         # match what serving stacks (it keys the compiled program);
         # both cache postures stage the images' STORAGE dtype.
@@ -166,7 +187,7 @@ def prewarm_renderer(specs: List[str], engine: str,
     while this runs (telemetry.READINESS).
     """
     from ..utils.telemetry import READINESS
-    from .batcher import group_cap, pick_bucket
+    from .batcher import group_cap, mcu_grid, pick_bucket
     # Malformed specs raise HERE, before the readiness flag flips or
     # any compile starts (the loader's contract: config errors are
     # loud, and a caller spawning this on a background thread gets the
@@ -184,7 +205,7 @@ def prewarm_renderer(specs: List[str], engine: str,
                     cpu_fallback_max_px)
                 continue
             t0 = time.perf_counter()
-            bucket = pick_bucket(edge, edge, buckets)
+            bucket = pick_bucket(*mcu_grid(edge, edge), buckets)
             batch_sizes = prewarm_batch_sizes(
                 group_cap(max_batch, bucket[0] * bucket[1]))
             try:

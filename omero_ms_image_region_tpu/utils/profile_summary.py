@@ -55,7 +55,7 @@ HOST_PLANE = "/host:CPU"
 STAGES = ("render", "jpeg.ycbcr420", "jpeg.dct_quant",
           "wire.sparse_pack", "wire.sparse_pack.scatter",
           "wire.sparse_pack.bits", "wire.huffman_pack",
-          "wire.compact_rows", "stage.channel_stack")
+          "wire.compact_rows", "stage.channel_stack", "stage.pad_mcu")
 UNNAMED = "unnamed"
 
 COMPILE = "xla.compile"

@@ -3552,6 +3552,7 @@ METRIC_TYPES: Dict[str, str] = {
     "imageregion_batcher_shape_slots_total": "counter",
     "imageregion_batcher_padded_slots_total": "counter",
     "imageregion_batcher_group_stacks_total": "counter",
+    "imageregion_batcher_bucket_px_total": "counter",
     "imageregion_renders_routed_total": "counter",
     "imageregion_batcher_queue_wait_max_ms": "gauge",
     "imageregion_compile_events_total": "counter",
@@ -3784,6 +3785,9 @@ METRIC_HELP: Dict[str, str] = {
     "imageregion_batcher_group_stacks_total":
         "Groups staged, by path: one program over the members' "
         "resident planes, or a stack of the members' own arrays",
+    "imageregion_batcher_bucket_px_total":
+        "Pixels of the groups launched, by part: the members' own "
+        "image, or the pad their buckets hold beyond it",
     "imageregion_rawcache_channel_loads_total":
         "Channel planes read (or handed over) and uploaded to the HBM "
         "raw cache",
@@ -4233,6 +4237,12 @@ def device_metric_lines(services, extra_labels: str = "") -> List[str]:
         for path, n in getattr(renderer, "group_stacks", {}).items():
             body = f'path="{path}"'
             lines.append("imageregion_batcher_group_stacks_total"
+                         f"{label(body)} {n}")
+        # Pixels launched: the members' own, and what their buckets
+        # hold beyond them.
+        for part, n in getattr(renderer, "bucket_px", {}).items():
+            body = f'part="{part}"'
+            lines.append("imageregion_batcher_bucket_px_total"
                          f"{label(body)} {n}")
     if hasattr(renderer, "queue_depth"):
         lb = label()

@@ -5,7 +5,7 @@ from tier-1 over a rehearsal that holds EVERY configuration of
 ``benchmark/tests/conftest.py`` builds the rehearsal from
 ``rehearsal/overrides.json``, which has no entry for the deployments
 later PRs added (``stock4-u16-t256``, PR 28; ``cycif40-u16-t1024``,
-PR 32): a PR may add files under ``benchmark/`` and edit none, so the
+PR 32; ``jump5-u16-p1080``, PR 34): a PR may add files under ``benchmark/`` and edit none, so the
 entries sit beside it in ``overrides_<deployment>.json`` and every user
 of the harness's ``rehearsal_root`` fixture errors when
 ``benchmark/tests`` is run by itself, until a ``benchmark`` PR merges
@@ -13,8 +13,9 @@ the files.  Until then the users run here: the harness's builder, its
 test functions and its planted faults are loaded by path and called
 with the merged rehearsal, so nothing of them is copied
 (``tests/test_benchmark_rehearsal.py``: the cells the harness had;
-``tests/test_benchmark_stock_cell.py`` and
-``tests/test_benchmark_toggle_cell.py``: the new ones).  A rehearsal
+``tests/test_benchmark_stock_cell.py``,
+``tests/test_benchmark_toggle_cell.py`` and
+``tests/test_benchmark_jump_cell.py``: the new ones).  A rehearsal
 gives counts, never speeds.
 """
 
@@ -31,6 +32,9 @@ TOGGLE_CELL = "cycif40-u16-t1024.toggle"
 TINY_TOGGLE_CELL = "tinycycif8-u16-t64.toggle"
 SINGLE_CELL = "stock4-u16-t256.single"
 TINY_SINGLE_CELL = "tinystock4-u16-t64.single"
+JUMP_CELL, TINY_JUMP_CELL = "jump5-u16-p1080.scan", "tinyjump5-u16-p120.scan"
+SINGLE1024_CELL = "wsi4-u16-t1024.single"
+TINY_SINGLE1024_CELL = "tiny4-u16-t64.single"
 
 # The harness's rehearsal tests (benchmark/tests/test_rehearsal.py):
 # those it runs once a cell, and those it runs on its first cell only

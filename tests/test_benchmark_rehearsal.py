@@ -61,7 +61,7 @@ def test_lane_hold_ms_is_added_at_the_end_and_reads_the_lanes_span():
     reader the other span means use."""
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    entry = bench["per_layer"][-5]
+    entry = {m["name"]: m for m in bench["per_layer"]}["lane_hold_ms"]
     assert entry == {
         "name": "lane_hold_ms", "unit": "ms", "better": "lower",
         "source": "program_span", "layer": "batcher",
@@ -89,13 +89,17 @@ def test_lane_hold_ms_is_added_at_the_end_and_reads_the_lanes_span():
 
 
 def test_plane_stack_share_is_added_at_the_end_and_reads_the_counter():
-    """PR 33's per-layer metric: the last list entry, in every cell; a
+    """PR 33's per-layer metric: the list's last entry when it came
+    (later PRs append after it), in every cell; a
     file that hands ``imageregion_batcher_group_stacks_total`` to the
     reader ``host_route_share`` uses; nothing from a server without
     the family (the parent), never 0 and never a raise."""
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    assert bench["per_layer"][-1] == {
+    names = [m["name"] for m in bench["per_layer"]]
+    assert names.index("plane_stack_share") == names.index(
+        "channel_stack_ms") + 1
+    assert bench["per_layer"][names.index("plane_stack_share")] == {
         "name": "plane_stack_share", "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "staging",
         "moves": "renders_per_s",

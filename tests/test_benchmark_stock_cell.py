@@ -47,7 +47,8 @@ def test_the_new_entries_are_the_issues():
               if CELL in m["workloads"]}
     assert listed == {m["name"] for m in bench["per_layer"]} - {
         "read_region_ms", "unpack_device_ms",
-        "shown_render_roofline"}       # PR 32's, of another deployment
+        "shown_render_roofline",       # PR 32's, of another deployment
+        "bucket_fill_share", "stack_pad_device_ms"}   # PR 34's: plates
     with open(os.path.join(REPO, "benchmark", "configs",
                            "stock4-u16-t256.json")) as f:
         config = json.load(f)
@@ -179,9 +180,12 @@ def test_rehearsal_traced_line_reads_the_layer_metrics(
     assert set(result["metrics"]) == want
     value = {k: v["value"] for k, v in result["metrics"].items()}
     assert value["host_route_share"] == 0.0
-    # PR 33: read in every cell; 64^2 tiles pad to the 256^2 bucket,
-    # so the rehearsal's groups stack their members' own stacks.
-    assert value["plane_stack_share"] == 0.0
+    # PR 33: read in every cell.  Since PR 34 the rehearsal's YAML
+    # states its 64^2 tiles (``prewarm: ["4x64@90"]``), they get a
+    # bucket of their own and ride to their groups as planes, as the
+    # cell's 256^2 tiles do (before: padded into the 256^2 bucket a
+    # request at a time, 0 %).
+    assert value["plane_stack_share"] == 100.0
     assert value["rawcache_hit_share"] >= 99.0
     assert value["prepare_ms"] > 0.0
     assert 0.0 <= value["group_pad_share"] < 50.0

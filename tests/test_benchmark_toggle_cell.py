@@ -41,16 +41,19 @@ def rehearsal_root(tmp_path_factory):
 
 def test_the_new_entries_are_the_issues():
     bench = _json("BENCHMARK.json")
-    assert [c["name"] for c in bench["configs"]][-1] == CONFIG
-    assert bench["configs"][-1]["reduced"] == ["level0_tiles", "images"]
-    toggle, single = bench["workloads"][-2:]
+    # The fourth configuration, the fourth and fifth cells (later PRs
+    # append).
+    assert [c["name"] for c in bench["configs"]][3] == CONFIG
+    assert bench["configs"][3]["reduced"] == ["level0_tiles", "images"]
+    toggle, single = bench["workloads"][3:5]
     assert (toggle["name"], toggle["config"], toggle["traffic"],
             toggle["chips"]) == (TOGGLE_CELL, CONFIG, "toggle", 1)
     assert (single["name"], single["config"], single["traffic"],
             single["chips"]) == (SINGLE_CELL, "stock4-u16-t256",
                                  "single", 1)
-    assert [m["name"] for m in bench["per_layer"]][-4:-1] == list(
-        NEW_METRICS)       # PR 33 appended one after them
+    names = [m["name"] for m in bench["per_layer"]]
+    first = names.index(NEW_METRICS[0])
+    assert names[first:first + 3] == list(NEW_METRICS)
     listed = {cell: {m["name"] for m in bench["per_layer"]
                      if cell in m["workloads"]}
               for cell in (w["name"] for w in bench["workloads"])}
@@ -349,11 +352,12 @@ def test_rehearsal_traced_line_reads_the_layer_metrics(
     assert value["channel_stack_ms"] > 0.0
     assert value["prepare_ms"] > 0.0
     assert value["group_renders"] >= 1.0
-    # PR 33: a number in every cell.  A 64^2 tile is smaller than the
-    # smallest shipped bucket (256^2), so each request is stacked and
-    # padded by itself; a tile that fills its bucket rides to its group
-    # as planes (tests/test_group_stack.py).
-    assert value["plane_stack_share"] == 0.0
+    # PR 33: a number in every cell.  Since PR 34 the rehearsal's
+    # stated 64^2 tiles (``prewarm: ["3x64@90", "4x64@90"]``) have a
+    # bucket of their own and ride to their groups as planes, as the
+    # cell's 1024^2 tiles do (before: padded into the 256^2 bucket a
+    # request at a time, 0 %).
+    assert value["plane_stack_share"] == 100.0
 
 
 def test_rehearsal_part_of_a_group_shed_comes_out_not_correct(

@@ -147,6 +147,35 @@ def test_group_stack_compiles_and_keeps_its_one_scope(shape, B, shown,
     assert scopes & set(STAGES) == {"stage.channel_stack"}
 
 
+@pytest.mark.parametrize("B", [1, 8])
+def test_a_stated_1080_field_compiles_in_its_1088_bucket(shape, B):
+    """A Cell Painting field (5 x uint16 x 1080^2, PR 34): the group's
+    one program stacks B x 5 resident planes and edge-replicates them
+    to the 1088^2 bucket under a scope of its own, and the served JPEG
+    program takes that array (1088 is 8.5 lane tiles: the compiler
+    has to accept a minor dimension off 128)."""
+    import re
+
+    from omero_ms_image_region_tpu.ops import jpegenc
+    from omero_ms_image_region_tpu.ops.render import stack_group_planes
+    from omero_ms_image_region_tpu.utils.profile_summary import STAGES
+    plane = shape((1080, 1080), "uint16")
+    text = _compiled(stack_group_planes.lower(
+        ((plane,) * 5,) * B, pad=(1088, 1088))).as_text()
+    assert f"u16[{B},5,1088,1088]" in text
+    scopes = set()
+    for op_name in re.findall(r'op_name="([^"]*)"', text):
+        scopes.update(op_name.split("/"))
+    assert scopes & set(STAGES) == {"stage.channel_stack",
+                                    "stage.pad_mcu"}
+    q = (shape((8, 8), "int32"), shape((8, 8), "int32"))
+    args = (shape((B, 5, 1088, 1088), "uint16"),) + _render_args(
+        shape, B=B, C=5)[1:]
+    _compiled(jpegenc.render_to_jpeg_sparse_compact.lower(
+        *args, *q, shape((), "int32"),
+        cap=jpegenc.default_sparse_cap(1088, 1088, QUALITY)))
+
+
 def test_mask_pyramid_projection_programs_compile(shape):
     from omero_ms_image_region_tpu.ops import maskops, projection, pyramid
     _compiled(maskops._rasterize_batch_jit.lower(

@@ -199,13 +199,22 @@ def test_prewarm_specs_parse_and_validate():
     assert parse_spec("1x256:uint8") == (1, 256, 85, np.dtype(np.uint8))
     assert parse_spec("2x256@70:float32") == (2, 256, 70,
                                               np.dtype(np.float32))
-    for bad in ("x1024", "4x", "4x1000", "0x256", "4x256@0", "4x256@101",
-                "4x20", "4x256:uint64", "4x256:bogus"):
+    # The edge is a plane's edge as the store holds it, any whole
+    # number in range (PR 34): a site states a 1080^2 field as it is.
+    assert parse_spec("5x1080@90") == (5, 1080, 90, np.dtype(np.uint16))
+    assert parse_spec("4x1000") == (4, 1000, 85, np.dtype(np.uint16))
+    assert parse_spec("4x20") == (4, 20, 85, np.dtype(np.uint16))
+    for bad in ("x1024", "4x", "4x15", "4x8193", "0x256", "4x256@0",
+                "4x256@101", "4x256:uint64", "4x256:bogus",
+                "4x1080.5"):
         with pytest.raises(ValueError):
             parse_spec(bad)
     # Malformed specs fail at config LOAD, not at first serving touch.
     with pytest.raises(ValueError):
-        AppConfig.from_dict({"renderer": {"prewarm": ["4x1000"]}})
+        AppConfig.from_dict({"renderer": {"prewarm": ["4x15"]}})
+    assert AppConfig.from_dict(
+        {"renderer": {"prewarm": ["5x1080@90"]}}
+    ).renderer.prewarm == ("5x1080@90",)
 
 
 def test_hot_path_knobs_parse_and_validate():

@@ -352,10 +352,16 @@ def test_every_scope_is_in_the_compiled_text_of_the_served_programs():
     assert _scopes_in(stack_group_planes.lower(
         ((plane, plane),) * 3).compile().as_text()) == {
         "stage.channel_stack"}
+    # Where the planes are smaller than their bucket the same program
+    # pads them (PR 34), under a stage of its own.
+    field = np.zeros((24, 24), np.uint16)
+    assert _scopes_in(stack_group_planes.lower(
+        ((field, field),) * 3, pad=(32, 32)).compile().as_text()) == {
+        "stage.channel_stack", "stage.pad_mcu"}
     assert set(ps.STAGES) == front | {
         "wire.sparse_pack", "wire.sparse_pack.scatter",
         "wire.sparse_pack.bits", "wire.compact_rows",
-        "wire.huffman_pack", "stage.channel_stack"}
+        "wire.huffman_pack", "stage.channel_stack", "stage.pad_mcu"}
 
 
 def _opcodes(compiled_text: str) -> set:

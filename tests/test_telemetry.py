@@ -514,6 +514,9 @@ _ALLOWED_LABEL_KEYS = frozenset({
     # How a group's raw array came to be (PR 33): "planes" or
     # "arrays", the two keys of ``BatchingRenderer.group_stacks``.
     "path",
+    # What a launched group's pixels were (PR 34): "image" or "pad",
+    # the two keys of ``BatchingRenderer.bucket_px``.
+    "part",
 })
 
 

@@ -176,6 +176,34 @@ def test_a_stated_1080_field_compiles_in_its_1088_bucket(shape, B):
         cap=jpegenc.default_sparse_cap(1088, 1088, QUALITY)))
 
 
+@pytest.mark.parametrize("edge", [2048, 1088])
+def test_jpeg_front_end_keeps_the_plane_width_minor(shape, edge):
+    """The 4:2:0 chroma mean (PR 35): no array of the compiled front
+    end has a minor dimension of 2.  The chip lays an array out in
+    (8, 128) tiles of its two minor dimensions, and the mean written
+    as ``reshape(B, H/2, 2, W/2, 2)`` left ``f32[..., 1024, 2]`` with 2
+    of every 128 lanes in use: half of a 2048^2 plane's device time.
+    The pooling that replaced it is named for its stage."""
+    import re
+
+    from omero_ms_image_region_tpu.ops import jpegenc
+    q = (shape((8, 8), "int32"), shape((8, 8), "int32"))
+    text = _compiled(jpegenc.packed_to_jpeg_coefficients.lower(
+        shape((1, edge, edge), "uint32"), *q)).as_text()
+    arrays = re.findall(r"\b[a-z]+\d+\[([\d,]+)\]\{([\d,]+)[:}]", text)
+    assert len(arrays) > 50
+    for dims, minor_to_major in arrays:
+        dims = [int(d) for d in dims.split(",")]
+        assert dims[int(minor_to_major.split(",")[0])] != 2, dims
+    pools = [line for line in text.splitlines()
+             if " reduce-window(" in line]
+    assert len(pools) == 2                   # cb and cr
+    for line in pools:
+        assert f"f32[1,{edge // 2},{edge // 2}]" in line
+        assert "jpeg.ycbcr420" in re.search(
+            r'op_name="([^"]*)"', line).group(1)
+
+
 def test_mask_pyramid_projection_programs_compile(shape):
     from omero_ms_image_region_tpu.ops import maskops, projection, pyramid
     _compiled(maskops._rasterize_batch_jit.lower(

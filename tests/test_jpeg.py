@@ -1378,3 +1378,104 @@ def test_the_two_halves_give_the_bytes_of_the_composed_call(
     assert wire.engine == ("sparse" if case == "padded" else engine)
     assert wire.cap == (default_cap if cap is None else 2 * cap)
     assert bool(dense_calls) == (case == "dense")
+
+
+# ------------------------------------------- the 4:2:0 chroma subsample
+
+def _plain_chroma_mean(x):
+    """The subsample as ``jpeg.ycbcr420`` wrote it until PR 35, kept
+    here as the plain reference (f32; a numpy or a traced array)."""
+    B, H, W = x.shape
+    return x.reshape(B, H // 2, 2, W // 2, 2).mean((2, 4))
+
+
+def _f32_sums_of_four(x):
+    """Every f32 result that adding a 2 x 2 block's four samples can
+    give: 12 running sums and 3 sums of two pairs."""
+    import itertools
+    s = [x[:, i::2, j::2] for i in (0, 1) for j in (0, 1)]
+    sums = [((s[a] + s[b]) + s[c]) + s[d]
+            for a, b, c, d in itertools.permutations(range(4)) if a < b]
+    sums += [(s[0] + s[a]) + (s[b] + s[c])
+             for a, b, c in ((1, 2, 3), (2, 1, 3), (3, 1, 2))]
+    return sums
+
+
+@pytest.mark.parametrize("content", ["seeded", "rails", "checkerboard"])
+@pytest.mark.parametrize("shape", [(2, 16, 16), (2, 256, 256),
+                                   (1, 1088, 1088), (3, 64, 2048)])
+def test_chroma_mean_is_the_plain_f32_mean_of_four(shape, content):
+    """The pooled subsample against the plain reshape-and-mean.  On the
+    rails and on a checkerboard every partial sum is exact, so the two
+    agree to the last bit whatever the order of the adds.  On seeded
+    planes an f32 sum of four depends on that order, which the compiler
+    chooses a program (numpy adds each row's pair, then the rows): each
+    output has to be one of the 15 f32 sums of its own four samples,
+    times 0.25 -- an f32 mean of the four, and nothing looser."""
+    import jax
+
+    from omero_ms_image_region_tpu.ops.jpegenc import _chroma_mean_2x2
+    B, H, W = shape
+    if content == "seeded":
+        x = np.random.default_rng(H * W).uniform(
+            -127.5, 127.5, shape).astype(np.float32)
+    elif content == "rails":
+        x = np.where(np.random.default_rng(H + W).random(shape) < 0.5,
+                     np.float32(-127.5), np.float32(127.5))
+        x[0, :2, :2] = 127.5
+        x[0, 2:4, :2] = -127.5
+    else:
+        yy, xx = np.mgrid[0:H, 0:W]
+        x = np.broadcast_to(np.where((yy + xx) % 2, -127.5, 127.5),
+                            shape).astype(np.float32)
+    got = np.asarray(jax.jit(_chroma_mean_2x2)(x))
+    want = _plain_chroma_mean(x)
+    assert got.dtype == np.float32 and got.shape == want.shape
+    if content == "seeded":
+        some_order = np.zeros(got.shape, bool)
+        for total in _f32_sums_of_four(x):
+            some_order |= got == total * np.float32(0.25)
+        assert some_order.all()
+    else:
+        np.testing.assert_array_equal(got, want)
+        if content == "rails":
+            assert got[0, 0, 0] == 127.5 and got[0, 1, 0] == -127.5
+
+
+@pytest.mark.parametrize("seed,B,H,W,noise", [
+    (11, 3, 32, 32, 0.0), (12, 2, 64, 96, 6.0), (13, 1, 256, 256, 2.0)])
+def test_front_end_gives_the_reshape_formulations_coefficients(
+        seed, B, H, W, noise):
+    """``packed_to_jpeg_coefficients`` against the front end as it was
+    until PR 35 (the chroma mean a reshape to ``[..., W/2, 2]``), on
+    seeded tiles: the same ``(y, cb, cr)``."""
+    import jax
+    import jax.numpy as jnp
+
+    from omero_ms_image_region_tpu.ops.jpegenc import _dct_quant_zigzag
+
+    @jax.jit
+    def reshape_formulation(packed, qy, qc):
+        r = (packed & 0xFF).astype(jnp.float32)
+        g = ((packed >> 8) & 0xFF).astype(jnp.float32)
+        b = ((packed >> 16) & 0xFF).astype(jnp.float32)
+        y = 0.299 * r + 0.587 * g + 0.114 * b - 128.0
+        cb = -0.168736 * r - 0.331264 * g + 0.5 * b
+        cr = 0.5 * r - 0.418688 * g - 0.081312 * b
+        zig = jnp.asarray(zigzag_order())
+        D = jnp.asarray(dct_matrix())
+        return (_dct_quant_zigzag(y, qy, zig, D),
+                _dct_quant_zigzag(_plain_chroma_mean(cb), qc, zig, D),
+                _dct_quant_zigzag(_plain_chroma_mean(cr), qc, zig, D))
+
+    packed = np.stack([pack(blob_image(H, W, seed=seed + 100 * i,
+                                       noise=noise)) for i in range(B)])
+    qy, qc = (t.astype(np.int32) for t in quant_tables(90))
+    got = packed_to_jpeg_coefficients(packed, qy, qc)
+    want = reshape_formulation(packed, qy, qc)
+    for g, w, blocks in zip(got, want, (H * W // 64, H * W // 256,
+                                        H * W // 256)):
+        assert g.shape == (B, blocks, 64) and g.dtype == jnp.int16
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+        # more than the DC terms: the comparison is of real content
+        assert np.count_nonzero(np.asarray(g)) > B * blocks

@@ -16,6 +16,7 @@ import threading
 from collections import OrderedDict
 from typing import List, Optional
 
+from ..utils.stopwatch import stopwatch
 from .ngff import NgffZarrSource, find_ngff
 from .ometiff import OmeTiffSource, find_tiff
 from .pixelsource import PixelSource
@@ -61,6 +62,13 @@ class PixelsService:
         # (see _drain_evicted) so fds/memmaps cannot outgrow max_open
         # under heavy image churn.
         self._evicted: List[PixelSource] = []
+        # /metrics imageregion_pixel_sources_opened_total: sources
+        # constructed (lookups the LRU missed).  Counted under ``_lock``.
+        self.opened = 0
+
+    def open_count(self) -> int:
+        """Sources the LRU holds open now (at most ``max_open``)."""
+        return len(self._open)
 
     def _drain_evicted_locked(self) -> int:
         """Close evicted sources no longer referenced anywhere else;
@@ -87,9 +95,10 @@ class PixelsService:
         exception traceback) can pin an evicted source until a cycle
         collection runs.  The collection happens OUTSIDE the lock so
         concurrent lookups are never stalled behind a full gc pass."""
-        gc.collect()
-        with self._lock:
-            self._drain_evicted_locked()
+        with stopwatch("PixelsService.gcDrain"):
+            gc.collect()
+            with self._lock:
+                self._drain_evicted_locked()
 
     def image_dir(self, image_id: int) -> str:
         return os.path.join(self.data_dir, str(image_id))
@@ -196,37 +205,46 @@ class PixelsService:
                     # refcount scan, trivial when the list is empty).
                     self._drain_evicted_locked()
                 return src
-        backend = self._sniff(image_id)
-        if backend is None and candidates and self.repo_root:
-            src = self._open_from_repo(image_id, candidates, pixels)
-        elif backend is None:
-            raise FileNotFoundError(
-                f"no pixel data for image {image_id} under "
-                f"{self.data_dir}"
-            )
-        elif backend[0] == "chunked":
-            src = ChunkedPyramidStore(backend[1])
-        elif backend[0] == "ngff":
-            src = NgffZarrSource(backend[1])
-        else:
-            src = OmeTiffSource(backend[1])
-        with self._lock:
-            # Double-check: a concurrent opener may have won the race;
-            # keep theirs and drop ours so no store leaks its memmaps.
-            existing = self._open.get(image_id)
-            if existing is not None:
-                self._open.move_to_end(image_id)
-                src.close()
-                return existing
-            self._open[image_id] = src
-            while len(self._open) > self.max_open:
-                # Do not close() here: a concurrent request may still be
-                # mid-read on the evicted source (close would yank the
-                # TIFF file handle out from under it).  Park it on the
-                # deferred-close list instead; it is closed on a later
-                # drain once its refcount shows no reader remains.
-                self._evicted.append(self._open.popitem(last=False)[1])
-            stragglers = self._drain_evicted_locked()
+        # The miss, on the thread that pays for it (the handler's
+        # ``to_thread`` worker): sniff, constructor, insert / evict /
+        # drain.  ``PixelsService.getPixelBuffer`` is the request's
+        # span, hit or miss; this one fires on misses only.
+        with stopwatch("PixelsService.openSource"):
+            backend = self._sniff(image_id)
+            if backend is None and candidates and self.repo_root:
+                src = self._open_from_repo(image_id, candidates, pixels)
+            elif backend is None:
+                raise FileNotFoundError(
+                    f"no pixel data for image {image_id} under "
+                    f"{self.data_dir}"
+                )
+            elif backend[0] == "chunked":
+                src = ChunkedPyramidStore(backend[1])
+            elif backend[0] == "ngff":
+                src = NgffZarrSource(backend[1])
+            else:
+                src = OmeTiffSource(backend[1])
+            with self._lock:
+                self.opened += 1
+                # Double-check: a concurrent opener may have won the
+                # race; keep theirs and drop ours so no store leaks its
+                # memmaps.
+                existing = self._open.get(image_id)
+                if existing is not None:
+                    self._open.move_to_end(image_id)
+                    src.close()
+                    return existing
+                self._open[image_id] = src
+                while len(self._open) > self.max_open:
+                    # Do not close() here: a concurrent request may
+                    # still be mid-read on the evicted source (close
+                    # would yank the TIFF file handle out from under
+                    # it).  Park it on the deferred-close list instead;
+                    # it is closed on a later drain once its refcount
+                    # shows no reader remains.
+                    self._evicted.append(
+                        self._open.popitem(last=False)[1])
+                stragglers = self._drain_evicted_locked()
         if stragglers > self._GC_THRESHOLD:
             self._gc_and_drain()
         return src

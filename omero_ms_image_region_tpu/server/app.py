@@ -685,6 +685,10 @@ def create_app(config: Optional[AppConfig] = None,
     # governor installs module-global so admission/handler hooks see
     # it); their tick loops start as tasks in on_startup.
     from . import pressure as pressure_mod
+    from ..utils.stopwatch import LoopLagSampler
+    # The event loop's lag, sampled whether the governor is on or off
+    # (span ``loop.lag``); its task starts in on_startup.
+    loop_lag = LoopLagSampler()
     governor = None
     if config.pressure.enabled:
         # Host-RSS watermarks default from the cgroup memory limit
@@ -692,7 +696,6 @@ def create_app(config: Optional[AppConfig] = None,
         # containerized deploy gets RSS brownouts with zero config;
         # the explicit knob still wins.
         pressure_mod.apply_cgroup_rss_defaults(config.pressure)
-        _gov_ref: list = []
         governor = pressure_mod.PressureGovernor(
             config.pressure,
             pressure_mod.build_actuators(config.pressure,
@@ -700,8 +703,7 @@ def create_app(config: Optional[AppConfig] = None,
                                          router=fleet_router),
             pressure_mod.build_sources(services=services,
                                        router=fleet_router,
-                                       governor_ref=_gov_ref))
-        _gov_ref.append(governor)
+                                       loop_lag=loop_lag))
         pressure_mod.install(governor)
 
     # Live perf-regression sentinel (deploy/DEPLOY.md "Perf
@@ -1594,6 +1596,7 @@ def create_app(config: Optional[AppConfig] = None,
         dump."""
         import time as _time
 
+        from ..utils.stopwatch import REGISTRY, stopwatch
         from ..utils.transient import deadline_scope
         deadline_ms = config.fault_tolerance.request_deadline_ms
 
@@ -1612,16 +1615,31 @@ def create_app(config: Optional[AppConfig] = None,
                 telemetry.TRACES.finish(trace_id)
                 telemetry.count_request(route, 499)
                 raise
-            total_ms = (_time.perf_counter() - t0) * 1000.0
+            t_end = _time.perf_counter()
+            total_ms = (t_end - t0) * 1000.0
             trace = telemetry.TRACES.finish(trace_id)
+            if trace is not None and trace.t_answered is not None:
+                # Span ``handler.respond``: the request's answer
+                # existed (its future settled, or the renderer returned
+                # where no batcher answered) -> here.  The last of a
+                # request's four phases: the loop's hop that resumes
+                # the coroutine, the byte cache's ``set``, the
+                # ``Response``.
+                respond_ms = (t_end - trace.t_answered) * 1000.0
+                REGISTRY.add("handler.respond", respond_ms)
+                trace.add_span("handler.respond", trace.t_answered,
+                               respond_ms)
             nbytes = request.get("streamed_nbytes")
             if nbytes is None:
                 # Buffered Response path; StreamResponse has no .body.
                 body = getattr(resp, "body", None)
                 nbytes = len(body) if body else 0
-            _finish_request(route, resp.status, nbytes,
-                            total_ms, trace,
-                            prov_ctx=request.get("prov_ctx"))
+            # The accounting's own cost, on the one thread every
+            # request shares.
+            with stopwatch("http.account"):
+                _finish_request(route, resp.status, nbytes,
+                                total_ms, trace,
+                                prov_ctx=request.get("prov_ctx"))
             return resp
 
         return wrapper
@@ -2331,7 +2349,7 @@ def create_app(config: Optional[AppConfig] = None,
         """Start the governor/watchdog tick loops (they need the
         running loop, so they cannot start in create_app)."""
         import asyncio
-        tasks = []
+        tasks = [asyncio.create_task(loop_lag.run(), name="loop-lag")]
         if unit_lifecycle is not None:
             # Spawn every member's sidecar unit (blocking per unit
             # until its socket accepts — off-loop); /readyz holds

@@ -38,7 +38,7 @@ import numpy as np
 
 from ..ops.render import render_tile_batch_packed
 from ..utils import telemetry
-from ..utils.stopwatch import REGISTRY, stopwatch
+from ..utils.stopwatch import REGISTRY, record_since, stopwatch
 
 DEFAULT_BUCKETS = ((256, 256), (512, 512), (1024, 1024), (2048, 2048))
 
@@ -216,6 +216,7 @@ class _Pending:
     pad_to: Optional[Tuple[int, int]] = None
     future: asyncio.Future = None  # type: ignore[assignment]
     t_enqueue: float = 0.0        # queue-wait waterfall span
+    t_popped: float = 0.0         # popped into a group: inGroup begins
     trace_id: str = None          # type: ignore[assignment]  # requester
     # Absolute time.monotonic() budget (utils.transient); queued work
     # whose budget is spent is cancelled at dispatch pop, never
@@ -225,6 +226,10 @@ class _Pending:
     # group; at watchdog_escalate_after the next fire escalates
     # instead of healing again.
     requeues: int = 0
+
+    def traces(self) -> Tuple[str, ...]:
+        """The requester's trace, as a span's ``trace_ids``."""
+        return (self.trace_id,) if self.trace_id else ()
 
 
 class _LiveGroup:
@@ -536,14 +541,52 @@ class BatchingRenderer:
         series = ("batcher.queueWait.cancelled" if cancelled
                   else "batcher.queueWait")
         for p in group:
+            p.t_popped = now
             wait_ms = (now - p.t_enqueue) * 1000.0
-            REGISTRY.record(series, wait_ms)
             if not cancelled and wait_ms > self.queue_wait_max_ms:
                 self.queue_wait_max_ms = wait_ms
-            if p.trace_id:
-                telemetry.record_span(
-                    series, p.t_enqueue, wait_ms,
-                    trace_ids=(p.trace_id,))
+            record_since(series, p.t_enqueue, now, p.traces())
+
+    def _answer(self, p: _Pending, tiles: int, out=None,
+                exc: Optional[BaseException] = None) -> None:
+        """Settle one popped request's future, on the event loop, and
+        close its span ``batcher.inGroup``: popped into a group of
+        ``tiles`` (where ``batcher.queueWait`` ended) -> this request's
+        own answer, stamped immediately before the future gets it.  A
+        request's, recorded once like its queue wait: a first-tile-out
+        settle ends it before the group's last tile's.  The stamp goes
+        on the request's trace, where ``handler.respond`` begins."""
+        if p.future.done():
+            return
+        now = record_since("batcher.inGroup", p.t_popped,
+                           trace_ids=p.traces(), tiles=tiles)
+        if p.trace_id:
+            telemetry.mark_answered(now, p.trace_id)
+        if exc is None:
+            p.future.set_result(out)
+        else:
+            p.future.set_exception(exc)
+
+    @staticmethod
+    def _record_slot(t_slot: float, t_run: Optional[float],
+                     t_ran: Optional[float], t_settle: float) -> None:
+        """A pipeline slot's turn, from four stamps (every part crosses
+        a thread, so none is a ``stopwatch``): ``batcher.slot``, the
+        dispatcher's ``slots.acquire()`` returned -> ``settle`` about to
+        release it; ``batcher.slotStart``, the same acquire -> the first
+        line of ``run`` on the worker thread (the pop, bookkeeping,
+        ``create_task``, the executor's queue, the thread's start);
+        ``batcher.settleLag``, ``run``'s last line -> ``settle``'s first
+        on the loop (``call_soon_threadsafe`` and the loop's own queue).
+        ``slot`` = ``slotStart`` + ``batcher.group`` + ``settleLag``.
+        The series alone, on no trace: the turn is the group's, a
+        member's trace has its own ``batcher.inGroup`` and the group's
+        span, and on the JPEG route every member has its answer (and a
+        closed trace) before the group settles."""
+        record_since("batcher.slot", t_slot, t_settle, ())
+        if t_run is not None and t_ran is not None:
+            record_since("batcher.slotStart", t_slot, t_run, ())
+            record_since("batcher.settleLag", t_ran, t_settle, ())
 
     # ------------------------------------------------------------- public
 
@@ -725,7 +768,9 @@ class BatchingRenderer:
             if (len(queue) < self.group_cap(bucket_px)
                     and self.linger_ms > 0 and not lone_idle):
                 await asyncio.sleep(self.linger_ms / 1000.0)
+            t_want = time.perf_counter()
             await slots.acquire()
+            t_slot = time.perf_counter()
             if self._lane_cap and len(self._inflight) >= self._lane_cap:
                 # Brownout: the governor capped concurrent groups
                 # below pipeline_depth; park briefly and re-check
@@ -801,6 +846,10 @@ class BatchingRenderer:
             # synchronously at pop (not when the group task happens to
             # run), once per pending.
             self._record_queue_waits(group, time.perf_counter())
+            # Span ``batcher.slotWait``: how long the formed queue
+            # waited for a free slot, which its queue waits contain.
+            # The series alone: each member's trace has its queue wait.
+            record_since("batcher.slotWait", t_want, t_slot, ())
             telemetry.FLIGHT.record(
                 "batch.formed", key=_key_label(key), tiles=len(group),
                 queued=len(queue), inflight=len(self._inflight))
@@ -811,7 +860,7 @@ class BatchingRenderer:
             else:
                 render = self._render_group
             task = asyncio.create_task(
-                self._run_group(render, group, slots, key))
+                self._run_group(render, group, slots, key, t_slot))
             self._inflight.add(task)
             task.add_done_callback(self._inflight.discard)
 
@@ -837,8 +886,8 @@ class BatchingRenderer:
         return max(1, min(cap, -(-qlen // open_streams)))
 
     async def _run_group(self, render, group: List[_Pending],
-                         slots: asyncio.Semaphore,
-                         key: tuple = ()) -> None:
+                         slots: asyncio.Semaphore, key: tuple,
+                         t_slot: float) -> None:
         """Render one popped group on a worker thread.
 
         Settlement (slot release + waiter resolution) happens in the
@@ -871,27 +920,37 @@ class BatchingRenderer:
         else:
             run_inner = render_hooked
         trace_ids = tuple(p.trace_id for p in group if p.trace_id)
+        # The worker thread's first and last lines (``_record_slot``).
+        t_run = t_ran = None
 
         def run():
+            nonlocal t_run, t_ran
+            t_run = time.perf_counter()
             # Worker-thread trace target: the group's device render,
             # wire fetch and encode spans land on EVERY member's
             # waterfall (each request really did wait on them).  The
             # group's own span is the parent of them all, by nesting
             # on this thread.
-            with telemetry.group_trace(trace_ids), stopwatch(
-                    "batcher.group", group_id=next(self._group_ids),
-                    tiles=len(group),
-                    padded=_pad_batch_size(
-                        len(group), self.group_cap(group[0].bucket_px)),
-                    key=_key_label(key), bucket=_bucket_label(key)):
-                return run_inner()
+            try:
+                with telemetry.group_trace(trace_ids), stopwatch(
+                        "batcher.group", group_id=next(self._group_ids),
+                        tiles=len(group),
+                        padded=_pad_batch_size(
+                            len(group),
+                            self.group_cap(group[0].bucket_px)),
+                        key=_key_label(key), bucket=_bucket_label(key)):
+                    return run_inner()
+            finally:
+                t_ran = time.perf_counter()
 
         inner = asyncio.ensure_future(asyncio.to_thread(run))
         live = _LiveGroup(key, group, time.monotonic())
         self._live_groups[inner] = live
 
         def settle(fut: asyncio.Future) -> None:
+            t_settle = time.perf_counter()
             slots.release()
+            self._record_slot(t_slot, t_run, t_ran, t_settle)
             self._live_groups.pop(fut, None)
             if not live.fires:
                 # Healed (stuck) groups stay out of the duration
@@ -905,12 +964,10 @@ class BatchingRenderer:
                 exc = fut.exception()
             if exc is not None:
                 for p in group:
-                    if not p.future.done():
-                        p.future.set_exception(exc)
+                    self._answer(p, len(group), exc=exc)
                 return
             for p, out in zip(group, fut.result()):
-                if not p.future.done():
-                    p.future.set_result(out)
+                self._answer(p, len(group), out)
 
         inner.add_done_callback(settle)
         try:
@@ -1102,12 +1159,9 @@ class BatchingRenderer:
             fut = group[i].future
             if fut is None:
                 return    # harness-driven group (no waiter to settle)
-
-            def settle() -> None:
-                if not fut.done():
-                    fut.set_result(data)
             try:
-                fut.get_loop().call_soon_threadsafe(settle)
+                fut.get_loop().call_soon_threadsafe(
+                    self._answer, group[i], n, data)
             except RuntimeError:
                 pass                       # loop already closed
         return on_tile

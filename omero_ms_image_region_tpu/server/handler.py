@@ -36,7 +36,7 @@ from ..services.cache import Caches
 from ..services.metadata import CanReadMemo, MetadataService
 from ..utils import telemetry
 from ..utils.color import split_html_color
-from ..utils.stopwatch import REGISTRY, stopwatch
+from ..utils.stopwatch import record_since, stopwatch
 from .config import RendererConfig
 from .ctx import BadRequestError, ImageRegionCtx, ShapeMaskCtx
 from .region import RegionDef, clamp_region_to_plane, get_region_def
@@ -211,9 +211,12 @@ async def check_can_read(services: ImageRegionServices, object_type: str,
         session_key, object_type, object_id)
     if memo is not None:
         return memo
-    with stopwatch("canRead"):
+    t0 = time.perf_counter()
+    try:
         ok = await services.metadata.can_read(object_type, object_id,
                                               session_key)
+    finally:
+        record_since("canRead", t0)
     await services.can_read_memo.put_async(
         session_key, object_type, object_id, ok)
     return ok
@@ -252,9 +255,12 @@ class ImageRegionHandler:
                 return Pixels.from_json(json.loads(cached))
             except (ValueError, KeyError):
                 pass  # poisoned entry: fall through to the service
-        with stopwatch("get_pixels_description"):
+        t0 = time.perf_counter()
+        try:
             pixels = await self.s.metadata.get_pixels_description(
                 ctx.image_id, ctx.omero_session_key)
+        finally:
+            record_since("get_pixels_description", t0)
         if pixels is not None:
             await self.s.caches.pixels_metadata.set(
                 key, json.dumps(pixels.to_json()).encode())
@@ -282,11 +288,9 @@ class ImageRegionHandler:
         dispatching, so the member-level get would be a guaranteed
         miss paying a wasted walk of the memory/disk tiers on the hot
         path.  The write-back below still runs."""
-        import time as _time
-
         from ..services.cache import get_with_tier
         from ..utils import provenance
-        t0 = _time.perf_counter()
+        t0 = time.perf_counter()
         if ctx.t_accept is None:
             ctx.t_accept = t0     # no HTTP layer stamped the request
         cached, cache_tier = ((None, None) if skip_byte_cache else
@@ -300,7 +304,7 @@ class ImageRegionHandler:
                 # (the render stages below never ran).
                 telemetry.record_span(
                     "cache.hit", t0,
-                    (_time.perf_counter() - t0) * 1000.0)
+                    (time.perf_counter() - t0) * 1000.0)
                 provenance.mark(
                     ctx, tier=("disk" if cache_tier == "disk"
                                else "byte_cache"))
@@ -311,6 +315,10 @@ class ImageRegionHandler:
         if pixels is None or not await self._can_read(
                 "Image", ctx.image_id, ctx.omero_session_key):
             raise NotFoundError(f"Cannot find Image:{ctx.image_id}")
+        # Span ``handler.metadata``: accepted -> the pixels description
+        # and the ACL resolved (the byte cache's probe before them).
+        # The first part of ``handler.prepare``, recorded as it is.
+        record_since("handler.metadata", ctx.t_accept)
 
         single_flight = self.s.single_flight
         admission = self.s.admission
@@ -388,7 +396,7 @@ class ImageRegionHandler:
             # await on the leader's pipeline, not a pipeline of its own.
             telemetry.record_span(
                 "dedup.coalesced", t0,
-                (_time.perf_counter() - t0) * 1000.0)
+                (time.perf_counter() - t0) * 1000.0)
             provenance.mark(ctx, coalesced=True)
         return data
 
@@ -450,8 +458,13 @@ class ImageRegionHandler:
             raise BadRequestError(
                 f"Parameter 'theT' not within bounds: {ctx.t}")
 
-        with stopwatch("PixelsService.getPixelBuffer"):
+        # Once a request, hit or miss; the open itself has a span on
+        # the thread that does it (``PixelsService.openSource``).
+        t0 = time.perf_counter()
+        try:
             src = await self._open_pixel_source(ctx.image_id, pixels)
+        finally:
+            record_since("PixelsService.getPixelBuffer", t0)
 
         if src.resolution_levels() > 1:
             levels: Sequence[Sequence[int]] = [
@@ -538,11 +551,13 @@ class ImageRegionHandler:
 
         if tiny:
             self._record_prepare(ctx, route)
-            return await asyncio.to_thread(
+            data = await asyncio.to_thread(
                 self._render_cpu, np.asarray(raw), active_rdef, ctx)
+            telemetry.mark_answered(time.perf_counter())
+            return data
 
         settings = pack_settings(active_rdef, self.s.lut_provider)
-        self._record_prepare(ctx, route)
+        t_handoff = self._record_prepare(ctx, route)
 
         if ctx.format == "jpeg":
             # Device JPEG path: flips fold into the raw planes (render is
@@ -555,12 +570,21 @@ class ImageRegionHandler:
             h, w = (raw[0] if isinstance(raw, tuple) else raw).shape[-2:]
             quality = codecs.quality_percent(ctx.compression_quality)
             quality = _pressure_quality(quality, ctx)
-            with stopwatch("Renderer.renderAsPackedInt"):
+            try:
                 return await self.s.renderer.render_jpeg(
                     raw, settings, quality, w, h)
+            finally:
+                # The hand-off -> the answer back in this coroutine.
+                # Where no batcher stamped the answer earlier, this is
+                # where ``handler.respond`` begins.
+                telemetry.mark_answered(record_since(
+                    "Renderer.renderAsPackedInt", t_handoff))
 
-        with stopwatch("Renderer.renderAsPackedInt"):
+        try:
             packed = await self.s.renderer.render(raw, settings)
+        finally:
+            telemetry.mark_answered(record_since(
+                "Renderer.renderAsPackedInt", t_handoff))
 
         if ctx.flip_horizontal or ctx.flip_vertical:
             if ctx.flip_vertical:
@@ -571,7 +595,7 @@ class ImageRegionHandler:
         return await asyncio.to_thread(self._encode_rgba, rgba, ctx)
 
     @staticmethod
-    def _record_prepare(ctx: ImageRegionCtx, route: str) -> None:
+    def _record_prepare(ctx: ImageRegionCtx, route: str) -> float:
         """Span ``handler.prepare``: request accepted (the HTTP layer's
         ``ctx.t_accept``, else this handler's entry) to the hand-off to
         the batcher or the host render thread — parse, metadata, ACL,
@@ -580,10 +604,9 @@ class ImageRegionHandler:
         like ``batcher.queueWait``, not under ``stopwatch``: it spans
         awaits, where a profiler annotation would interleave with other
         requests' on the loop's thread.  On the request's trace it
-        carries the route the render took."""
-        REGISTRY.record("handler.prepare",
-                        (time.perf_counter() - ctx.t_accept) * 1000.0,
-                        route=route)
+        carries the route the render took.  Returns the hand-off's
+        stamp."""
+        return record_since("handler.prepare", ctx.t_accept, route=route)
 
     def _encode_rgba(self, rgba: np.ndarray, ctx: ImageRegionCtx) -> bytes:
         """Shared encode tail (format dispatch + 404 on unknown format)."""
@@ -834,17 +857,15 @@ class ShapeMaskHandler:
         the tile route's footing (already-rendered bytes never cost a
         session token and never shed).  None = miss or unreadable
         (the render path then decides 404 vs render)."""
-        import time as _time
-
         from ..services.cache import get_with_tier
         from ..utils import provenance
-        t0 = _time.perf_counter()
+        t0 = time.perf_counter()
         cached, cache_tier = await get_with_tier(
             self.s.caches.shape_mask, ctx.cache_key())
         if cached is None or not await self._can_read(ctx):
             return None
         telemetry.record_span(
-            "cache.hit", t0, (_time.perf_counter() - t0) * 1000.0)
+            "cache.hit", t0, (time.perf_counter() - t0) * 1000.0)
         provenance.mark(ctx, tier=("disk" if cache_tier == "disk"
                                    else "byte_cache"))
         return cached
@@ -856,9 +877,12 @@ class ShapeMaskHandler:
         if not await self._can_read(ctx):
             raise NotFoundError(f"Cannot find Shape:{ctx.shape_id}")
 
-        with stopwatch("getMask"):
+        t0 = time.perf_counter()
+        try:
             mask = await self.s.metadata.get_mask(ctx.shape_id,
                                                   ctx.omero_session_key)
+        finally:
+            record_since("getMask", t0)
         if mask is None:
             raise NotFoundError(f"Cannot find Shape:{ctx.shape_id}")
 
@@ -868,7 +892,8 @@ class ShapeMaskHandler:
             if color is None:
                 raise BadRequestError(f"Invalid color '{ctx.color}'")
 
-        with stopwatch("renderShapeMask"):
+        t0 = time.perf_counter()
+        try:
             rasterize = (getattr(self.s.renderer, "rasterize_mask", None)
                          if self.device_masks else None)
             if rasterize is not None:
@@ -879,6 +904,8 @@ class ShapeMaskHandler:
                 png = await asyncio.to_thread(self._render, mask, color,
                                               ctx)
                 telemetry.WORKLOADS.count_request("mask_host")
+        finally:
+            record_since("renderShapeMask", t0)
 
         # Cached only under an explicit color, as the reference: a cached
         # default-color PNG would mask later changes to the stored fill
@@ -970,9 +997,12 @@ class WorkloadsHandler:
             if not await check_can_read(self.s, "Mask", sid,
                                         ctx.omero_session_key):
                 raise NotFoundError(f"Cannot find Shape:{sid}")
-            with stopwatch("getMask"):
+            t0 = time.perf_counter()
+            try:
                 mask = await self.s.metadata.get_mask(
                     sid, ctx.omero_session_key)
+            finally:
+                record_since("getMask", t0)
             if mask is None:
                 raise NotFoundError(f"Cannot find Shape:{sid}")
             masks.append(mask)
@@ -1000,8 +1030,11 @@ class WorkloadsHandler:
                                            fill)[0]
             return codecs.encode_rgba(out, "png")
 
-        with stopwatch("renderOverlay"):
+        t0 = time.perf_counter()
+        try:
             body = await asyncio.to_thread(composite)
+        finally:
+            record_since("renderOverlay", t0)
         telemetry.WORKLOADS.count_request("overlay")
         return body
 
@@ -1026,8 +1059,7 @@ class WorkloadsHandler:
             raise BadRequestError(
                 f"animation of {len(frame_ctxs)} frames exceeds the "
                 f"configured cap of {self.max_frames}")
-        import time as _time
-        t0 = _time.perf_counter()
+        t0 = time.perf_counter()
         telemetry.WORKLOADS.count_stream()
         telemetry.FLIGHT.record(
             "animation.stream", image=frame_ctxs[0].image_id,
@@ -1041,7 +1073,7 @@ class WorkloadsHandler:
                 body = await task
                 if served == 0:
                     telemetry.WORKLOADS.observe_first_frame_ms(
-                        (_time.perf_counter() - t0) * 1000.0)
+                        (time.perf_counter() - t0) * 1000.0)
                 served += 1
                 telemetry.WORKLOADS.count_frames()
                 yield frame_record(body)

@@ -62,7 +62,6 @@ flight-recorder event and an ``imageregion_pressure_*`` series.
 from __future__ import annotations
 
 import logging
-import time
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Tuple
 
@@ -198,9 +197,6 @@ class PressureGovernor:
         self._hot_streak = 0
         self._ok_streak = 0
         self._signal_states: Dict[str, _SignalState] = {}
-        # Set by the async runner (actual vs expected tick interval);
-        # read back as the loop_lag_ms signal.
-        self.loop_lag_ms = 0.0
         # Last published prefetch budget (change detection for the
         # flight event + gauge — the budget is a pure function of
         # level/ladder state, so publishing on transitions only keeps
@@ -410,19 +406,16 @@ class PressureGovernor:
     # ------------------------------------------------------------ runner
 
     async def run(self) -> None:
-        """Asyncio tick loop; measures its own scheduling lag as the
-        ``loop_lag_ms`` signal (a loop that cannot keep a sleep on
-        schedule is a loop that cannot keep responses on schedule)."""
+        """Asyncio tick loop.  The loop's own scheduling lag (a loop
+        that cannot keep a sleep on schedule is a loop that cannot keep
+        responses on schedule) is not timed here: the ``loop_lag_ms``
+        source reads the serving loop's one sampler
+        (``utils.stopwatch.LoopLagSampler``, span ``loop.lag``)."""
         import asyncio
 
         interval = max(0.05, self.config.interval_s)
         while True:
-            t0 = time.monotonic()
             await asyncio.sleep(interval)
-            lag_ms = max(0.0,
-                         (time.monotonic() - t0 - interval) * 1000.0)
-            # EWMA so one GC pause doesn't read as sustained lag.
-            self.loop_lag_ms += 0.3 * (lag_ms - self.loop_lag_ms)
             self.tick()
 
 
@@ -482,11 +475,13 @@ def pressure_quality(quality: int, ctx) -> int:
 
 
 def build_sources(services=None, renderer=None, router=None,
-                  governor_ref: Optional[list] = None
+                  loop_lag=None
                   ) -> Dict[str, Callable[[], Optional[float]]]:
     """The standard signal set over a service stack.  Every source is
     duck-typed and None-safe, so one missing subsystem just drops its
-    signal rather than failing the governor."""
+    signal rather than failing the governor.  ``loop_lag`` is the
+    serving loop's ``LoopLagSampler``: the ``loop_lag_ms`` signal is
+    its smoothed reading."""
     raw_cache = getattr(services, "raw_cache", None)
     caches = getattr(services, "caches", None)
     disk = getattr(caches, "disk", None)
@@ -510,17 +505,13 @@ def build_sources(services=None, renderer=None, router=None,
             depth = renderer.queue_depth()
         return None if depth is None else float(depth)
 
-    def loop_lag() -> Optional[float]:
-        if governor_ref:
-            return governor_ref[0].loop_lag_ms
-        return None
-
     return {
         "hbm": hbm,
         "host_rss_mb": lambda: read_rss_mb(),
         "disk": disk_frac,
         "queue": queue,
-        "loop_lag_ms": loop_lag,
+        "loop_lag_ms": lambda: (None if loop_lag is None
+                                else loop_lag.ewma_ms),
     }
 
 

@@ -1243,17 +1243,18 @@ async def run_sidecar(config, socket_path: Optional[str] = None,
     # lanes live; the frontend's copies watch its own wire side.
     from . import pressure as pressure_mod
     from .watchdog import build_watchdog
-    robustness_tasks: list = []
+    from ..utils.stopwatch import LoopLagSampler
+    loop_lag = LoopLagSampler()
+    robustness_tasks: list = [asyncio.create_task(
+        loop_lag.run(), name="loop-lag")]
     governor = None
     if config.pressure.enabled:
-        _gov_ref: list = []
         governor = pressure_mod.PressureGovernor(
             config.pressure,
             pressure_mod.build_actuators(config.pressure,
                                          services=services),
             pressure_mod.build_sources(services=services,
-                                       governor_ref=_gov_ref))
-        _gov_ref.append(governor)
+                                       loop_lag=loop_lag))
         pressure_mod.install(governor)
         robustness_tasks.append(asyncio.create_task(
             governor.run(), name="pressure-governor"))

@@ -33,9 +33,14 @@ What the summary states, and by which rule:
 * ``idle_ms`` by what the host was doing: every gap between busy
   stretches is split by overlap with the host spans in the fixed order
   of :data:`IDLE_ORDER` (an instant two threads spend in different
-  spans goes to the earlier class), then ``no_group`` (no
-  ``batcher.group`` alive: the server waited for a request), then
-  ``unattributed``.  The classes sum to ``traced_ms - busy_ms``.
+  spans goes to the earlier class): a group's own spans first, then
+  what the threads between groups do (a read of the store, the open of
+  a pixel source and its forced collection, a request's accounting on
+  the event loop).  What is left goes to ``no_group`` where no
+  ``batcher.group`` was alive either (no group and no listed span: the
+  event loop's own work of preparing requests and speaking HTTP, or
+  true silence) and to ``unattributed`` where one was.  The classes sum
+  to ``traced_ms - busy_ms``.
 """
 
 from __future__ import annotations
@@ -62,9 +67,15 @@ COMPILE = "xla.compile"
 GROUP = "batcher.group"
 WAIT = "device.wait"
 COPY = "wire.d2h"
-# Precedence of the host spans an idle gap is put down to.
+# Precedence of the host spans an idle gap is put down to.  The spans
+# of the threads between groups come last, so that a class listed
+# before them reads what it read before they were listed.
 IDLE_ORDER = (COMPILE, "device.dispatch", "batcher.stage",
-              "batcher.laneWait", COPY, "jfif.encodeBatch", WAIT)
+              "batcher.laneWait", COPY, "jfif.encodeBatch", WAIT,
+              "PixelsService.readRegion", "PixelsService.openSource",
+              "PixelsService.gcDrain", "http.account")
+# No ``batcher.group`` alive and no span of IDLE_ORDER running: the
+# event loop's own work (prepare, HTTP), or true silence.
 NO_GROUP = "no_group"
 UNATTRIBUTED = "unattributed"
 

@@ -19,13 +19,14 @@ stage timings double as waterfall child spans.
 
 from __future__ import annotations
 
+import asyncio
 import logging
 import threading
 import time
 from contextlib import contextmanager, nullcontext
 from typing import Dict
 
-from .telemetry import Histogram, observe_span
+from .telemetry import Histogram, observe_span, record_span
 
 log = logging.getLogger("omero_ms_image_region_tpu.perf")
 
@@ -70,13 +71,17 @@ class StopWatchRegistry:
         self._lock = threading.Lock()
         self._spans: Dict[str, SpanStats] = {}
 
-    def record(self, name: str, ms: float, **meta) -> None:
-        """``meta`` goes with the span on the request's trace only."""
+    def add(self, name: str, ms: float) -> None:
+        """The span's series alone, no trace's child span."""
         with self._lock:
             stats = self._spans.get(name)
             if stats is None:
                 stats = self._spans[name] = SpanStats()
             stats.add(ms)
+
+    def record(self, name: str, ms: float, **meta) -> None:
+        """``meta`` goes with the span on the request's trace only."""
+        self.add(name, ms)
         # Outside the lock: trace recording takes the trace's own lock.
         observe_span(name, ms, **meta)
 
@@ -167,3 +172,47 @@ def stopwatch(name: str, registry: StopWatchRegistry = REGISTRY, **meta):
             span.ms = ms = (time.perf_counter() - t0) * 1000.0
             registry.record(name, ms, **meta)
             log.debug("time[%s] = %.3f ms", name, ms)
+
+
+def record_since(name: str, t0: float, t1: float = None,
+                 trace_ids: tuple = None, **meta) -> float:
+    """Record span ``name`` from the ``time.perf_counter`` stamp ``t0``
+    to the stamp ``t1`` (now, when None), and return that end.  For a
+    span that crosses an ``await`` or a thread: ``stopwatch`` there
+    would put a profiler annotation on the event loop's line that
+    interleaves with every other request's, or on the wrong thread.
+    Same series on ``/metrics``; the child span, with ``meta``, goes on
+    the traces ``trace_ids`` names (the context's, when None; the
+    batcher's callers run under none and name their requests')."""
+    end = time.perf_counter() if t1 is None else t1
+    ms = (end - t0) * 1000.0
+    REGISTRY.add(name, ms)
+    record_span(name, t0, ms, trace_ids=trace_ids, **meta)
+    return end
+
+
+class LoopLagSampler:
+    """How late the event loop runs what is due: sleep ``INTERVAL_S``,
+    record the lateness as span ``loop.lag``.  One task a serving loop,
+    always on.  ``ewma_ms`` is what the pressure governor reads as its
+    ``loop_lag_ms`` signal: smoothed over about three seconds, the
+    memory the governor's own timing had (0.3 of each one-second
+    tick's lateness), so that one GC pause does not read as sustained
+    lag: a single 300 ms stall moves it by 10 ms."""
+
+    INTERVAL_S = 0.1
+    # 1 - 0.7 ** INTERVAL_S: ten samples weigh what one tick's 0.3 did.
+    ALPHA = 0.035
+
+    def __init__(self):
+        self.ewma_ms = 0.0
+
+    def observe(self, lag_ms: float) -> None:
+        REGISTRY.add("loop.lag", lag_ms)
+        self.ewma_ms += self.ALPHA * (lag_ms - self.ewma_ms)
+
+    async def run(self) -> None:
+        while True:
+            due = time.perf_counter() + self.INTERVAL_S
+            await asyncio.sleep(self.INTERVAL_S)
+            self.observe(max(0.0, (time.perf_counter() - due) * 1000.0))

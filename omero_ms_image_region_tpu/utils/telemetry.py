@@ -236,7 +236,7 @@ def new_trace_id() -> str:
 
 class Trace:
     __slots__ = ("trace_id", "route", "t0", "wall_ts", "spans", "lock",
-                 "costs")
+                 "costs", "t_answered")
 
     def __init__(self, trace_id: str, route: str = ""):
         self.trace_id = trace_id
@@ -244,6 +244,10 @@ class Trace:
         self.t0 = time.perf_counter()
         self.wall_ts = time.time()
         self.spans: List[dict] = []
+        # ``time.perf_counter`` stamp of the instant the request's
+        # answer existed (``mark_answered``): where ``batcher.inGroup``
+        # ends and ``handler.respond`` begins.  None: nothing rendered.
+        self.t_answered: Optional[float] = None
         # Per-request cost ledger: numeric accumulators attributed to
         # this request (device-execute ms pro-rata from its batch
         # group, staged vs dedup-skipped HBM bytes, ...).  Written by
@@ -455,6 +459,17 @@ def record_span(name: str, t_start: float, dur_ms: float,
     for tid in ids:
         trace = TRACES.get_or_create(tid)
         trace.add_span(name, t_start, dur_ms, **meta)
+
+
+def mark_answered(t: float, trace_id: Optional[str] = None) -> None:
+    """Stamp the instant a request's answer existed on its trace (the
+    context's when ``trace_id`` is None).  The first stamp stands: the
+    batcher's, taken as it settles the request's future, comes before
+    the handler's, taken when the renderer has returned (the only one
+    where no batcher answered).  Never creates a trace."""
+    trace = TRACES._active.get(trace_id or current_trace_id())
+    if trace is not None and trace.t_answered is None:
+        trace.t_answered = t
 
 
 def observe_span(name: str, dur_ms: float, **meta) -> None:
@@ -3539,6 +3554,8 @@ METRIC_TYPES: Dict[str, str] = {
     "imageregion_rawcache_evictions": "counter",
     "imageregion_rawcache_bytes": "gauge",
     "imageregion_rawcache_channel_loads_total": "counter",
+    "imageregion_pixel_sources_opened_total": "counter",
+    "imageregion_pixel_sources_open": "gauge",
     "imageregion_planecache_hits": "counter",
     "imageregion_planecache_misses": "counter",
     "imageregion_singleflight_hits": "counter",
@@ -3791,6 +3808,11 @@ METRIC_HELP: Dict[str, str] = {
     "imageregion_rawcache_channel_loads_total":
         "Channel planes read (or handed over) and uploaded to the HBM "
         "raw cache",
+    "imageregion_pixel_sources_opened_total":
+        "Pixel sources constructed: lookups the LRU of open sources "
+        "missed",
+    "imageregion_pixel_sources_open":
+        "Pixel sources the LRU holds open now",
     "imageregion_federation_manifest_version":
         "Shard epoch of the agreed fleet manifest",
     "imageregion_federation_agreements_total":
@@ -4207,6 +4229,17 @@ def device_metric_lines(services, extra_labels: str = "") -> List[str]:
                 f"imageregion_planecache_misses{lb} "
                 f"{raw_cache.plane_misses}",
             ]
+    pixels_service = getattr(services, "pixels_service", None)
+    if hasattr(pixels_service, "opened"):
+        # The LRU of open pixel sources: a plate of more images than
+        # it holds re-opens one a request.
+        lb = label()
+        lines += [
+            f"imageregion_pixel_sources_opened_total{lb} "
+            f"{pixels_service.opened}",
+            f"imageregion_pixel_sources_open{lb} "
+            f"{pixels_service.open_count()}",
+        ]
     single_flight = getattr(services, "single_flight", None)
     if single_flight is not None:
         lb = label()

@@ -336,6 +336,53 @@ class TestBatchedApp:
         # Same spatial bucket -> the device JPEG groups actually coalesce.
         assert renderer.batches_dispatched < len(sizes)
 
+    @pytest.mark.parametrize("fmt", ["jpeg", "png"])
+    def test_a_requests_life_is_four_phases(self, data_dir, fmt):
+        """PR 36: accept -> last line in ``handler.prepare``,
+        ``batcher.queueWait``, ``batcher.inGroup``, ``handler.respond``,
+        one after the other on the request's trace, with nothing
+        between them."""
+        from omero_ms_image_region_tpu.utils import telemetry
+        from omero_ms_image_region_tpu.utils.stopwatch import REGISTRY
+        telemetry.reset()
+        path = (f"/webgateway/render_image_region/{IMG}/0/0"
+                f"?tile=0,0,0,32,32&format={fmt}&m=c&c=1|0:%d$FF0000")
+        # The first request compiles; the second is the one read.
+        _gather_requests(data_dir, [path % 20000])
+        telemetry.reset()
+        REGISTRY.reset()
+        _gather_requests(data_dir, [path % 30000])
+        [trace] = telemetry.TRACES.recent
+        [cost] = telemetry.COST_TOPK.snapshot()
+        assert cost["trace"] == trace.trace_id
+        phases = ["handler.prepare", "batcher.queueWait",
+                  "batcher.inGroup", "handler.respond"]
+        spans = {s["name"]: s for s in trace.spans}
+        assert [n for n in phases if n in spans] == phases
+        for name in phases:
+            assert [s["name"] for s in trace.spans].count(name) == 1
+        seams = []
+        for before, after in zip(phases, phases[1:]):
+            end = spans[before]["start_ms"] + spans[before]["dur_ms"]
+            # No overlap (the stamps are rounded to a microsecond).
+            assert spans[after]["start_ms"] >= end - 0.002, after
+            seams.append(spans[after]["start_ms"] - end)
+        # One stamp ends a phase and begins the next, but at the
+        # hand-off, where the batcher pads a request that does not fill
+        # its bucket (this 32 x 32 tile; no cell's) before it enqueues.
+        assert seams[1] <= 0.002 and seams[2] <= 0.002
+        total = sum(spans[n]["dur_ms"] for n in phases) + seams[0]
+        assert total == pytest.approx(cost["total_ms"],
+                                      abs=max(1.0, 0.05 * total))
+        # Inside prepare: the metadata, then the source.
+        assert spans["handler.metadata"]["dur_ms"] <= \
+            spans["handler.prepare"]["dur_ms"]
+        assert "PixelsService.getPixelBuffer" in spans
+        assert spans["batcher.inGroup"]["tiles"] == 1
+        # The accounting is a span of the process, not of the request.
+        assert REGISTRY.snapshot()["http.account"]["count"] == 1
+        assert "http.account" not in spans
+
     def test_huffman_engine_through_batcher(self, data_dir):
         """renderer.jpeg-engine='huffman' serves batched JPEG groups via
         the device fixed-table Huffman wire (exact tiles) and the dense

@@ -934,3 +934,241 @@ class TestLingerBypass:
         finally:
             loop.run_until_complete(r.close())
             loop.close()
+
+
+class TestSlotSpans:
+    """A pipeline slot's turn in spans (PR 36): ``batcher.slot`` is
+    ``batcher.slotStart`` + ``batcher.group`` + ``batcher.settleLag``,
+    recorded for a slot that carried a group and for no other; and a
+    request's own ``batcher.inGroup`` ends where ITS answer came."""
+
+    @staticmethod
+    def _tile(seed):
+        rng = np.random.default_rng(seed)
+        return rng.integers(0, 60000, size=(3, 32, 32)).astype(np.float32)
+
+    @staticmethod
+    def _spans():
+        from omero_ms_image_region_tpu.utils.stopwatch import REGISTRY
+        return REGISTRY.snapshot()
+
+    @pytest.fixture(autouse=True)
+    def _fresh(self):
+        from omero_ms_image_region_tpu.utils import telemetry
+        from omero_ms_image_region_tpu.utils.stopwatch import REGISTRY
+        REGISTRY.reset()
+        telemetry.TRACES.reset()
+        yield
+        REGISTRY.reset()
+        telemetry.TRACES.reset()
+
+    @pytest.mark.parametrize("route", ["jpeg", "packed"])
+    def test_a_slot_is_its_three_parts(self, route):
+        from omero_ms_image_region_tpu.utils.stopwatch import REGISTRY
+
+        settings = _settings()
+        tiles = [self._tile(s) for s in (31, 32, 33)]
+
+        async def main():
+            batcher = BatchingRenderer(linger_ms=5.0,
+                                       buckets=((64, 64),))
+
+            async def three():
+                if route == "jpeg":
+                    return await asyncio.gather(*(
+                        batcher.render_jpeg(t, settings, 85, 32, 32)
+                        for t in tiles))
+                return await asyncio.gather(*(
+                    batcher.render(t, settings) for t in tiles))
+            try:
+                await three()       # compiles; the series' first records
+                # First-tile-out answers before the group has settled.
+                while batcher._inflight:
+                    await asyncio.sleep(0.005)
+                REGISTRY.reset()
+                return await three()
+            finally:
+                await batcher.close()
+
+        assert len(run(main())) == 3
+        spans = self._spans()
+        groups = spans["batcher.group"]["count"]
+        assert 1 <= groups <= 3
+        for name in ("batcher.slot", "batcher.slotStart",
+                     "batcher.settleLag", "batcher.slotWait"):
+            assert spans[name]["count"] == groups, name
+        parts = sum(spans[name]["total_ms"] for name in (
+            "batcher.slotStart", "batcher.group", "batcher.settleLag"))
+        slot = spans["batcher.slot"]["total_ms"]
+        # What lies between the parts is a stamp and a span's own
+        # record: microseconds (2 % more on a machine that is shared).
+        slack = 0.5 * groups + 0.02 * slot
+        assert parts - slack <= slot <= parts + slack
+        # One inGroup a request, never longer than its group's slot.
+        assert spans["batcher.inGroup"]["count"] == 3
+        assert spans["batcher.inGroup"]["max_ms"] <= \
+            spans["batcher.slot"]["max_ms"] + 0.5
+
+    @pytest.mark.parametrize("path", ["lane_cap", "dead_member"])
+    def test_a_slot_given_back_records_no_slot_span(self, path,
+                                                    monkeypatch):
+        """The dispatcher takes a slot and gives it straight back under
+        an engaged ``cap_lanes`` step, and when everything it popped
+        was already settled: neither is a slot's turn."""
+        import threading
+
+        from omero_ms_image_region_tpu.ops import jpegenc
+
+        entered, release = threading.Event(), threading.Event()
+        real_finish = jpegenc.finish_wire_to_jpegs
+
+        def finish(*args, **kw):
+            first = not entered.is_set()
+            entered.set()
+            if first:
+                release.wait(timeout=60)
+            return real_finish(*args, **kw)
+
+        monkeypatch.setattr(jpegenc, "finish_wire_to_jpegs", finish)
+        settings = _settings()
+        a, b = self._tile(41), self._tile(42)
+
+        async def main():
+            batcher = BatchingRenderer(
+                linger_ms=0.0, buckets=((64, 64),),
+                pipeline_depth=2 if path == "lane_cap" else 1)
+            if path == "lane_cap":
+                batcher.set_lane_cap(1)
+            try:
+                first = asyncio.ensure_future(
+                    batcher.render_jpeg(a, settings, 85, 32, 32))
+                assert await asyncio.to_thread(entered.wait, 60)
+                second = asyncio.ensure_future(
+                    batcher.render_jpeg(b, settings, 85, 32, 32))
+                # The dispatcher meets the second request while the
+                # first group holds its slot.
+                await asyncio.sleep(0.1)
+                if path == "dead_member":
+                    second.cancel()
+                held = self._spans().get("batcher.slot",
+                                         {}).get("count", 0)
+                release.set()
+                await first
+                if path == "lane_cap":
+                    await second
+                else:
+                    await asyncio.gather(second, return_exceptions=True)
+                    # Let the dispatcher pop the corpse.
+                    await asyncio.sleep(0.1)
+                return held
+            finally:
+                release.set()
+                await batcher.close()
+
+        assert run(main()) == 0     # nothing recorded while it was held
+        spans = self._spans()
+        groups = 2 if path == "lane_cap" else 1
+        assert spans["batcher.group"]["count"] == groups
+        assert spans["batcher.slot"]["count"] == groups
+        assert spans["batcher.slotWait"]["count"] == groups
+        if path == "dead_member":
+            assert spans["batcher.queueWait.cancelled"]["count"] == 1
+            assert spans["batcher.inGroup"]["count"] == 1
+
+    def test_an_early_settled_tile_leaves_its_group_first(
+            self, monkeypatch):
+        """First-tile-out: tile 0's answer is stamped when its bytes
+        exist, the last tile's at the group's settle."""
+        import time
+
+        from omero_ms_image_region_tpu.ops import jpegenc
+        from omero_ms_image_region_tpu.utils import telemetry
+
+        real_finish = jpegenc.finish_wire_to_jpegs
+
+        def finish(wire, on_tile=None, **kw):
+            jpegs = real_finish(wire, **kw)
+            on_tile(0, jpegs[0])
+            time.sleep(0.05)            # the rest of the entropy tail
+            return jpegs
+
+        monkeypatch.setattr(jpegenc, "finish_wire_to_jpegs", finish)
+        settings = _settings()
+
+        async def one(batcher, tid, seed):
+            with telemetry.trace_scope(tid):
+                return await batcher.render_jpeg(
+                    self._tile(seed), settings, 85, 32, 32)
+
+        async def main():
+            batcher = BatchingRenderer(linger_ms=20.0,
+                                       buckets=((64, 64),))
+            try:
+                return await asyncio.gather(
+                    one(batcher, "early", 51), one(batcher, "late", 52))
+            finally:
+                await batcher.close()
+
+        assert all(body[:2] == b"\xff\xd8" for body in run(main()))
+        assert self._spans()["batcher.group"]["count"] == 1
+        early = telemetry.TRACES.get_or_create("early")
+        late = telemetry.TRACES.get_or_create("late")
+
+        def only(trace, name):
+            [span] = [s for s in trace.spans if s["name"] == name]
+            return span
+
+        first, last = (only(t, "batcher.inGroup") for t in (early, late))
+        assert first["tiles"] == last["tiles"] == 2
+        assert last["dur_ms"] - first["dur_ms"] >= 40.0
+        assert late.t_answered - early.t_answered >= 0.04
+        # Where the queue wait ended, inGroup began.
+        for trace in (early, late):
+            wait = only(trace, "batcher.queueWait")
+            in_group = only(trace, "batcher.inGroup")
+            assert in_group["start_ms"] == pytest.approx(
+                wait["start_ms"] + wait["dur_ms"], abs=0.002)
+
+    @pytest.mark.parametrize("route", ["jpeg", "packed"])
+    def test_a_slots_turn_goes_on_no_members_trace(self, route):
+        """The turn is the group's: its spans and the dispatcher's wait
+        for the slot are series only (a copy a member is a record a
+        request on the loop's thread); a member's trace keeps its own
+        two phases in the batcher and the group's span."""
+        from omero_ms_image_region_tpu.utils import telemetry
+
+        settings = _settings()
+        tids = [f"slot-{route}-{n}" for n in range(3)]
+
+        async def one(batcher, tid, seed):
+            with telemetry.trace_scope(tid):
+                if route == "jpeg":
+                    return await batcher.render_jpeg(
+                        self._tile(seed), settings, 85, 32, 32)
+                return await batcher.render(self._tile(seed), settings)
+
+        async def main():
+            batcher = BatchingRenderer(linger_ms=20.0,
+                                       buckets=((64, 64),))
+            try:
+                return await asyncio.gather(*(
+                    one(batcher, tid, 60 + n)
+                    for n, tid in enumerate(tids)))
+            finally:
+                await batcher.close()
+
+        assert len(run(main())) == 3
+        spans = self._spans()
+        assert spans["batcher.group"]["count"] == 1
+        for part in ("batcher.slot", "batcher.slotStart",
+                     "batcher.settleLag", "batcher.slotWait"):
+            assert spans[part]["count"] == 1, part
+        names = {tid: [s["name"] for s in
+                       telemetry.TRACES.get_or_create(tid).spans]
+                 for tid in tids}
+        assert not any(name.startswith("batcher.slot")
+                       for n in names.values() for name in n)
+        for n in names.values():
+            assert n.count("batcher.queueWait") == 1
+            assert n.count("batcher.inGroup") == 1
+            assert n.count("batcher.group") == 1

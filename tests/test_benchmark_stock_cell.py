@@ -48,7 +48,8 @@ def test_the_new_entries_are_the_issues():
     assert listed == {m["name"] for m in bench["per_layer"]} - {
         "read_region_ms", "unpack_device_ms",
         "shown_render_roofline",       # PR 32's, of another deployment
-        "bucket_fill_share", "stack_pad_device_ms"}   # PR 34's: plates
+        "bucket_fill_share", "stack_pad_device_ms",   # PR 34's: plates
+        "source_open_ms", "idle_read_share"}   # PR 36's: the scans' open
     with open(os.path.join(REPO, "benchmark", "configs",
                            "stock4-u16-t256.json")) as f:
         config = json.load(f)

@@ -324,3 +324,128 @@ def test_banded_cold_staging_matches_single_shot(tmp_path):
     again = handler._read_region(src, ctx, region, 0, [0, 1])
     np.testing.assert_array_equal(np.asarray(again), direct)
     assert (cache.channel_loads, cache.hits) == (2, 2)
+
+
+# ----------------------------------------------- the source open (PR 36)
+
+def test_a_plate_larger_than_the_lru_opens_a_source_a_request(tmp_path):
+    """Three images walked cyclically over ``PixelsService(max_open=2)``:
+    every lookup misses and opens, the gauge never passes 2,
+    ``PixelsService.openSource`` fires on the misses only and
+    ``PixelsService.getPixelBuffer`` on every request."""
+    from omero_ms_image_region_tpu.utils import telemetry
+    from omero_ms_image_region_tpu.utils.stopwatch import REGISTRY
+
+    rng = np.random.default_rng(36)
+    for image in (1, 2, 3):
+        build_pyramid(
+            rng.integers(0, 60000, size=(1, 1, 32, 32)).astype(np.uint16),
+            str(tmp_path / str(image)), chunk=(32, 32), n_levels=1)
+    svc = PixelsService(str(tmp_path), max_open=2)
+    services = ImageRegionServices(
+        pixels_service=svc, metadata=LocalMetadataService(str(tmp_path)),
+        caches=Caches.from_config(CacheConfig()),
+        can_read_memo=CanReadMemo(), renderer=Renderer(),
+        lut_provider=LutProvider(), cpu_fallback_max_px=0)
+    handler = ImageRegionHandler(services)
+
+    def count(span):
+        return REGISTRY.snapshot().get(span, {}).get("count", 0)
+
+    def lines():
+        return telemetry.device_metric_lines(services)
+
+    REGISTRY.reset()
+    walk = [1, 2, 3] * 3
+    for n, image in enumerate(walk, 1):
+        ctx = ImageRegionCtx.from_params(
+            {"imageId": str(image), "theZ": "0", "theT": "0",
+             "format": "png", "c": f"1|0:{1000 * n}$FF0000"})
+        assert run(handler.render_image_region(ctx))[:4] == b"\x89PNG"
+        assert svc.opened == n and svc.open_count() <= 2
+        assert f"imageregion_pixel_sources_opened_total {n}" in lines()
+    assert count("PixelsService.openSource") == len(walk)
+    assert count("PixelsService.getPixelBuffer") == len(walk)
+    assert "imageregion_pixel_sources_open 2" in lines()
+    assert count("PixelsService.gcDrain") == 0
+    # A hit: the request's span fires, the open's does not.
+    for _ in range(2):
+        ctx = ImageRegionCtx.from_params(
+            {"imageId": "3", "theZ": "0", "theT": "0", "format": "png"})
+        run(handler.render_image_region(ctx))
+    assert svc.opened == len(walk)
+    assert count("PixelsService.openSource") == len(walk)
+    assert count("PixelsService.getPixelBuffer") == len(walk) + 2
+    # The forced collection has a span of its own, and so a count.
+    svc._gc_and_drain()
+    assert count("PixelsService.gcDrain") == 1
+    svc.close()
+
+
+def _stopwatches_around_an_await():
+    """``(line, span name)`` of every ``with stopwatch(...)`` in
+    ``server/handler.py`` whose body awaits: a profiler annotation
+    there stays open on the event loop's thread while other requests
+    run on it."""
+    import ast
+    import inspect
+
+    from omero_ms_image_region_tpu.server import handler
+    tree = ast.parse(inspect.getsource(handler))
+    found, seen = [], []
+    for node in ast.walk(tree):
+        if not isinstance(node, (ast.With, ast.AsyncWith)):
+            continue
+        for item in node.items:
+            call = item.context_expr
+            if (isinstance(call, ast.Call)
+                    and getattr(call.func, "id", "") == "stopwatch"):
+                name = call.args[0].value
+                seen.append(name)
+                if any(isinstance(n, (ast.Await, ast.AsyncFor,
+                                      ast.AsyncWith))
+                       for body in node.body for n in ast.walk(body)):
+                    found.append((node.lineno, name))
+    return found, seen
+
+
+@pytest.mark.parametrize("span", [
+    "canRead", "get_pixels_description", "PixelsService.getPixelBuffer",
+    "Renderer.renderAsPackedInt", "getMask", "renderShapeMask",
+    "renderOverlay"])
+def test_no_stopwatch_of_the_handler_encloses_an_await(span):
+    """The seven spans that did (PR 36) are recorded from two stamps,
+    under the names they had; no other has joined them."""
+    found, seen = _stopwatches_around_an_await()
+    assert found == []
+    assert span not in seen
+    assert "PixelsService.readRegion" in seen     # the walk sees spans
+
+
+@pytest.mark.parametrize("span, where, fmt", [
+    ("canRead", ("metadata", "can_read"), "png"),
+    ("get_pixels_description",
+     ("metadata", "get_pixels_description"), "png"),
+    ("PixelsService.getPixelBuffer",
+     ("pixels_service", "get_pixel_source"), "png"),
+    ("Renderer.renderAsPackedInt", ("renderer", "render"), "png"),
+    ("Renderer.renderAsPackedInt", ("renderer", "render_jpeg"), "jpeg")])
+def test_a_span_over_an_await_counts_the_request_that_failed(
+        services, monkeypatch, span, where, fmt):
+    """As under ``stopwatch``'s ``finally``: the requests that end in a
+    deadline, a shed or an error, usually the slowest, stay in the
+    series' count, p99 and max."""
+    from omero_ms_image_region_tpu.utils.stopwatch import REGISTRY
+
+    class Stalled(RuntimeError):
+        pass
+
+    def fail(*args, **kw):
+        raise Stalled(span)
+
+    monkeypatch.setattr(getattr(services, where[0]), where[1], fail)
+    REGISTRY.reset()
+    with pytest.raises(Stalled):
+        run(ImageRegionHandler(services).render_image_region(
+            _ctx(format=fmt)))
+    assert REGISTRY.snapshot()[span]["count"] == 1

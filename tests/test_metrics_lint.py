@@ -123,6 +123,35 @@ class TestExpositionBudget:
         assert any("imageregion_httpcache_304_total" in f
                    for f in findings)
 
+    def test_pixel_source_families_lint_clean_with_help_and_type(
+            self, lint, budget):
+        """PR 36's two label-free families, from a live
+        ``PixelsService``: budgeted, typed, each with its own HELP."""
+        from types import SimpleNamespace
+
+        from omero_ms_image_region_tpu.io.service import PixelsService
+        services = SimpleNamespace(pixels_service=PixelsService("/none"))
+        text = telemetry.finalize_exposition([
+            line for line in telemetry.device_metric_lines(services)
+            if "_pixel_sources_" in line])
+        for family, kind in (
+                ("imageregion_pixel_sources_opened_total", "counter"),
+                ("imageregion_pixel_sources_open", "gauge")):
+            assert f"\n{family} 0\n" in text
+            assert f"# TYPE {family} {kind}\n" in text
+            assert text.count(f"# HELP {family} ") == 1
+            assert telemetry.METRIC_HELP[family]
+            assert budget["families"][family] == {"labels": []}
+        assert lint.lint_exposition(text, budget) == []
+        smuggled = text + 'imageregion_pixel_sources_open{image="7"} 1\n'
+        assert any("imageregion_pixel_sources_open" in f
+                   for f in lint.lint_exposition(smuggled, budget))
+
+    def test_every_idle_class_fits_the_during_bound(self, budget):
+        from omero_ms_image_region_tpu.utils import profile_summary as ps
+        classes = set(ps.IDLE_ORDER) | {ps.NO_GROUP, ps.UNATTRIBUTED}
+        assert len(classes) <= budget["label_bounds"]["during"]
+
     def test_exemplar_tail_tolerated(self, lint, budget):
         text = self._exposition()
         assert " # {" in text, "exemplar did not reach exposition"

@@ -460,3 +460,133 @@ class TestConsumerHooks:
             await renderer.close()
 
         asyncio.run(scenario())
+
+
+class TestLoopLag:
+    """PR 36: one measure of the event loop's lag, always on
+    (``utils.stopwatch.LoopLagSampler``, span ``loop.lag``); the
+    governor's ``loop_lag_ms`` signal reads it and times nothing."""
+
+    @staticmethod
+    def _lag_count():
+        from omero_ms_image_region_tpu.utils.stopwatch import REGISTRY
+        return REGISTRY.snapshot().get("loop.lag", {}).get("count", 0)
+
+    @pytest.mark.parametrize("enabled", [False, True])
+    def test_the_app_samples_the_loop_governor_on_or_off(self, tmp_path,
+                                                         enabled):
+        from aiohttp.test_utils import TestClient, TestServer
+
+        from omero_ms_image_region_tpu.server.app import create_app
+        from omero_ms_image_region_tpu.utils.stopwatch import (
+            REGISTRY, LoopLagSampler)
+
+        config = AppConfig.from_dict(
+            {"pressure": {"enabled": enabled, "interval-s": 0.05}})
+        config.data_dir = str(tmp_path)
+        assert config.pressure.enabled is enabled
+        REGISTRY.reset()
+
+        async def main():
+            client = TestClient(TestServer(create_app(config)))
+            await client.start_server()
+            try:
+                await asyncio.sleep(3.5 * LoopLagSampler.INTERVAL_S)
+                text = await (await client.get("/metrics")).text()
+                return self._lag_count(), text, pressure.active()
+            finally:
+                await client.close()
+
+        samples, text, governor = asyncio.run(main())
+        assert 2 <= samples <= 4
+        assert 'imageregion_span_count{span="loop.lag"}' in text
+        assert (governor is not None) is enabled
+        if enabled:
+            # The governor ticked, and its signal is the sampler's.
+            assert 'imageregion_pressure_signal{signal="loop_lag_ms"}' \
+                in text
+            assert governor.sources["loop_lag_ms"]() is not None
+
+    def test_the_governors_signal_follows_the_sampler(self):
+        from omero_ms_image_region_tpu.utils.stopwatch import (
+            LoopLagSampler)
+
+        sampler = LoopLagSampler()
+        sources = pressure.build_sources(loop_lag=sampler)
+        config = AppConfig.from_dict(
+            {"pressure": {"enabled": True, "loop-lag-high-ms": 100,
+                          "loop-lag-low-ms": 20}}).pressure
+        gov = pressure.PressureGovernor(
+            config, {}, {"loop_lag_ms": sources["loop_lag_ms"]})
+        assert not hasattr(gov, "loop_lag_ms")
+        gov.tick()
+        assert gov.level == pressure.LEVEL_OK
+        sampler.ewma_ms = 150.0
+        gov.tick()
+        assert gov.level >= pressure.LEVEL_ELEVATED
+        sampler.ewma_ms = 1.0
+        gov.tick()
+        assert gov.level == pressure.LEVEL_OK
+        # No sampler, no signal (a stack built without a loop).
+        assert pressure.build_sources()["loop_lag_ms"]() is None
+
+    def test_a_blocked_loop_reads_as_lag(self):
+        import time
+
+        from omero_ms_image_region_tpu.utils.stopwatch import (
+            REGISTRY, LoopLagSampler)
+
+        REGISTRY.reset()
+        sampler = LoopLagSampler()
+
+        async def main():
+            task = asyncio.ensure_future(sampler.run())
+            await asyncio.sleep(0.01)       # the sampler sleeps
+            # The loop runs nothing for 80 ms past the sample's due time.
+            time.sleep(LoopLagSampler.INTERVAL_S + 0.08)
+            await asyncio.sleep(0.01)
+            task.cancel()
+            await asyncio.gather(task, return_exceptions=True)
+
+        asyncio.run(main())
+        lag = REGISTRY.snapshot()["loop.lag"]
+        assert lag["count"] == 1 and lag["max_ms"] >= 60.0
+        assert sampler.ewma_ms == pytest.approx(
+            LoopLagSampler.ALPHA * lag["max_ms"], rel=1e-3)
+
+    def test_one_pause_is_not_sustained_lag(self):
+        """The signal keeps the three seconds of memory the governor's
+        own timing had (0.3 of a one-second tick's lateness), fed every
+        100 ms: one 300 ms GC pause stays under the shipped low mark at
+        every sample after it, a loop that is late every time climbs
+        past the high mark in seconds, and comes down as slowly."""
+        from omero_ms_image_region_tpu.server.config import PressureConfig
+        from omero_ms_image_region_tpu.utils.stopwatch import (
+            LoopLagSampler)
+
+        marks = PressureConfig()
+        per_s = round(1.0 / LoopLagSampler.INTERVAL_S)
+        assert (1 - LoopLagSampler.ALPHA) ** per_s == pytest.approx(
+            0.7, abs=0.005)
+        sampler = LoopLagSampler()
+        peak = 0.0
+        for lag_ms in [300.0] + [1.0] * (3 * per_s):
+            sampler.observe(lag_ms)
+            peak = max(peak, sampler.ewma_ms)
+        assert peak < marks.loop_lag_low_ms / 4
+        # Late by 300 ms at every wake-up: sustained.
+        samples = 0
+        while sampler.ewma_ms <= marks.loop_lag_high_ms:
+            sampler.observe(300.0)
+            samples += 1
+        assert 3 * per_s < samples < 8 * per_s
+        # One second on time does not clear it.
+        for _ in range(per_s):
+            sampler.observe(0.0)
+        assert sampler.ewma_ms > marks.loop_lag_low_ms
+
+    def test_pressure_times_no_sleep_of_its_own(self):
+        import inspect
+        source = inspect.getsource(pressure)
+        assert "import time" not in source
+        assert "perf_counter" not in source and "monotonic" not in source

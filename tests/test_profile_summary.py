@@ -138,6 +138,49 @@ def test_gaps_are_split_in_the_stated_order_and_sum_to_idle():
         < ps.IDLE_ORDER.index("device.wait")
 
 
+def test_a_gap_goes_to_the_reading_threads_before_it_goes_to_nobody():
+    """PR 36: the spans of the threads between groups come after every
+    class the order had, so those read what they read; only ``no_group``
+    and ``unattributed`` give way."""
+    device, host = hand_made_capture()
+    before = ps.summarize(device, host)["idle_ms"]
+    host = host + [
+        # A read under the entropy coding [195, 200) and the group's
+        # tail [200, 205): the coding keeps its 5 ms, the read takes 5
+        # of what had no span though a group was alive.
+        _host("r0", "PixelsService.readRegion", 195, 10),
+        # No group alive: a read [220, 260), an open that begins under
+        # it [250, 270), its forced collection, a request's accounting.
+        _host("r1", "PixelsService.readRegion", 220, 40),
+        _host("r2", "PixelsService.openSource", 250, 20, backend="tiff"),
+        _host("r2", "PixelsService.gcDrain", 270, 2),
+        _host("loop", "http.account", 280, 1),
+        # One under a busy stretch takes nothing.
+        _host("r0", "PixelsService.readRegion", 100, 40),
+    ]
+    s = ps.summarize(device, host)
+    old = set(ps.IDLE_ORDER[:7])
+    assert old == {"xla.compile", "device.dispatch", "batcher.stage",
+                   "batcher.laneWait", "wire.d2h", "jfif.encodeBatch",
+                   "device.wait"}
+    assert {k: v for k, v in s["idle_ms"].items() if k in old} \
+        == pytest.approx({k: v for k, v in before.items() if k in old})
+    assert s["idle_ms"] == pytest.approx({
+        "device.dispatch": 4, "batcher.laneWait": 3, "device.wait": 5,
+        "wire.d2h": 5, "jfif.encodeBatch": 20,
+        "PixelsService.readRegion": 5 + 40,
+        "PixelsService.openSource": 10, "PixelsService.gcDrain": 2,
+        "http.account": 1,
+        "unattributed": 3 + 10 - 5, "no_group": 90 - 40 - 10 - 2 - 1})
+    assert sum(s["idle_ms"].values()) == pytest.approx(
+        s["traced_ms"] - s["busy_ms"])
+    assert ps.IDLE_ORDER[7:] == (
+        "PixelsService.readRegion", "PixelsService.openSource",
+        "PixelsService.gcDrain", "http.account")
+    assert set(ps.IDLE_ORDER) <= ps.HOST_SPANS
+    assert s["host_spans"]["PixelsService.readRegion"]["count"] == 3
+
+
 def test_a_compile_takes_a_gap_before_the_dispatch_it_lies_in():
     device = [_dev(TPU0, "jit(p)/render/mul", 0, 10),
               _dev(TPU0, "jit(p)/render/mul", 110, 10)]

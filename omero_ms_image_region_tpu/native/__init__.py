@@ -12,6 +12,8 @@ runs concurrently across shards — the point of having this tier in C++.
 
 from __future__ import annotations
 
+import collections
+import contextlib
 import ctypes
 import hashlib
 import os
@@ -136,17 +138,27 @@ def _configure_tilecache(lib: ctypes.CDLL) -> None:
 
 
 def _configure_jpegenc(lib: ctypes.CDLL) -> None:
-    lib.jpeg_encode.restype = ctypes.c_longlong
+    lib.jpeg_scratch_new.restype = ctypes.c_void_p
+    lib.jpeg_scratch_new.argtypes = []
+    lib.jpeg_scratch_free.restype = None
+    lib.jpeg_scratch_free.argtypes = [ctypes.c_void_p]
+    lib.jpeg_scratch_bytes.restype = ctypes.c_size_t
+    lib.jpeg_scratch_bytes.argtypes = [ctypes.c_void_p]
+    lib.jpeg_scratch_growths.restype = ctypes.c_longlong
+    lib.jpeg_scratch_growths.argtypes = []
+    lib.jpeg_encode.restype = ctypes.c_void_p
     lib.jpeg_encode.argtypes = [
+        ctypes.c_void_p,
         ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
         ctypes.c_int, ctypes.c_int, ctypes.c_int,
-        ctypes.c_void_p, ctypes.c_size_t,
+        ctypes.POINTER(ctypes.c_longlong),
     ]
-    lib.jpeg_encode_sparse.restype = ctypes.c_longlong
-    lib.jpeg_encode_sparse.argtypes = [
-        ctypes.c_void_p, ctypes.c_size_t,
-        ctypes.c_int, ctypes.c_int, ctypes.c_int, ctypes.c_int,
-        ctypes.c_void_p, ctypes.c_size_t,
+    lib.jpeg_encode_sparse_run.restype = ctypes.c_void_p
+    lib.jpeg_encode_sparse_run.argtypes = [
+        ctypes.c_void_p, ctypes.c_int,
+        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+        ctypes.c_int, ctypes.c_int,
+        ctypes.c_void_p, ctypes.c_void_p,
     ]
 
 
@@ -356,6 +368,51 @@ def jpeg_native_available() -> bool:
         return False
 
 
+# The coder's scratches (jpegenc.cpp ``Scratch``: symbol records, block
+# offsets, output stream), kept from one call to the next so that a
+# call allocates nothing.  A call takes one and gives it back; last in,
+# first out, so as many exist as threads have ever coded at the same
+# moment, and the ones in use stay warm.  No more are KEPT than the
+# cores the process may use (more threads than that cannot code at
+# once to any purpose): what the process retains is at most that many
+# times the largest tile's 272 bytes a block (26.7 MB at 2048^2).
+_SCRATCHES: collections.deque = collections.deque()
+_SCRATCHES_KEPT = len(os.sched_getaffinity(0))
+
+
+@contextlib.contextmanager
+def _scratch(lib: ctypes.CDLL):
+    """A scratch for one coding call: the one given back last, a new
+    one where every scratch is in use."""
+    try:
+        scratch = _SCRATCHES.pop()
+    except IndexError:
+        scratch = lib.jpeg_scratch_new()
+        if not scratch:
+            raise MemoryError("jpeg_scratch_new failed")
+    try:
+        yield scratch
+    finally:
+        if len(_SCRATCHES) < _SCRATCHES_KEPT:
+            _SCRATCHES.append(scratch)
+        else:
+            lib.jpeg_scratch_free(scratch)
+
+
+def jpeg_scratch_stats() -> dict:
+    """``growths``: times any scratch of the process took a larger
+    block than it had (it stands still while tiles of sizes already
+    seen are coded: the contract check that the scratch is kept);
+    ``idle`` / ``idle_bytes``: the scratches no call holds right now,
+    and what they retain."""
+    lib = _load_jpeg()
+    idle = list(_SCRATCHES)
+    return {"growths": int(lib.jpeg_scratch_growths()),
+            "idle": len(idle),
+            "idle_bytes": sum(int(lib.jpeg_scratch_bytes(s))
+                              for s in idle)}
+
+
 def jpeg_encode_native(y, cb, cr, width: int, height: int,
                        quality: int) -> bytes:
     """Entropy-encode device JPEG coefficients to a JFIF stream (C++).
@@ -377,21 +434,46 @@ def jpeg_encode_native(y, cb, cr, width: int, height: int,
             f"coefficient sizes {y.size}/{cb.size}/{cr.size} do not match "
             f"a {w16}x{h16}-MCU frame"
         )
-    # emit_jfif buffers internally and returns -needed on a short cap, at
-    # the price of a full re-encode — so start at a safe worst case
-    # (~4 bytes/coefficient covers even max-entropy tiles).
-    cap = (y.size + cb.size + cr.size) * 4 + 4096
-    while True:
-        out = ctypes.create_string_buffer(cap)
-        n = lib.jpeg_encode(
-            y.ctypes.data, cb.ctypes.data, cr.ctypes.data,
-            width, height, quality, out, cap,
-        )
-        if n >= 0:
-            return out.raw[:n]
-        if n == -1:
+    n = ctypes.c_longlong()
+    with _scratch(lib) as scratch:
+        out = lib.jpeg_encode(
+            scratch, y.ctypes.data, cb.ctypes.data, cr.ctypes.data,
+            width, height, quality, ctypes.byref(n))
+        if not out:
             raise ValueError("jpeg_encode: invalid arguments")
-        cap = -n
+        return ctypes.string_at(out, n.value)
+
+
+def jpeg_encode_sparse_run(rows, dims, quality: int, cap: int) -> list:
+    """JFIF-encode a run of tiles straight from their sparse wire rows,
+    in ONE native call: the thread gives the GIL up once a run and not
+    once a tile.
+
+    ``rows`` are the u8[...] rows from ``ops.jpegenc.render_to_jpeg_sparse``
+    (read where they lie: a contiguous row is not copied), ``dims`` each
+    tile's ``(width, height)``.  One entry a row comes back: its JFIF
+    ``bytes``, or the coder's return code where it gave none: ``-2``, the
+    tile's coefficient density exceeded ``cap`` and the dense path must
+    be taken; ``-1``, the row is malformed.
+    """
+    import numpy as np
+    lib = _load_jpeg()
+    n = len(rows)
+    rows = [np.ascontiguousarray(r, dtype=np.uint8) for r in rows]
+    bufs = (ctypes.c_void_p * n)(*[r.ctypes.data for r in rows])
+    # The TRUE lengths are what the decoder validates against: a
+    # truncated row errors instead of decoding its last entry from
+    # what lies behind it.
+    lens = (ctypes.c_size_t * n)(*[r.size for r in rows])
+    widths = (ctypes.c_int * n)(*[w for w, _ in dims])
+    heights = (ctypes.c_int * n)(*[h for _, h in dims])
+    rc = (ctypes.c_longlong * n)()
+    off = (ctypes.c_size_t * n)()
+    with _scratch(lib) as scratch:
+        out = lib.jpeg_encode_sparse_run(
+            scratch, n, bufs, lens, widths, heights, quality, cap, rc, off)
+        return [ctypes.string_at(out + off[i], rc[i]) if rc[i] >= 0
+                else int(rc[i]) for i in range(n)]
 
 
 def jpeg_encode_sparse_native(buf, width: int, height: int, quality: int,
@@ -402,31 +484,12 @@ def jpeg_encode_sparse_native(buf, width: int, height: int, quality: int,
     Raises :class:`SparseOverflowError` when the tile's coefficient density
     exceeded ``cap`` and the dense path must be taken instead.
     """
-    import numpy as np
-    lib = _load_jpeg()
-    buf = np.ascontiguousarray(buf, dtype=np.uint8)
-    true_len = buf.size
-    # Pad so the decoder's 32-bit window reads at the 18-bit stream tail
-    # stay in bounds (jpegenc.cpp read_entry18); prefix fetches
-    # especially.  The TRUE length is what the decoder validates against
-    # — counting the pad would let a truncated buffer decode its last
-    # entry from zeros instead of erroring.
-    buf = np.pad(buf, (0, 4))
-    out_cap = buf.size * 4 + 65536
-    while True:
-        out = ctypes.create_string_buffer(out_cap)
-        n = lib.jpeg_encode_sparse(
-            buf.ctypes.data, true_len, width, height, quality, cap,
-            out, out_cap,
-        )
-        if n >= 0:
-            return out.raw[:n]
-        if n == -2:
-            raise SparseOverflowError(
-                f"sparse buffer overflow (cap={cap})")
-        if n == -1:
-            raise ValueError("jpeg_encode_sparse: invalid arguments")
-        out_cap = -n
+    coded, = jpeg_encode_sparse_run([buf], [(width, height)], quality, cap)
+    if coded == -2:
+        raise SparseOverflowError(f"sparse buffer overflow (cap={cap})")
+    if coded == -1:
+        raise ValueError("jpeg_encode_sparse: invalid arguments")
+    return coded
 
 
 def jpeg_decode_baseline(data: bytes, tables: "bytes | None"):

@@ -341,7 +341,7 @@ def sparse_pack(y, cb, cr, cap: int):
     quantizer clips to ±2047, so 12 bits are exact) of the j-th nonzero
     in (block, zigzag) scan order — exactly the run-length stream
     baseline JPEG entropy-codes, so the host encoder
-    (``jpeg_encode_sparse``) reads it directly.  Block order is luma
+    (``jpeg_encode_sparse_run``) reads it directly.  Block order is luma
     raster, then Cb raster, then Cr raster.  Entries beyond ``cap`` are
     dropped (detected host-side via total_entries > cap; the caller then
     falls back to the dense path).
@@ -846,7 +846,7 @@ def sparse_to_dense(buf: np.ndarray, H: int, W: int, cap: int):
     """
     # The wire buffer is packed for the 16-aligned (MCU-padded) grid, so
     # block counts use ceil — H/W may be the tile's true, unaligned size
-    # (the native encoder does the same, jpegenc.cpp jpeg_encode_sparse).
+    # (the native encoder does the same, jpegenc.cpp encode_sparse_row).
     h16, w16 = (H + 15) // 16, (W + 15) // 16
     nb_y = h16 * w16 * 4
     nb_c = h16 * w16
@@ -1316,6 +1316,34 @@ def sparse_encoder():
     return _encode
 
 
+def sparse_run_encoder():
+    """The sparse entropy coder over a run of tiles: native (one call a
+    run, the GIL given up once) if available, else Python.
+
+    Returns ``encode(rows, dims, quality, cap) -> list``, one entry a
+    row: its ``bytes``, or ``-2`` where the row dropped entries (the
+    dense path must be taken) and ``-1`` where it is malformed, as
+    ``native.jpeg_encode_sparse_run``.
+    """
+    from ..native import SparseOverflowError, jpeg_native_available
+    if jpeg_native_available():
+        from ..native import jpeg_encode_sparse_run
+        return jpeg_encode_sparse_run
+
+    _encode = sparse_encoder()
+
+    def _encode_run(rows, dims, q, cap_):
+        out = []
+        for buf, (w, h) in zip(rows, dims):
+            try:
+                out.append(_encode(buf, w, h, q, cap_))
+            except SparseOverflowError:
+                out.append(-2)
+        return out
+
+    return _encode_run
+
+
 def encode_sparse_buffers(bufs: np.ndarray, width: int, height: int,
                           quality: int, cap: int, executor=None,
                           dense_fallback=None) -> list:
@@ -1579,31 +1607,50 @@ def finish_sparse_to_jpegs(bufs, dims, H: int, W: int, quality: int,
     ceil-16 grid is smaller than the bucketed (H, W) are entropy-coded
     from the top-left block subgrid, and tiles that overflowed ``cap``
     re-render through ``dense_coefficients(i) -> (y, cb, cr)``.
+
+    The tiles are coded in runs, side by side: by this thread and by
+    the process's coding threads (``utils.entropypool``), a tail of one
+    run by this thread alone.  ``on_tile(i, bytes)`` fires once a tile,
+    the moment its run has returned, FROM THE THREAD THAT CODED IT and
+    in no promised order.  A tile that needs the dense path takes it on
+    the thread that found it.  The first exception of any tile is raised
+    from here once every thread has stopped; tiles not yet coded then
+    stay uncoded and fire nothing.
     """
-    from ..native import SparseOverflowError
+    from ..utils import entropypool
 
-    _encode = sparse_encoder()
+    _encode_run = sparse_run_encoder()
     _dense_encode = dense_encoder()
+    n = len(dims)
+    exact = [(h_ + 15) // 16 * 16 == H and (w_ + 15) // 16 * 16 == W
+             for (w_, h_) in dims]
+    out = [None] * n
 
-    out = []
-    for i, (w_, h_) in enumerate(dims):
-        exact = ((h_ + 15) // 16 * 16 == H and (w_ + 15) // 16 * 16 == W)
-        try:
-            if exact:
-                out.append(_encode(bufs[i], w_, h_, quality, cap))
-                if on_tile is not None:
-                    on_tile(i, out[-1])
-                continue
-            dense = sparse_to_dense(bufs[i], H, W, cap)
-            if dense is None:
-                raise SparseOverflowError(f"overflow (cap={cap})")
-        except SparseOverflowError:
+    def dense_tile(i: int) -> bytes:
+        w_, h_ = dims[i]
+        dense = None if exact[i] else sparse_to_dense(bufs[i], H, W, cap)
+        if dense is None:                   # the row overflowed its cap
             dense = dense_coefficients(i)
-        y, cb, cr = slice_block_subgrid(*dense, H, W, w_, h_) \
-            if not exact else dense
-        out.append(_dense_encode(y, cb, cr, w_, h_, quality))
-        if on_tile is not None:
-            on_tile(i, out[-1])
+        y, cb, cr = dense if exact[i] else \
+            slice_block_subgrid(*dense, H, W, w_, h_)
+        return _dense_encode(y, cb, cr, w_, h_, quality)
+
+    def code_run(run: range) -> None:
+        rows = [i for i in run if exact[i]]
+        coded = dict(zip(rows, _encode_run(
+            [bufs[i] for i in rows], [dims[i] for i in rows],
+            quality, cap)))
+        for i in run:
+            body = coded.get(i, -2)
+            if body == -2:
+                body = dense_tile(i)
+            elif body == -1:
+                raise ValueError("jpeg_encode_sparse: invalid arguments")
+            out[i] = body
+            if on_tile is not None:
+                on_tile(i, body)
+
+    entropypool.pool().code(n, H * W, code_run)
     return out
 
 

@@ -37,7 +37,7 @@ import time
 import numpy as np
 
 from ..ops.render import render_tile_batch_packed
-from ..utils import telemetry
+from ..utils import entropypool, telemetry
 from ..utils.stopwatch import REGISTRY, record_since, stopwatch
 
 DEFAULT_BUCKETS = ((256, 256), (512, 512), (1024, 1024), (2048, 2048))
@@ -311,6 +311,9 @@ class BatchingRenderer:
                                           pipeline_depth))
         self.jpeg_engine = jpeg_engine
         self.pipeline_depth = pipeline_depth
+        # The group threads code their groups' tiles too: the coding
+        # pool leaves cores for them.
+        entropypool.expect_group_threads(pipeline_depth)
         # ``planes``: the plane shapes (h, w) the site states
         # (``renderer.prewarm``).  Each gets a bucket no larger than
         # its MCU grid (``bucket_lattice``), and resident planes of a

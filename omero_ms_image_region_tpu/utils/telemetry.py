@@ -43,6 +43,8 @@ from bisect import bisect_left
 from contextlib import contextmanager
 from typing import Dict, List, Mapping, Optional, Tuple
 
+from . import entropypool
+
 log = logging.getLogger("omero_ms_image_region_tpu.telemetry")
 
 # --------------------------------------------------------------- histograms
@@ -3570,6 +3572,7 @@ METRIC_TYPES: Dict[str, str] = {
     "imageregion_batcher_padded_slots_total": "counter",
     "imageregion_batcher_group_stacks_total": "counter",
     "imageregion_batcher_bucket_px_total": "counter",
+    "imageregion_entropy_tiles_total": "counter",
     "imageregion_renders_routed_total": "counter",
     "imageregion_batcher_queue_wait_max_ms": "gauge",
     "imageregion_compile_events_total": "counter",
@@ -3805,6 +3808,10 @@ METRIC_HELP: Dict[str, str] = {
     "imageregion_batcher_bucket_px_total":
         "Pixels of the groups launched, by part: the members' own "
         "image, or the pad their buckets hold beyond it",
+    "imageregion_entropy_tiles_total":
+        "JPEG tiles entropy-coded, by path: pooled in a group's tail "
+        "that several threads coded, inline in one its own thread "
+        "coded alone",
     "imageregion_rawcache_channel_loads_total":
         "Channel planes read (or handed over) and uploaded to the HBM "
         "raw cache",
@@ -4291,6 +4298,12 @@ def device_metric_lines(services, extra_labels: str = "") -> List[str]:
             # p50 cannot see at all.
             lines.append(f"imageregion_batcher_queue_wait_max_ms{lb} "
                          f"{round(renderer.queue_wait_max_ms, 3)}")
+    # JPEG tiles coded, by whether their group's tail ran on more than
+    # one thread.  The process's, as its one coding pool is.
+    for path, n in entropypool.TILES.items():
+        body = f'path="{path}"'
+        lines.append("imageregion_entropy_tiles_total"
+                     f"{label(body)} {n}")
     lb = label()
     lines += [
         f"imageregion_compile_events_total{lb} {COMPILE.events}",

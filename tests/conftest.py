@@ -117,3 +117,18 @@ def pytest_collection_modifyitems(config, items):
                f"(env-blocked since seed, not a regression): {reason}")
     for item in multihost:
         item.add_marker(marker)
+
+
+# ---------------------------------- the JPEG tail's coding pool (PR 37)
+
+@pytest.fixture
+def coding_pool(monkeypatch):
+    """A pool of three coding threads in the process's place, and its
+    counter from zero; yields ``utils.entropypool``."""
+    from omero_ms_image_region_tpu.utils import entropypool
+
+    pool = entropypool.EntropyPool(3)
+    monkeypatch.setattr(entropypool, "_POOL", pool)
+    monkeypatch.setattr(entropypool, "TILES", {"pooled": 0, "inline": 0})
+    yield entropypool
+    pool._executor.shutdown(wait=True)

@@ -1172,3 +1172,80 @@ class TestSlotSpans:
             assert n.count("batcher.queueWait") == 1
             assert n.count("batcher.inGroup") == 1
             assert n.count("batcher.group") == 1
+
+
+# ------------------ a group's tiles coded side by side (PR 37)
+
+def test_a_groups_members_are_answered_from_the_coding_threads(
+        monkeypatch, coding_pool):
+    """Six requests, one group: its tiles are coded by the group's
+    thread and the pool's, each member's future is settled from the
+    thread that coded its tile (first-tile-out crosses threads with
+    ``call_soon_threadsafe``), every answer is the direct renderer's,
+    and ``/metrics`` says the tail was pooled."""
+    import threading
+
+    from omero_ms_image_region_tpu.ops import jpegenc
+    from omero_ms_image_region_tpu.utils import telemetry
+
+    real = jpegenc.sparse_run_encoder()
+    threads, met = set(), threading.Event()
+
+    def meeting_encoder():
+        def encode(rows, dims, quality, cap):
+            # The first thread waits here for a second to bring a run.
+            threads.add(threading.get_ident())
+            if len(threads) > 1:
+                met.set()
+            met.wait(10.0)
+            return real(rows, dims, quality, cap)
+        return encode
+
+    monkeypatch.setattr(jpegenc, "sparse_run_encoder", meeting_encoder)
+    settings = _settings()
+    rng = np.random.default_rng(37)
+    tiles = [rng.integers(0, 60000, size=(3, 64, 64)).astype(np.float32)
+             for _ in range(6)]
+
+    async def main():
+        batcher = BatchingRenderer(max_batch=8, linger_ms=50.0,
+                                   buckets=((64, 64),))
+        direct = Renderer()
+        try:
+            got = await asyncio.gather(*[
+                batcher.render_jpeg(t, settings, 85, 64, 64)
+                for t in tiles])
+            want = [await direct.render_jpeg(t, settings, 85, 64, 64)
+                    for t in tiles]
+        finally:
+            await batcher.close()
+        return got, want, batcher.batches_dispatched
+
+    got, want, dispatched = run(main())
+    assert dispatched == 1 and got == want
+    assert len(threads) > 1
+    # The direct renderer's six tiles were groups of one, in line.
+    assert coding_pool.TILES == {"pooled": 6, "inline": 6}
+    text = telemetry.finalize_exposition(
+        telemetry.device_metric_lines(None))
+    assert 'imageregion_entropy_tiles_total{path="pooled"} 6\n' in text
+    assert 'imageregion_entropy_tiles_total{path="inline"} 6\n' in text
+    assert "# TYPE imageregion_entropy_tiles_total counter" in text
+    assert text.count("# HELP imageregion_entropy_tiles_total") == 1
+
+
+def test_a_batcher_tells_the_coding_pool_its_depth(monkeypatch):
+    import os
+
+    from omero_ms_image_region_tpu.utils import entropypool
+
+    monkeypatch.setattr(entropypool, "_GROUP_THREADS", 1)
+    monkeypatch.setattr(os, "sched_getaffinity",
+                        lambda pid: set(range(13)))
+
+    async def main():
+        batcher = BatchingRenderer(pipeline_depth=4)
+        await batcher.close()
+
+    run(main())
+    assert entropypool.pool_threads() == 8

@@ -87,10 +87,12 @@ def test_the_fifteen_entries_are_the_tables_appended_in_its_order():
     bench = _bench()
     cells = [w["name"] for w in bench["workloads"]]
     assert len(cells) == 7
-    assert [m["name"] for m in bench["per_layer"]][-15:] == list(METRICS)
-    assert len(bench["per_layer"]) == 31 + 15
-    layers = {m["layer"] for m in bench["per_layer"][:-15]}
-    for entry in bench["per_layer"][-15:]:
+    # PR 37 appended one more behind them (``entropy_pooled_share``).
+    mine = bench["per_layer"][31:31 + 15]
+    assert [m["name"] for m in mine] == list(METRICS)
+    assert len(bench["per_layer"]) == 31 + 15 + 1
+    layers = {m["layer"] for m in bench["per_layer"][:31]}
+    for entry in mine:
         layer, source, moves, reader, listed = METRICS[entry["name"]]
         assert entry == {
             "name": entry["name"], "unit": entry["unit"],
@@ -195,3 +197,45 @@ def test_the_idle_shares_split_the_capture_s_idle_by_class():
         assert term["labels"]["during"] in ps.IDLE_ORDER
     assert _spec("idle_no_group_share")["args"]["numerator"][0][
         "labels"]["during"] == ps.NO_GROUP
+
+
+def test_entropy_pooled_share_is_appended_and_reads_the_tails_counter():
+    """PR 37's one metric: the last entry, every cell, a data file over
+    the reader ``plane_stack_share`` uses; nothing from a server
+    without the family (the parent), 0 where every group is of one."""
+    bench = _bench()
+    cells = [w["name"] for w in bench["workloads"]]
+    assert bench["per_layer"][-1] == {
+        "name": "entropy_pooled_share", "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "host entropy tail",
+        "moves": "p50_ms", "workloads": cells}
+    entropy_ms = {m["name"]: m for m in bench["per_layer"]}["entropy_ms"]
+    assert (entropy_ms["layer"], entropy_ms["moves"]) == (
+        "host entropy tail", "p50_ms")
+    spec = _spec("entropy_pooled_share")
+    family = "imageregion_entropy_tiles_total"
+    assert spec == {
+        "name": "entropy_pooled_share", "layer": "host entropy tail",
+        "unit": "%", "moves": "p50_ms", "source": "program_counter",
+        "reader": "labelled_ratio",
+        "args": {"numerator": [{"family": family,
+                                "labels": {"path": "pooled"}}],
+                 "denominator": [{"family": family}], "percent": True}}
+    m0 = {f'{family}{{path="pooled"}}': 60.0,
+          f'{family}{{path="inline"}}': 40.0}
+    m1 = {f'{family}{{path="pooled"}}': 60.0 + 570.0,
+          f'{family}{{path="inline"}}': 40.0 + 30.0}
+    assert _read("entropy_pooled_share", m0, m1) == pytest.approx(95.0)
+    lone = {f'{family}{{path="pooled"}}': 60.0,
+            f'{family}{{path="inline"}}': 4000.0}
+    assert _read("entropy_pooled_share", m0, lone) == 0.0
+    assert _read("entropy_pooled_share", m1, m1) is None
+    assert _read("entropy_pooled_share", {},
+                 {"imageregion_tiles_rendered": 9.0}) is None
+    # The series are the program's own, from its one coding pool.
+    from benchmark.prom import parse_metrics
+    from omero_ms_image_region_tpu.utils import entropypool, telemetry
+    live = parse_metrics(telemetry.finalize_exposition(
+        telemetry.device_metric_lines(None)))
+    for path, n in entropypool.TILES.items():
+        assert live[f'{family}{{path="{path}"}}'] == float(n)

@@ -1304,7 +1304,7 @@ def test_the_two_halves_give_the_bytes_of_the_composed_call(
     """``render_batch_to_wire`` then ``finish_wire_to_jpegs``, called
     apart as the batcher calls them, return what
     ``render_batch_to_jpeg`` returns, byte for byte, and ``on_tile``
-    fires for the same tiles with the same bytes in the same order: on
+    fires for the same tiles with the same bytes: on
     grid-exact and bucket-padded ``dims``, through the one-shot cap
     widening, and with a tile that overflows the doubled cap too and
     is coded from its dense coefficients."""
@@ -1369,6 +1369,9 @@ def test_the_two_halves_give_the_bytes_of_the_composed_call(
         got, got_fired, wire = apart()
     finally:
         forget()
+    # Once a tile, in no promised order since PR 37 (the sparse tail's
+    # tiles are coded side by side).
+    got_fired, want_fired = sorted(got_fired), sorted(want_fired)
     assert got == want and got_fired == want_fired
     assert [i for i, _ in got_fired] == list(range(B))
     assert [d for _, d in got_fired] == got
@@ -1479,3 +1482,352 @@ def test_front_end_gives_the_reshape_formulations_coefficients(
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
         # more than the DC terms: the comparison is of real content
         assert np.count_nonzero(np.asarray(g)) > B * blocks
+
+
+# ------------------------- the sparse tail on several threads (PR 37)
+
+def _wire_row(H, W, seed, per_block=6.0):
+    """A sparse wire row of an H x W tile, made on the host: ``[total
+    i32 | counts u8[nb] | 18-bit (pos << 12 | val) entries]`` with
+    about ``per_block`` non-zero coefficients a block at ascending
+    zigzag positions.  Returns ``(row, total)``."""
+    rng = np.random.default_rng(seed)
+    nb = ((H + 15) // 16) * ((W + 15) // 16) * 6
+    counts = np.minimum(rng.poisson(per_block, nb), 64).astype(np.uint8)
+    total = int(counts.sum())
+    taken = np.arange(64)[None, :] < counts[:, None]
+    pos = np.where(taken, np.argsort(rng.random((nb, 64)), axis=1), 64)
+    pos.sort(axis=1)
+    pos = pos[pos < 64]                 # block by block, ascending
+    mag = np.clip(rng.exponential(6.0, total).astype(np.int64), 1, 2047)
+    val = np.where(rng.random(total) < 0.5, -mag, mag)
+    field = (pos.astype(np.uint32) << 12) | (val & 0xFFF).astype(np.uint32)
+    bits = ((field[:, None] >> np.arange(17, -1, -1)[None, :]) & 1)
+    return np.concatenate([
+        np.array([total], "<i4").view(np.uint8), counts,
+        np.packbits(bits.astype(np.uint8).ravel())]), total
+
+
+def _parent_tail(bufs, dims, H, W, quality, cap, dense_coefficients,
+                 on_tile=None):
+    """``finish_sparse_to_jpegs`` as it was before PR 37, kept as the
+    plain reference: one tile after the other on the calling thread."""
+    from omero_ms_image_region_tpu.ops.jpegenc import (
+        dense_encoder, slice_block_subgrid, sparse_encoder)
+
+    _encode = sparse_encoder()
+    _dense_encode = dense_encoder()
+    out = []
+    for i, (w_, h_) in enumerate(dims):
+        exact = ((h_ + 15) // 16 * 16 == H and (w_ + 15) // 16 * 16 == W)
+        try:
+            if exact:
+                out.append(_encode(bufs[i], w_, h_, quality, cap))
+                if on_tile is not None:
+                    on_tile(i, out[-1])
+                continue
+            dense = sparse_to_dense(bufs[i], H, W, cap)
+            if dense is None:
+                raise SparseOverflowError(f"overflow (cap={cap})")
+        except SparseOverflowError:
+            dense = dense_coefficients(i)
+        y, cb, cr = slice_block_subgrid(*dense, H, W, w_, h_) \
+            if not exact else dense
+        out.append(_dense_encode(y, cb, cr, w_, h_, quality))
+        if on_tile is not None:
+            on_tile(i, out[-1])
+    return out
+
+
+def _tail_group(n, E):
+    """``n`` rows of an E x E bucket and their ``dims``: where the group
+    is large enough, row 1 is smaller than the bucket's grid
+    (``slice_block_subgrid``) and row 3 overflows the cap (the dense
+    path); two batch-shape pad rows follow the group's own."""
+    made = [_wire_row(E, E, 37 * E + i, 20.0 if i == 3 else 6.0)
+            for i in range(n)]
+    rows = [r for r, _ in made]
+    totals = [t for _, t in made]
+    dims = [(E, E)] * n
+    if n > 1:
+        dims[1] = (E - 24, E - 8)
+    cap = max(t for i, t in enumerate(totals) if i != 3)
+    if n > 3:
+        assert totals[3] > cap
+
+    def dense_coefficients(i):
+        assert i == 3
+        return sparse_to_dense(rows[i], E, E, totals[i])
+
+    pads = [np.zeros(0, np.uint8)] * 2
+    return rows + pads, dims, cap, dense_coefficients
+
+
+class _Meeting:
+    """An ``on_tile`` that records what fired, and holds the first
+    thread's first tile until a second thread has brought one: a tail
+    whose threads meet here ran on more than one of them."""
+
+    def __init__(self, wait=True):
+        import threading
+        self.fired = []
+        self.threads = set()
+        self._met = threading.Event()
+        self._wait = wait
+        self._ident = threading.get_ident
+
+    def __call__(self, i, body):
+        self.fired.append((i, body))
+        self.threads.add(self._ident())
+        if len(self.threads) > 1:
+            self._met.set()
+        if self._wait:
+            self._met.wait(10.0)
+
+
+@pytest.mark.parametrize("E", [64, 256])
+@pytest.mark.parametrize("n", [1, 2, 6, 33])
+def test_the_pooled_tail_returns_the_serial_loops_bytes(n, E, coding_pool):
+    """Groups of 1, 2, 6 and 33 rows at two sizes: the tail coded on
+    several threads returns, byte for byte, what the parent's serial
+    loop returns (a row that overflows its cap, a member smaller than
+    the bucket's grid and batch-shape pad rows among them), and
+    ``on_tile`` fires exactly once a tile with the returned entry's
+    content, from whichever thread coded it."""
+    from omero_ms_image_region_tpu.ops.jpegenc import finish_sparse_to_jpegs
+
+    rows, dims, cap, dense_coefficients = _tail_group(n, E)
+    want_fired = []
+    want = _parent_tail(rows, dims, E, E, 90, cap, dense_coefficients,
+                        on_tile=lambda i, d: want_fired.append((i, d)))
+    assert [i for i, _ in want_fired] == list(range(n))
+
+    meeting = _Meeting(wait=n > 1)
+    got = finish_sparse_to_jpegs(rows, dims, E, E, 90, cap,
+                                 dense_coefficients, on_tile=meeting)
+    assert got == want
+    assert sorted(meeting.fired) == want_fired
+    assert all(type(d) is bytes for _, d in meeting.fired)
+    for (w_, h_), body in zip(dims, got):
+        assert Image.open(io.BytesIO(body)).size == (w_, h_)
+    if n == 1:
+        # A group of one is coded in line: no hop, no pool thread.
+        assert coding_pool.TILES == {"pooled": 0, "inline": 1}
+        assert len(meeting.threads) == 1
+    else:
+        assert len(meeting.threads) > 1
+        assert coding_pool.TILES == {"pooled": n, "inline": 0}
+    # Without callbacks: the same list.
+    assert finish_sparse_to_jpegs(rows, dims, E, E, 90, cap,
+                                  dense_coefficients) == want
+
+
+def test_a_group_of_one_never_touches_the_pool(coding_pool):
+    from omero_ms_image_region_tpu.ops.jpegenc import finish_sparse_to_jpegs
+
+    class Untouched:
+        def submit(self, *a, **kw):
+            raise AssertionError("a group of one reached the pool")
+
+    real = coding_pool._POOL._executor
+    coding_pool._POOL._executor = Untouched()
+    try:
+        row, total = _wire_row(64, 64, 5)
+        meeting = _Meeting(wait=False)
+        got = finish_sparse_to_jpegs([row], [(64, 64)], 64, 64, 90, total,
+                                     None, on_tile=meeting)
+    finally:
+        coding_pool._POOL._executor = real
+    assert meeting.fired == [(0, got[0])]
+    assert coding_pool.TILES == {"pooled": 0, "inline": 1}
+
+
+@pytest.mark.parametrize("bad", [0, 4, 8])
+def test_one_malformed_row_fails_the_tail_and_nothing_fires_twice(
+        bad, coding_pool):
+    """A row whose counts do not sum to its total fails the tail as it
+    did the serial loop (``ValueError``, once, from
+    ``finish_sparse_to_jpegs``); the other tiles' callbacks have fired
+    or not, each at most once and never for the malformed one."""
+    from omero_ms_image_region_tpu.ops.jpegenc import finish_sparse_to_jpegs
+
+    n, E = 9, 64
+    made = [_wire_row(E, E, 100 + i) for i in range(n)]
+    rows = [r.copy() for r, _ in made]
+    cap = max(t for _, t in made)
+    good = _parent_tail(rows, [(E, E)] * n, E, E, 90, cap, None)
+    rows[bad][4] += 1                       # the first block's count
+    with pytest.raises(ValueError):
+        _parent_tail(rows, [(E, E)] * n, E, E, 90, cap, None)
+    meeting = _Meeting(wait=False)
+    with pytest.raises(ValueError):
+        finish_sparse_to_jpegs(rows, [(E, E)] * n, E, E, 90, cap, None,
+                               on_tile=meeting)
+    fired = [i for i, _ in meeting.fired]
+    assert len(fired) == len(set(fired)) and bad not in fired
+    assert all(body == good[i] for i, body in meeting.fired)
+
+
+def test_a_callbacks_exception_surfaces_once_from_the_tail(coding_pool):
+    from omero_ms_image_region_tpu.ops.jpegenc import finish_sparse_to_jpegs
+
+    n, E = 6, 64
+    made = [_wire_row(E, E, 200 + i) for i in range(n)]
+    fired = []
+
+    def on_tile(i, body):
+        fired.append(i)
+        if i == 2:
+            raise RuntimeError("the waiter is gone")
+
+    with pytest.raises(RuntimeError, match="the waiter is gone"):
+        finish_sparse_to_jpegs([r for r, _ in made], [(E, E)] * n, E, E,
+                               90, max(t for _, t in made), None,
+                               on_tile=on_tile)
+    assert len(fired) == len(set(fired)) and 2 in fired
+
+
+def test_a_busy_pool_leaves_the_tail_to_its_own_thread(coding_pool):
+    """Helpers that never get a thread (the pool is coding other
+    groups' tiles) are cancelled: the group's thread codes every run
+    itself and does not wait for them."""
+    import threading
+
+    from omero_ms_image_region_tpu.ops.jpegenc import finish_sparse_to_jpegs
+
+    release = threading.Event()
+    busy = [coding_pool._POOL._executor.submit(release.wait, 30.0)
+            for _ in range(3)]
+    try:
+        n, E = 6, 64
+        made = [_wire_row(E, E, 300 + i) for i in range(n)]
+        rows, cap = [r for r, _ in made], max(t for _, t in made)
+        meeting = _Meeting(wait=False)
+        got = finish_sparse_to_jpegs(rows, [(E, E)] * n, E, E, 90, cap,
+                                     None, on_tile=meeting)
+    finally:
+        release.set()
+    for b in busy:
+        b.result()
+    assert got == _parent_tail(rows, [(E, E)] * n, E, E, 90, cap, None)
+    assert meeting.threads == {threading.get_ident()}
+    assert coding_pool.TILES == {"pooled": 0, "inline": n}
+
+
+def test_the_pools_runs_follow_the_tiles_and_the_threads():
+    from omero_ms_image_region_tpu.utils.entropypool import (
+        EntropyPool, RUN_PX)
+
+    pool = EntropyPool(8)
+    try:
+        # Large tiles: one a run, whatever the threads.
+        assert pool.runs(6, 1024 * 1024) == [range(i, i + 1)
+                                             for i in range(6)]
+        assert pool.runs(2, 2048 * 2048) == [range(0, 1), range(1, 2)]
+        assert pool.runs(1, 256 * 256) == [range(0, 1)]
+        # Small ones: a run a thread, and no more than RUN_PX of them.
+        assert pool.runs(32, 256 * 256) == [range(a, a + 4)
+                                            for a in range(0, 32, 4)]
+        assert RUN_PX // (256 * 256) == 16
+    finally:
+        pool._executor.shutdown()
+    lone = EntropyPool(0)
+    assert lone.runs(40, 256 * 256) == [range(0, 16), range(16, 32),
+                                        range(32, 40)]
+    coded = []
+    lone.code(3, 1024 * 1024, coded.append)
+    assert coded == [range(0, 1), range(1, 2), range(2, 3)]
+
+
+def test_the_pool_is_sized_from_the_cores_and_the_group_threads(
+        monkeypatch):
+    import os
+
+    from omero_ms_image_region_tpu.utils import entropypool
+
+    monkeypatch.setattr(entropypool, "_GROUP_THREADS", 1)
+    monkeypatch.setattr(os, "sched_getaffinity",
+                        lambda pid: set(range(13)))
+    assert entropypool.pool_threads() == 11
+    entropypool.expect_group_threads(4)
+    assert entropypool.pool_threads() == 8      # 13 - the loop - 4
+    entropypool.expect_group_threads(2)         # the deepest stands
+    assert entropypool.pool_threads() == 8
+    monkeypatch.setattr(os, "sched_getaffinity", lambda pid: {0, 1})
+    assert entropypool.pool_threads() == 0
+
+
+@pytest.mark.skipif(not HAVE_NATIVE, reason="no native toolchain")
+def test_the_coders_scratch_is_kept_between_calls():
+    """A count, not a speed: once a thread has coded a tile of a size,
+    twenty more of that size (sparse runs and the dense coder) grow no
+    scratch, and what is idle afterwards holds the size's records."""
+    from omero_ms_image_region_tpu.native import (
+        jpeg_encode_sparse_run, jpeg_scratch_stats)
+
+    E = 256
+    made = [_wire_row(E, E, 400 + i) for i in range(4)]
+    rows, cap = [r for r, _ in made], max(t for _, t in made)
+    first = jpeg_encode_sparse_run(rows, [(E, E)] * 4, 90, cap)
+    dense = sparse_to_dense(rows[0], E, E, cap)
+    first_dense = jpeg_encode_native(*dense, E, E, 90)
+    grown = jpeg_scratch_stats()["growths"]
+    for _ in range(5):
+        assert jpeg_encode_sparse_run(rows, [(E, E)] * 4, 90, cap) == first
+        assert jpeg_encode_native(*dense, E, E, 90) == first_dense
+    stats = jpeg_scratch_stats()
+    assert stats["growths"] == grown
+    # 272 bytes a block, six blocks an MCU.
+    assert stats["idle"] >= 1
+    assert stats["idle_bytes"] >= (E // 16) ** 2 * 6 * 272
+    # A larger tile grows it, once.
+    big, total = _wire_row(2 * E, 2 * E, 7)
+    one = jpeg_encode_sparse_native(big, 2 * E, 2 * E, 90, total)
+    grown = jpeg_scratch_stats()["growths"]
+    assert jpeg_encode_sparse_native(big, 2 * E, 2 * E, 90, total) == one
+    assert jpeg_scratch_stats()["growths"] == grown
+
+
+@pytest.mark.skipif(not HAVE_NATIVE, reason="no native toolchain")
+def test_eight_threads_coding_at_once_give_the_lone_threads_bytes():
+    import threading
+    from concurrent.futures import ThreadPoolExecutor
+
+    sizes = [64, 256, 128, 64, 256, 128, 512, 64]
+    made = [_wire_row(E, E, 500 + i) for i, E in enumerate(sizes)]
+    want = [jpeg_encode_sparse_native(row, E, E, 90, total)
+            for (row, total), E in zip(made, sizes)]
+    start = threading.Barrier(8)
+
+    def code(k):
+        (row, total), E = made[k], sizes[k]
+        start.wait(10.0)
+        return [jpeg_encode_sparse_native(row, E, E, 90, total)
+                for _ in range(6)]
+
+    with ThreadPoolExecutor(8) as ex:
+        got = list(ex.map(code, range(8)))
+    assert got == [[w] * 6 for w in want]
+
+
+@pytest.mark.skipif(not HAVE_NATIVE, reason="no native toolchain")
+def test_a_run_reads_its_rows_in_place_and_reports_each_rows_code():
+    """No pad of the row: a row that ends with its last entry (nothing
+    readable behind it in its own array) codes as the same row with
+    bytes behind it; a malformed and an overflowing row of a run leave
+    the others as they are."""
+    from omero_ms_image_region_tpu.native import jpeg_encode_sparse_run
+
+    E = 64
+    made = [_wire_row(E, E, 600 + i, 3.0 + i) for i in range(5)]
+    rows = [r for r, _ in made]
+    cap = made[3][1]
+    assert made[4][1] > cap
+    lone = [jpeg_encode_sparse_native(r, E, E, 90, cap) for r in rows[:4]]
+    tailed = [np.concatenate([r, np.full(9, 0xAB, np.uint8)])[:r.size]
+              for r in rows]
+    broken = rows[1].copy()
+    broken[4] += 1
+    got = jpeg_encode_sparse_run([tailed[0], broken, tailed[2], tailed[3],
+                                  tailed[4]], [(E, E)] * 5, 90, cap)
+    assert got == [lone[0], -1, lone[2], lone[3], -2]

@@ -147,6 +147,23 @@ class TestExpositionBudget:
         assert any("imageregion_pixel_sources_open" in f
                    for f in lint.lint_exposition(smuggled, budget))
 
+    def test_entropy_tiles_family_lints_clean(self, lint, budget):
+        """PR 37's family: budgeted under ``path``, typed, one HELP."""
+        family = "imageregion_entropy_tiles_total"
+        text = telemetry.finalize_exposition([
+            line for line in telemetry.device_metric_lines(None)
+            if line.startswith(family)])
+        for path in ("pooled", "inline"):
+            assert f'\n{family}{{path="{path}"}} ' in text
+        assert f"# TYPE {family} counter\n" in text
+        assert text.count(f"# HELP {family} ") == 1
+        assert telemetry.METRIC_HELP[family]
+        assert budget["families"][family] == {"labels": ["path"]}
+        assert lint.lint_exposition(text, budget) == []
+        smuggled = text + f'{family}{{path="pooled",bucket="1024"}} 1\n'
+        assert any(family in f
+                   for f in lint.lint_exposition(smuggled, budget))
+
     def test_every_idle_class_fits_the_during_bound(self, budget):
         from omero_ms_image_region_tpu.utils import profile_summary as ps
         classes = set(ps.IDLE_ORDER) | {ps.NO_GROUP, ps.UNATTRIBUTED}

@@ -73,6 +73,12 @@ class DeviceRawCache:
                  digest_index: bool = True):
         self.max_bytes = max_bytes
         self.digest_index = digest_index
+        # The device a host plane is uploaded to: a fleet member's pin
+        # (``parallel.fleet.build_local_members``), so that whoever
+        # loads into this shard (the member's handler, the shared
+        # prefetcher, a drain's prestage) lands it on the member's
+        # chip.  None = the process default device.
+        self.device = None
         self._lock = threading.Lock()
         self._entries: "OrderedDict[Hashable, object]" = OrderedDict()
         # Content-digest index: digest -> the keys whose entries hold
@@ -178,6 +184,8 @@ class DeviceRawCache:
             self.misses += 1
         import jax
         import numpy as np
+
+        from .staging import pin_scope
         loaded = loader()
         arr = None
         if isinstance(loaded, np.ndarray):
@@ -198,8 +206,11 @@ class DeviceRawCache:
                                        loaded.nbytes)
             if arr is None:
                 # Host ndarray miss: the plane goes up as it is, one
-                # asynchronous transfer in its storage dtype.
-                arr = jax.device_put(loaded)
+                # asynchronous transfer in its storage dtype, to this
+                # shard's device (uncommitted, as prewarm's planes:
+                # a committed array would key other programs).
+                with pin_scope(self.device):
+                    arr = jax.device_put(loaded)
                 from ..utils import telemetry
                 telemetry.add_cost("staged_bytes", loaded.nbytes)
         else:

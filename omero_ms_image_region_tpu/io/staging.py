@@ -26,17 +26,27 @@ import numpy as np
 @contextlib.contextmanager
 def pin_scope(device):
     """Run the enclosed dispatches on ``device`` — per-member device
-    pinning for the combined federated role (``parallel.federation``
-    partitions ``jax.local_devices()`` across a host's members, so
-    each member's staging and render executes on ITS device set).
-    ``None`` yields straight through: the process default device, the
-    pre-federation behavior, at zero cost."""
+    pinning for the combined role's in-process fleet and federation
+    (``parallel.fleet.partition_local_devices`` partitions
+    ``jax.local_devices()`` across a host's members, so each member's
+    uploads, staging and render execute on ITS chip).  Arrays stay
+    uncommitted, as prewarm's: a committed one would compile every
+    program again.  ``None`` yields straight through: the process
+    default device, at zero cost."""
     if device is None:
         yield
         return
     import jax
     with jax.default_device(device):
         yield
+
+
+def device_id(arr):
+    """The id of the device ``arr`` lies on (the first of them), None
+    for a host array: where a group's program ran, read off its
+    output."""
+    devices = getattr(arr, "devices", None)
+    return None if devices is None else min(d.id for d in devices())
 
 
 def stage_deduped(arr: np.ndarray, cache, digest: str = None):

@@ -1417,9 +1417,14 @@ def render_batch_to_wire(raw, window_start, window_end, family,
     lets go of its device lane."""
     B, C, H, W = raw.shape
 
-    def dispatched(span) -> None:
+    from ..io.staging import device_id
+
+    def dispatched(span, out) -> None:
         if timings is not None:
             timings["device_ms"] = timings.get("device_ms", 0.0) + span.ms
+            # Where the group ran, read off its output: a fleet
+            # member's pin as it held (``BatchingRenderer._count_batch``).
+            timings["device"] = device_id(out)
 
     if cap is None:
         cap = default_sparse_cap(H, W, quality)
@@ -1457,7 +1462,7 @@ def render_batch_to_wire(raw, window_start, window_end, family,
                     *spec_arrays, np.int32(n),
                     h16=H // 16, w16=W // 16, cap=c, cap_words=cw)
                 handle = fetcher.start(bufs)
-            dispatched(span)
+            dispatched(span, bufs)
             return fetcher.finish(handle, n, timings)[:n]
 
         cap_words = default_words_cap(H, W, quality)
@@ -1494,7 +1499,7 @@ def render_batch_to_wire(raw, window_start, window_end, family,
                 reverse, cd_start, cd_end, tables, qy, qc, np.int32(n),
                 cap=c)
             handle = fetcher.start(bufs)
-        dispatched(span)
+        dispatched(span, bufs)
         return fetcher.finish(handle, n, timings)[:n]
 
     memo_key = ("sparse", H, W, quality)

@@ -51,10 +51,10 @@ makes the fleet span ``cluster.initialize()`` process/host boundaries:
   themselves over the v3 wire (ring-eligible bodies) — the successor
   cannot re-read this host's pixel store, so the hint-list prestage
   of the single-host drain would arrive cold.
-* **Per-member device pinning** (:func:`partition_local_devices`): the
-  combined role partitions ``jax.local_devices()`` across its local
-  members, so the fleet's members own real device sets per host —
-  previously only ``fleet.sockets`` sidecar topologies did.
+* **Per-member device pinning** (:func:`.fleet.partition_local_devices`,
+  as the in-process fleet's): the combined role partitions
+  ``jax.local_devices()`` across its local members, so the fleet's
+  members own real device sets per host.
 * **Shard-aware prefetch** rides
   :meth:`~.fleet.FleetRouter.remote_prestage_for_route`: a predicted
   plane whose ring owner is remote stages on its OWNER's host.
@@ -77,7 +77,7 @@ import time
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
-from .fleet import HashRing
+from .fleet import HashRing, partition_local_devices
 
 logger = logging.getLogger(__name__)
 
@@ -968,36 +968,6 @@ def reset_gossip() -> None:
     _GOSSIP_VIEW.clear()
     _LOCAL_LAST.clear()
     _LOCAL_SEQ = 0
-
-
-# ------------------------------------------------------- device pinning
-
-def partition_local_devices(n_members: int,
-                            devices: Optional[Sequence] = None
-                            ) -> List[list]:
-    """Partition this process's devices across ``n_members`` local
-    members — contiguous, deterministic, remainder to the earliest
-    members (so member 0, the mesh/bulk lane, is never the short one).
-    Fewer devices than members leaves the tail members unpinned
-    (process default device) rather than oversubscribing one chip with
-    two members' pins."""
-    if n_members < 1:
-        raise ValueError("partition needs >= 1 member")
-    if devices is None:
-        import jax
-        devices = jax.local_devices()
-    devices = list(devices)
-    n_dev = len(devices)
-    if n_dev == 0:
-        return [[] for _ in range(n_members)]
-    base, extra = divmod(n_dev, n_members)
-    out: List[list] = []
-    i = 0
-    for m in range(n_members):
-        take = base + (1 if m < extra else 0)
-        out.append(devices[i:i + take])
-        i += take
-    return out
 
 
 # --------------------------------------------------------- construction

@@ -2089,6 +2089,7 @@ class FleetRouter:
 
     async def _lane(self, name: str) -> None:
         from ..utils import provenance, telemetry, transient
+        from ..utils.stopwatch import record_since
 
         # Lanes are long-lived tasks spawned from the FIRST request's
         # context; detach from its trace ids or every span any render
@@ -2200,17 +2201,14 @@ class FleetRouter:
                 if not work.future.done():
                     work.future.set_exception(e)
             else:
-                if work.trace_ids:
-                    # The render hop itself: which member executed,
-                    # and under what acquisition (owned / stolen /
-                    # failed-over) — the widest lane of the stitched
-                    # waterfall.
-                    telemetry.record_span(
-                        "fleet.hop", t_render,
-                        (time.perf_counter() - t_render) * 1000.0,
-                        trace_ids=work.trace_ids, member=name,
-                        hop="render", plane=work.route_key,
-                        **({"stolen": 1} if work.stolen else {}))
+                # The render hop itself: which member executed, and
+                # under what acquisition (owned / stolen / failed-over)
+                # — the widest lane of the stitched waterfall, and the
+                # one hop with a duration, so the one on /metrics.
+                record_since(
+                    "fleet.hop", t_render, trace_ids=work.trace_ids,
+                    member=name, hop="render", plane=work.route_key,
+                    **({"stolen": 1} if work.stolen else {}))
                 if not work.future.done():
                     work.future.set_result(data)
                 if work.stolen:
@@ -2474,6 +2472,35 @@ class FleetImageHandler:
 
 # ---------------------------------------------------------- construction
 
+def partition_local_devices(n_members: int,
+                            devices: Optional[Sequence] = None
+                            ) -> List[list]:
+    """Partition this process's devices across ``n_members`` local
+    members — contiguous, deterministic, remainder to the earliest
+    members (so member 0, the mesh/bulk lane, is never the short one).
+    Fewer devices than members leaves the tail members unpinned
+    (process default device) rather than oversubscribing one chip with
+    two members' pins.  The in-process fleet's and the federation's
+    local members are pinned from this one partition."""
+    if n_members < 1:
+        raise ValueError("partition needs >= 1 member")
+    if devices is None:
+        import jax
+        devices = jax.local_devices()
+    devices = list(devices)
+    n_dev = len(devices)
+    if n_dev == 0:
+        return [[] for _ in range(n_members)]
+    base, extra = divmod(n_dev, n_members)
+    out: List[list] = []
+    i = 0
+    for m in range(n_members):
+        take = base + (1 if m < extra else 0)
+        out.append(devices[i:i + take])
+        i += take
+    return out
+
+
 def build_local_members(config, base_services, n: int,
                         device_sets: Optional[Sequence] = None
                         ) -> List[LocalMember]:
@@ -2483,13 +2510,13 @@ def build_local_members(config, base_services, n: int,
     + ``DeviceRawCache`` (their shard of HBM) and share everything
     host-side — pixel stores, byte caches, metadata, ACL memo, LUTs.
 
-    One JAX process: the members shard serving state (cache, queues,
-    lanes) but all dispatch to the process's default device — this
-    topology does NOT spread compute across a multi-chip host.  Real
-    per-member device sets are the ``fleet.sockets`` topology, one
-    sidecar process per member, each restricted to its chip by the
-    per-process TPU environment of deploy/DEPLOY.md "Fleet serving"
-    (per-member device pinning here is an open roadmap item).
+    One JAX process, one chip a member where ``device_sets`` gives one
+    (the combined role passes :func:`partition_local_devices`): the
+    member's renderer dispatches its groups there, its raw cache
+    uploads there and its handler reads there (``pin_scope``), so a
+    four-chip host serves one slide from four HBM shards.  A member
+    without a device set (more members than devices) uses the
+    process's default device.
 
     Member-level single-flight and admission are disabled on the extra
     members: both concerns live fleet-wide above the router."""
@@ -2513,9 +2540,11 @@ def build_local_members(config, base_services, n: int,
     base_services.pin_device = (devices_for(0)[0]
                                 if devices_for(0) and not lockstep
                                 else None)
-    if base_services.pin_device is not None \
-            and hasattr(base_services.renderer, "device"):
-        base_services.renderer.device = base_services.pin_device
+    if base_services.pin_device is not None:
+        if hasattr(base_services.renderer, "device"):
+            base_services.renderer.device = base_services.pin_device
+        if base_services.raw_cache is not None:
+            base_services.raw_cache.device = base_services.pin_device
     members = [LocalMember(
         "m0",
         ImageRegionHandler(base_services), services=base_services,
@@ -2541,6 +2570,8 @@ def build_local_members(config, base_services, n: int,
             config.raw_cache.max_bytes,
             digest_index=config.raw_cache.digest_dedup)
             if config.raw_cache.enabled else None)
+        if raw_cache is not None and devices_for(i):
+            raw_cache.device = devices_for(i)[0]
         services = ImageRegionServices(
             pixels_service=base_services.pixels_service,
             metadata=base_services.metadata,

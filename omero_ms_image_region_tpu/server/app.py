@@ -327,40 +327,58 @@ def build_services(config: AppConfig) -> "ImageRegionServices":
                 config.persistence.rehydrate_concurrency))
         services.warmstate.start(
             rehydrate=config.persistence.rehydrate)
-    if (config.renderer.prewarm and config.batcher.enabled
-            and not config.parallel.enabled):
-        # Compile the listed shapes' serving programs so the first
-        # request of each shape doesn't pay 20-40 s of jit.
-        # MeshRenderer is excluded: its sharded steps are warmed by
-        # the pod bring-up dryrun instead.
-        #
-        # On a BACKGROUND thread, flagged in telemetry.READINESS: the
-        # listener binds immediately and /readyz answers 503 until the
-        # compiles land, so orchestration (the systemd ExecStartPost
-        # poll, k8s readiness probes) gates traffic on warm — instead
-        # of minutes of connection-refused during a blocking prewarm
-        # that no probe could distinguish from a hung boot.
-        import threading
-
-        from .prewarm import parse_spec, prewarm_renderer
-        for spec in config.renderer.prewarm:
-            parse_spec(spec)   # malformed specs fail the BOOT, loudly —
-            # never a background thread dying into a silently-unwarmed
-            # "ready" service (YAML loads validate too; this covers
-            # programmatic AppConfigs).
-        telemetry.READINESS.prewarm_pending = True
-        threading.Thread(
-            target=prewarm_renderer,
-            args=(list(config.renderer.prewarm), renderer.jpeg_engine,
-                  renderer.max_batch, renderer.buckets),
-            kwargs={"cpu_fallback_max_px":
-                    config.renderer.cpu_fallback_max_px,
-                    # Persistence: warmed packed programs deserialize
-                    # from a prior life instead of compiling, and
-                    # fresh compiles are serialized for the next one.
-                    "exec_cache": exec_cache},
-            name="prewarm", daemon=True).start()
+    if not _local_fleet(config):
+        # An in-process fleet is warmed by create_app once its members
+        # hold their devices.
+        start_prewarm(config, renderer)
     return services
+
+
+def _local_fleet(config: AppConfig) -> bool:
+    """Does this process serve an in-process fleet (create_app builds
+    its members over the base stack: ``fleet`` or ``federation`` in
+    the combined role)?"""
+    return ((config.fleet.enabled or config.federation.enabled)
+            and config.sidecar.role == "combined")
+
+
+def start_prewarm(config: AppConfig, renderer, members=None) -> None:
+    """Compile the listed shapes' serving programs so the first
+    request of each shape doesn't pay 20-40 s of jit; an in-process
+    fleet's ``members`` (``(name, device)``) each on its own device.
+    MeshRenderer is excluded: its sharded steps are warmed by the pod
+    bring-up dryrun instead.
+
+    On a BACKGROUND thread, flagged in telemetry.READINESS: the
+    listener binds immediately and /readyz answers 503 until the
+    compiles land, so orchestration (the systemd ExecStartPost poll,
+    k8s readiness probes) gates traffic on warm — instead of minutes
+    of connection-refused during a blocking prewarm that no probe
+    could distinguish from a hung boot."""
+    if not (config.renderer.prewarm and config.batcher.enabled
+            and not config.parallel.enabled):
+        return
+    import threading
+
+    from .prewarm import parse_spec, prewarm_renderer
+    for spec in config.renderer.prewarm:
+        parse_spec(spec)   # malformed specs fail the BOOT, loudly —
+        # never a background thread dying into a silently-unwarmed
+        # "ready" service (YAML loads validate too; this covers
+        # programmatic AppConfigs).
+    telemetry.READINESS.prewarm_pending = True
+    threading.Thread(
+        target=prewarm_renderer,
+        args=(list(config.renderer.prewarm), renderer.jpeg_engine,
+              renderer.max_batch, renderer.buckets),
+        kwargs={"cpu_fallback_max_px":
+                config.renderer.cpu_fallback_max_px,
+                # Persistence: warmed packed programs deserialize
+                # from a prior life instead of compiling, and
+                # fresh compiles are serialized for the next one.
+                "exec_cache": getattr(renderer, "exec_cache", None),
+                "members": members},
+        name="prewarm", daemon=True).start()
 
 
 def create_app(config: Optional[AppConfig] = None,
@@ -535,18 +553,18 @@ def create_app(config: Optional[AppConfig] = None,
         injected = services is not None
         if services is None:
             services = build_services(config)
-        if ((config.fleet.enabled or config.federation.enabled)
-                and not injected
-                and config.sidecar.role == "combined"):
+        if _local_fleet(config) and not injected:
             # In-process device fleet: member 0 is the base stack
             # (the lockstep mesh lane in mesh deployments); members
-            # 1..N-1 own their renderer + DeviceRawCache shard.
+            # 1..N-1 own their renderer + DeviceRawCache shard; each
+            # member holds a chip of its own where the host has one.
             # Single-flight and admission MOVE above the router so
             # identical renders coalesce once fleet-wide and shedding
             # sees the fleet's total depth.
             from ..parallel.fleet import (FleetImageHandler,
                                           FleetRouter,
-                                          build_local_members)
+                                          build_local_members,
+                                          partition_local_devices)
             ring_seed = ""
             wire_handoff = False
             if config.federation.enabled:
@@ -569,8 +587,20 @@ def create_app(config: Optional[AppConfig] = None,
                 wire_handoff = True
             else:
                 fed_manifest = None
+                # One chip a member, as far as the host's go.
                 fleet_members = build_local_members(
-                    config, services, config.fleet.members)
+                    config, services, config.fleet.members,
+                    device_sets=partition_local_devices(
+                        config.fleet.members))
+            local = [m for m in fleet_members
+                     if not getattr(m, "remote", False)]
+            if services.device is not None:
+                # /readyz: which chip each member holds.
+                services.device = dict(services.device, members={
+                    m.name: telemetry.pinned_device_id(m.services)
+                    for m in local})
+            start_prewarm(config, services.renderer, members=[
+                (m.name, m.services.pin_device) for m in local])
             fleet_router = FleetRouter(
                 fleet_members, lane_width=config.fleet.lane_width,
                 steal_min_backlog=config.fleet.steal_min_backlog,
@@ -1699,6 +1729,10 @@ def create_app(config: Optional[AppConfig] = None,
                 lines.append("# sidecar metrics unavailable")
         else:
             lines += telemetry.device_metric_lines(services)
+            if fleet_router is not None:
+                # The other in-process members' shards and batchers.
+                lines += telemetry.fleet_shard_metric_lines(
+                    fleet_members, services)
         if openmetrics:
             # The OpenMetrics exposition is grammar-strict (the
             # finalizer drops free-form comments and maps the legacy

@@ -401,15 +401,19 @@ class BatchingRenderer:
         # returned list's entries); settlement is loop-threadsafe.
         self.first_tile_out = True
 
-    def _count_batch(self, tiles: int, shape: int) -> None:
-        """Metrics update (``shape``: the padded batch shape launched);
-        group renders run concurrently on worker threads, so the
-        increments need the lock."""
+    def _count_batch(self, tiles: int, shape: int,
+                     device: Optional[int] = None) -> None:
+        """Metrics update (``shape``: the padded batch shape launched;
+        ``device``: the id of the device the group's output lies on,
+        where it has one); group renders run concurrently on worker
+        threads, so the increments need the lock."""
         with self._stats_lock:
             self.batches_dispatched += 1
             self.tiles_rendered += tiles
             self.shape_slots += shape
             self.padded_slots += shape - tiles
+        if device is not None:
+            telemetry.DEVICE_RENDERS.count(device, tiles)
 
     def group_cap(self, bucket_px: int) -> int:
         """This renderer's cap for a bucket, at its current (possibly
@@ -1036,7 +1040,8 @@ class BatchingRenderer:
                 # never back traffic up unboundedly.
                 time.sleep(freeze)
         t0 = time.perf_counter()
-        with stopwatch("batcher.stage"):
+        from ..io.staging import pin_scope
+        with stopwatch("batcher.stage"), pin_scope(self.device):
             raw, stack = self._group_arrays(group)
             staged_bytes = (raw.nbytes
                             if isinstance(raw, np.ndarray) else 0)
@@ -1107,7 +1112,7 @@ class BatchingRenderer:
         loaded_fn = (self.exec_cache.lookup("render_tile_batch_packed",
                                             args)
                      if self.exec_cache is not None else None)
-        from ..io.staging import pin_scope
+        from ..io.staging import device_id, pin_scope
         with self._lane(), pin_scope(self.device):
             t0 = time.perf_counter()
             with stopwatch("Renderer.renderAsPackedInt.batch"):
@@ -1129,6 +1134,7 @@ class BatchingRenderer:
                     out.block_until_ready()
                 with stopwatch("wire.d2h", tiles=n):
                     host = np.asarray(out)
+            device = device_id(out)
             exec_ms = (time.perf_counter() - t0) * 1000.0
         if loaded_fn is None and self.exec_cache is not None:
             # First group of this signature in this life: capture the
@@ -1142,7 +1148,7 @@ class BatchingRenderer:
         if estimate:
             _capture_shape_estimate(shape, render_tile_batch_packed,
                                     args)
-        self._count_batch(n, raw.shape[0])
+        self._count_batch(n, raw.shape[0], device)
         return [host[i, :p.h, :p.w] for i, p in enumerate(group[:n])]
 
     def _early_settle_cb(self, group: List[_Pending]):
@@ -1210,5 +1216,5 @@ class BatchingRenderer:
         exec_ms = timings.get("device_ms", 0.0)
         telemetry.add_cost("device_ms", exec_ms / n)
         telemetry.SHAPE_COSTS.observe(shape, exec_ms)
-        self._count_batch(n, raw.shape[0])
+        self._count_batch(n, raw.shape[0], timings.get("device"))
         return jpegs

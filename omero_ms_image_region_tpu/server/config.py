@@ -177,10 +177,10 @@ class FleetConfig:
     enabled: bool = False
     # Combined role: N in-process member lanes (member 0 is the base
     # stack — the lockstep mesh lane in mesh deployments; members
-    # 1..N-1 get their own renderer + DeviceRawCache shard).  NOTE:
-    # one JAX process — members shard cache/queues but all dispatch
-    # to the process's default device; real per-member device SETS
-    # are the ``sockets`` topology (one pinned sidecar process each).
+    # 1..N-1 get their own renderer + DeviceRawCache shard).  One JAX
+    # process: each member holds a chip of its own where the host has
+    # one (``parallel.fleet.partition_local_devices``), the tail
+    # members of a host with fewer chips the default device.
     members: int = 2
     # Frontend role: one render sidecar per address; each sidecar owns
     # its own device set.  Overrides ``members``.

@@ -189,10 +189,10 @@ class ImageRegionServices:
     # /readyz and the sidecar ping; None for injected test stacks.
     device: Optional[dict] = None
     native: Optional[dict] = None
-    # This member's dispatch device (cross-host federation: the
-    # combined role partitions the host's devices across its members —
-    # parallel.federation.partition_local_devices).  None = the
-    # process default device, the pre-federation behavior.
+    # This member's dispatch device (an in-process fleet or federation
+    # partitions the host's devices across its members —
+    # parallel.fleet.partition_local_devices); its reads upload there.
+    # None = the process default device.
     pin_device: object = None
 
 
@@ -665,7 +665,9 @@ class ImageRegionHandler:
                     and takes(*planes[0].shape,
                               jpeg=ctx.format == "jpeg")):
                 return tuple(planes)
-            return stack_channel_planes(*planes)
+            from ..io.staging import pin_scope
+            with pin_scope(getattr(self.s, "pin_device", None)):
+                return stack_channel_planes(*planes)
 
     def _read_region(self, src, ctx: ImageRegionCtx, region: RegionDef,
                      level: int, active: List[int],
@@ -690,7 +692,10 @@ class ImageRegionHandler:
         without it a slow store and a slow device look identical in a
         waterfall.
         """
-        with stopwatch("PixelsService.readRegion"):
+        from ..io.staging import pin_scope
+        # A fleet member's reads upload to its own chip.
+        with stopwatch("PixelsService.readRegion"), \
+                pin_scope(getattr(self.s, "pin_device", None)):
             if self.s.raw_cache is None or not device_cache:
                 # Storage dtype, not float32: the kernels cast on
                 # device (dtype keys the batch group), and a float32

@@ -175,7 +175,7 @@ def prewarm_batch_sizes(cap: int) -> tuple:
 def prewarm_renderer(specs: List[str], engine: str,
                      max_batch: int, buckets,
                      cpu_fallback_max_px: int = 0,
-                     exec_cache=None) -> None:
+                     exec_cache=None, members=None) -> None:
     """Compile the serving programs for each spec; failures are logged,
     never fatal (serving still works, it just compiles lazily).
 
@@ -185,9 +185,17 @@ def prewarm_renderer(specs: List[str], engine: str,
     skipped: the handler routes those renders to the host kernel, so a
     device program would never be hit.  ``/readyz`` reports degraded
     while this runs (telemetry.READINESS).
+
+    ``members``: ``(name, device)`` of each member of an in-process
+    fleet, warmed one after the other, each under its own device's
+    ``pin_scope`` and span ``prewarm.member`` (a program is compiled a
+    device), and ``/readyz`` turns ready when the last is warm; the
+    first member's serialized executables are ``exec_cache``'s.  None
+    warms the process default device.
     """
+    from ..io.staging import pin_scope
+    from ..utils.stopwatch import stopwatch
     from ..utils.telemetry import READINESS
-    from .batcher import group_cap, mcu_grid, pick_bucket
     # Malformed specs raise HERE, before the readiness flag flips or
     # any compile starts (the loader's contract: config errors are
     # loud, and a caller spawning this on a background thread gets the
@@ -196,32 +204,48 @@ def prewarm_renderer(specs: List[str], engine: str,
     parsed = [(spec,) + tuple(parse_spec(spec)) for spec in specs]
     READINESS.prewarm_pending = bool(specs)
     try:
-        for spec, C, edge, quality, raw_dtype in parsed:
-            if edge * edge <= cpu_fallback_max_px:
-                logger.info(
-                    "prewarm %s skipped: %dx%d px is at/below "
-                    "renderer.cpu-fallback-max-px (%d) and serves on "
-                    "the host kernel", spec, edge, edge,
-                    cpu_fallback_max_px)
-                continue
-            t0 = time.perf_counter()
-            bucket = pick_bucket(*mcu_grid(edge, edge), buckets)
-            batch_sizes = prewarm_batch_sizes(
-                group_cap(max_batch, bucket[0] * bucket[1]))
-            try:
-                _warm_one(C, edge, quality, batch_sizes, engine,
-                          bucket, raw_dtype, exec_cache=exec_cache)
-            except Exception:
-                # Per-spec: one shape's dead compile must not strand
-                # the others (serving still works, it compiles lazily).
-                logger.warning("prewarm %s failed; first requests of "
-                               "this shape will compile lazily", spec,
-                               exc_info=True)
-            else:
-                logger.info("prewarmed %s (engine %s, batches %s, %s) "
-                            "in %.1fs", spec, engine,
-                            "/".join(map(str, batch_sizes)),
-                            np.dtype(raw_dtype).name,
-                            time.perf_counter() - t0)
+        if members is None:
+            _warm_specs(parsed, engine, max_batch, buckets,
+                        cpu_fallback_max_px, exec_cache)
+            return
+        for i, (name, device) in enumerate(members):
+            with stopwatch("prewarm.member", member=name), \
+                    pin_scope(device):
+                _warm_specs(parsed, engine, max_batch, buckets,
+                            cpu_fallback_max_px,
+                            exec_cache if i == 0 else None)
     finally:
         READINESS.prewarm_pending = False
+
+
+def _warm_specs(parsed: list, engine: str, max_batch: int, buckets,
+                cpu_fallback_max_px: int, exec_cache) -> None:
+    """:func:`prewarm_renderer`'s compiles on the current device."""
+    from .batcher import group_cap, mcu_grid, pick_bucket
+    for spec, C, edge, quality, raw_dtype in parsed:
+        if edge * edge <= cpu_fallback_max_px:
+            logger.info(
+                "prewarm %s skipped: %dx%d px is at/below "
+                "renderer.cpu-fallback-max-px (%d) and serves on "
+                "the host kernel", spec, edge, edge,
+                cpu_fallback_max_px)
+            continue
+        t0 = time.perf_counter()
+        bucket = pick_bucket(*mcu_grid(edge, edge), buckets)
+        batch_sizes = prewarm_batch_sizes(
+            group_cap(max_batch, bucket[0] * bucket[1]))
+        try:
+            _warm_one(C, edge, quality, batch_sizes, engine,
+                      bucket, raw_dtype, exec_cache=exec_cache)
+        except Exception:
+            # Per-spec: one shape's dead compile must not strand
+            # the others (serving still works, it compiles lazily).
+            logger.warning("prewarm %s failed; first requests of "
+                           "this shape will compile lazily", spec,
+                           exc_info=True)
+        else:
+            logger.info("prewarmed %s (engine %s, batches %s, %s) "
+                        "in %.1fs", spec, engine,
+                        "/".join(map(str, batch_sizes)),
+                        np.dtype(raw_dtype).name,
+                        time.perf_counter() - t0)

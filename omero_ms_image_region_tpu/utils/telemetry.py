@@ -1265,6 +1265,37 @@ class RouteStats:
 ROUTES = RouteStats()
 
 
+class DeviceRenderStats:
+    """Renders by the device that ran them, read off each group's
+    output array when its render returns (``BatchingRenderer``): the
+    chip a fleet member is pinned to, as the program found it, not as
+    the code asked.  ``/metrics imageregion_device_renders_total
+    {device="<id>"}``; a device appears once it has rendered."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.reset()
+
+    def reset(self) -> None:
+        with self._lock:
+            self.counts: Dict[int, int] = {}
+
+    def count(self, device: int, renders: int) -> None:
+        with self._lock:
+            self.counts[device] = self.counts.get(device, 0) + renders
+
+    def metric_lines(self, extra_labels: str = "") -> List[str]:
+        extra = extra_labels.lstrip(",")
+        with self._lock:
+            return [
+                f"imageregion_device_renders_total{{device=\"{device}\""
+                + (f",{extra}" if extra else "") + f"}} {n}"
+                for device, n in sorted(self.counts.items())]
+
+
+DEVICE_RENDERS = DeviceRenderStats()
+
+
 def capture_profile(directory: str, ms: float) -> dict:
     """Wrap ``jax.profiler`` around whatever the device is doing for
     ``ms`` milliseconds; returns the artifact manifest with the
@@ -3445,6 +3476,14 @@ def robustness_metric_lines(extra_labels: str = "") -> List[str]:
             + session_metric_lines(extra_labels))
 
 
+def pinned_device_id(services):
+    """The id of the device an in-process fleet member is pinned to
+    (``services.pin_device``); None where it uses the process
+    default."""
+    pin = getattr(services, "pin_device", None)
+    return None if pin is None else getattr(pin, "id", pin)
+
+
 def fleet_metric_lines(router=None, extra_labels: str = "",
                        single_flight=None) -> List[str]:
     """The ``imageregion_fleet_*`` families: the process-global
@@ -3495,6 +3534,14 @@ def fleet_metric_lines(router=None, extra_labels: str = "",
             f"imageregion_fleet_member_planes{label(name)} "
             f"{member.resident_planes()}",
         ]
+        device = pinned_device_id(getattr(member, "services", None))
+        if device is not None:
+            # The chip this member holds, which its renders, uploads
+            # and prewarm run on.
+            body = f'member="{name}",device="{device}"'
+            lines.append("imageregion_fleet_member_device{"
+                         + body + (f",{extra}" if extra else "")
+                         + "} 1")
     return lines
 
 
@@ -3574,6 +3621,7 @@ METRIC_TYPES: Dict[str, str] = {
     "imageregion_batcher_bucket_px_total": "counter",
     "imageregion_entropy_tiles_total": "counter",
     "imageregion_renders_routed_total": "counter",
+    "imageregion_device_renders_total": "counter",
     "imageregion_batcher_queue_wait_max_ms": "gauge",
     "imageregion_compile_events_total": "counter",
     "imageregion_compile_ms_total": "counter",
@@ -3654,6 +3702,7 @@ METRIC_TYPES: Dict[str, str] = {
     "imageregion_fleet_member_inflight": "gauge",
     "imageregion_fleet_member_healthy": "gauge",
     "imageregion_fleet_member_planes": "gauge",
+    "imageregion_fleet_member_device": "gauge",
     "imageregion_fleet_routed_total": "counter",
     "imageregion_fleet_stolen_total": "counter",
     "imageregion_fleet_failed_over_total": "counter",
@@ -3934,6 +3983,12 @@ METRIC_HELP: Dict[str, str] = {
         "Serialized render executables deserialized from disk",
     "imageregion_fleet_member_planes":
         "HBM-resident plane entries owned by the member (shard size)",
+    "imageregion_fleet_member_device":
+        "1 for the device an in-process member is pinned to (its "
+        "renders, uploads and prewarm run there)",
+    "imageregion_device_renders_total":
+        "Renders by the device that ran them, read off each group's "
+        "output array",
     "imageregion_fleet_stolen_total":
         "Renders the member stole from a backlogged peer (no cache "
         "adoption)",
@@ -4177,6 +4232,92 @@ def _device_peak_bytes() -> Optional[int]:
     return max(peaks) if peaks else None
 
 
+def _shard_cache_lines(raw_cache, label) -> List[str]:
+    """The series of one HBM raw-cache shard (a process's, or an
+    in-process fleet member's: :func:`fleet_shard_metric_lines`)."""
+    lines: List[str] = []
+    if raw_cache is not None:
+        lb = label()
+        lines += [
+            f"imageregion_rawcache_hits{lb} {raw_cache.hits}",
+            f"imageregion_rawcache_misses{lb} {raw_cache.misses}",
+            f"imageregion_rawcache_bytes{lb} {raw_cache.size_bytes}",
+        ]
+        if hasattr(raw_cache, "evictions"):
+            lines.append(f"imageregion_rawcache_evictions{lb} "
+                         f"{raw_cache.evictions}")
+        if hasattr(raw_cache, "channel_loads"):
+            # Channel planes read (or handed over) and uploaded: what
+            # a change of the shown channels costs the store and the
+            # link, beside the hits and misses of the planes' lookups.
+            lines.append(f"imageregion_rawcache_channel_loads_total{lb} "
+                         f"{raw_cache.channel_loads}")
+        if hasattr(raw_cache, "plane_hits"):
+            # Content-digest staging skips: uploads the plane cache
+            # saved (hits) vs paid (misses) — wire probes included.
+            lines += [
+                f"imageregion_planecache_hits{lb} "
+                f"{raw_cache.plane_hits}",
+                f"imageregion_planecache_misses{lb} "
+                f"{raw_cache.plane_misses}",
+            ]
+    return lines
+
+
+def _shard_renderer_lines(renderer, label) -> List[str]:
+    """The counters of one batcher (a process's, or an in-process fleet
+    member's)."""
+    lines: List[str] = []
+    if hasattr(renderer, "batches_dispatched"):
+        lb = label()
+        lines += [
+            f"imageregion_batches_dispatched{lb} "
+            f"{renderer.batches_dispatched}",
+            f"imageregion_tiles_rendered{lb} "
+            f"{renderer.tiles_rendered}",
+            # Slots of the padded shapes launched, and those of them
+            # that held a repeat instead of a render.
+            f"imageregion_batcher_shape_slots_total{lb} "
+            f"{renderer.shape_slots}",
+            f"imageregion_batcher_padded_slots_total{lb} "
+            f"{renderer.padded_slots}",
+        ]
+        # Groups staged by one program over their members' resident
+        # planes ("planes"), or from the members' own stacks ("arrays").
+        for path, n in getattr(renderer, "group_stacks", {}).items():
+            body = f'path="{path}"'
+            lines.append("imageregion_batcher_group_stacks_total"
+                         f"{label(body)} {n}")
+        # Pixels launched: the members' own, and what their buckets
+        # hold beyond them.
+        for part, n in getattr(renderer, "bucket_px", {}).items():
+            body = f'part="{part}"'
+            lines.append("imageregion_batcher_bucket_px_total"
+                         f"{label(body)} {n}")
+    return lines
+
+
+def fleet_shard_metric_lines(members, base_services) -> List[str]:
+    """Each in-process fleet member's raw-cache shard and batcher
+    counters under ``member="<name>"``, but the one over
+    ``base_services``, whose are the process's own series
+    (:func:`device_metric_lines`): each family's sum is then the whole
+    fleet's."""
+    lines: List[str] = []
+    for member in members:
+        services = getattr(member, "services", None)
+        if services is None or services is base_services:
+            continue            # a remote member exports its own
+
+        def label(body: str = "", name=member.name) -> str:
+            inner = f'member="{name}"'
+            return "{" + (f"{body},{inner}" if body else inner) + "}"
+
+        lines += _shard_cache_lines(services.raw_cache, label)
+        lines += _shard_renderer_lines(services.renderer, label)
+    return lines
+
+
 def device_metric_lines(services, extra_labels: str = "") -> List[str]:
     """Series owned by a device-side process (combined app or sidecar):
     caches, raw cache, batcher gauges, compile events, link health.
@@ -4210,32 +4351,8 @@ def device_metric_lines(services, extra_labels: str = "") -> List[str]:
             if evictions is not None:
                 lines.append(
                     f"imageregion_cache_evictions{lb} {evictions}")
-    raw_cache = getattr(services, "raw_cache", None)
-    if raw_cache is not None:
-        lb = label()
-        lines += [
-            f"imageregion_rawcache_hits{lb} {raw_cache.hits}",
-            f"imageregion_rawcache_misses{lb} {raw_cache.misses}",
-            f"imageregion_rawcache_bytes{lb} {raw_cache.size_bytes}",
-        ]
-        if hasattr(raw_cache, "evictions"):
-            lines.append(f"imageregion_rawcache_evictions{lb} "
-                         f"{raw_cache.evictions}")
-        if hasattr(raw_cache, "channel_loads"):
-            # Channel planes read (or handed over) and uploaded: what
-            # a change of the shown channels costs the store and the
-            # link, beside the hits and misses of the planes' lookups.
-            lines.append(f"imageregion_rawcache_channel_loads_total{lb} "
-                         f"{raw_cache.channel_loads}")
-        if hasattr(raw_cache, "plane_hits"):
-            # Content-digest staging skips: uploads the plane cache
-            # saved (hits) vs paid (misses) — wire probes included.
-            lines += [
-                f"imageregion_planecache_hits{lb} "
-                f"{raw_cache.plane_hits}",
-                f"imageregion_planecache_misses{lb} "
-                f"{raw_cache.plane_misses}",
-            ]
+    lines += _shard_cache_lines(getattr(services, "raw_cache", None),
+                                label)
     pixels_service = getattr(services, "pixels_service", None)
     if hasattr(pixels_service, "opened"):
         # The LRU of open pixel sources: a plate of more images than
@@ -4258,32 +4375,7 @@ def device_metric_lines(services, extra_labels: str = "") -> List[str]:
             f"{single_flight.inflight()}",
         ]
     renderer = getattr(services, "renderer", None)
-    if hasattr(renderer, "batches_dispatched"):
-        lb = label()
-        lines += [
-            f"imageregion_batches_dispatched{lb} "
-            f"{renderer.batches_dispatched}",
-            f"imageregion_tiles_rendered{lb} "
-            f"{renderer.tiles_rendered}",
-            # Slots of the padded shapes launched, and those of them
-            # that held a repeat instead of a render.
-            f"imageregion_batcher_shape_slots_total{lb} "
-            f"{renderer.shape_slots}",
-            f"imageregion_batcher_padded_slots_total{lb} "
-            f"{renderer.padded_slots}",
-        ]
-        # Groups staged by one program over their members' resident
-        # planes ("planes"), or from the members' own stacks ("arrays").
-        for path, n in getattr(renderer, "group_stacks", {}).items():
-            body = f'path="{path}"'
-            lines.append("imageregion_batcher_group_stacks_total"
-                         f"{label(body)} {n}")
-        # Pixels launched: the members' own, and what their buckets
-        # hold beyond them.
-        for part, n in getattr(renderer, "bucket_px", {}).items():
-            body = f'part="{part}"'
-            lines.append("imageregion_batcher_bucket_px_total"
-                         f"{label(body)} {n}")
+    lines += _shard_renderer_lines(renderer, label)
     if hasattr(renderer, "queue_depth"):
         lb = label()
         lines += [
@@ -4324,6 +4416,8 @@ def device_metric_lines(services, extra_labels: str = "") -> List[str]:
     lines += PROFILE.metric_lines(extra_labels)
     # The handler's choice between the device and the host render.
     lines += ROUTES.metric_lines(extra_labels)
+    # Which chip ran them.
+    lines += DEVICE_RENDERS.metric_lines(extra_labels)
     # Warm-state persistence tier (disk byte cache, snapshot engine,
     # boot rehydrator) — device-side state, merged like the rest.
     lines += PERSIST.metric_lines(extra_labels)
@@ -4382,6 +4476,7 @@ def reset() -> None:
     SHAPE_COSTS.reset()
     PROFILE.reset()
     ROUTES.reset()
+    DEVICE_RENDERS.reset()
     PERSIST.reset()
     WIRE.reset()
     FLEET.reset()

@@ -103,7 +103,8 @@ def test_plane_stack_share_is_added_at_the_end_and_reads_the_counter():
         "name": "plane_stack_share", "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "staging",
         "moves": "renders_per_s",
-        "workloads": [w["name"] for w in bench["workloads"]]}
+        # Every cell of its time; PR 38's four-chip cell came after.
+        "workloads": [w["name"] for w in bench["workloads"]][:7]}
     with open(os.path.join(REPO, "benchmark", "layer_metrics",
                            "plane_stack_share.json")) as f:
         spec = json.load(f)

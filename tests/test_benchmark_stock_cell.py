@@ -49,7 +49,9 @@ def test_the_new_entries_are_the_issues():
         "read_region_ms", "unpack_device_ms",
         "shown_render_roofline",       # PR 32's, of another deployment
         "bucket_fill_share", "stack_pad_device_ms",   # PR 34's: plates
-        "source_open_ms", "idle_read_share"}   # PR 36's: the scans' open
+        "source_open_ms", "idle_read_share",   # PR 36's: the scans' open
+        "busiest_chip_share", "fleet_hop_ms",  # PR 38's: the fleet's
+        "fleet_steal_share", "fleet_render_roofline"}
     with open(os.path.join(REPO, "benchmark", "configs",
                            "stock4-u16-t256.json")) as f:
         config = json.load(f)

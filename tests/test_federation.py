@@ -22,11 +22,10 @@ import pytest
 from omero_ms_image_region_tpu.io.store import build_pyramid
 from omero_ms_image_region_tpu.parallel import federation
 from omero_ms_image_region_tpu.parallel.federation import (
-    FederationCoordinator, FederationError, FleetManifest, MemberSpec,
-    partition_local_devices)
+    FederationCoordinator, FederationError, FleetManifest, MemberSpec)
 from omero_ms_image_region_tpu.parallel.fleet import (
     FleetImageHandler, FleetRouter, HashRing, RemoteMember,
-    plane_route_key)
+    partition_local_devices, plane_route_key)
 from omero_ms_image_region_tpu.server.config import (
     AppConfig, BatcherConfig, RawCacheConfig, RendererConfig)
 from omero_ms_image_region_tpu.server.ctx import ImageRegionCtx
@@ -242,9 +241,20 @@ class TestDevicePartition:
         assert partition_local_devices(3, list("abcde")) == \
             [["a", "b"], ["c", "d"], ["e"]]
 
-    def test_fewer_devices_than_members_leaves_tail_unpinned(self):
-        assert partition_local_devices(3, ["d0"]) == [["d0"], [], []]
-        assert partition_local_devices(2, []) == [[], []]
+    @pytest.mark.parametrize("members,devices,want", [
+        (3, ["d0"], [["d0"], [], []]),
+        (2, [], [[], []]),
+        # The in-process fleet of four on a host of two chips: the
+        # first two members hold one each, the tail uses the default.
+        (4, ["d0", "d1"], [["d0"], ["d1"], [], []]),
+    ])
+    def test_fewer_devices_than_members_leaves_tail_unpinned(
+            self, members, devices, want):
+        assert partition_local_devices(members, devices) == want
+
+    def test_federation_partitions_with_the_fleets_function(self):
+        assert federation.partition_local_devices \
+            is partition_local_devices
 
     def test_members_carry_their_device_sets(self, data_dir):
         from omero_ms_image_region_tpu.parallel.fleet import (

@@ -517,6 +517,9 @@ _ALLOWED_LABEL_KEYS = frozenset({
     # What a launched group's pixels were (PR 34): "image" or "pad",
     # the two keys of ``BatchingRenderer.bucket_px``.
     "part",
+    # The chip that ran a render, or that a fleet member holds (PR 38):
+    # a JAX device id of this process, as many as the host's chips.
+    "device",
 })
 
 

@@ -17,12 +17,20 @@ staged-once semantics ride the existing digest probes unchanged.
 Re-window/re-color traffic for a hot plane always finds its bytes
 already resident on its owner.
 
-Load skew is handled by **bounded work stealing**: each member drains
-its own queue through ``lane_width`` worker lanes, and an idle lane
-may steal the oldest queued request from the most-backlogged member —
-the stolen render runs from source bytes *without adopting cache
-ownership* (``adopt_cache=False`` rides the wire as the ``adopt``
-header), so stealing never fragments the shard map.
+A member runs as many renders at once as it can group: an in-process
+member whose renderer is a ``BatchingRenderer`` takes
+``pipeline_depth x max_batch`` (its groups' slots times their size),
+every other member (a sidecar, a plain or lockstep renderer)
+``lane_width``.  A request whose owner has room starts its render at
+once; only one whose owner is full waits in the owner's queue, and a
+finishing render takes that queue's next.
+
+Load skew is handled by **bounded work stealing**: once a full
+member's backlog reaches ``steal_min_backlog``, a peer with room takes
+its oldest queued request — the stolen render runs from source bytes
+*without adopting cache ownership* (``adopt_cache=False`` rides the
+wire as the ``adopt`` header), so stealing never fragments the shard
+map.
 
 Membership is decided by the PR-3 breaker/supervisor machinery: a
 member whose connection died through every policy retry (or whose
@@ -43,6 +51,7 @@ from __future__ import annotations
 
 import asyncio
 import collections
+import contextvars
 import hashlib
 import bisect
 import logging
@@ -226,7 +235,7 @@ class HeatTracker:
 class MemberDownError(ConnectionError):
     """A member's fast-fail refusal while it is ALREADY marked down.
 
-    The lane must not treat this as a fresh death observation:
+    The router must not treat this as a fresh death observation:
     re-marking on every routed request would push ``_down_until``
     forward each time, so any shard seeing >= 1 request per cooldown
     window would keep its member down forever — after the outage
@@ -314,6 +323,20 @@ class LocalMember:
         renderer = getattr(self.services, "renderer", None)
         return (renderer.queue_depth()
                 if hasattr(renderer, "queue_depth") else 0)
+
+    def render_capacity(self) -> Optional[int]:
+        """Renders this member can take at once and still group: a
+        ``BatchingRenderer`` runs ``pipeline_depth`` groups of up to
+        ``max_batch`` each.  None where no batcher groups them (a plain
+        ``Renderer``, the lockstep mesh renderer, no services): the
+        router then admits its ``lane_width``."""
+        renderer = getattr(self.services, "renderer", None)
+        if renderer is None or getattr(renderer, "lockstep", False):
+            return None
+        from ..server.batcher import BatchingRenderer
+        if not isinstance(renderer, BatchingRenderer):
+            return None
+        return renderer.pipeline_depth * renderer.max_batch
 
     def resident_digests(self):
         cache = getattr(self.services, "raw_cache", None)
@@ -494,7 +517,7 @@ class RemoteMember:
     """A render sidecar owning a device set, reached over the wire.
 
     Health is the PR-3 machinery's verdict: the client's circuit
-    breaker open, or a connection death observed by a lane worker,
+    breaker open, or a connection death observed by a render task,
     marks the member down for ``down_cooldown_s`` — its shard fails
     over hash-ring-next while the supervisor restarts the process, and
     the ring re-adopts it at the next successful call after cooldown.
@@ -860,12 +883,13 @@ class _Work:
         self.hops = 0
         self.deadline = deadline
         self.t_enqueue = time.perf_counter()
-        # The requester's trace id(s), captured at enqueue: the lane
-        # tasks run OUTSIDE any request context (they must — a lane is
-        # long-lived), so every hop span and the member render itself
-        # re-adopt these explicitly.  Without this, every lane span
-        # would attach to whichever request's context happened to
-        # spawn the lanes (the classic contextvars-snapshot leak).
+        # The requester's trace id(s), captured at enqueue: the render
+        # tasks run OUTSIDE any request context (they must — a task
+        # renders whatever its member queued next), so every hop span
+        # and the member render itself re-adopt these explicitly.
+        # Without this, every span of a task would attach to whichever
+        # request's context happened to start it (the classic
+        # contextvars-snapshot leak).
         from ..utils import telemetry
         self.trace_ids = telemetry.current_trace_ids()
         # QoS class, computed ONCE at enqueue: the same
@@ -900,8 +924,8 @@ class _MemberQueue:
         self.qos_weight = max(0, int(qos_weight))
         self._ic_run = 0
         # Interactive-unit count, maintained O(1) on every mutation:
-        # idle lanes poll steal_depth() on every wake evaluation, and
-        # a deep bulk backlog must not turn that into a deque walk.
+        # every dispatch to a full member reads steal_depth(), and a
+        # deep bulk backlog must not turn that into a deque walk.
         self._ic = 0
 
     def append(self, work: _Work) -> None:
@@ -986,15 +1010,22 @@ class _MemberQueue:
 class FleetRouter:
     """Consistent-hash request router over N fleet members.
 
-    Per-member queues drained by ``lane_width`` asyncio lanes each (a
-    lane models one device lane of that member's set); an idle lane
-    steals the oldest request from the most-backlogged peer once that
-    backlog reaches ``steal_min_backlog`` — bounded, oldest-first, and
-    cache-ownership-neutral (stolen renders carry
-    ``adopt_cache=False``).  Member death (ConnectionError through the
-    retry policy / breaker) marks the member down, re-assigns its
-    queued work hash-ring-next and fails the dead call over the same
-    way, so a mid-burst kill yields zero 5xx-without-shed.
+    Each member runs up to ``capacity[name]`` renders at once: what
+    its renderer can group (``LocalMember.render_capacity``:
+    ``pipeline_depth x max_batch`` for a ``BatchingRenderer``), else
+    ``lane_width``.  A dispatch whose owner has room starts a render
+    task at once; otherwise the unit waits in the owner's queue, which
+    the owner's tasks drain as they finish (span ``fleet.queueWait``:
+    enqueue -> taken).  Once a full owner's backlog reaches
+    ``steal_min_backlog``, one peer with room steals its oldest unit —
+    bounded, oldest-first, and cache-ownership-neutral (stolen renders
+    carry ``adopt_cache=False``); a finishing task with no work of its
+    own steals the same way.  A dispatch starts at most one task, so
+    its cost on the loop does not grow with the capacity.  Member
+    death (ConnectionError through the retry policy / breaker) marks
+    the member down, re-assigns its queued work hash-ring-next and
+    fails the dead call over the same way, so a mid-burst kill yields
+    zero 5xx-without-shed.
     """
 
     def __init__(self, members: Sequence, lane_width: int = 2,
@@ -1021,26 +1052,28 @@ class FleetRouter:
         # another host cannot re-read this host's pixel store, so a
         # hint-list prestage would arrive cold.
         self.wire_handoff = bool(wire_handoff)
-        self.lane_width = lane_width
         # 0 disables stealing entirely.
         self.steal_min_backlog = max(0, int(steal_min_backlog))
         self.failover = failover
         # Tiered QoS (config.qos): interactive units jump bulk
         # backlogs at this weight; 0 = plain FIFO (pre-QoS behavior).
         self.qos_weight = max(0, int(qos_weight))
+        # Renders each member may run at once: the capacity it states,
+        # else lane_width.
+        self.capacity: Dict[str, int] = {
+            m.name: getattr(m, "render_capacity", lambda: None)()
+            or lane_width for m in members}
         # The admission controller reads this as the fleet's service
         # parallelism (estimated wait = depth * EWMA / lanes).
-        self.device_lanes = lane_width * len(members)
+        self.device_lanes = sum(self.capacity.values())
         self._queues: Dict[str, _MemberQueue] = {
             name: _MemberQueue(self.qos_weight)
             for name in self.order}
         self._inflight: Dict[str, int] = {n: 0 for n in self.order}
-        # ONE wake event for all idle lanes: stealing means any lane
-        # may be interested in any member's new work, and at fleet
-        # scale (N <= ~16 members) a broadcast wake is cheaper than a
-        # correct per-member + steal-candidate wake dance.
-        self._wake: Optional[asyncio.Event] = None
-        self._lanes: List[asyncio.Task] = []
+        # Render tasks alive a member (each holds one place of its
+        # capacity from start to exit), and the tasks themselves.
+        self._running: Dict[str, int] = {n: 0 for n in self.order}
+        self._tasks: set = set()
         self._closed = False
         # Fleet-global byte tier (deploy/DEPLOY.md "Edge caching"):
         # probe the shard authority's byte cache before any
@@ -1410,6 +1443,10 @@ class FleetRouter:
     def member_inflight(self, name: str) -> int:
         return self._inflight[name]
 
+    def member_capacity(self, name: str) -> int:
+        """Renders ``name`` is admitted to run at once."""
+        return self.capacity[name]
+
     def healthy_members(self) -> List[str]:
         return [n for n in self.order if self.members[n].healthy]
 
@@ -1485,7 +1522,7 @@ class FleetRouter:
         ``imageregion_drain_*`` transition):
 
         1. **draining** — the member stops accepting routes (new
-           arrivals and failovers walk past it; its lanes stop
+           arrivals and failovers walk past it; it stops
            stealing) and its QUEUED work re-homes hash-ring-next with
            adoption, exactly the failover remap bound (~1/N).
         2. **settle** — in-flight renders finish on the member (a
@@ -1517,7 +1554,7 @@ class FleetRouter:
                                 phase="draining", intent=intent,
                                 queued=len(self._queues[name]),
                                 inflight=self._inflight[name])
-        # Queued work re-homes NOW (the lanes would drain it anyway,
+        # Queued work re-homes NOW (its tasks would drain it anyway,
         # but re-homing bounds the drain's tail latency by the
         # in-flight work only).
         self._reassign(name, reason="drain")
@@ -1683,24 +1720,6 @@ class FleetRouter:
 
     # ---------------------------------------------------------- dispatch
 
-    def _ensure_lanes(self) -> None:
-        if self._lanes or self._closed:
-            return
-        from ..utils import transient
-        self._wake = asyncio.Event()
-        # Lanes are spawned lazily from the FIRST request's context —
-        # detach them from its deadline contextvar (create_task
-        # snapshots the context), or every render in every lane would
-        # permanently inherit that one request's budget and start
-        # 504ing fleet-wide the moment it expires.  Each unit's own
-        # budget is re-established around its render from
-        # ``work.deadline``.
-        with transient.deadline_scope(None):
-            for name in self.order:
-                for lane in range(self.lane_width):
-                    self._lanes.append(asyncio.create_task(
-                        self._lane(name), name=f"fleet-{name}-l{lane}"))
-
     async def dispatch(self, ctx) -> bytes:
         """Route one render to its shard owner and await the bytes.
         Runs on the event loop; all queue bookkeeping is loop-confined
@@ -1709,7 +1728,6 @@ class FleetRouter:
 
         if self._closed:
             raise ConnectionError("fleet router is closed")
-        self._ensure_lanes()
         if self._heat is not None and not self._pinned(ctx):
             # Hot-key tier: every dispatched (non-pinned) request
             # feeds the heat tracker; a promoted route's reads then
@@ -1732,18 +1750,18 @@ class FleetRouter:
                 plane=work.route_key)
         self._queues[owner].append(work)
         telemetry.FLEET.count_routed(owner)
-        self._wake.set()
+        self._admit(owner)
         remaining = transient.remaining_ms()
         if remaining is None:
             return await work.future
         try:
             # The member render enforces its own budget too; this
-            # bound covers a lane wedged in an uncancellable render.
+            # bound covers a task wedged in an uncancellable render.
             return await asyncio.wait_for(
                 asyncio.shield(work.future),
                 timeout=max(0.0, remaining) / 1000.0)
         except asyncio.TimeoutError:
-            # The waiter is gone: cancel the unit so a lane popping
+            # The waiter is gone: cancel the unit so a task popping
             # it later skips instead of rendering bytes nobody will
             # retrieve (and so no 'exception never retrieved' noise).
             if not work.future.done():
@@ -1953,36 +1971,76 @@ class FleetRouter:
         self._putback_tasks.add(task)
         task.add_done_callback(self._putback_tasks.discard)
 
-    def _takeable(self, name: str) -> bool:
-        """Is there work this member's lanes could take right now —
-        its own backlog, or a peer backlog past the steal threshold?"""
-        if self._queues[name]:
-            return True
-        if self.steal_min_backlog <= 0 or not self._routable(name):
-            return False
-        # Mirrors _pop_work's steal candidates exactly (stealable =
-        # INTERACTIVE backlog; pinned/bulk units are never stealable)
-        # — a backlog this lane can NEVER steal must park it on the
-        # wake event, not busy-spin it.
-        return any(
-            self._queues[other].steal_depth() >= self.steal_min_backlog
-            for other in self.order if other != name)
+    def _free(self, name: str) -> int:
+        """Renders ``name`` could start now, within its capacity."""
+        return self.capacity[name] - self._running[name]
+
+    def _admit(self, owner: str) -> None:
+        """A unit was just queued on ``owner``: start it there if the
+        owner has room; else, once the owner's stealable backlog
+        reaches ``steal_min_backlog``, start the one peer with the most
+        room on a steal of the oldest unit.  One task at most, however
+        large the capacities: no wake of idle tasks, since none wait."""
+        if self._free(owner) > 0:
+            self._start(owner, self._pop_work(owner))
+            return
+        if self.steal_min_backlog <= 0 or self._queues[
+                owner].steal_depth() < self.steal_min_backlog:
+            return
+        thief, room = None, 0
+        for name in self.order:
+            if name != owner and self._free(name) > room \
+                    and self._routable(name):
+                thief, room = name, self._free(name)
+        if thief is not None:
+            self._start(thief, self._pop_work(thief))
+
+    def _fill(self) -> None:
+        """Queued work moved between members (a failover or a drain):
+        every member with room takes what it may, its own queue first."""
+        for name in self.order:
+            while self._free(name) > 0:
+                work = self._pop_work(name)
+                if work is None:
+                    break
+                self._start(name, work)
+
+    def _start(self, name: str, work: Optional[_Work]) -> None:
+        """A render task on ``name`` for ``work``, holding one place of
+        the member's capacity until it finds nothing more to take.  It
+        runs in a context of its own: never the dispatching request's
+        deadline or trace (each unit re-enters its own around its
+        render)."""
+        if work is None:
+            return
+        if self._closed:
+            if not work.future.done():
+                work.future.set_exception(
+                    RuntimeError("fleet router shut down"))
+            return
+        self._running[name] += 1
+        task = asyncio.get_running_loop().create_task(
+            self._run(name, work), name=f"fleet-{name}",
+            context=contextvars.Context())
+        self._tasks.add(task)
+        task.add_done_callback(self._tasks.discard)
 
     def _pop_work(self, name: str) -> Optional[_Work]:
-        """This lane's next unit: own queue first (weighted dequeue —
+        """This member's next unit: own queue first (weighted dequeue —
         interactive jumps bulk backlogs when QoS is on); otherwise
         steal the OLDEST interactive request from the most-backlogged
         healthy-owned queue at or past the steal threshold
         (oldest-first keeps the latency tail honest — LIFO stealing
         would starve the convoy head).  Pinned mesh-lane (bulk) jobs
         are never stealable — they exist to run on member 0's lockstep
-        renderer, not a single-device lane."""
+        renderer, not a single-device lane.  The unit's wait since its
+        enqueue is span ``fleet.queueWait``."""
         queue = self._queues[name]
         if queue:
-            return queue.popleft()
+            return self._taken(name, queue.popleft())
         if self.steal_min_backlog <= 0 or not self._routable(name):
-            # A draining member's lanes drain their own queue (the
-            # reassign empties it) but never steal new work.
+            # A draining member drains its own queue (the reassign
+            # empties it) but never steals new work.
             return None
         victim = None
         depth = 0
@@ -2004,11 +2062,19 @@ class FleetRouter:
                                 owner=work.owner, backlog=depth)
         if work.trace_ids:
             # Hop: the steal decision — this unit leaves its owner's
-            # queue for the thief's lane (cache-ownership-neutral).
+            # queue for the thief (cache-ownership-neutral).
             telemetry.record_span(
                 "fleet.hop", time.perf_counter(), 0.0,
                 trace_ids=work.trace_ids, member=name, hop="steal",
                 plane=work.route_key)
+        return self._taken(name, work)
+
+    @staticmethod
+    def _taken(name: str, work: _Work) -> _Work:
+        from ..utils.stopwatch import record_since
+        record_since("fleet.queueWait", work.t_enqueue,
+                     trace_ids=work.trace_ids, member=name,
+                     **({"stolen": 1} if work.stolen else {}))
         return work
 
     def _reassign(self, dead: str, reason: str = "failover") -> None:
@@ -2027,7 +2093,7 @@ class FleetRouter:
         if moved:
             telemetry.FLIGHT.record("fleet.drain", member=dead,
                                     moved=moved)
-            self._wake.set()
+            self._fill()
 
     def _fail_queue(self, dead: str, error: Exception) -> None:
         """failover=False: a dead member's queued work fails with it."""
@@ -2087,137 +2153,136 @@ class FleetRouter:
             work.future.set_exception(ConnectionError(
                 "no healthy fleet member for shard"))
 
-    async def _lane(self, name: str) -> None:
+    async def _run(self, name: str, work: _Work) -> None:
+        """One render task of member ``name``: ``work``, then the
+        member's next queued unit, or one it steals, until there is
+        none; its place of the member's capacity is then free again."""
+        try:
+            while True:
+                await self._render(name, work)
+                work = None if self._closed else self._pop_work(name)
+                if work is None:
+                    return
+        finally:
+            self._running[name] -= 1
+
+    async def _render(self, name: str, work: _Work) -> None:
+        """One unit on member ``name``: skipped when its waiter gave up
+        or its deadline passed while it was queued, else rendered under
+        its own budget and trace; a member's death fails its shard
+        over."""
         from ..utils import provenance, telemetry, transient
         from ..utils.stopwatch import record_since
 
-        # Lanes are long-lived tasks spawned from the FIRST request's
-        # context; detach from its trace ids or every span any render
-        # ever records here would graft onto that one request's
-        # waterfall (each unit re-adopts its own ids around its
-        # render below).
-        telemetry.clear_context()
         member = self.members[name]
-        while not self._closed:
-            work = self._pop_work(name)
-            if work is None:
-                self._wake.clear()
-                # Re-check under the cleared event for work THIS lane
-                # could take (a dispatch between pop and clear must
-                # not be lost — but peers' sub-threshold backlogs must
-                # not busy-spin a lane that cannot steal them).
-                if self._takeable(name):
-                    continue
-                await self._wake.wait()
-                continue
-            if work.future.done():
-                continue              # waiter gave up while queued
-            if work.deadline is not None \
-                    and time.monotonic() >= work.deadline:
-                telemetry.RESILIENCE.count_deadline_cancelled(1)
-                if not work.future.done():
-                    work.future.set_exception(
-                        transient.DeadlineExceededError(
-                            "deadline exceeded in fleet queue"))
-                continue
-            self._inflight[name] += 1
-            # Provenance: the member actually serving, and how the
-            # unit got there (marked before the render so a failing
-            # member still leaves an attributable record).
-            provenance.mark(work.ctx, member=name,
-                            **({"stolen": True} if work.stolen
-                               else {}))
-            t_render = time.perf_counter()
-            try:
-                # A stolen render executes on THIS member from source
-                # bytes without adopting cache ownership; owned (and
-                # failed-over) work adopts — the failover target IS
-                # the shard's new ring owner.  The unit's remaining
-                # budget re-enters the context here (the lane task
-                # itself is deadline-free), so the member pipeline's
-                # own check_deadline / wire deadline_ms still bite.
-                # The unit's OWN trace ids re-enter too (group_trace):
-                # member-side spans — and, for remote members, the
-                # trace id riding the wire — attach to the requester's
-                # waterfall, not to whatever context spawned the lane.
-                with telemetry.group_trace(work.trace_ids):
-                    if work.deadline is not None:
-                        remaining_ms = max(
-                            1.0, (work.deadline - time.monotonic())
-                            * 1000.0)
-                        with transient.deadline_scope(remaining_ms):
-                            data = await member.render(
-                                work.ctx, adopt_cache=not work.stolen)
-                    else:
+        if work.future.done():
+            return                    # waiter gave up while queued
+        if work.deadline is not None \
+                and time.monotonic() >= work.deadline:
+            telemetry.RESILIENCE.count_deadline_cancelled(1)
+            if not work.future.done():
+                work.future.set_exception(
+                    transient.DeadlineExceededError(
+                        "deadline exceeded in fleet queue"))
+            return
+        self._inflight[name] += 1
+        # Provenance: the member actually serving, and how the
+        # unit got there (marked before the render so a failing
+        # member still leaves an attributable record).
+        provenance.mark(work.ctx, member=name,
+                        **({"stolen": True} if work.stolen
+                           else {}))
+        t_render = time.perf_counter()
+        try:
+            # A stolen render executes on THIS member from source
+            # bytes without adopting cache ownership; owned (and
+            # failed-over) work adopts — the failover target IS
+            # the shard's new ring owner.  The unit's remaining
+            # budget re-enters the context here (the render task
+            # itself is deadline-free), so the member pipeline's
+            # own check_deadline / wire deadline_ms still bite.
+            # The unit's OWN trace ids re-enter too (group_trace):
+            # member-side spans — and, for remote members, the
+            # trace id riding the wire — attach to the requester's
+            # waterfall, not to whatever context spawned the task.
+            with telemetry.group_trace(work.trace_ids):
+                if work.deadline is not None:
+                    remaining_ms = max(
+                        1.0, (work.deadline - time.monotonic())
+                        * 1000.0)
+                    with transient.deadline_scope(remaining_ms):
                         data = await member.render(
                             work.ctx, adopt_cache=not work.stolen)
-            except (ConnectionError, OSError) as e:
-                if not member.remote \
-                        and not isinstance(e, ConnectionError):
-                    # A LOCAL render's OSError (missing/truncated
-                    # pyramid file, EIO) is that one request's
-                    # failure, never member death — treating it as
-                    # death would cascade a bad file into marking
-                    # every member down in failover order.
-                    if not work.future.done():
-                        work.future.set_exception(e)
-                    continue
-                if not isinstance(e, MemberDownError):
-                    # A fast-fail from an already-down member is not
-                    # a new death — re-marking would extend the
-                    # cooldown on every request and the member could
-                    # never rejoin under steady traffic.
-                    member.mark_down()
-                    telemetry.FLIGHT.record("fleet.member-down",
-                                            member=name,
-                                            error=str(e)[:120])
-                if not self.failover:
-                    # Contract: the shard fails as the member does —
-                    # queued work included, never re-homed.
-                    logger.warning("fleet member %s down (%s); "
-                                   "failover disabled, failing its "
-                                   "shard", name, e)
-                    self._fail_queue(name, e)
-                    if not work.future.done():
-                        work.future.set_exception(e)
-                    continue
-                logger.warning("fleet member %s down (%s); failing "
-                               "its shard over hash-ring-next", name, e)
-                self._reassign(name)
-                if work.hops < len(self.order) - 1:
-                    self._route_failover(work)
-                    self._wake.set()
-                elif not work.future.done():
-                    work.future.set_exception(e)
-            except asyncio.CancelledError:
-                # Router teardown mid-render: waiters sit in HTTP
-                # handlers whose ``except Exception`` must map this to
-                # a 500, never a dropped connection.
-                if not work.future.done():
-                    work.future.set_exception(
-                        RuntimeError("fleet router shut down"))
-                raise
-            except Exception as e:
+                else:
+                    data = await member.render(
+                        work.ctx, adopt_cache=not work.stolen)
+        except (ConnectionError, OSError) as e:
+            if not member.remote \
+                    and not isinstance(e, ConnectionError):
+                # A LOCAL render's OSError (missing/truncated
+                # pyramid file, EIO) is that one request's
+                # failure, never member death — treating it as
+                # death would cascade a bad file into marking
+                # every member down in failover order.
                 if not work.future.done():
                     work.future.set_exception(e)
-            else:
-                # The render hop itself: which member executed, and
-                # under what acquisition (owned / stolen / failed-over)
-                # — the widest lane of the stitched waterfall, and the
-                # one hop with a duration, so the one on /metrics.
-                record_since(
-                    "fleet.hop", t_render, trace_ids=work.trace_ids,
-                    member=name, hop="render", plane=work.route_key,
-                    **({"stolen": 1} if work.stolen else {}))
+                return
+            if not isinstance(e, MemberDownError):
+                # A fast-fail from an already-down member is not
+                # a new death — re-marking would extend the
+                # cooldown on every request and the member could
+                # never rejoin under steady traffic.
+                member.mark_down()
+                telemetry.FLIGHT.record("fleet.member-down",
+                                        member=name,
+                                        error=str(e)[:120])
+            if not self.failover:
+                # Contract: the shard fails as the member does —
+                # queued work included, never re-homed.
+                logger.warning("fleet member %s down (%s); "
+                               "failover disabled, failing its "
+                               "shard", name, e)
+                self._fail_queue(name, e)
                 if not work.future.done():
-                    work.future.set_result(data)
-                if work.stolen:
-                    # The thief's render lands on the shard authority's
-                    # byte tier too (fire-and-forget byte_put): one
-                    # member's render becomes every member's hit.
-                    self._byte_putback(work, data)
-            finally:
-                self._inflight[name] -= 1
+                    work.future.set_exception(e)
+                return
+            logger.warning("fleet member %s down (%s); failing "
+                           "its shard over hash-ring-next", name, e)
+            self._reassign(name)
+            if work.hops < len(self.order) - 1:
+                self._route_failover(work)
+                self._fill()
+            elif not work.future.done():
+                work.future.set_exception(e)
+        except asyncio.CancelledError:
+            # Router teardown mid-render: waiters sit in HTTP
+            # handlers whose ``except Exception`` must map this to
+            # a 500, never a dropped connection.
+            if not work.future.done():
+                work.future.set_exception(
+                    RuntimeError("fleet router shut down"))
+            raise
+        except Exception as e:
+            if not work.future.done():
+                work.future.set_exception(e)
+        else:
+            # The render hop itself: which member executed, and
+            # under what acquisition (owned / stolen / failed-over)
+            # — the widest lane of the stitched waterfall, and the
+            # one hop with a duration, so the one on /metrics.
+            record_since(
+                "fleet.hop", t_render, trace_ids=work.trace_ids,
+                member=name, hop="render", plane=work.route_key,
+                **({"stolen": 1} if work.stolen else {}))
+            if not work.future.done():
+                work.future.set_result(data)
+            if work.stolen:
+                # The thief's render lands on the shard authority's
+                # byte tier too (fire-and-forget byte_put): one
+                # member's render becomes every member's hit.
+                self._byte_putback(work, data)
+        finally:
+            self._inflight[name] -= 1
 
     # --------------------------------------------------------- accounting
 
@@ -2271,11 +2336,11 @@ class FleetRouter:
 
     async def close(self) -> None:
         self._closed = True
-        for task in self._lanes:
+        tasks = list(self._tasks)
+        for task in tasks:
             task.cancel()
-        if self._lanes:
-            await asyncio.gather(*self._lanes, return_exceptions=True)
-        self._lanes = []
+        if tasks:
+            await asyncio.gather(*tasks, return_exceptions=True)
         for task in list(self._putback_tasks):
             task.cancel()
         if self._putback_tasks:

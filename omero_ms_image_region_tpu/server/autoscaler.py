@@ -137,9 +137,14 @@ class Autoscaler:
 
     # ----------------------------------------------------------- signals
 
+    def _lanes(self, names: List[str]) -> int:
+        """Renders ``names`` are admitted to run at once: each
+        member's own figure in the router (``member_capacity``)."""
+        return sum(self.router.member_capacity(n) for n in names)
+
     def signals(self) -> dict:
         routable = self.routable_members()
-        lanes = self.router.lane_width * max(1, len(routable))
+        lanes = max(1, self._lanes(routable))
         depth = self.router.queue_depth()
         demand = None
         if self.demand_source is not None:
@@ -148,7 +153,7 @@ class Autoscaler:
             except Exception:
                 demand = None
         level = self.governor.level if self.governor is not None else 0
-        capacity_tps = (len(routable) * self.router.lane_width
+        capacity_tps = (self._lanes(routable)
                         * self.config.lane_capacity_tps)
         # Hot-key replica pressure (parallel.fleet): the hottest
         # promoted route's heat in promotion-threshold units —
@@ -198,15 +203,15 @@ class Autoscaler:
             up = True
         if up:
             return "up"
-        routable = len(self.routable_members())
         down = (sig["queue_per_lane"] <= c.queue_low_per_lane
                 and sig["pressure_level"] == 0)
         if down and demand is not None and c.lane_capacity_tps > 0:
             # Shrinking must leave enough measured capacity for the
             # PREDICTED demand, not just the instantaneous queue — a
             # quiet second inside a busy day must not shed a member
-            # the next minute needs back.
-            after = ((routable - 1) * self.router.lane_width
+            # the next minute needs back (a scale-down drains the last
+            # routable member).
+            after = (self._lanes(self.routable_members()[:-1])
                      * c.lane_capacity_tps)
             down = demand <= after
         return "down" if down else None

@@ -185,13 +185,15 @@ class FleetConfig:
     # Frontend role: one render sidecar per address; each sidecar owns
     # its own device set.  Overrides ``members``.
     sockets: Tuple[str, ...] = ()
-    # Concurrent renders per member (models the member's device
-    # lanes); fleet admission sees lane-width x members as the
-    # service parallelism.
+    # Concurrent renders of a member that states no capacity of its
+    # own (a sidecar, a plain or lockstep renderer).  An in-process
+    # member whose renderer batches runs pipeline-depth x max-batch
+    # at once, what its groups can hold.  Fleet admission sees the
+    # members' sum as the service parallelism.
     lane_width: int = 2
-    # An idle member lane steals the OLDEST queued request from the
-    # most-backlogged peer once that backlog reaches this depth; the
-    # stolen render runs from source bytes without adopting cache
+    # A member with room steals the OLDEST queued request from the
+    # most-backlogged full peer once that backlog reaches this depth;
+    # the stolen render runs from source bytes without adopting cache
     # ownership.  0 disables stealing.
     steal_min_backlog: int = 2
     # Virtual nodes per member on the hash ring (higher = smoother

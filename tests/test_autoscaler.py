@@ -103,6 +103,9 @@ class _FakeRouter:
     def queue_depth(self):
         return self.depth
 
+    def member_capacity(self, name):
+        return self.lane_width
+
     async def drain_member(self, name, intent="operator", **_kw):
         member = self.members[name]
         member.draining = True

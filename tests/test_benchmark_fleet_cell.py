@@ -26,6 +26,8 @@ CELL = CONFIG + ".rewindow"
 TINY_CELL = "fleet4-tiny4-u16-t64.rewindow"
 NEW_METRICS = ("busiest_chip_share", "fleet_hop_ms", "fleet_steal_share",
                "fleet_render_roofline")
+# The router's queue wait: appended after the cell's first four.
+QUEUE_WAIT = "fleet_queue_wait_ms"
 CHIP_NEUTRAL = (
     "rawcache_hit_share", "device_idle_share", "queue_wait_ms",
     "lane_wait_ms", "lane_hold_ms", "dispatch_ms", "device_wait_ms",
@@ -62,8 +64,8 @@ def test_the_new_entries_are_the_issues():
             cell["chips"]) == (CELL, CONFIG, "rewindow16", 4)
     # The benchmark's only four-chip cell.
     assert [w["chips"] for w in bench["workloads"]] == [1] * 7 + [4]
-    assert [m["name"] for m in bench["per_layer"]][-4:] == list(
-        NEW_METRICS)
+    assert [m["name"] for m in bench["per_layer"]][-5:] == list(
+        NEW_METRICS) + [QUEUE_WAIT]
     by_name = {m["name"]: m for m in bench["per_layer"]}
     for name, layer, source, moves in zip(
             NEW_METRICS,
@@ -80,7 +82,7 @@ def test_the_new_entries_are_the_issues():
             REPO, "benchmark", "readers", spec["reader"] + ".py"))
     listed = {m["name"] for m in bench["per_layer"]
               if CELL in m["workloads"]}
-    assert listed == set(CHIP_NEUTRAL) | set(NEW_METRICS)
+    assert listed == set(CHIP_NEUTRAL) | set(NEW_METRICS) | {QUEUE_WAIT}
     # The single chip's roofline would read up to four times high here.
     assert CELL not in by_name["render_path_roofline"]["workloads"]
     for name in CHIP_NEUTRAL:
@@ -90,6 +92,20 @@ def test_the_new_entries_are_the_issues():
         "wsi4-u16-t1024", "plate3-u16-p2048", "stock4-u16-t256",
         "cycif40-u16-t1024", "jump5-u16-p1080"]
     assert bench["run_seconds"] == 51
+
+
+def test_the_queue_wait_metric_lists_the_fleet_cell_alone():
+    bench = _json("BENCHMARK.json")
+    entry = bench["per_layer"][-1]
+    assert entry == {
+        "name": QUEUE_WAIT, "unit": "ms", "better": "lower",
+        "source": "program_span", "layer": "fleet router",
+        "moves": "p50_ms", "workloads": [CELL]}
+    spec = _json("benchmark", "layer_metrics", QUEUE_WAIT + ".json")
+    assert (spec["reader"], spec["args"]) == ("span_mean",
+                                              {"span": "fleet.queueWait"})
+    assert {k: spec[k] for k in ("layer", "unit", "moves", "source")} == {
+        k: entry[k] for k in ("layer", "unit", "moves", "source")}
 
 
 def test_the_configuration_is_wsi4s_on_a_larger_slide():
@@ -453,6 +469,9 @@ def test_rehearsal_traced_line_reads_the_cells_metrics(
     assert value["rawcache_hit_share"] == 100.0
     assert value["fleet_hop_ms"] > 0.0
     assert 0.0 <= value["fleet_steal_share"] < 100.0
+    # Every member admits what its batcher groups (32 renders), more
+    # than the cell holds in flight: each request starts at once.
+    assert 0.0 <= value[QUEUE_WAIT] < 10.0
 
 
 def test_rehearsal_every_member_on_device_0_reads_100(

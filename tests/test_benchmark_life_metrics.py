@@ -90,11 +90,11 @@ def test_the_fifteen_entries_are_the_tables_appended_in_its_order():
     cells = [w["name"] for w in bench["workloads"]][:7]
     fleet = "fleet4-wsi4-u16-t1024.rewindow"
     # PR 37 appended one more behind them (``entropy_pooled_share``),
-    # PR 38 four.
+    # the four-chip cell's four, then its queue wait.
     mine = [dict(m, workloads=[w for w in m["workloads"] if w != fleet])
             for m in bench["per_layer"][31:31 + 15]]
     assert [m["name"] for m in mine] == list(METRICS)
-    assert len(bench["per_layer"]) == 31 + 15 + 1 + 4
+    assert len(bench["per_layer"]) == 31 + 15 + 1 + 4 + 1
     layers = {m["layer"] for m in bench["per_layer"][:31]}
     for entry in mine:
         layer, source, moves, reader, listed = METRICS[entry["name"]]

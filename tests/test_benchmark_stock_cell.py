@@ -51,7 +51,8 @@ def test_the_new_entries_are_the_issues():
         "bucket_fill_share", "stack_pad_device_ms",   # PR 34's: plates
         "source_open_ms", "idle_read_share",   # PR 36's: the scans' open
         "busiest_chip_share", "fleet_hop_ms",  # PR 38's: the fleet's
-        "fleet_steal_share", "fleet_render_roofline"}
+        "fleet_steal_share", "fleet_render_roofline",
+        "fleet_queue_wait_ms"}                 # the fleet's too
     with open(os.path.join(REPO, "benchmark", "configs",
                            "stock4-u16-t256.json")) as f:
         config = json.load(f)

@@ -379,6 +379,265 @@ class TestFleetRouter:
         asyncio.run(main())
 
 
+# ------------------------------------------------- admitted concurrency
+
+class _Gate(_FakeHandler):
+    """A handler whose renders wait for ``release``; ``running`` and
+    ``peak`` count the renders inside it at once."""
+
+    def __init__(self, name):
+        super().__init__(name)
+        self.release = asyncio.Event()
+        self.running = self.peak = 0
+
+    async def render_image_region(self, ctx, adopt_cache=True):
+        self.calls.append((ctx, adopt_cache))
+        self.running += 1
+        self.peak = max(self.peak, self.running)
+        try:
+            await self.release.wait()
+        finally:
+            self.running -= 1
+        return self.name.encode()
+
+
+def _batching_services(pipeline_depth=4, max_batch=8):
+    from types import SimpleNamespace
+
+    from omero_ms_image_region_tpu.server.batcher import BatchingRenderer
+    return SimpleNamespace(renderer=BatchingRenderer(
+        pipeline_depth=pipeline_depth, max_batch=max_batch))
+
+
+async def _settle(turns=5):
+    for _ in range(turns):
+        await asyncio.sleep(0)
+
+
+class TestAdmittedConcurrency:
+    """A member runs as many renders at once as its own batcher can
+    group (``pipeline_depth x max_batch``); a member that states no
+    capacity runs ``lane_width``."""
+
+    def setup_method(self):
+        telemetry.reset()
+
+    @pytest.mark.parametrize("batching,want", [(True, 32), (False, 3)])
+    def test_a_member_runs_its_capacity_at_once(self, batching, want):
+        async def main():
+            gate = _Gate("m0")
+            member = LocalMember(
+                "m0", gate,
+                services=_batching_services() if batching else None)
+            router = FleetRouter([member], lane_width=3)
+            try:
+                assert router.member_capacity("m0") == want
+                tasks = [asyncio.create_task(router.dispatch(_ctx(
+                    c=f"1|{i}:60000$FF0000"))) for i in range(want + 8)]
+                await _settle()
+                assert gate.running == want
+                assert router.member_inflight("m0") == want
+                assert router.member_depth("m0") == 8
+                gate.release.set()
+                assert all(await asyncio.gather(*tasks))
+                assert gate.peak == want
+                assert router.queue_depth() == 0
+            finally:
+                await router.close()
+
+        asyncio.run(main())
+
+    def test_the_lockstep_renderer_states_no_capacity(self):
+        services = _batching_services()
+        services.renderer.lockstep = True
+        member = LocalMember("m0", _FakeHandler("m0"), services=services)
+        assert member.render_capacity() is None
+        assert FleetRouter([member], lane_width=2).member_capacity(
+            "m0") == 2
+
+    def test_device_lanes_is_the_sum_of_the_members_capacities(self):
+        members = [
+            LocalMember("m0", _FakeHandler("m0"),
+                        services=_batching_services()),
+            LocalMember("m1", _FakeHandler("m1"),
+                        services=_batching_services(2, 4)),
+            LocalMember("m2", _FakeHandler("m2"))]
+        router = FleetRouter(members, lane_width=2)
+        assert [router.member_capacity(n) for n in router.order] == [
+            32, 8, 2]
+        assert router.device_lanes == 42
+
+    @pytest.mark.parametrize("pipeline_depth", [1, 4, 16])
+    def test_a_dispatch_costs_the_loop_the_same_at_any_capacity(
+            self, pipeline_depth, monkeypatch):
+        """Counted by ``_pop_work``'s calls: one when the dispatch
+        starts its render, one when the render finds nothing more to
+        take; while the member is busy, one a dispatch.  No task sits
+        idle, so none is woken."""
+        async def main():
+            gate = _Gate("m0")
+            member = LocalMember(
+                "m0", gate,
+                services=_batching_services(pipeline_depth, 8))
+            router = FleetRouter([member, LocalMember(
+                "m1", _FakeHandler("m1"))], lane_width=1,
+                steal_min_backlog=2)
+            pops = []
+            real = router._pop_work
+            monkeypatch.setattr(router, "_pop_work",
+                                lambda name: pops.append(name)
+                                or real(name))
+            ctxs = [_ctx(c=f"1|{i}:60000$FF0000") for i in range(4)]
+            assert {router.owner_of(c) for c in ctxs} == {"m0"}
+            try:
+                tasks = []
+                for c in ctxs:
+                    before = len(pops)
+                    tasks.append(asyncio.create_task(router.dispatch(c)))
+                    await _settle()
+                    assert len(pops) - before == 1
+                gate.release.set()
+                await asyncio.gather(*tasks)
+                await _settle()
+                # Each of the four tasks found nothing more.
+                assert pops == ["m0"] * 8
+            finally:
+                await router.close()
+
+        asyncio.run(main())
+
+    def test_a_full_members_oldest_unit_is_stolen_past_the_backlog(self):
+        """Owner full (capacity 1, busy): its queue fills to
+        ``steal_min_backlog`` and the peer with room takes the OLDEST
+        unit, without adopting cache ownership; the newer one waits
+        for its owner."""
+        async def main():
+            owner, peer = _Gate("m0"), _Gate("m1")
+            router = FleetRouter(
+                [LocalMember("m0", owner), LocalMember("m1", peer)],
+                lane_width=1, steal_min_backlog=2)
+            ctxs = [_ctx(c=f"1|{i}:60000$FF0000") for i in range(3)]
+            assert {router.owner_of(c) for c in ctxs} == {"m0"}
+            try:
+                tasks = []
+                for c in ctxs[:2]:
+                    tasks.append(asyncio.create_task(router.dispatch(c)))
+                    await _settle()
+                # One running on the owner, one queued: under the bar.
+                assert [c for c, _ in owner.calls] == [ctxs[0]]
+                assert peer.calls == []
+                tasks.append(asyncio.create_task(router.dispatch(
+                    ctxs[2])))
+                await _settle()
+                assert peer.calls == [(ctxs[1], False)]
+                assert router.member_depth("m0") == 1
+                assert telemetry.FLEET.totals()["stolen"] == 1
+                owner.release.set()
+                peer.release.set()
+                assert await asyncio.gather(*tasks) == [b"m0", b"m1",
+                                                        b"m0"]
+                assert owner.calls[-1] == (ctxs[2], True)
+            finally:
+                await router.close()
+
+        asyncio.run(main())
+
+    def test_capacity_holds_under_random_load_with_steals(self):
+        """Stress: 600 renders of random length over members of
+        capacity 32, 8, 1 and 1, stealing on; no member ever runs more
+        than its capacity, every waiter gets its member's bytes, and
+        every place is given back."""
+        import random
+
+        class _Counting(_FakeHandler):
+            def __init__(self, name, cap, rng):
+                super().__init__(name)
+                self.cap, self.rng = cap, rng
+                self.running = self.peak = 0
+
+            async def render_image_region(self, ctx, adopt_cache=True):
+                self.running += 1
+                self.peak = max(self.peak, self.running)
+                assert self.running <= self.cap
+                try:
+                    for _ in range(self.rng.randrange(4)):
+                        await asyncio.sleep(0)
+                    await asyncio.sleep(self.rng.random() * 0.002)
+                finally:
+                    self.running -= 1
+                return self.name.encode()
+
+        async def main():
+            rng = random.Random(39)
+            caps = [32, 8, 1, 1]
+            handlers = [_Counting(f"m{i}", c, rng)
+                        for i, c in enumerate(caps)]
+            members = [LocalMember(
+                f"m{i}", handlers[i],
+                services=_batching_services(4, 8) if i == 0
+                else _batching_services(2, 4) if i == 1 else None)
+                for i in range(4)]
+            router = FleetRouter(members, lane_width=1,
+                                 steal_min_backlog=2)
+            try:
+                out = await asyncio.wait_for(asyncio.gather(*(
+                    router.dispatch(_ctx(
+                        tile=f"0,{rng.randrange(6)},{rng.randrange(6)},"
+                             "128,128", c=f"1|{i}:60000$FF0000"))
+                    for i in range(600))), timeout=60)
+                assert len(out) == 600
+                assert all(b in (b"m0", b"m1", b"m2", b"m3") for b in out)
+                assert [h.peak <= h.cap for h in handlers] == [True] * 4
+                assert telemetry.FLEET.totals()["stolen"] > 0
+                await _settle()
+                assert router._running == {f"m{i}": 0 for i in range(4)}
+                assert router.queue_depth() == 0
+            finally:
+                await router.close()
+
+        asyncio.run(main())
+
+    def test_queue_wait_is_a_series_and_a_span_on_the_trace(self):
+        from omero_ms_image_region_tpu.utils.stopwatch import (
+            REGISTRY, span_lines)
+
+        async def main():
+            gate = _Gate("m0")
+            router = FleetRouter([LocalMember("m0", gate)], lane_width=1)
+            try:
+                tids = [telemetry.new_trace_id() for _ in range(2)]
+                tasks = []
+                for i, tid in enumerate(tids):
+                    with telemetry.trace_scope(tid, "drill"):
+                        tasks.append(asyncio.create_task(router.dispatch(
+                            _ctx(c=f"1|{i}:60000$FF0000"))))
+                await _settle()
+                await asyncio.sleep(0.05)
+                gate.release.set()
+                await asyncio.gather(*tasks)
+                waits = [[s for s in telemetry.TRACES.finish(tid)
+                          .export_spans()
+                          if s["name"] == "fleet.queueWait"]
+                         for tid in tids]
+            finally:
+                await router.close()
+            return waits
+
+        before = REGISTRY.snapshot().get("fleet.queueWait", {}).get(
+            "count", 0)
+        first, second = asyncio.run(main())
+        # The first started at once; the second waited for the first.
+        assert len(first) == len(second) == 1
+        assert first[0]["dur_ms"] < 5.0
+        assert second[0]["dur_ms"] >= 45.0
+        assert first[0]["member"] == "m0"
+        assert REGISTRY.snapshot()["fleet.queueWait"]["count"] \
+            == before + 2
+        assert any(ln.startswith(
+            'imageregion_span_count{span="fleet.queueWait"}')
+            for ln in span_lines())
+
+
 # ---------------------------------------------------------- chaos drill
 
 class TestFleetChaos:

@@ -66,7 +66,9 @@ class DeviceRawCache:
     supplies the host ndarray on miss.  Thread-safe (the render path runs
     in worker threads); the device transfer happens outside the lock, and
     concurrent misses on one key may both load — last write wins, which
-    is correct for immutable pixel data.
+    is correct for immutable pixel data.  The load that finds its key
+    resident when it inserts is counted, by its caller's ``by``
+    (``telemetry.DUPLICATE_LOADS``): the read and upload the race cost.
     """
 
     def __init__(self, max_bytes: int = 2 * 1024 * 1024 * 1024,
@@ -174,7 +176,8 @@ class DeviceRawCache:
 
     def get_or_load(self, key: Hashable, loader: Callable,
                     digest: Optional[str] = None,
-                    route_key: Optional[str] = None):
+                    route_key: Optional[str] = None,
+                    by: str = "request"):
         with self._lock:
             arr = self._entries.get(key)
             if arr is not None:
@@ -223,6 +226,7 @@ class DeviceRawCache:
                 self.channel_loads += 1
             old = self._entries.pop(key, None)
             if old is not None:
+                # Another thread loaded this key during this read.
                 self._release_bytes(key, old)
             digest = digest if self.digest_index else None
             if digest is not None:
@@ -256,10 +260,12 @@ class DeviceRawCache:
                 self.evictions += 1
                 evicted_labels.append((str(evicted_key)[:80],
                                        evicted.nbytes))
+        from ..utils import telemetry
+        if old is not None:
+            telemetry.DUPLICATE_LOADS.count(by)
         if evicted_labels:
             # Black box (outside the lock): an eviction storm right
             # before a stall is the "hot set no longer fits" signature.
-            from ..utils import telemetry
             for label, nbytes in evicted_labels:
                 telemetry.FLIGHT.record("rawcache.evict", key=label,
                                         bytes=nbytes)

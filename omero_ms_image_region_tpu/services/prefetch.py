@@ -48,6 +48,7 @@ import numpy as np
 
 from ..io.devicecache import DeviceRawCache, region_key
 from ..utils import telemetry
+from ..utils.stopwatch import stopwatch
 
 logger = logging.getLogger(__name__)
 
@@ -332,7 +333,9 @@ class TilePrefetcher:
     def _load(self, src, cache, missing, route, z: int, t: int,
               level: int, region, token) -> None:
         """Stage the ``missing`` (channel, key) planes of one predicted
-        tile.  ``staged`` and the predictive hits count planes."""
+        tile, under span ``prefetch.stage`` (on this pool thread's host
+        line too: the device's idle gaps can be put down to it).
+        ``staged`` and the predictive hits count planes."""
         try:
             # Budget changes bind QUEUED work too: an item whose turn
             # comes after the budget hit zero exits without touching
@@ -342,18 +345,21 @@ class TilePrefetcher:
                 telemetry.PREFETCH.count_skipped("paused")
                 return
 
-            for c, key in missing:
-                loaded = [False]
+            with stopwatch("prefetch.stage", tiles=1,
+                           planes=len(missing)):
+                for c, key in missing:
+                    loaded = [False]
 
-                def loader(c=c, loaded=loaded) -> np.ndarray:
-                    loaded[0] = True
-                    return src.get_region(z, c, t, region, level)
+                    def loader(c=c, loaded=loaded) -> np.ndarray:
+                        loaded[0] = True
+                        return src.get_region(z, c, t, region, level)
 
-                cache.get_or_load(key, loader, route_key=route)
-                if loaded[0]:
-                    self.staged += 1
-                    telemetry.PREFETCH.count_staged()
-                    self._mark_staged(key)
+                    cache.get_or_load(key, loader, route_key=route,
+                                      by="prefetch")
+                    if loaded[0]:
+                        self.staged += 1
+                        telemetry.PREFETCH.count_staged()
+                        self._mark_staged(key)
         except Exception as e:  # best-effort: foreground re-reads on miss
             logger.debug("prefetch failed for %s: %r", token, e)
         finally:

@@ -36,7 +36,8 @@ What the summary states, and by which rule:
   spans goes to the earlier class): a group's own spans first, then
   what the threads between groups do (a read of the store, the open of
   a pixel source and its forced collection, a request's accounting on
-  the event loop).  What is left goes to ``no_group`` where no
+  the event loop, the prefetcher staging a predicted tile on its own
+  thread).  What is left goes to ``no_group`` where no
   ``batcher.group`` was alive either (no group and no listed span: the
   event loop's own work of preparing requests and speaking HTTP, or
   true silence) and to ``unattributed`` where one was.  The classes sum
@@ -73,7 +74,7 @@ COPY = "wire.d2h"
 IDLE_ORDER = (COMPILE, "device.dispatch", "batcher.stage",
               "batcher.laneWait", COPY, "jfif.encodeBatch", WAIT,
               "PixelsService.readRegion", "PixelsService.openSource",
-              "PixelsService.gcDrain", "http.account")
+              "PixelsService.gcDrain", "http.account", "prefetch.stage")
 # No ``batcher.group`` alive and no span of IDLE_ORDER running: the
 # event loop's own work (prepare, HTTP), or true silence.
 NO_GROUP = "no_group"

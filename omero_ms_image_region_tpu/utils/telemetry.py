@@ -1296,6 +1296,42 @@ class DeviceRenderStats:
 DEVICE_RENDERS = DeviceRenderStats()
 
 
+class DuplicateLoadStats:
+    """Channel planes that ``DeviceRawCache.get_or_load`` read and
+    uploaded although another thread had loaded the same key during
+    this read (the cache has no single flight: both loads ran, the
+    later insert replaced the earlier one), by who lost the race:
+    ``prefetch`` (``services.prefetch``'s pool) or ``request`` (every
+    other caller).  ``/metrics
+    imageregion_rawcache_duplicate_loads_total{by=...}``; both series
+    always present."""
+
+    BY = ("prefetch", "request")
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.reset()
+
+    def reset(self) -> None:
+        with self._lock:
+            self.counts = dict.fromkeys(self.BY, 0)
+
+    def count(self, by: str) -> None:
+        with self._lock:
+            self.counts[by] += 1
+
+    def metric_lines(self, extra_labels: str = "") -> List[str]:
+        extra = extra_labels.lstrip(",")
+        with self._lock:
+            return [
+                "imageregion_rawcache_duplicate_loads_total"
+                f"{{by=\"{by}\"" + (f",{extra}" if extra else "")
+                + f"}} {n}" for by, n in self.counts.items()]
+
+
+DUPLICATE_LOADS = DuplicateLoadStats()
+
+
 def capture_profile(directory: str, ms: float) -> dict:
     """Wrap ``jax.profiler`` around whatever the device is doing for
     ``ms`` milliseconds; returns the artifact manifest with the
@@ -3603,6 +3639,7 @@ METRIC_TYPES: Dict[str, str] = {
     "imageregion_rawcache_evictions": "counter",
     "imageregion_rawcache_bytes": "gauge",
     "imageregion_rawcache_channel_loads_total": "counter",
+    "imageregion_rawcache_duplicate_loads_total": "counter",
     "imageregion_pixel_sources_opened_total": "counter",
     "imageregion_pixel_sources_open": "gauge",
     "imageregion_planecache_hits": "counter",
@@ -3864,6 +3901,9 @@ METRIC_HELP: Dict[str, str] = {
     "imageregion_rawcache_channel_loads_total":
         "Channel planes read (or handed over) and uploaded to the HBM "
         "raw cache",
+    "imageregion_rawcache_duplicate_loads_total":
+        "Channel planes read and uploaded to the HBM raw cache while "
+        "another thread loaded the same key, by who lost the race",
     "imageregion_pixel_sources_opened_total":
         "Pixel sources constructed: lookups the LRU of open sources "
         "missed",
@@ -4418,6 +4458,8 @@ def device_metric_lines(services, extra_labels: str = "") -> List[str]:
     lines += ROUTES.metric_lines(extra_labels)
     # Which chip ran them.
     lines += DEVICE_RENDERS.metric_lines(extra_labels)
+    # Loads of a raw-cache key that another thread loaded meanwhile.
+    lines += DUPLICATE_LOADS.metric_lines(extra_labels)
     # Warm-state persistence tier (disk byte cache, snapshot engine,
     # boot rehydrator) — device-side state, merged like the rest.
     lines += PERSIST.metric_lines(extra_labels)
@@ -4477,6 +4519,7 @@ def reset() -> None:
     PROFILE.reset()
     ROUTES.reset()
     DEVICE_RENDERS.reset()
+    DUPLICATE_LOADS.reset()
     PERSIST.reset()
     WIRE.reset()
     FLEET.reset()

@@ -4,9 +4,11 @@ from tier-1 over a rehearsal that holds EVERY configuration of
 
 ``benchmark/tests/conftest.py`` builds the rehearsal from
 ``rehearsal/overrides.json``, which has no entry for the deployments
-later PRs added (``stock4-u16-t256``, PR 28; ``cycif40-u16-t1024``,
-PR 32; ``jump5-u16-p1080``, PR 34): a PR may add files under ``benchmark/`` and edit none, so the
-entries sit beside it in ``overrides_<deployment>.json`` and every user
+added after it (``stock4-u16-t256``, ``cycif40-u16-t1024``,
+``jump5-u16-p1080``, ``fleet4-wsi4-u16-t1024``,
+``wsi4-u16-t1024x24``): a change that is not one of the harness itself
+adds files under ``benchmark/`` and edits none, so the entries sit
+beside it in ``overrides_<deployment>.json`` and every user
 of the harness's ``rehearsal_root`` fixture errors when
 ``benchmark/tests`` is run by itself, until a ``benchmark`` PR merges
 the files.  Until then the users run here: the harness's builder, its
@@ -14,8 +16,10 @@ test functions and its planted faults are loaded by path and called
 with the merged rehearsal, so nothing of them is copied
 (``tests/test_benchmark_rehearsal.py``: the cells the harness had;
 ``tests/test_benchmark_stock_cell.py``,
-``tests/test_benchmark_toggle_cell.py`` and
-``tests/test_benchmark_jump_cell.py``: the new ones).  A rehearsal
+``tests/test_benchmark_toggle_cell.py``,
+``tests/test_benchmark_jump_cell.py``,
+``tests/test_benchmark_fleet_cell.py`` and
+``tests/test_benchmark_coldpan_cell.py``: the new ones).  A rehearsal
 gives counts, never speeds.
 """
 
@@ -35,6 +39,8 @@ TINY_SINGLE_CELL = "tinystock4-u16-t64.single"
 JUMP_CELL, TINY_JUMP_CELL = "jump5-u16-p1080.scan", "tinyjump5-u16-p120.scan"
 SINGLE1024_CELL = "wsi4-u16-t1024.single"
 TINY_SINGLE1024_CELL = "tiny4-u16-t64.single"
+COLDPAN_CELL = "wsi4-u16-t1024x24.coldpan"
+TINY_COLDPAN_CELL = "tinywsi4x24-u16-t64.coldpan"
 
 # The harness's rehearsal tests (benchmark/tests/test_rehearsal.py):
 # those it runs once a cell, and those it runs on its first cell only
@@ -88,6 +94,11 @@ def build_rehearsal(tmp_path_factory) -> str:
         for key in ("configs", "traffic"):
             assert not set(over[key]) & set(more[key]), path
             over[key].update(more[key])
+    # ``conftest`` renames configurations by substring, in the order it
+    # meets them: a name that holds another (``wsi4-u16-t1024x24``,
+    # ``fleet4-wsi4-u16-t1024``) has to go before the one it holds.
+    over["configs"] = dict(sorted(over["configs"].items(),
+                                  key=lambda item: -len(item[0])))
     for mix, sets in STEADY.items():
         over["traffic"][mix].update(sets)
     with open(staged / "rehearsal" / "overrides.json", "w") as f:

@@ -88,13 +88,17 @@ def test_the_fifteen_entries_are_the_tables_appended_in_its_order():
     # The seven cells of its time; PR 38 appended a four-chip one and
     # put it on some of these lists.
     cells = [w["name"] for w in bench["workloads"]][:7]
-    fleet = "fleet4-wsi4-u16-t1024.rewindow"
+    # Cells appended later, on some of these lists: the four-chip one
+    # and the cold pan.
+    later = set(w["name"] for w in bench["workloads"][7:])
     # PR 37 appended one more behind them (``entropy_pooled_share``),
-    # the four-chip cell's four, then its queue wait.
-    mine = [dict(m, workloads=[w for w in m["workloads"] if w != fleet])
+    # the four-chip cell's four, then its queue wait; the cold pan's
+    # four came last.
+    mine = [dict(m, workloads=[w for w in m["workloads"]
+                               if w not in later])
             for m in bench["per_layer"][31:31 + 15]]
     assert [m["name"] for m in mine] == list(METRICS)
-    assert len(bench["per_layer"]) == 31 + 15 + 1 + 4 + 1
+    assert len(bench["per_layer"]) == 31 + 15 + 1 + 4 + 1 + 4
     layers = {m["layer"] for m in bench["per_layer"][:31]}
     for entry in mine:
         layer, source, moves, reader, listed = METRICS[entry["name"]]
@@ -208,12 +212,14 @@ def test_entropy_pooled_share_is_appended_and_reads_the_tails_counter():
     the reader ``plane_stack_share`` uses; nothing from a server
     without the family (the parent), 0 where every group is of one."""
     bench = _bench()
-    # The seven cells of its time; PR 38 appended four metrics after it.
+    # The seven cells of its time, and the cold pan appended to them;
+    # later metrics came after it.
     cells = [w["name"] for w in bench["workloads"]][:7]
     assert bench["per_layer"][31 + 15] == {
         "name": "entropy_pooled_share", "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "host entropy tail",
-        "moves": "p50_ms", "workloads": cells}
+        "moves": "p50_ms",
+        "workloads": cells + ["wsi4-u16-t1024x24.coldpan"]}
     entropy_ms = {m["name"]: m for m in bench["per_layer"]}["entropy_ms"]
     assert (entropy_ms["layer"], entropy_ms["moves"]) == (
         "host entropy tail", "p50_ms")

@@ -52,7 +52,9 @@ def test_the_new_entries_are_the_issues():
         "source_open_ms", "idle_read_share",   # PR 36's: the scans' open
         "busiest_chip_share", "fleet_hop_ms",  # PR 38's: the fleet's
         "fleet_steal_share", "fleet_render_roofline",
-        "fleet_queue_wait_ms"}                 # the fleet's too
+        "fleet_queue_wait_ms",                 # the fleet's too
+        "prefetch_stage_ms", "prefetch_used_share",   # the cold pan's
+        "rawcache_dup_load_share", "idle_prefetch_share"}
     with open(os.path.join(REPO, "benchmark", "configs",
                            "stock4-u16-t256.json")) as f:
         config = json.load(f)

@@ -164,6 +164,26 @@ class TestExpositionBudget:
         assert any(family in f
                    for f in lint.lint_exposition(smuggled, budget))
 
+    def test_duplicate_loads_family_lints_clean(self, lint, budget):
+        """The raw cache's lost load races: budgeted under ``by``,
+        typed, one HELP, both series from the first scrape."""
+        family = "imageregion_rawcache_duplicate_loads_total"
+        telemetry.DUPLICATE_LOADS.count("prefetch")
+        text = telemetry.finalize_exposition([
+            line for line in telemetry.device_metric_lines(None)
+            if line.startswith(family)])
+        assert f'\n{family}{{by="prefetch"}} 1\n' in text
+        assert f'\n{family}{{by="request"}} 0\n' in text
+        assert f"# TYPE {family} counter\n" in text
+        assert text.count(f"# HELP {family} ") == 1
+        assert budget["families"][family] == {"labels": ["by"]}
+        assert budget["label_bounds"]["by"] == len(
+            telemetry.DUPLICATE_LOADS.BY)
+        assert lint.lint_exposition(text, budget) == []
+        smuggled = text + f'{family}{{by="prefetch",member="m0"}} 1\n'
+        assert any(family in f
+                   for f in lint.lint_exposition(smuggled, budget))
+
     def test_every_idle_class_fits_the_during_bound(self, budget):
         from omero_ms_image_region_tpu.utils import profile_summary as ps
         classes = set(ps.IDLE_ORDER) | {ps.NO_GROUP, ps.UNATTRIBUTED}

@@ -176,9 +176,39 @@ def test_a_gap_goes_to_the_reading_threads_before_it_goes_to_nobody():
         s["traced_ms"] - s["busy_ms"])
     assert ps.IDLE_ORDER[7:] == (
         "PixelsService.readRegion", "PixelsService.openSource",
-        "PixelsService.gcDrain", "http.account")
+        "PixelsService.gcDrain", "http.account", "prefetch.stage")
     assert set(ps.IDLE_ORDER) <= ps.HOST_SPANS
     assert s["host_spans"]["PixelsService.readRegion"]["count"] == 3
+
+
+def test_a_gap_under_the_prefetcher_alone_is_put_down_to_it():
+    """The prefetcher's staging, on its own thread, comes after every
+    class the order had: alone under a gap it takes what went to
+    ``no_group``; beside a request's read the read keeps the gap."""
+    device, host = hand_made_capture()
+    before = ps.summarize(device, host)["idle_ms"]
+    host = host + [
+        # No group alive: the prefetcher alone [220, 250), then beside
+        # a read [255, 285) that holds its [260, 280).
+        _host("p0", "prefetch.stage", 220, 30, tiles=1, planes=4),
+        _host("p1", "prefetch.stage", 260, 20, tiles=1, planes=4),
+        _host("r0", "PixelsService.readRegion", 255, 30),
+        # One under a busy stretch takes nothing.
+        _host("p0", "prefetch.stage", 100, 30, tiles=1, planes=4),
+    ]
+    s = ps.summarize(device, host)
+    assert ps.IDLE_ORDER[-1] == "prefetch.stage"
+    assert "prefetch.stage" in ps.HOST_SPANS
+    moved = {"prefetch.stage", "PixelsService.readRegion", "no_group"}
+    assert {k: v for k, v in s["idle_ms"].items() if k not in moved} \
+        == pytest.approx({k: v for k, v in before.items()
+                          if k not in moved})
+    assert s["idle_ms"]["prefetch.stage"] == pytest.approx(30)
+    assert s["idle_ms"]["PixelsService.readRegion"] == pytest.approx(30)
+    assert s["idle_ms"]["no_group"] == pytest.approx(90 - 30 - 30)
+    assert sum(s["idle_ms"].values()) == pytest.approx(
+        s["traced_ms"] - s["busy_ms"])
+    assert s["host_spans"]["prefetch.stage"]["count"] == 3
 
 
 def test_a_compile_takes_a_gap_before_the_dispatch_it_lies_in():

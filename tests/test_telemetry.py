@@ -520,6 +520,9 @@ _ALLOWED_LABEL_KEYS = frozenset({
     # The chip that ran a render, or that a fleet member holds (PR 38):
     # a JAX device id of this process, as many as the host's chips.
     "device",
+    # Who lost a raw-cache load race: "prefetch" or "request", the two
+    # keys of ``telemetry.DUPLICATE_LOADS``.
+    "by",
 })
 
 

@@ -160,6 +160,17 @@ def _configure_jpegenc(lib: ctypes.CDLL) -> None:
         ctypes.c_int, ctypes.c_int,
         ctypes.c_void_p, ctypes.c_void_p,
     ]
+    lib.jpeg_split_begin.restype = ctypes.c_longlong
+    lib.jpeg_split_begin.argtypes = [
+        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_size_t,
+        ctypes.c_int, ctypes.c_int, ctypes.c_int, ctypes.c_int,
+        ctypes.c_int,
+    ]
+    lib.jpeg_split_work.restype = ctypes.c_int
+    lib.jpeg_split_work.argtypes = [ctypes.c_void_p]
+    lib.jpeg_split_finish.restype = ctypes.c_void_p
+    lib.jpeg_split_finish.argtypes = [
+        ctypes.c_void_p, ctypes.POINTER(ctypes.c_longlong)]
 
 
 def _configure_jpegdec(lib: ctypes.CDLL) -> None:
@@ -474,6 +485,36 @@ def jpeg_encode_sparse_run(rows, dims, quality: int, cap: int) -> list:
             scratch, n, bufs, lens, widths, heights, quality, cap, rc, off)
         return [ctypes.string_at(out + off[i], rc[i]) if rc[i] >= 0
                 else int(rc[i]) for i in range(n)]
+
+
+def jpeg_encode_sparse_split(row, dims, quality: int, cap: int,
+                             segments: int, fan):
+    """JFIF-encode one tile from its sparse wire row in ``segments``
+    segments of whole MCU rows (no more than the tile has rows), byte
+    for byte what :func:`jpeg_encode_sparse_run` gives for the row.
+
+    ``fan(n, work)`` codes the ``n`` segments: it calls ``work()`` on
+    this thread and on as many others as it likes, at once, and returns
+    when every call has returned (``utils.entropypool.EntropyPool.
+    code_segments``).  A ``work()`` call is one native call that gives
+    the GIL up: it claims segments until none is left, the tables built
+    once between the segments' two passes, and returns how many it
+    coded.  Returns the ``bytes``, or the coder's return code where it
+    gave none, as :func:`jpeg_encode_sparse_run`.
+    """
+    import numpy as np
+    lib = _load_jpeg()
+    row = np.ascontiguousarray(row, dtype=np.uint8)
+    width, height = dims
+    with _scratch(lib) as scratch:
+        n = lib.jpeg_split_begin(scratch, row.ctypes.data, row.size,
+                                 width, height, quality, cap, segments)
+        if n < 0:
+            return int(n)
+        fan(n, lambda: lib.jpeg_split_work(scratch))
+        size = ctypes.c_longlong()
+        out = lib.jpeg_split_finish(scratch, ctypes.byref(size))
+        return ctypes.string_at(out, size.value) if out else -1
 
 
 def jpeg_encode_sparse_native(buf, width: int, height: int, quality: int,

@@ -1613,20 +1613,24 @@ def finish_sparse_to_jpegs(bufs, dims, H: int, W: int, quality: int,
     from the top-left block subgrid, and tiles that overflowed ``cap``
     re-render through ``dense_coefficients(i) -> (y, cb, cr)``.
 
-    The tiles are coded in runs, side by side: by this thread and by
-    the process's coding threads (``utils.entropypool``), a tail of one
-    run by this thread alone.  ``on_tile(i, bytes)`` fires once a tile,
-    the moment its run has returned, FROM THE THREAD THAT CODED IT and
-    in no promised order.  A tile that needs the dense path takes it on
-    the thread that found it.  The first exception of any tile is raised
-    from here once every thread has stopped; tiles not yet coded then
-    stay uncoded and fire nothing.
+    The tiles are coded in runs, side by side, by this thread and the
+    process's coding threads (``utils.entropypool``); in a tail of one
+    run coded alone, each sparse-path tile is cut into segments of MCU
+    rows coded side by side (``EntropyPool.segments``; the same
+    bytes).  ``on_tile(i, bytes)`` fires once a tile, when its run has
+    returned, FROM THE THREAD THAT CODED IT (a split tile's: the one
+    that spliced it), in no promised order.  A dense-path tile is coded
+    by the thread that found it.  The first exception is raised here
+    once every thread has stopped; tiles not yet coded fire nothing.
     """
     from ..utils import entropypool
 
+    pool = entropypool.pool()
+    n = len(dims)
+    segments = pool.segments(n, H // 16, W // 16)
+    _encode_split = sparse_split_encoder() if segments > 1 else None
     _encode_run = sparse_run_encoder()
     _dense_encode = dense_encoder()
-    n = len(dims)
     exact = [(h_ + 15) // 16 * 16 == H and (w_ + 15) // 16 * 16 == W
              for (w_, h_) in dims]
     out = [None] * n
@@ -1640,11 +1644,18 @@ def finish_sparse_to_jpegs(bufs, dims, H: int, W: int, quality: int,
             slice_block_subgrid(*dense, H, W, w_, h_)
         return _dense_encode(y, cb, cr, w_, h_, quality)
 
-    def code_run(run: range) -> None:
+    def code_run(run: range) -> set:
         rows = [i for i in run if exact[i]]
-        coded = dict(zip(rows, _encode_run(
-            [bufs[i] for i in rows], [dims[i] for i in rows],
-            quality, cap)))
+        coders = set()                  # the threads that coded segments
+        if _encode_split is None:
+            coded = dict(zip(rows, _encode_run(
+                [bufs[i] for i in rows], [dims[i] for i in rows],
+                quality, cap)))
+        else:
+            coded = {i: _encode_split(
+                bufs[i], dims[i], quality, cap, segments,
+                lambda k, work: coders.update(pool.code_segments(k, work)))
+                for i in rows}
         for i in run:
             body = coded.get(i, -2)
             if body == -2:
@@ -1654,8 +1665,9 @@ def finish_sparse_to_jpegs(bufs, dims, H: int, W: int, quality: int,
             out[i] = body
             if on_tile is not None:
                 on_tile(i, body)
+        return coders
 
-    entropypool.pool().code(n, H * W, code_run)
+    pool.code(n, H * W, code_run)
     return out
 
 
@@ -1707,3 +1719,14 @@ def slice_block_subgrid(y, cb, cr, grid_h: int, grid_w: int,
     return (np.ascontiguousarray(y).reshape(-1, 64),
             np.ascontiguousarray(cb).reshape(-1, 64),
             np.ascontiguousarray(cr).reshape(-1, 64))
+
+
+def sparse_split_encoder():
+    """The sparse entropy coder of one tile in segments
+    (``native.jpeg_encode_sparse_split``), or None where no native coder
+    was built: the Python one codes a tile whole."""
+    from ..native import jpeg_native_available
+    if jpeg_native_available():
+        from ..native import jpeg_encode_sparse_split
+        return jpeg_encode_sparse_split
+    return None

@@ -3657,6 +3657,7 @@ METRIC_TYPES: Dict[str, str] = {
     "imageregion_batcher_group_stacks_total": "counter",
     "imageregion_batcher_bucket_px_total": "counter",
     "imageregion_entropy_tiles_total": "counter",
+    "imageregion_entropy_split_tiles_total": "counter",
     "imageregion_renders_routed_total": "counter",
     "imageregion_device_renders_total": "counter",
     "imageregion_batcher_queue_wait_max_ms": "gauge",
@@ -3898,6 +3899,9 @@ METRIC_HELP: Dict[str, str] = {
         "JPEG tiles entropy-coded, by path: pooled in a group's tail "
         "that several threads coded, inline in one its own thread "
         "coded alone",
+    "imageregion_entropy_split_tiles_total":
+        "JPEG tiles entropy-coded in more than one segment of MCU rows, "
+        "the segments side by side",
     "imageregion_rawcache_channel_loads_total":
         "Channel planes read (or handed over) and uploaded to the HBM "
         "raw cache",
@@ -4438,6 +4442,8 @@ def device_metric_lines(services, extra_labels: str = "") -> List[str]:
                      f"{label(body)} {n}")
     lb = label()
     lines += [
+        f"imageregion_entropy_split_tiles_total{lb} "
+        f"{entropypool.SPLIT_TILES}",
         f"imageregion_compile_events_total{lb} {COMPILE.events}",
         f"imageregion_compile_ms_total{lb} "
         f"{round(COMPILE.total_ms, 3)}",
@@ -4520,6 +4526,7 @@ def reset() -> None:
     ROUTES.reset()
     DEVICE_RENDERS.reset()
     DUPLICATE_LOADS.reset()
+    entropypool.reset_counts()
     PERSIST.reset()
     WIRE.reset()
     FLEET.reset()

@@ -124,11 +124,12 @@ def pytest_collection_modifyitems(config, items):
 @pytest.fixture
 def coding_pool(monkeypatch):
     """A pool of three coding threads in the process's place, and its
-    counter from zero; yields ``utils.entropypool``."""
+    counters from zero; yields ``utils.entropypool``."""
     from omero_ms_image_region_tpu.utils import entropypool
 
     pool = entropypool.EntropyPool(3)
     monkeypatch.setattr(entropypool, "_POOL", pool)
     monkeypatch.setattr(entropypool, "TILES", {"pooled": 0, "inline": 0})
+    monkeypatch.setattr(entropypool, "SPLIT_TILES", 0)
     yield entropypool
     pool._executor.shutdown(wait=True)

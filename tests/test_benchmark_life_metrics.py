@@ -93,12 +93,12 @@ def test_the_fifteen_entries_are_the_tables_appended_in_its_order():
     later = set(w["name"] for w in bench["workloads"][7:])
     # PR 37 appended one more behind them (``entropy_pooled_share``),
     # the four-chip cell's four, then its queue wait; the cold pan's
-    # four came last.
+    # four, then ``entropy_split_share``.
     mine = [dict(m, workloads=[w for w in m["workloads"]
                                if w not in later])
             for m in bench["per_layer"][31:31 + 15]]
     assert [m["name"] for m in mine] == list(METRICS)
-    assert len(bench["per_layer"]) == 31 + 15 + 1 + 4 + 1 + 4
+    assert len(bench["per_layer"]) == 31 + 15 + 1 + 4 + 1 + 4 + 1
     layers = {m["layer"] for m in bench["per_layer"][:31]}
     for entry in mine:
         layer, source, moves, reader, listed = METRICS[entry["name"]]
@@ -250,3 +250,45 @@ def test_entropy_pooled_share_is_appended_and_reads_the_tails_counter():
         telemetry.device_metric_lines(None)))
     for path, n in entropypool.TILES.items():
         assert live[f'{family}{{path="{path}"}}'] == float(n)
+
+
+def test_entropy_split_share_is_appended_and_reads_the_split_counter():
+    """The split tiles' metric: appended last, on the cells of
+    ``entropy_pooled_share`` (its denominator's family), a data file
+    over the reader ``rawcache_dup_load_share`` uses; nothing from a
+    server without the counter (an older one), 0 where no tile was
+    split."""
+    bench = _bench()
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    assert bench["per_layer"][-1] == {
+        "name": "entropy_split_share", "unit": "tiles/tile",
+        "better": "higher", "source": "program_counter",
+        "layer": "host entropy tail", "moves": "p50_ms",
+        "workloads": by_name["entropy_pooled_share"]["workloads"]}
+    for cell in ("wsi4-u16-t1024.single", "plate3-u16-p2048.scan",
+                 "jump5-u16-p1080.scan", "wsi4-u16-t1024.rewindow",
+                 "stock4-u16-t256.pan", "stock4-u16-t256.single"):
+        assert cell in bench["per_layer"][-1]["workloads"]
+    split = "imageregion_entropy_split_tiles_total"
+    tiles = "imageregion_entropy_tiles_total"
+    assert _spec("entropy_split_share") == {
+        "name": "entropy_split_share", "layer": "host entropy tail",
+        "unit": "tiles/tile", "moves": "p50_ms",
+        "source": "program_counter", "reader": "new_counter_ratio",
+        "args": {"numerator": [split], "denominator": [tiles]}}
+    m0 = {split: 10.0, f'{tiles}{{path="pooled"}}': 10.0,
+          f'{tiles}{{path="inline"}}': 5.0}
+    m1 = {split: 10.0 + 180.0, f'{tiles}{{path="pooled"}}': 10.0 + 190.0,
+          f'{tiles}{{path="inline"}}': 5.0 + 10.0}
+    assert _read("entropy_split_share", m0, m1) == pytest.approx(0.9)
+    stock = dict(m1, **{split: 10.0})
+    assert _read("entropy_split_share", m0, stock) == 0.0
+    assert _read("entropy_split_share", m1, m1) is None
+    older = {k: v for k, v in m1.items() if k != split}
+    assert _read("entropy_split_share", {}, older) is None
+    # The series is the program's own.
+    from benchmark.prom import parse_metrics
+    from omero_ms_image_region_tpu.utils import entropypool, telemetry
+    live = parse_metrics(telemetry.finalize_exposition(
+        telemetry.device_metric_lines(None)))
+    assert live[split] == float(entropypool.SPLIT_TILES)

@@ -22,7 +22,7 @@ from omero_ms_image_region_tpu.ops.jpegenc import (
 
 from omero_ms_image_region_tpu.native import (
     SparseOverflowError, jpeg_encode_native, jpeg_encode_sparse_native,
-    jpeg_native_available,
+    jpeg_encode_sparse_run, jpeg_native_available,
 )
 
 HAVE_NATIVE = jpeg_native_available()
@@ -1501,11 +1501,20 @@ def _wire_row(H, W, seed, per_block=6.0):
     pos = pos[pos < 64]                 # block by block, ascending
     mag = np.clip(rng.exponential(6.0, total).astype(np.int64), 1, 2047)
     val = np.where(rng.random(total) < 0.5, -mag, mag)
-    field = (pos.astype(np.uint32) << 12) | (val & 0xFFF).astype(np.uint32)
+    return _pack_wire(counts, pos, val), total
+
+
+def _pack_wire(counts, pos, val):
+    """``[total i32 | counts u8[nb] | 18-bit (pos << 12 | val)
+    entries]``: the row of blocks with ``counts`` entries each, entry by
+    entry ``pos`` and ``val``."""
+    field = ((np.asarray(pos).astype(np.uint32) << 12)
+             | (np.asarray(val).astype(np.int64) & 0xFFF).astype(np.uint32))
     bits = ((field[:, None] >> np.arange(17, -1, -1)[None, :]) & 1)
     return np.concatenate([
-        np.array([total], "<i4").view(np.uint8), counts,
-        np.packbits(bits.astype(np.uint8).ravel())]), total
+        np.array([int(np.sum(counts))], "<i4").view(np.uint8),
+        np.asarray(counts, np.uint8),
+        np.packbits(bits.astype(np.uint8).ravel())])
 
 
 def _parent_tail(bufs, dims, H, W, quality, cap, dense_coefficients,
@@ -1831,3 +1840,565 @@ def test_a_run_reads_its_rows_in_place_and_reports_each_rows_code():
     got = jpeg_encode_sparse_run([tailed[0], broken, tailed[2], tailed[3],
                                   tailed[4]], [(E, E)] * 5, 90, cap)
     assert got == [lone[0], -1, lone[2], lone[3], -2]
+
+
+# ------------------------------- one tile's tail on several threads
+
+_SPLIT_ROWS = {}
+
+
+def _split_case(E):
+    """A row of an E x E tile, its serial coder's bytes, and the Python
+    reference's (``jfif.encode_jfif`` of the row's dense coefficients),
+    made once a size."""
+    if E not in _SPLIT_ROWS:
+        row, total = _wire_row(E, E, 4100 + E)
+        serial, = jpeg_encode_sparse_run([row], [(E, E)], 90, total)
+        reference = encode_jfif(*sparse_to_dense(row, E, E, total), E, E,
+                                90)
+        _SPLIT_ROWS[E] = (row, total, serial, reference)
+    return _SPLIT_ROWS[E]
+
+
+def _in_order(n, work):
+    """The split coder's one coder: this thread (``_in_order.segments``
+    keeps the number of segments the coder cut)."""
+    _in_order.segments = n
+    work()
+
+
+def _threads(count):
+    """A fan that runs the split coder's ``work()`` on ``count`` plain
+    threads at once and returns what each coded."""
+    import threading
+
+    def fan(n, work):
+        start = threading.Barrier(count)
+        coded = [None] * count
+
+        def run(i):
+            start.wait(10.0)
+            coded[i] = work()
+
+        threads = [threading.Thread(target=run, args=(i,))
+                   for i in range(count)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(30.0)
+        assert not any(t.is_alive() for t in threads)
+        fan.coded = coded
+
+    return fan
+
+
+def _scan(jfif: bytes) -> bytes:
+    """The entropy-coded scan of a stream, its 0x00 stuffing taken out."""
+    at = jfif.index(b"\xff\xda")
+    at += 2 + int.from_bytes(jfif[at + 2:at + 4], "big")
+    return jfif[at:-2].replace(b"\xff\x00", b"\xff")
+
+
+@pytest.mark.skipif(not HAVE_NATIVE, reason="no native toolchain")
+@pytest.mark.parametrize("segments", [1, 2, 3, 8, 9])
+@pytest.mark.parametrize("E", [1024, 1088, 2048])
+def test_a_split_tile_is_the_serial_coders_bytes(E, segments):
+    """Cut into 1, 2, 3, 8 or 9 segments of whole MCU rows, a tile
+    codes to the serial coder's bytes and the Python reference's."""
+    from omero_ms_image_region_tpu.native import jpeg_encode_sparse_split
+
+    row, total, serial, reference = _split_case(E)
+    assert serial == reference
+    got = jpeg_encode_sparse_split(row, (E, E), 90, total, segments,
+                                   _in_order)
+    assert got == serial
+    assert _in_order.segments == segments
+
+
+@pytest.mark.skipif(not HAVE_NATIVE, reason="no native toolchain")
+@pytest.mark.parametrize("count", [2, 9, 16])
+@pytest.mark.parametrize("E", [1024, 2048])
+def test_many_coders_at_once_give_the_serial_coders_bytes(E, count):
+    """Two, nine or sixteen threads in the coder's ``work`` at once on
+    nine segments: every segment coded once, by whichever claimed it,
+    the tables built once between the passes: the serial bytes; and
+    after the first tile of a size no scratch grows."""
+    from omero_ms_image_region_tpu.native import (
+        jpeg_encode_sparse_split, jpeg_scratch_stats)
+
+    row, total, serial, _ = _split_case(E)
+    fan = _threads(count)
+    for i in range(3):
+        assert jpeg_encode_sparse_split(row, (E, E), 90, total, 9,
+                                        fan) == serial
+        assert sum(fan.coded) == 2 * 9     # each segment's two passes
+        if i == 0:
+            grown = jpeg_scratch_stats()["growths"]
+    assert jpeg_scratch_stats()["growths"] == grown
+    malformed, bad_total = _malformed_in_last_rows(1024)
+    assert jpeg_encode_sparse_split(malformed, (1024, 1024), 90, bad_total,
+                                    9, fan) == -1
+
+
+@pytest.mark.skipif(not HAVE_NATIVE, reason="no native toolchain")
+def test_a_stock_tile_is_never_cut():
+    """256^2 is 256 MCUs, one segment's least: every pool, however
+    large, codes a stock tile whole, and the coder asked for more
+    segments than rows gives one a row, the same bytes."""
+    from omero_ms_image_region_tpu.native import jpeg_encode_sparse_split
+    from omero_ms_image_region_tpu.utils.entropypool import (
+        SEGMENT_MCUS, EntropyPool)
+
+    assert SEGMENT_MCUS == 256
+    for threads in (0, 3, 8, 64):
+        assert EntropyPool(threads).segments(1, 16, 16) == 1
+    row, total = _wire_row(256, 256, 4141)
+    serial, = jpeg_encode_sparse_run([row], [(256, 256)], 90, total)
+    assert jpeg_encode_sparse_split(row, (256, 256), 90, total, 40,
+                                    _in_order) == serial
+    assert _in_order.segments == 16
+
+
+@pytest.mark.skipif(not HAVE_NATIVE, reason="no native toolchain")
+@pytest.mark.parametrize("segments", [2, 3, 8, 9])
+def test_the_dc_predictor_crosses_every_boundary(segments):
+    """A tile whose only coefficients are DC: every symbol but EOB is a
+    difference from the last block's DC, so a predictor lost at a
+    boundary would change the bytes."""
+    from omero_ms_image_region_tpu.native import jpeg_encode_sparse_split
+
+    E = 1024
+    nb = (E // 16) ** 2 * 6
+    rng = np.random.default_rng(41)
+    val = rng.integers(-1000, 1000, nb)
+    val[val == 0] = 7
+    counts = np.ones(nb, np.uint8)
+    row = _pack_wire(counts, np.zeros(nb, np.int64), val)
+    serial, = jpeg_encode_sparse_run([row], [(E, E)], 90, nb)
+    assert serial == encode_jfif(*sparse_to_dense(row, E, E, nb), E, E, 90)
+    assert jpeg_encode_sparse_split(row, (E, E), 90, nb, segments,
+                                    _in_order) == serial
+    # A block with no entries (DC 0) before a boundary: the predictor
+    # there is 0, not the DC of the block before it.
+    counts[(E // 16) * 4 * 32 - 1] = 0
+    keep = np.ones(nb, bool)
+    keep[(E // 16) * 4 * 32 - 1] = False
+    holed = _pack_wire(counts, np.zeros(nb - 1, np.int64), val[keep])
+    serial, = jpeg_encode_sparse_run([holed], [(E, E)], 90, nb - 1)
+    assert jpeg_encode_sparse_split(holed, (E, E), 90, nb - 1, 2,
+                                    _in_order) == serial
+
+
+def _ones_at_row_ends(E, seed):
+    """A row built to end every MCU row with 1-bits and start the next
+    with a long code: the last Cr block of each MCU row ends at position
+    63 with 1023 (ten 1-bits, no EOB), and the first luma block jumps to
+    a rare DC category (a long code of leading 1-bits)."""
+    rng = np.random.default_rng(seed)
+    h16 = w16 = E // 16
+    nb_y, nb_c = h16 * w16 * 4, h16 * w16
+    nb = nb_y + 2 * nb_c
+    dc = rng.integers(-40, 40, nb)
+    dc[[4 * r * w16 for r in range(h16)]] = 1500
+    entries = [[(0, int(v))] for v in dc]
+    for b in range(nb):
+        if rng.random() < 0.5:
+            entries[b].append((int(rng.integers(1, 20)),
+                               int(rng.integers(-30, 30)) or 1))
+    for r in range(h16):
+        entries[nb_y + nb_c + r * w16 + w16 - 1] = [(0, int(dc[0])),
+                                                     (63, 1023)]
+    counts = np.array([len(e) for e in entries], np.uint8)
+    flat = [x for e in entries for x in e]
+    return (_pack_wire(counts, [p for p, _ in flat], [v for _, v in flat]),
+            len(flat))
+
+
+def _mcu_row_starts(row, E, total):
+    """Where each MCU row of an E x E tile's scan starts, in bits, and
+    where the scan ends: each block's codes and amplitude bits under the
+    Python reference's symbols and optimal tables."""
+    from omero_ms_image_region_tpu import jfif
+
+    y, cb, cr = sparse_to_dense(row, E, E, total)
+    mcus = jfif._mcu_block_indices(E // 16, E // 16)
+    (y_rec, y_dc, y_ac), (cb_rec, cb_dc, cb_ac), (cr_rec, cr_dc, cr_ac) = [
+        jfif._component_symbols(blocks) for blocks in (
+            [y[i] for m in mcus for i in m[0]], [cb[m[1]] for m in mcus],
+            [cr[m[1]] for m in mcus])]
+
+    def lengths(freq):
+        return jfif._codes_from_table(*build_huffman_table(freq))[1]
+
+    def bits(rec, dc_len, ac_len):
+        dc_sym, _, acs = rec
+        return int(dc_len[dc_sym]) + dc_sym + sum(
+            int(ac_len[sym]) + (sym & 0x0F) for sym, _ in acs)
+
+    luma = lengths(y_dc), lengths(y_ac)
+    chroma = lengths(cb_dc + cr_dc), lengths(cb_ac + cr_ac)
+    mcu_bits = [sum(bits(r, *luma) for r in y_rec[4 * m:4 * m + 4])
+                + bits(cb_rec[m], *chroma) + bits(cr_rec[m], *chroma)
+                for m in range(len(mcus))]
+    ends = np.cumsum(mcu_bits)
+    return [0] + [int(b) for b in ends[E // 16 - 1::E // 16]]
+
+
+@pytest.mark.skipif(not HAVE_NATIVE, reason="no native toolchain")
+def test_a_0xff_across_a_segment_boundary_is_stuffed_as_the_serial_coder():
+    """Rows searched until one's spliced bits put a 0xFF byte across a
+    boundary (part of it from each segment, one an MCU row): that byte
+    is stuffed, and the stream is the serial coder's."""
+    from omero_ms_image_region_tpu.native import jpeg_encode_sparse_split
+
+    E = 256
+    for seed in range(200):
+        row, total = _ones_at_row_ends(E, 4300 + seed)
+        serial, = jpeg_encode_sparse_run([row], [(E, E)], 90, total)
+        scan = _scan(serial)
+        got = jpeg_encode_sparse_split(row, (E, E), 90, total, 16,
+                                       _in_order)
+        assert got == serial and _in_order.segments == 16
+        *offsets, end = _mcu_row_starts(row, E, total)
+        assert len(scan) == -(-end // 8)
+        across = [b for b in offsets[1:]
+                  if b % 8 and scan[b // 8] == 0xFF]
+        if across:
+            break
+    else:
+        pytest.fail("no row put a 0xFF across a boundary")
+    # The byte is stuffed in the stream: an 0xFF then 0x00 at its place.
+    ffs = scan[:across[0] // 8].count(0xFF)
+    at = serial.index(b"\xff\xda")
+    at += 2 + int.from_bytes(serial[at + 2:at + 4], "big")
+    at += across[0] // 8 + ffs
+    assert serial[at:at + 2] == b"\xff\x00"
+
+
+def _malformed_in_last_rows(E):
+    """A row whose last block's entries are not ascending (its second
+    entry at its first's position)."""
+    rng = np.random.default_rng(4142)
+    nb = (E // 16) ** 2 * 6
+    counts = np.full(nb, 3, np.uint8)
+    pos = np.tile([0, 5, 9], nb)
+    pos[-2] = 0                         # the last Cr block's second entry
+    val = rng.integers(1, 500, 3 * nb)
+    return _pack_wire(counts, pos, val), 3 * nb
+
+
+@pytest.mark.skipif(not HAVE_NATIVE, reason="no native toolchain")
+@pytest.mark.parametrize("segments", [1, 2, 9])
+def test_a_malformed_block_in_the_last_segment_gives_the_serial_code(
+        segments, coding_pool):
+    from omero_ms_image_region_tpu.native import jpeg_encode_sparse_split
+
+    E = 1024
+    row, total = _malformed_in_last_rows(E)
+    assert jpeg_encode_sparse_run([row], [(E, E)], 90, total) == [-1]
+    assert jpeg_encode_sparse_split(row, (E, E), 90, total, segments,
+                                    _in_order) == -1
+    assert jpeg_encode_sparse_split(
+        row, (E, E), 90, total, segments,
+        coding_pool._POOL.code_segments) == -1
+    # Overflow and a short row: the run coder's codes, before a segment.
+    assert jpeg_encode_sparse_split(row, (E, E), 90, total - 1,
+                                    segments, _in_order) == -2
+    assert jpeg_encode_sparse_split(row[:100], (E, E), 90, total,
+                                    segments, _in_order) == -1
+
+
+def test_a_pool_of_no_threads_codes_every_segment_on_its_caller():
+    import threading
+
+    from omero_ms_image_region_tpu.utils import entropypool
+
+    pool = entropypool.EntropyPool(0)
+    calls = []
+
+    def work():
+        calls.append(threading.get_ident())
+        return 5
+
+    assert pool.code_segments(5, work) == {threading.get_ident()}
+    assert calls == [threading.get_ident()]
+
+
+def test_segments_follow_the_grid_and_the_free_coders():
+    from omero_ms_image_region_tpu.utils.entropypool import EntropyPool
+
+    pool = EntropyPool(8)
+    try:
+        assert pool.segments(1, 64, 64) == 9        # a lone 1024^2 tile
+        assert pool.segments(1, 128, 128) == 9      # a lone 2048^2 plane
+        assert pool.segments(2, 128, 128) == 1      # two runs: not cut
+        assert pool.segments(3, 68, 68) == 1
+        assert pool.segments(9, 64, 64) == 1
+        assert pool.segments(16, 16, 16) == 1       # 256^2: never
+        assert pool.segments(1, 32, 32) == 4        # 512^2: 8 rows each
+        assert pool.segments(1, 3, 200) == 1        # 2 rows a segment
+    finally:
+        pool._executor.shutdown()
+    assert EntropyPool(0).segments(1, 64, 64) == 1
+
+
+@pytest.mark.skipif(not HAVE_NATIVE, reason="no native toolchain")
+def test_a_lone_large_tile_is_split_and_counted_once(coding_pool,
+                                                     monkeypatch):
+    """A group of one 1024^2 tile: cut into 1 + 3 segments (the pool's
+    three threads free), its coder's ``work`` entered by more than one
+    thread (the first waits for a second), counted once as split and as
+    ``pooled`` when more than one of them coded a segment; ``on_tile``
+    fires once, from the thread that spliced it, with the serial
+    coder's bytes."""
+    import threading
+
+    from omero_ms_image_region_tpu.ops.jpegenc import finish_sparse_to_jpegs
+
+    pool = coding_pool._POOL
+    real = pool.code_segments
+    seen, met, coders = set(), threading.Event(), []
+
+    def meeting(n, work):
+        assert n == 4
+
+        def met_work():
+            seen.add(threading.get_ident())
+            if len(seen) > 1:
+                met.set()
+            met.wait(10.0)
+            return work()
+
+        coders.append(real(n, met_work))
+        return coders[-1]
+
+    monkeypatch.setattr(pool, "code_segments", meeting)
+    row, total, serial, _ = _split_case(1024)
+    fired = []
+    got = finish_sparse_to_jpegs(
+        [row], [(1024, 1024)], 1024, 1024, 90, total, None,
+        on_tile=lambda i, b: fired.append((i, b, threading.get_ident())))
+    assert got == [serial]
+    assert fired == [(0, serial, threading.get_ident())]
+    assert len(seen) > 1 and len(coders) == 1 and len(coders[0]) >= 1
+    assert coding_pool.TILES == ({"pooled": 1, "inline": 0}
+                                 if len(coders[0]) > 1 else
+                                 {"pooled": 0, "inline": 1})
+    assert coding_pool.SPLIT_TILES == 1
+
+
+@pytest.mark.skipif(not HAVE_NATIVE, reason="no native toolchain")
+@pytest.mark.parametrize("n", [1, 2, 3])
+def test_a_split_tail_fires_each_tile_once_with_the_serial_bytes(
+        n, coding_pool):
+    """Groups of one to three 1024^2 tiles (a lone tile cut in four on
+    three idle threads, two or three tiles not cut): the bytes and
+    callbacks of the parent's loop, a cut tile counted once as split."""
+    from omero_ms_image_region_tpu.ops.jpegenc import finish_sparse_to_jpegs
+
+    rows, dims, cap, dense_coefficients = _tail_group(n, 1024)
+    want = _parent_tail(rows, dims, 1024, 1024, 90, cap, dense_coefficients)
+    fired = []
+    got = finish_sparse_to_jpegs(rows, dims, 1024, 1024, 90, cap,
+                                 dense_coefficients,
+                                 on_tile=lambda i, b: fired.append((i, b)))
+    assert got == want
+    assert sorted(fired) == list(enumerate(want))
+    assert coding_pool._POOL.segments(n, 64, 64) == [4, 1, 1][n - 1]
+    assert coding_pool.SPLIT_TILES == (n == 1)
+    assert sum(coding_pool.TILES.values()) == n
+
+
+@pytest.mark.skipif(not HAVE_NATIVE, reason="no native toolchain")
+def test_a_busy_pool_leaves_a_split_tile_to_its_own_thread(coding_pool):
+    """Every pool thread held by another tail: the tile is still cut,
+    its helpers never start, and its own thread codes every segment."""
+    import threading
+
+    from omero_ms_image_region_tpu.ops.jpegenc import finish_sparse_to_jpegs
+
+    release = threading.Event()
+    busy = [coding_pool._POOL._executor.submit(release.wait, 30.0)
+            for _ in range(3)]
+    try:
+        row, total, serial, _ = _split_case(1024)
+        fired = []
+        got = finish_sparse_to_jpegs(
+            [row], [(1024, 1024)], 1024, 1024, 90, total, None,
+            on_tile=lambda i, b: fired.append(threading.get_ident()))
+    finally:
+        release.set()
+    for b in busy:
+        b.result()
+    assert got == [serial]
+    assert fired == [threading.get_ident()]
+    assert coding_pool.TILES == {"pooled": 0, "inline": 1}
+    assert coding_pool.SPLIT_TILES == 1
+
+
+def test_a_tile_is_not_cut_while_another_tail_is_coded(coding_pool):
+    """While another tail is being coded (here a lone tile on its own
+    group's thread, the pool idle), a lone 1024^2 tile is not cut; once
+    that tail has returned, or failed, it is cut in four again."""
+    import threading
+
+    pool = coding_pool._POOL
+    assert pool.segments(1, 64, 64) == 4
+    entered, release = threading.Event(), threading.Event()
+
+    def code_run(run):
+        entered.set()
+        release.wait(30.0)
+        raise RuntimeError("a coder failed")
+
+    def other_tail():
+        with pytest.raises(RuntimeError):
+            pool.code(1, 1024 * 1024, code_run)
+
+    holder = threading.Thread(target=other_tail)
+    holder.start()
+    try:
+        assert entered.wait(30.0)
+        assert pool.segments(1, 64, 64) == 1
+        assert pool.segments(1, 128, 128) == 1
+    finally:
+        release.set()
+        holder.join(30.0)
+    assert not holder.is_alive()
+    assert pool.segments(1, 64, 64) == 4
+
+
+def test_a_coders_exception_is_raised_once_after_every_coder_stopped(
+        coding_pool):
+    import threading
+    import time
+
+    pool = coding_pool._POOL
+    lock, active, calls = threading.Lock(), [0], []
+
+    def work():
+        with lock:
+            active[0] += 1
+            calls.append(threading.get_ident())
+            first = len(calls) == 1
+        try:
+            time.sleep(0.05)
+            if first:
+                raise RuntimeError("a coder failed")
+            return 1
+        finally:
+            with lock:
+                active[0] -= 1
+
+    with pytest.raises(RuntimeError, match="a coder failed"):
+        pool.code_segments(4, work)
+    assert active[0] == 0
+    assert len(calls) >= 1
+    assert coding_pool.SPLIT_TILES == 0
+
+
+@pytest.mark.skipif(not HAVE_NATIVE, reason="no native toolchain")
+def test_a_failing_coder_fails_the_tail_and_its_tile_fires_nothing(
+        coding_pool, monkeypatch):
+    import omero_ms_image_region_tpu.ops.jpegenc as jpegenc
+
+    real = jpegenc.sparse_split_encoder()
+
+    def failing(row, dims, quality, cap, segments, fan):
+        def broken(n, work):
+            def work_():
+                raise RuntimeError("a coder failed")
+            return fan(n, work_)
+        return real(row, dims, quality, cap, segments, broken)
+
+    monkeypatch.setattr(jpegenc, "sparse_split_encoder", lambda: failing)
+    row, total, _, _ = _split_case(1024)
+    fired = []
+    with pytest.raises(RuntimeError, match="a coder failed"):
+        jpegenc.finish_sparse_to_jpegs(
+            [row], [(1024, 1024)], 1024, 1024, 90, total, None,
+            on_tile=lambda i, b: fired.append(i))
+    assert fired == []
+    assert coding_pool.TILES == {"pooled": 0, "inline": 0}
+
+
+@pytest.mark.skipif(not HAVE_NATIVE, reason="no native toolchain")
+def test_segments_fanned_out_from_pool_threads_do_not_deadlock(coding_pool):
+    """Every thread of the pool codes a run whose tile fans out its
+    segments into the same pool (no thread left for a helper): each
+    run's own thread codes what no helper took, and the tail ends with
+    the serial bytes."""
+    import threading
+
+    from omero_ms_image_region_tpu.native import jpeg_encode_sparse_split
+
+    pool = coding_pool._POOL
+    E = 256
+    made = [_wire_row(E, E, 4400 + i) for i in range(8)]
+    want = [jpeg_encode_sparse_run([r], [(E, E)], 90, t)[0] for r, t in made]
+    got = [None] * 8
+
+    def code_run(run):
+        for i in run:
+            row, total = made[i]
+            got[i] = jpeg_encode_sparse_split(row, (E, E), 90, total, 4,
+                                              pool.code_segments)
+
+    done = threading.Event()
+    worker = threading.Thread(
+        target=lambda: (pool.code(8, 1024 * 1024, code_run), done.set()),
+        daemon=True)
+    worker.start()
+    worker.join(60.0)
+    assert done.is_set()
+    assert got == want
+    assert coding_pool.SPLIT_TILES == 8
+    assert sum(coding_pool.TILES.values()) == 8
+
+
+@pytest.mark.skipif(not HAVE_NATIVE, reason="no native toolchain")
+def test_many_tails_splitting_at_once_lose_no_segment_and_no_count(
+        coding_pool, monkeypatch):
+    """Twelve tails at once on a pool of sixteen threads (more than the
+    cores), each run's tile cut into four segments, under a short
+    switch interval: every tile is the serial coder's bytes and no
+    count is lost."""
+    import sys
+    import threading
+
+    from omero_ms_image_region_tpu.native import jpeg_encode_sparse_split
+    from omero_ms_image_region_tpu.utils import entropypool
+
+    pool = entropypool.EntropyPool(16)
+    monkeypatch.setattr(entropypool, "_POOL", pool)
+    E, tails, runs_a_tail = 256, 12, 3
+    made = [_wire_row(E, E, 4500 + i) for i in range(runs_a_tail)]
+    want = [jpeg_encode_sparse_run([r], [(E, E)], 90, t)[0] for r, t in made]
+    got, lock = [], threading.Lock()
+
+    def code_run(run):
+        for i in run:
+            row, total = made[i]
+            body = jpeg_encode_sparse_split(row, (E, E), 90, total, 4,
+                                            pool.code_segments)
+            with lock:
+                got.append((i, body))
+
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        callers = [threading.Thread(
+            target=lambda: pool.code(runs_a_tail, 1024 * 1024, code_run),
+            daemon=True) for _ in range(tails)]
+        for t in callers:
+            t.start()
+        for t in callers:
+            t.join(60.0)
+    finally:
+        sys.setswitchinterval(switch)
+        pool._executor.shutdown(wait=False)
+    assert not any(t.is_alive() for t in callers)
+    assert len(got) == tails * runs_a_tail
+    assert all(body == want[i] for i, body in got)
+    assert entropypool.SPLIT_TILES == tails * runs_a_tail
+    assert sum(entropypool.TILES.values()) == tails * runs_a_tail

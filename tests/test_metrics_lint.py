@@ -164,6 +164,36 @@ class TestExpositionBudget:
         assert any(family in f
                    for f in lint.lint_exposition(smuggled, budget))
 
+    def test_entropy_split_family_lints_clean_and_resets(self, lint,
+                                                         budget):
+        """One label-free counter, typed, one HELP, no label keys; a
+        tile coded in segments counts once, and ``telemetry.reset()``
+        clears it."""
+        from omero_ms_image_region_tpu.utils import entropypool
+        family = "imageregion_entropy_split_tiles_total"
+
+        def scrape():
+            return telemetry.finalize_exposition([
+                line for line in telemetry.device_metric_lines(None)
+                if line.startswith(family)])
+
+        assert f"\n{family} 0\n" in scrape()
+        entropypool.EntropyPool(0).code_segments(3, lambda: 3)
+        entropypool.EntropyPool(0).code_segments(1, lambda: 1)
+        text = scrape()
+        assert f"\n{family} 1\n" in text
+        assert f"# TYPE {family} counter\n" in text
+        assert text.count(f"# HELP {family} ") == 1
+        assert telemetry.METRIC_HELP[family]
+        assert budget["families"][family] == {"labels": []}
+        assert lint.lint_exposition(text, budget) == []
+        smuggled = text + f'{family}{{path="pooled"}} 1\n'
+        assert any(family in f
+                   for f in lint.lint_exposition(smuggled, budget))
+        telemetry.reset()
+        assert entropypool.SPLIT_TILES == 0
+        assert f"\n{family} 0\n" in scrape()
+
     def test_duplicate_loads_family_lints_clean(self, lint, budget):
         """The raw cache's lost load races: budgeted under ``by``,
         typed, one HELP, both series from the first scrape."""

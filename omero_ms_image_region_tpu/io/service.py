@@ -10,7 +10,9 @@ here a data directory holds one chunked pyramid per image
 from __future__ import annotations
 
 import gc
+import logging
 import os
+import resource
 import sys
 import threading
 from collections import OrderedDict
@@ -22,13 +24,87 @@ from .ometiff import OmeTiffSource, find_tiff
 from .pixelsource import PixelSource
 from .store import ChunkedPyramidStore
 
-DEFAULT_MAX_OPEN = 128
+log = logging.getLogger(__name__)
+
+# The bound on open sources follows what the process can hold
+# (``derive_max_open``), and is never below this.  It is also the
+# constructor's default, and the most OME-TIFF sources kept open.
+MAX_OPEN_FLOOR = 128
+# What one open source is reckoned to hold.  A ChunkedPyramidStore holds
+# one descriptor and one mapping for each level it has mapped (the
+# ``mmap`` under ``np.memmap`` keeps a duplicate of the file's
+# descriptor); a ROMIO buffer one descriptor; an NGFF source none
+# between reads.  Eight is a store read at eight levels (a 32k² slide
+# down to 256²); a screening plate's field maps one.  OME-TIFF sources
+# are held to the floor instead (``PixelsService._bound_tiffs_locked``).
+SOURCE_FDS = 8
+SOURCE_MAPS = 8
+# Left to the rest of the process.  Descriptors: sockets, logs, the
+# device runtime's files, the compile cache.  Mappings: shared
+# libraries, thread stacks, malloc's arenas and large buffers (each a
+# mapping of its own), the device runtime.
+FD_RESERVE = 4096
+MAP_RESERVE = 16384
+MAX_MAP_COUNT_PATH = "/proc/sys/vm/max_map_count"
+
+
+def raise_fd_limit() -> int:
+    """Raise this process's soft ``RLIMIT_NOFILE`` to its hard limit,
+    never lowering it; return the soft limit as it stands after.  A
+    refused raise is logged and leaves the limit as it was.  The serving
+    loop waits with epoll, so descriptors above 1024 are safe."""
+    soft, hard = resource.getrlimit(resource.RLIMIT_NOFILE)
+    if hard <= soft:
+        return soft
+    try:
+        resource.setrlimit(resource.RLIMIT_NOFILE, (hard, hard))
+    except (ValueError, OSError) as e:
+        log.warning("could not raise RLIMIT_NOFILE from %d to %d: %s",
+                    soft, hard, e)
+        return soft
+    return hard
+
+
+def read_max_map_count(path: str = MAX_MAP_COUNT_PATH) -> Optional[int]:
+    """The kernel's limit on one process's mappings, or None where the
+    host does not say (no ``/proc``)."""
+    try:
+        with open(path) as f:
+            return int(f.read())
+    except (OSError, ValueError):
+        return None
+
+
+def open_source_bound(fds: Optional[int], maps: Optional[int]) -> int:
+    """How many sources the process can keep open: each limit less its
+    reserve, divided by what one source holds, the smaller of the two,
+    and never below ``MAX_OPEN_FLOOR``.  None is a limit the process
+    does not have; with neither, the floor."""
+    terms = [(limit - reserve) // per
+             for limit, reserve, per in ((fds, FD_RESERVE, SOURCE_FDS),
+                                         (maps, MAP_RESERVE, SOURCE_MAPS))
+             if limit is not None]
+    return max(MAX_OPEN_FLOOR, min(terms, default=MAX_OPEN_FLOOR))
+
+
+def derive_max_open() -> int:
+    """Raise the descriptor limit, read the mapping limit, and bound the
+    open sources by both.  A server calls this once, in the process that
+    builds its ``PixelsService``; the service itself changes no limit."""
+    fds = raise_fd_limit()
+    maps = read_max_map_count()
+    bound = open_source_bound(fds, maps)
+    log.info("pixel sources: up to %d open (RLIMIT_NOFILE %s, "
+             "vm.max_map_count %s)", bound, fds, maps)
+    return bound
 
 
 class PixelsService:
     """Opens pixel sources from a data directory, with a bounded LRU handle
     cache (each open store holds live memmaps, so the bound caps fds and
-    address space on long-running servers).
+    mappings on long-running servers).  A server passes the bound its
+    process can hold (``derive_max_open``); OME-TIFF sources are held to
+    ``MAX_OPEN_FLOOR`` of them whatever the bound.
 
     Backend is sniffed per image directory: a ``meta.json`` selects the
     chunked pyramid store; ``.zattrs``/``.zarray`` markers (directly or
@@ -43,7 +119,7 @@ class PixelsService:
     # source's refcount high until a cycle collection runs.
     _GC_THRESHOLD = 8
 
-    def __init__(self, data_dir: str, max_open: int = DEFAULT_MAX_OPEN,
+    def __init__(self, data_dir: str, max_open: int = MAX_OPEN_FLOOR,
                  repo_root: Optional[str] = None):
         self.data_dir = data_dir
         self.max_open = max_open
@@ -244,10 +320,24 @@ class PixelsService:
                     # shows no reader remains.
                     self._evicted.append(
                         self._open.popitem(last=False)[1])
+                if isinstance(src, OmeTiffSource):
+                    self._bound_tiffs_locked()
                 stragglers = self._drain_evicted_locked()
         if stragglers > self._GC_THRESHOLD:
             self._gc_and_drain()
         return src
+
+    def _bound_tiffs_locked(self) -> None:
+        """Park all but the ``MAX_OPEN_FLOOR`` most recently used
+        OME-TIFF sources.  Each keeps a segment memo of up to 64 MiB on
+        the heap and a descriptor for each file of its fileset, which
+        the per-source figures of the derived bound do not reckon; so
+        they stay within what 128 open sources held before the bound
+        followed the process's limits.  Caller holds ``self._lock``."""
+        tiffs = [image_id for image_id, src in self._open.items()
+                 if isinstance(src, OmeTiffSource)]
+        for image_id in tiffs[:-MAX_OPEN_FLOOR]:
+            self._evicted.append(self._open.pop(image_id))
 
     def invalidate(self, image_id: int) -> None:
         """Drop a cached open handle so the next request re-sniffs the

@@ -27,7 +27,7 @@ from aiohttp import web
 
 from .. import __version__, codecs
 from ..io.devicecache import DeviceRawCache
-from ..io.service import PixelsService
+from ..io.service import PixelsService, derive_max_open
 from ..ops.lut import LutProvider
 from ..services.cache import Caches
 from ..services.metadata import CanReadMemo, LocalMetadataService
@@ -244,6 +244,7 @@ def build_services(config: AppConfig) -> "ImageRegionServices":
                     "shared canRead memo disabled")
     services = ImageRegionServices(
         pixels_service=PixelsService(config.data_dir,
+                                     max_open=derive_max_open(),
                                      repo_root=config.omero_data_dir),
         metadata=LocalMetadataService(config.data_dir),
         caches=caches,

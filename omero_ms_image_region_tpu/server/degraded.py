@@ -47,13 +47,14 @@ class DegradedCpuHandler:
     and exception contract."""
 
     def __init__(self, config):
-        from ..io.service import PixelsService
+        from ..io.service import PixelsService, derive_max_open
         from ..ops.lut import LutProvider
         from ..services.metadata import LocalMetadataService
 
         self.config = config
         self.pixels_service = PixelsService(
-            config.data_dir, repo_root=config.omero_data_dir)
+            config.data_dir, max_open=derive_max_open(),
+            repo_root=config.omero_data_dir)
         self.metadata = LocalMetadataService(config.data_dir)
         self.lut_provider = LutProvider(config.lut_root)
         self.max_tile_length = config.max_tile_length

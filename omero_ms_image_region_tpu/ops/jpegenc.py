@@ -467,22 +467,6 @@ _FETCHERS: dict = {}
 _FETCHERS_LOCK = threading.Lock()
 
 
-def _observe_fetch(nbytes: int, seconds: float,
-                   conflated: bool = False) -> None:
-    """``conflated``: the timed window synchronized on device EXECUTION
-    as well as the transfer (the first fetch of a dispatched program),
-    so bytes/seconds is a LOWER BOUND on the link rate, not a
-    measurement of it."""
-    # The link-health EWMA gauge (/metrics imageregion_link_mb_s) rides
-    # every fetch — it is what settles "weather or regression?" when a
-    # bench headline moves.
-    from ..utils.telemetry import LINK
-    try:
-        LINK.observe(nbytes, seconds, conflated)
-    except Exception:       # pragma: no cover - telemetry must never
-        pass                # break the serving path
-
-
 def _fetch_first(pre, tiles: int = None,
                  timings: dict = None) -> np.ndarray:
     """The first host copy of a dispatched program's output, as the
@@ -491,7 +475,7 @@ def _fetch_first(pre, tiles: int = None,
     copy alone; it begins when ``tiles`` real tiles are rendered: how a
     profiler capture counts renders).  The wait's milliseconds go into
     ``timings["device_ms"]`` for the caller's cost ledger."""
-    with stopwatch("wire.fetch") as fetch:
+    with stopwatch("wire.fetch"):
         with stopwatch("device.wait", tiles=tiles or 0) as wait:
             if hasattr(pre, "block_until_ready"):
                 pre.block_until_ready()
@@ -499,19 +483,14 @@ def _fetch_first(pre, tiles: int = None,
             host = np.asarray(pre)
     if timings is not None:
         timings["device_ms"] = timings.get("device_ms", 0.0) + wait.ms
-    # Conflated: this wait covers the device render completing, not
-    # just the wire, so its rate is only a lower bound on the link.
-    _observe_fetch(host.nbytes, fetch.ms / 1000.0, conflated=True)
     return host
 
 
 def _fetch_rest(rest) -> np.ndarray:
     """The follow-up copy of an under-predicted prefix
     (``wire.fetch2``): a slice program and its copy, nothing else."""
-    with stopwatch("wire.fetch2") as fetch:
-        host = np.asarray(rest)
-    _observe_fetch(host.nbytes, fetch.ms / 1000.0)
-    return host
+    with stopwatch("wire.fetch2"):
+        return np.asarray(rest)
 
 
 def wire_fetcher(H: int, W: int, cap: int) -> SparseWireFetcher:

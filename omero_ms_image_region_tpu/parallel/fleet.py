@@ -2585,11 +2585,8 @@ def build_local_members(config, base_services, n: int,
 
     Member-level single-flight and admission are disabled on the extra
     members: both concerns live fleet-wide above the router."""
-    from ..io.devicecache import DeviceRawCache
-    from ..server.batcher import BatchingRenderer
-    from ..server.handler import (ImageRegionHandler,
-                                  ImageRegionServices, Renderer)
-    from ..server.prewarm import stated_planes
+    from ..server.batcher import build_renderer, pin_to_device
+    from ..server.handler import ImageRegionHandler, ImageRegionServices
 
     def devices_for(i: int) -> tuple:
         if not device_sets or i >= len(device_sets):
@@ -2606,37 +2603,16 @@ def build_local_members(config, base_services, n: int,
                                 if devices_for(0) and not lockstep
                                 else None)
     if base_services.pin_device is not None:
-        if hasattr(base_services.renderer, "device"):
-            base_services.renderer.device = base_services.pin_device
-        if base_services.raw_cache is not None:
-            base_services.raw_cache.device = base_services.pin_device
+        pin_to_device(base_services.renderer, base_services.raw_cache,
+                      base_services.pin_device)
     members = [LocalMember(
         "m0",
         ImageRegionHandler(base_services), services=base_services,
         down_cooldown_s=cooldown, byte_cache_prechecked=True,
         devices=devices_for(0))]
     for i in range(1, n):
-        if config.batcher.enabled and not config.parallel.enabled:
-            renderer = BatchingRenderer(
-                max_batch=config.batcher.max_batch,
-                max_batch_limit=config.batcher.max_batch_limit,
-                linger_ms=config.batcher.linger_ms,
-                jpeg_engine=config.renderer.jpeg_engine,
-                pipeline_depth=config.batcher.pipeline_depth,
-                target_inflight=config.batcher.target_inflight,
-                device_lanes=config.batcher.device_lanes,
-                planes=stated_planes(config.renderer.prewarm))
-            renderer.first_tile_out = config.wire.streaming
-        else:
-            renderer = Renderer(jpeg_engine=config.renderer.jpeg_engine)
-        if devices_for(i):
-            renderer.device = devices_for(i)[0]
-        raw_cache = (DeviceRawCache(
-            config.raw_cache.max_bytes,
-            digest_index=config.raw_cache.digest_dedup)
-            if config.raw_cache.enabled else None)
-        if raw_cache is not None and devices_for(i):
-            raw_cache.device = devices_for(i)[0]
+        pin = devices_for(i)[0] if devices_for(i) else None
+        renderer, raw_cache = build_renderer(config, device=pin)
         services = ImageRegionServices(
             pixels_service=base_services.pixels_service,
             metadata=base_services.metadata,
@@ -2647,8 +2623,7 @@ def build_local_members(config, base_services, n: int,
             max_tile_length=base_services.max_tile_length,
             raw_cache=raw_cache,
             cpu_fallback_max_px=base_services.cpu_fallback_max_px,
-            pin_device=(devices_for(i)[0] if devices_for(i)
-                        else None),
+            pin_device=pin,
         )
         members.append(LocalMember(
             f"m{i}",

@@ -197,8 +197,7 @@ class MeshRenderer(BatchingRenderer):
     def __init__(self, mesh: Mesh, max_batch: int | None = None,
                  linger_ms: float = 2.0, buckets=None,
                  jpeg_engine: str = "sparse", pipeline_depth: int = 4,
-                 max_batch_limit: int = None, device_lanes: int = 2,
-                 planes=()):
+                 max_batch_limit: int = None, planes=()):
         data = mesh.shape["data"]
         if max_batch is None:
             max_batch = max(8, 2 * data)
@@ -216,15 +215,14 @@ class MeshRenderer(BatchingRenderer):
                            "(was %d) — sharded launches must not "
                            "overlap", pipeline_depth)
             pipeline_depth = 1
+        kwargs = {} if buckets is None else {"buckets": buckets}
         if multihost:
             # The two-stage fetch/execute split likewise must not let
             # two groups' sharded launches race a host-local gate order.
-            device_lanes = 1
-        kwargs = {} if buckets is None else {"buckets": buckets}
+            kwargs["device_lanes"] = 1
         super().__init__(max_batch=max_batch, linger_ms=linger_ms,
                          pipeline_depth=pipeline_depth,
-                         max_batch_limit=max_batch_limit,
-                         device_lanes=device_lanes, planes=planes,
+                         max_batch_limit=max_batch_limit, planes=planes,
                          **kwargs)
         if multihost:
             # One launch slot shared across ALL bucket keys: without it,

@@ -26,7 +26,6 @@ from typing import Optional
 from aiohttp import web
 
 from .. import __version__, codecs
-from ..io.devicecache import DeviceRawCache
 from ..io.service import PixelsService, derive_max_open
 from ..ops.lut import LutProvider
 from ..services.cache import Caches
@@ -167,9 +166,9 @@ def build_services(config: AppConfig) -> "ImageRegionServices":
             config.persistence.dir, "bytecache")
         config.caches.disk_max_bytes = \
             config.persistence.disk_cache_max_bytes
-    from .batcher import BatchingRenderer
-    from .handler import ImageRegionServices, Renderer
-    from .prewarm import stated_planes
+    from .batcher import BatchingRenderer, batcher_knobs, build_renderer
+    from .handler import ImageRegionServices
+    renderer = None
     if config.parallel.enabled:
         # Mesh-sharded serving (≙ the reference's -cluster mode):
         # groups dispatch through the (data, chan) mesh steps.
@@ -200,26 +199,8 @@ def build_services(config: AppConfig) -> "ImageRegionServices":
             n_devices=config.parallel.n_devices)
         log.info("mesh serving enabled: %s (jpeg engine %s)",
                  dict(mesh.shape), config.renderer.jpeg_engine)
-        renderer = MeshRenderer(
-            mesh, max_batch=config.batcher.max_batch,
-            max_batch_limit=config.batcher.max_batch_limit,
-            linger_ms=config.batcher.linger_ms,
-            jpeg_engine=config.renderer.jpeg_engine,
-            pipeline_depth=config.batcher.pipeline_depth,
-            device_lanes=config.batcher.device_lanes,
-            planes=stated_planes(config.renderer.prewarm))
-    elif config.batcher.enabled:
-        renderer = BatchingRenderer(
-            max_batch=config.batcher.max_batch,
-            max_batch_limit=config.batcher.max_batch_limit,
-            linger_ms=config.batcher.linger_ms,
-            jpeg_engine=config.renderer.jpeg_engine,
-            pipeline_depth=config.batcher.pipeline_depth,
-            target_inflight=config.batcher.target_inflight,
-            device_lanes=config.batcher.device_lanes,
-            planes=stated_planes(config.renderer.prewarm))
-    else:
-        renderer = Renderer(jpeg_engine=config.renderer.jpeg_engine)
+        renderer = MeshRenderer(mesh, **batcher_knobs(config))
+    renderer, raw_cache = build_renderer(config, renderer=renderer)
     # Say ONCE what this process serves from, and refuse the CPU
     # backend unless JAX_PLATFORMS asked for it: a server that found
     # no chip must not look like one that did.  The same document
@@ -233,11 +214,6 @@ def build_services(config: AppConfig) -> "ImageRegionServices":
              device["platform"], device["kind"], device["count"],
              device["ids"], native_status["entropy_coder"],
              native_status["tile_cache"], cache_dir)
-    if hasattr(renderer, "first_tile_out"):
-        # First-tile-out settlement rides the streaming knob: with
-        # wire.streaming off the batcher reverts to barrier
-        # settlement (the v2 behavior, for A/B measurement).
-        renderer.first_tile_out = config.wire.streaming
     caches = Caches.from_config(config.caches)
     if config.caches.redis_uri and caches.redis is None:
         log.warning("redis package unavailable; redis cache tier and "
@@ -261,10 +237,7 @@ def build_services(config: AppConfig) -> "ImageRegionServices":
         # tiles without re-crossing the host link.  The digest index
         # makes it content-addressed: planes resident under any key
         # (wire pushes included) are never re-shipped.
-        raw_cache=(DeviceRawCache(
-            config.raw_cache.max_bytes,
-            digest_index=config.raw_cache.digest_dedup)
-            if config.raw_cache.enabled else None),
+        raw_cache=raw_cache,
         device=device,
         native=native_status,
     )

@@ -74,11 +74,10 @@ def bucket_lattice(ladder=DEFAULT_BUCKETS, planes=()) -> tuple:
                         key=lambda b: (b[0] * b[1], b)))
 
 
-# Allowed padded batch shapes: powers of two plus 3 and 6, so the
-# inflight-aware group split (see _pop_size) can run ~3 concurrent
-# groups from a 16-request burst without paying 8-shape execution for
-# 5-6 real tiles.  Every entry is one compile per bucket key (cached
-# persistently); pad tiles are excluded from the wire by compaction.
+# Allowed padded batch shapes: powers of two plus 3 and 6, so a group
+# of 5-6 real tiles does not pay 8-shape execution.  Every entry is one
+# compile per bucket key (cached persistently); pad tiles are excluded
+# from the wire by compaction.
 _BATCH_SHAPES = (1, 2, 3, 4, 6, 8, 16, 32, 64)
 
 
@@ -269,8 +268,7 @@ class BatchingRenderer:
     def __init__(self, max_batch: int = 8, linger_ms: float = 2.0,
                  buckets=DEFAULT_BUCKETS, jpeg_engine: str = "sparse",
                  pipeline_depth: int = 4, max_batch_limit: int = None,
-                 target_inflight: int = 1, device_lanes: int = 2,
-                 planes=()):
+                 device_lanes: int = 2, planes=()):
         if jpeg_engine not in ("sparse", "huffman"):
             raise ValueError(
                 f"batched jpeg engine must be 'sparse' or 'huffman', "
@@ -303,12 +301,6 @@ class BatchingRenderer:
         # process replays the identical post-drop group.
         self._deadline_drop_enabled = True
         self.linger_ms = linger_ms
-        # Preferred concurrent group count under backlog (see
-        # BatcherConfig.target_inflight: default 1 = max_batch
-        # convoys; >1 splits bursts across streams).  Capped by
-        # pipeline_depth.
-        self.target_inflight = max(1, min(target_inflight,
-                                          pipeline_depth))
         self.jpeg_engine = jpeg_engine
         self.pipeline_depth = pipeline_depth
         # The group threads code their groups' tiles too: the coding
@@ -791,11 +783,10 @@ class BatchingRenderer:
             # loop's await points) can never orphan a popped group.
             group: List[_Pending] = []
             cap = self.group_cap(bucket_px)
-            take = self._pop_size(len(queue), cap)
             now_mono = time.monotonic()
             expired: List[_Pending] = []
             dead: List[_Pending] = []
-            while queue and len(group) < take:
+            while queue and len(group) < cap:
                 p = queue.popleft()
                 if p.future.done():
                     # Already settled while queued — the waiter
@@ -870,27 +861,6 @@ class BatchingRenderer:
                 self._run_group(render, group, slots, key, t_slot))
             self._inflight.add(task)
             task.add_done_callback(self._inflight.discard)
-
-    def _pop_size(self, qlen: int, cap: int) -> int:
-        """How many requests this group takes, of at most ``cap`` (the
-        bucket's: ``group_cap``).
-
-        Splits a backlog across the remaining pipeline slots so
-        ``target_inflight`` wire streams overlap (each fetch pays the
-        link RTT up front; concurrent streams hide it), instead of two
-        ``cap``-sized convoys.  Multi-host meshes keep the plain
-        ``cap`` pop: group sizes there must not depend on host-local
-        queue timing (same reason growth is disabled —
-        ``parallel/serve.py`` lockstep).
-        """
-        if (not self._growth_enabled or self.target_inflight <= 1
-                or qlen <= cap):
-            # Small backlogs coalesce into one dispatch — splitting
-            # only pays when there is more than a full batch to spread
-            # across streams.
-            return cap
-        open_streams = max(1, self.target_inflight - len(self._inflight))
-        return max(1, min(cap, -(-qlen // open_streams)))
 
     async def _run_group(self, render, group: List[_Pending],
                          slots: asyncio.Semaphore, key: tuple,
@@ -1218,3 +1188,60 @@ class BatchingRenderer:
         telemetry.SHAPE_COSTS.observe(shape, exec_ms)
         self._count_batch(n, raw.shape[0], timings.get("device"))
         return jpegs
+
+
+def batcher_knobs(config) -> dict:
+    """The batcher's settings in ``config`` (``AppConfig``), as keyword
+    arguments of ``BatchingRenderer`` and of the mesh's subclass."""
+    from .prewarm import stated_planes
+
+    batcher = config.batcher
+    return dict(max_batch=batcher.max_batch,
+                max_batch_limit=batcher.max_batch_limit,
+                linger_ms=batcher.linger_ms,
+                jpeg_engine=config.renderer.jpeg_engine,
+                pipeline_depth=batcher.pipeline_depth,
+                planes=stated_planes(config.renderer.prewarm))
+
+
+def pin_to_device(renderer, raw_cache, device) -> None:
+    """Dispatch ``renderer``'s groups and ``raw_cache``'s uploads (None:
+    no cache) on ``device``."""
+    if hasattr(renderer, "device"):
+        renderer.device = device
+    if raw_cache is not None:
+        raw_cache.device = device
+
+
+def build_renderer(config, device=None, renderer=None):
+    """A chip's renderer and raw cache, as ``config`` (``AppConfig``)
+    sets them: the one place the batcher's and the raw cache's settings
+    reach their constructors.  Returns ``(renderer, raw_cache)``;
+    ``raw_cache`` is None with ``raw-cache.enabled`` off.
+
+    ``renderer``, when given, is one the caller built from
+    :func:`batcher_knobs` (the server's ``MeshRenderer`` under
+    ``parallel.enabled``).  Otherwise the renderer is a
+    ``BatchingRenderer``, or the direct ``handler.Renderer`` with the
+    batcher off and on a ``parallel`` deployment's extra fleet members.
+    ``device`` pins the renderer and the cache to that device; None
+    keeps the process default."""
+    from ..io.devicecache import DeviceRawCache
+    from .handler import Renderer
+
+    if renderer is None:
+        if config.batcher.enabled and not config.parallel.enabled:
+            renderer = BatchingRenderer(**batcher_knobs(config))
+        else:
+            renderer = Renderer(jpeg_engine=config.renderer.jpeg_engine)
+    if isinstance(renderer, BatchingRenderer):
+        # With wire.streaming off a JPEG group answers its members at
+        # its end, so each request's trace holds the group's render and
+        # encode spans when it is finished.
+        renderer.first_tile_out = config.wire.streaming
+    raw_cache = (DeviceRawCache(config.raw_cache.max_bytes,
+                                digest_index=config.raw_cache.digest_dedup)
+                 if config.raw_cache.enabled else None)
+    if device is not None:
+        pin_to_device(renderer, raw_cache, device)
+    return renderer, raw_cache

@@ -39,23 +39,18 @@ class BatcherConfig:
     # benchmark cell runs.  Its A/B was taken over a device link that
     # is gone; ROADMAP S3 measures it on the current chip.
     pipeline_depth: int = 4
-    # Preferred concurrent group count under backlog: >1 makes the
-    # dispatcher split a burst across that many wire streams instead
-    # of popping max_batch-sized convoys.  1 (off) is the value every
-    # benchmark cell runs; its A/B, too, was taken over the link that
-    # is gone, and ROADMAP S3 measures it.  Single-host only;
-    # multi-host meshes always pop max_batch.
-    target_inflight: int = 1
-    # Bounded device-execute stage of the group pipeline: a group
-    # render is fetch/stage (stack + host->device upload), then
-    # device-execute (the jitted call until the group's wire rows are
-    # in host memory), then for JPEG the host's entropy coding, and at
-    # most this many groups occupy the execute stage at once; the
-    # other two hold no lane (span batcher.laneHold is the hold).
-    # Default 2 (double-buffered): group N+1's program is queued
-    # behind group N's without letting every pipeline_depth group pile
-    # onto the device.  Multi-host meshes force 1 (SPMD launch order).
-    device_lanes: int = 2
+
+
+# Batcher keys that no longer exist, with what stands in their place: a
+# file that still sets one fails at load rather than quietly running
+# something else.
+_REMOVED_BATCHER_KEYS = {
+    "target-inflight": "a group takes its bucket's whole cap (the split "
+                       "across wire streams served a device link that "
+                       "is gone)",
+    "device-lanes": "the batcher keeps two device lanes (one on a "
+                    "multi-host mesh)",
+}
 
 
 @dataclass
@@ -158,9 +153,12 @@ class WireConfig:
     # falls back to socket bodies automatically.
     ring_bytes: int = 32 * 1024 * 1024
     ring_min_body_bytes: int = 4096
-    # Progressive first-tile-out streaming: render responses leave as
-    # per-tile chunk frames the moment the tile's encode slice lands,
-    # and the HTTP frontend forwards them as a chunked response.
+    # Progressive first-tile-out streaming: a JPEG group answers each
+    # member as its tile is coded, the sidecar sends per-tile chunk
+    # frames and the HTTP frontend forwards them as a chunked response.
+    # false answers with one body, and a group answers its members at
+    # its end (barrier settlement), so each request's trace and slow
+    # dump hold the group's render and encode spans.
     streaming: bool = True
     chunk_max_bytes: int = 256 * 1024
 
@@ -1027,6 +1025,10 @@ class AppConfig:
                             .get("enabled", False)),
         )
         batcher = raw.get("batcher", {}) or {}
+        for key, instead in _REMOVED_BATCHER_KEYS.items():
+            if key in batcher:
+                raise ValueError(f"batcher.{key} was removed: {instead}; "
+                                 f"delete the key")
         defaults = BatcherConfig()
         cfg.batcher = BatcherConfig(
             enabled=bool(batcher.get("enabled", defaults.enabled)),
@@ -1037,17 +1039,9 @@ class AppConfig:
             linger_ms=float(batcher.get("linger-ms", defaults.linger_ms)),
             pipeline_depth=int(batcher.get("pipeline-depth",
                                            defaults.pipeline_depth)),
-            target_inflight=int(batcher.get("target-inflight",
-                                            defaults.target_inflight)),
-            device_lanes=int(batcher.get("device-lanes",
-                                         defaults.device_lanes)),
         )
         if cfg.batcher.pipeline_depth < 1:
             raise ValueError("batcher.pipeline-depth must be >= 1")
-        if cfg.batcher.target_inflight < 1:
-            raise ValueError("batcher.target-inflight must be >= 1")
-        if cfg.batcher.device_lanes < 1:
-            raise ValueError("batcher.device-lanes must be >= 1")
         # An EMPTY "single-flight:" section (all children commented
         # out, the standard pattern in the example config) parses as
         # YAML null and must keep the default — only an explicit value

@@ -664,7 +664,7 @@ async def _serve_connection(image_handler, mask_handler, reader, writer,
                         prov = provenance.marks(ctx) or None
             elif op == "metrics":
                 # Device-process series (spans, caches, batcher gauges,
-                # compile events, link health); frontends merge these
+                # compile events); frontends merge these
                 # into their /metrics exposition.  No # TYPE lines here
                 # — the frontend's finalizer owns the headers.
                 from ..utils.stopwatch import span_lines

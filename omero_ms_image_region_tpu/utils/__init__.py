@@ -1,5 +1,5 @@
 """Host utilities: hashing, colors, config, telemetry (request traces,
-bucketed histograms, link health, readiness state)."""
+bucketed histograms, readiness state)."""
 
 from .siphash import siphash24, guava_siphash24_hex
 from .color import split_html_color

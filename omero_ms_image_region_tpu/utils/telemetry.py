@@ -15,11 +15,9 @@ module is the grown-up form of that layer for the TPU service:
   (Prometheus ``_bucket``/``_sum``/``_count`` semantics), replacing the
   p50-only ring that could not distinguish a tail regression from link
   weather.
-* **Gauges** — link-health EWMA from the wire fetch observations
-  (settles the weather-vs-structure question when a bench headline
-  moves), XLA compile events (count + cumulative ms — a lazily compiled
-  batch shape shows up here mechanically), queue depth and pipeline
-  occupancy are read live from the batcher at scrape time.
+* **Gauges** — XLA compile events (count + cumulative ms — a lazily
+  compiled batch shape shows up here mechanically), queue depth and
+  pipeline occupancy are read live from the batcher at scrape time.
 * **Slow-request dumps** — requests over a configured threshold write
   their full waterfall JSON to a spool directory
   (``scripts/trace_report.py`` renders them).
@@ -519,71 +517,6 @@ def merge_costs(trace_id: str, costs: Dict[str, float]) -> None:
             trace.add_cost(str(key), float(value))
         except (TypeError, ValueError):
             pass    # malformed wire field: drop it, keep serving
-
-
-# ------------------------------------------------------------- link health
-
-class LinkHealth:
-    """EWMAs of the device->host link rate, fed by the wire fetchers
-    (``ops.jpegenc._observe_fetch``).
-
-    Two gauges, because almost every PRIMARY prefetch is ``conflated``
-    (its timed window covers device execution as well as the transfer):
-
-    * ``effective_mb_s`` — EWMA over ALL bandwidth-class fetches, both
-      directions.  This is the rate requests actually experience, and
-      the one that TRACKS a link slowdown (a conflated-only stream
-      would otherwise never move a lower bound downward).
-    * ``ewma_mb_s`` — floor estimate of the RAW link: conflated
-      observations update it only upward (a conflated 40 MB/s proves
-      the link is at least that fast; a conflated 2 MB/s proves
-      nothing — it may be compile or execution stall, not wire).
-
-    Effective falling while the floor holds reads as device-side
-    weather; both falling together is the link itself.
-    """
-
-    MIN_BYTES = 256 * 1024      # below this, latency dominates
-
-    def __init__(self, alpha: float = 0.3):
-        self.alpha = alpha
-        self._lock = threading.Lock()
-        self.ewma_mb_s: Optional[float] = None
-        self.effective_mb_s: Optional[float] = None
-        self.fetches = 0
-        self.bytes_total = 0
-        self.last_ts = 0.0
-
-    def _blend(self, prev: Optional[float], rate: float) -> float:
-        return rate if prev is None else prev + self.alpha * (rate
-                                                              - prev)
-
-    def observe(self, nbytes: int, seconds: float,
-                conflated: bool = False) -> None:
-        with self._lock:
-            self.fetches += 1
-            self.bytes_total += int(nbytes)
-            self.last_ts = time.time()
-            if seconds <= 0 or nbytes < self.MIN_BYTES:
-                return
-            rate = nbytes / seconds / 1e6
-            self.effective_mb_s = self._blend(self.effective_mb_s,
-                                              rate)
-            if conflated and (self.ewma_mb_s is not None
-                              and rate <= self.ewma_mb_s):
-                return
-            self.ewma_mb_s = self._blend(self.ewma_mb_s, rate)
-
-    def reset(self) -> None:
-        with self._lock:
-            self.ewma_mb_s = None
-            self.effective_mb_s = None
-            self.fetches = 0
-            self.bytes_total = 0
-            self.last_ts = 0.0
-
-
-LINK = LinkHealth()
 
 
 # ---------------------------------------------------------- compile events
@@ -3671,10 +3604,6 @@ METRIC_TYPES: Dict[str, str] = {
     "imageregion_profile_idle_ms_total": "counter",
     "imageregion_compile_cache_hits_total": "counter",
     "imageregion_device_peak_bytes": "gauge",
-    "imageregion_link_mb_s": "gauge",
-    "imageregion_link_effective_mb_s": "gauge",
-    "imageregion_link_fetches_total": "counter",
-    "imageregion_link_fetch_bytes_total": "counter",
     "imageregion_ready": "gauge",
     "imageregion_breaker_state": "gauge",
     "imageregion_breaker_opens_total": "counter",
@@ -4364,7 +4293,7 @@ def fleet_shard_metric_lines(members, base_services) -> List[str]:
 
 def device_metric_lines(services, extra_labels: str = "") -> List[str]:
     """Series owned by a device-side process (combined app or sidecar):
-    caches, raw cache, batcher gauges, compile events, link health.
+    caches, raw cache, batcher gauges, compile events.
 
     ``services`` is duck-typed (``server.handler.ImageRegionServices``)
     so this module stays importable without the server stack;
@@ -4449,8 +4378,6 @@ def device_metric_lines(services, extra_labels: str = "") -> List[str]:
         f"{round(COMPILE.total_ms, 3)}",
         f"imageregion_compile_cache_hits_total{lb} "
         f"{COMPILE.cache_hits}",
-        f"imageregion_link_fetches_total{lb} {LINK.fetches}",
-        f"imageregion_link_fetch_bytes_total{lb} {LINK.bytes_total}",
     ]
     peak = _device_peak_bytes()
     if peak is not None:
@@ -4492,15 +4419,6 @@ def device_metric_lines(services, extra_labels: str = "") -> List[str]:
             f"imageregion_flight_dumps_total{lb} "
             f"{FLIGHT.dumps_written}",
         ]
-    if LINK.fetches:
-        # 0.0 until a bandwidth-class fetch has been rated (small
-        # fetches are latency-dominated and carry no rate signal).
-        lines += [
-            f"imageregion_link_mb_s{lb} "
-            f"{round(LINK.ewma_mb_s or 0.0, 3)}",
-            f"imageregion_link_effective_mb_s{lb} "
-            f"{round(LINK.effective_mb_s or 0.0, 3)}",
-        ]
     return lines
 
 
@@ -4512,7 +4430,6 @@ def reset() -> None:
     REQUEST_HIST.reset()
     with _REQ_LOCK:
         _REQ_TOTALS.clear()
-    LINK.reset()
     COMPILE.reset()
     READINESS.reset()
     RESILIENCE.reset()

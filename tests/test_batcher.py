@@ -897,6 +897,38 @@ class TestQueuePressure:
             loop.close()
 
 
+class TestGroupPop:
+    def test_a_backlog_pops_cap_sized_groups_in_arrival_order(self):
+        """A backlog larger than the bucket's cap leaves in groups of
+        the cap, oldest first, the last group taking what is left."""
+        from omero_ms_image_region_tpu.flagship import flagship_rdef
+        from omero_ms_image_region_tpu.ops.render import pack_settings
+
+        popped = []
+
+        class Probe(BatchingRenderer):
+            def _render_group(self, group):
+                popped.append([int(p.raw[0, 0, 0]) for p in group])
+                return [np.zeros((p.h, p.w), np.uint32) for p in group]
+
+        # A 1024^2 bucket, so the cap is max_batch; no growth.
+        r = Probe(max_batch=3, max_batch_limit=3, linger_ms=0.0,
+                  pipeline_depth=1, buckets=((1024, 1024),))
+        settings = pack_settings(flagship_rdef(1))
+
+        async def burst():
+            try:
+                return await asyncio.gather(*(
+                    r.render(np.full((1, 8, 8), i, np.float32), settings)
+                    for i in range(8)))
+            finally:
+                await r.close()
+
+        out = asyncio.run(burst())
+        assert len(out) == 8
+        assert popped == [[0, 1, 2], [3, 4, 5], [6, 7]]
+
+
 class TestLingerBypass:
     def test_lone_idle_request_skips_linger(self, monkeypatch):
         """A single request on an idle renderer dispatches immediately

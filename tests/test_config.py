@@ -218,23 +218,18 @@ def test_prewarm_specs_parse_and_validate():
 
 
 def test_hot_path_knobs_parse_and_validate():
-    """PR 2's hot-path knobs: two-stage device lanes, single-flight
-    dedup, and the raw cache's content-digest index."""
-    import pytest
-
+    """The hot-path knobs: single-flight dedup and the raw cache's
+    content-digest index."""
     from omero_ms_image_region_tpu.server.config import AppConfig
 
     cfg = AppConfig.from_dict({})
-    assert cfg.batcher.device_lanes == 2          # double-buffered
     assert cfg.single_flight is True
     assert cfg.raw_cache.digest_dedup is True
 
     cfg = AppConfig.from_dict({
-        "batcher": {"device-lanes": 3},
         "single-flight": {"enabled": False},
         "raw-cache": {"digest-dedup": False},
     })
-    assert cfg.batcher.device_lanes == 3
     assert cfg.single_flight is False
     assert cfg.raw_cache.digest_dedup is False
 
@@ -242,8 +237,13 @@ def test_hot_path_knobs_parse_and_validate():
     assert AppConfig.from_dict(
         {"single-flight": False}).single_flight is False
 
-    with pytest.raises(ValueError, match="device-lanes"):
-        AppConfig.from_dict({"batcher": {"device-lanes": 0}})
+
+@pytest.mark.parametrize("key", ["target-inflight", "device-lanes"])
+def test_removed_batcher_options_are_rejected(key):
+    """A file that still sets a removed batcher key fails at load,
+    naming it, instead of running without it."""
+    with pytest.raises(ValueError, match=f"batcher.{key} was removed"):
+        AppConfig.from_dict({"batcher": {key: 1}})
 
 
 def test_fleet_block_parses_and_validates():

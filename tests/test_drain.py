@@ -23,8 +23,10 @@ from omero_ms_image_region_tpu.server.admission import (
 from omero_ms_image_region_tpu.server.app import build_services
 from omero_ms_image_region_tpu.server.config import (AppConfig,
                                                      BatcherConfig,
+                                                     FleetConfig,
                                                      RawCacheConfig,
-                                                     RendererConfig)
+                                                     RendererConfig,
+                                                     WireConfig)
 from omero_ms_image_region_tpu.server.ctx import ImageRegionCtx
 from omero_ms_image_region_tpu.server.singleflight import SingleFlight
 from omero_ms_image_region_tpu.utils import telemetry
@@ -312,3 +314,55 @@ class TestAdminDrainEndpoint:
                 await client.close()
 
         asyncio.run(scenario())
+
+
+def test_server_and_fleet_members_build_one_renderer(data_dir):
+    """The single-chip server's renderer and an extra fleet member's
+    are built by one function: one ``AppConfig`` gives both the same
+    batcher knobs and stated planes, and each member its own raw cache,
+    both pinned to the member's device."""
+    import jax
+
+    from omero_ms_image_region_tpu.server.batcher import BatchingRenderer
+
+    config = AppConfig(
+        data_dir=data_dir,
+        batcher=BatcherConfig(enabled=True, max_batch=4,
+                              max_batch_limit=6, linger_ms=3.5,
+                              pipeline_depth=3),
+        raw_cache=RawCacheConfig(enabled=True, prefetch=False,
+                                 max_bytes=64 << 20),
+        renderer=RendererConfig(cpu_fallback_max_px=0,
+                                prewarm=("2x1080@90",)),
+        # A fleet's base stack: its programs are warmed once the
+        # members exist, so nothing compiles here.
+        fleet=FleetConfig(enabled=True, members=2),
+        wire=WireConfig(streaming=False))
+    services = build_services(config)
+    try:
+        devices = jax.devices()[:2]
+        members = build_local_members(
+            config, services, 2, device_sets=[[d] for d in devices])
+        base, member = services, members[1].services
+        knobs = ("max_batch", "max_batch_limit", "linger_ms",
+                 "pipeline_depth", "device_lanes", "jpeg_engine",
+                 "planes", "first_tile_out")
+        assert type(base.renderer) is type(member.renderer) \
+            is BatchingRenderer
+        assert {k: getattr(member.renderer, k) for k in knobs} == \
+            {k: getattr(base.renderer, k) for k in knobs} == {
+                "max_batch": 4, "max_batch_limit": 6, "linger_ms": 3.5,
+                "pipeline_depth": 3, "device_lanes": 2,
+                "jpeg_engine": "sparse",
+                "planes": frozenset({(1080, 1080)}),
+                "first_tile_out": False}
+        assert base.renderer.device is devices[0]
+        assert base.raw_cache.device is devices[0]
+        assert member.renderer.device is devices[1]
+        assert isinstance(member.raw_cache, DeviceRawCache)
+        assert member.raw_cache is not base.raw_cache
+        assert member.raw_cache.device is devices[1]
+        assert member.raw_cache.max_bytes == base.raw_cache.max_bytes \
+            == 64 << 20
+    finally:
+        services.pixels_service.close()

@@ -754,38 +754,6 @@ class TestCompactWire:
         for j in missed:
             assert Image.open(io.BytesIO(j)).size == (W, H)
 
-    def test_fetches_feed_the_link_gauge(self, monkeypatch):
-        """Every fetch reports to the ``/metrics`` link gauge: the
-        first of a dispatched program as conflated with its execution,
-        the follow-up of an under-predicted prefix as the wire alone;
-        a gauge that raises never breaks the fetch."""
-        from omero_ms_image_region_tpu.ops import jpegenc as je
-        from omero_ms_image_region_tpu.utils import telemetry
-        B, C, H, W = 4, 2, 32, 32
-        raw, ws, we, fam, coef, rev, tables = self._args(B, C, H, W, 7)
-        qy, qc = (np.asarray(t, np.int32) for t in quant_tables(85))
-        cap = je.max_sparse_cap(H, W)
-        buf = je.render_to_jpeg_sparse_compact(
-            raw, ws, we, fam, coef, rev, 0, 255, tables, qy, qc,
-            np.int32(B), cap=cap)
-        seen = []
-        monkeypatch.setattr(
-            telemetry.LINK, "observe",
-            lambda n, s, conflated=False: seen.append((n, conflated)))
-        f = je.CompactWireFetcher(B, je.sparse_wire_width(H, W, cap))
-        f._k = f.hdr
-        rows = f.fetch(buf)
-        total = f.hdr + sum(len(r) for r in rows)
-        assert [c for _, c in seen] == [True, False]
-        assert seen[0][0] == f.hdr and f.hdr + seen[1][0] >= total
-
-        def broken(*a, **k):
-            raise RuntimeError("gauge down")
-
-        monkeypatch.setattr(telemetry.LINK, "observe", broken)
-        for got, want in zip(f.fetch(buf), rows):
-            np.testing.assert_array_equal(got, want)
-
     def test_batch_to_jpeg_end_to_end_decodable(self):
         from omero_ms_image_region_tpu.ops import jpegenc as je
         B, C, H, W = 3, 2, 32, 32

@@ -593,10 +593,6 @@ class TestExpositionLint:
         assert "imageregion_batcher_queue_depth" in text
         assert "imageregion_pipeline_inflight" in text
         assert "imageregion_compile_events_total" in text
-        assert "imageregion_link_fetches_total" in text
-        # The JPEG render's wire fetch registered, so the link-health
-        # gauge is live (0.0 until a bandwidth-class fetch rates it).
-        assert "imageregion_link_mb_s" in text
         # The attribution layer's families are live: per-route cost
         # histograms, the per-shape device cost model, and the flight
         # recorder's ring gauges.
@@ -966,36 +962,3 @@ class TestSatellites:
         stamp = os.stat(level0).st_mtime_ns + 10**9
         os.utime(level0, ns=(stamp, stamp))
         assert _ngff_meta_mtime(ngff) != before
-
-    def test_link_health_conflated_is_lower_bound(self):
-        link = telemetry.LinkHealth()
-        mb = 1024 * 1024
-        link.observe(8 * mb, 1.0)                  # 8 MB/s measured
-        assert link.ewma_mb_s == pytest.approx(8.39, rel=0.01)
-        # A conflated slow sample proves nothing about the RAW link ->
-        # the floor holds...
-        link.observe(8 * mb, 100.0, conflated=True)
-        assert link.ewma_mb_s == pytest.approx(8.39, rel=0.01)
-        # ...but the EFFECTIVE rate tracks the slowdown requests feel.
-        assert link.effective_mb_s < link.ewma_mb_s
-        # A conflated FAST sample raises the floor.
-        link.observe(80 * mb, 1.0, conflated=True)
-        assert link.ewma_mb_s > 20.0
-        # Tiny fetches are latency-dominated: counted, not rated.
-        before = link.ewma_mb_s
-        link.observe(1024, 5.0)
-        assert link.ewma_mb_s == before
-        assert link.fetches == 4
-
-    def test_link_effective_tracks_conflated_only_slowdown(self):
-        """An all-conflated stream (the real serving pattern) must
-        still move the effective gauge DOWN when the wire degrades."""
-        link = telemetry.LinkHealth()
-        mb = 1024 * 1024
-        for _ in range(5):
-            link.observe(80 * mb, 1.0, conflated=True)   # 80 MB/s
-        fast = link.effective_mb_s
-        for _ in range(20):
-            link.observe(8 * mb, 1.0, conflated=True)    # now 8 MB/s
-        assert link.effective_mb_s < fast / 5
-        assert link.ewma_mb_s >= fast                    # floor holds

@@ -29,6 +29,7 @@ import asyncio
 import collections
 import contextlib
 import itertools
+import logging
 from dataclasses import dataclass, field
 from typing import Deque, Dict, List, Optional, Tuple
 
@@ -39,6 +40,8 @@ import numpy as np
 from ..ops.render import render_tile_batch_packed
 from ..utils import entropypool, telemetry
 from ..utils.stopwatch import REGISTRY, record_since, stopwatch
+
+logger = logging.getLogger(__name__)
 
 DEFAULT_BUCKETS = ((256, 256), (512, 512), (1024, 1024), (2048, 2048))
 
@@ -312,6 +315,9 @@ class BatchingRenderer:
         # stated shape ride to their group as they are.
         self.planes = frozenset((int(h), int(w)) for h, w in planes)
         self.buckets = bucket_lattice(buckets, self.planes)
+        # (channels, h, w, dtype) of the one-request stacks and pads
+        # handed to ``warm_edges`` so far.
+        self._warmed_edges: set = set()
         self._queues: Dict[tuple, Deque[_Pending]] = {}
         self._dispatchers: Dict[tuple, asyncio.Task] = {}
         self._wakeups: Dict[tuple, asyncio.Event] = {}
@@ -605,21 +611,72 @@ class BatchingRenderer:
                                    self.buckets) == (h, w))
         return pick_bucket(h, w, self.buckets) == (h, w)
 
+    def warm_edges(self, channels: int, dtype, shapes) -> None:
+        """Compile, on a thread of its own, the one-request stack and pad
+        (``stack_channel_planes`` + ``pad_planes_to_mcu``) that a JPEG
+        request of ``channels`` resident planes of each ``(h, w)`` in
+        ``shapes`` takes where its group cannot take the planes: a
+        pyramid's edge tiles, whose shapes the open image's levels fix
+        (``region.edge_tile_shapes``), so that the rarest of them is
+        not compiled on the event loop when a viewer first meets it.
+        Each key once a process; a request that comes before the thread
+        has compiled its shape compiles it itself, as it always did."""
+        dtype = np.dtype(dtype)
+        todo = []
+        with self._stats_lock:
+            for h, w in shapes:
+                key = (channels, h, w, dtype.name)
+                if key in self._warmed_edges \
+                        or self.takes_planes(h, w, jpeg=True):
+                    continue
+                self._warmed_edges.add(key)
+                todo.append((h, w))
+        if not todo:
+            return
+
+        def warm():
+            import jax
+            from ..io.staging import pin_scope
+            from ..ops.jpegenc import pad_planes_to_mcu
+            from ..ops.render import stack_channel_planes
+            try:
+                with pin_scope(self.device):
+                    for h, w in todo:
+                        # As prewarm makes its planes: uncommitted.
+                        plane = jax.device_put(np.zeros((h, w), dtype))
+                        bh, bw = pick_bucket(*mcu_grid(h, w), self.buckets)
+                        pad_planes_to_mcu(
+                            stack_channel_planes(*[plane] * channels),
+                            bh, bw).block_until_ready()
+            except Exception:
+                # The request path compiles what this did not.
+                logger.exception("warming %d edge shapes failed",
+                                 len(todo))
+
+        import threading
+        threading.Thread(target=warm, name="edge-warm",
+                         daemon=True).start()
+
     async def render(self, raw, settings: dict) -> np.ndarray:
         """[C, H, W] raw (or its C resident planes) + packed settings
         -> u32[H, W] packed RGBA."""
         C, h, w, dtype = _raw_form(raw)
         bh, bw = pick_bucket(h, w, self.buckets)
         if (h, w) != (bh, bw):
-            raw = _request_stack(raw)
-            if isinstance(raw, np.ndarray):
-                padded = np.zeros((C, bh, bw), raw.dtype)
-                padded[:, :h, :w] = raw
-                raw = padded
-            else:
-                # Device-resident raw (HBM tile cache): pad on device.
-                import jax.numpy as jnp
-                raw = jnp.pad(raw, ((0, 0), (0, bh - h), (0, bw - w)))
+            # A shape its bucket's group cannot take: stacked and
+            # padded here, one request at a time.
+            with stopwatch("batcher.requestPad"):
+                raw = _request_stack(raw)
+                if isinstance(raw, np.ndarray):
+                    padded = np.zeros((C, bh, bw), raw.dtype)
+                    padded[:, :h, :w] = raw
+                    raw = padded
+                else:
+                    # Device-resident raw (HBM tile cache): pad on
+                    # device.
+                    import jax.numpy as jnp
+                    raw = jnp.pad(raw,
+                                  ((0, 0), (0, bh - h), (0, bw - w)))
         # tables is either [C, 3] ramp weights or [C, 256, 3] LUT tables
         # (ops.render.pack_settings); the two shapes cannot co-batch, nor
         # can raw dtypes (uint16 storage vs float32) mix in one stack.
@@ -657,7 +714,11 @@ class BatchingRenderer:
             if isinstance(raw, tuple) and (h, w) in self.planes:
                 pad_to = (bh, bw)      # the group's program pads them
             else:
-                raw = pad_planes_to_mcu(_request_stack(raw), bh, bw)
+                # A shape nobody stated (an edge tile, a client's
+                # region): stacked and padded here, one request at a
+                # time.
+                with stopwatch("batcher.requestPad"):
+                    raw = pad_planes_to_mcu(_request_stack(raw), bh, bw)
         key = ("jpeg", C, bh, bw, int(settings["cd_start"]),
                int(settings["cd_end"]), settings["tables"].ndim, quality,
                str(dtype))

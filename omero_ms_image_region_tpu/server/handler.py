@@ -39,7 +39,8 @@ from ..utils.color import split_html_color
 from ..utils.stopwatch import record_since, stopwatch
 from .config import RendererConfig
 from .ctx import BadRequestError, ImageRegionCtx, ShapeMaskCtx
-from .region import RegionDef, clamp_region_to_plane, get_region_def
+from .region import (RegionDef, clamp_region_to_plane, edge_tile_shapes,
+                     get_region_def, tile_extent)
 from .settings import render_identity_key, update_settings
 
 DEFAULT_MAX_TILE_LENGTH = 2048  # beanRefContext.xml:63-66
@@ -506,6 +507,23 @@ class ImageRegionHandler:
         # (/metrics imageregion_renders_routed_total{route=...}).
         route = "host" if tiny else "device"
         telemetry.ROUTES.count(route)
+        # What was resolved: its unpadded pixels and, for a tile, its
+        # level and whether the level's edge cut it below the tile.
+        tile_level = edge = None
+        if ctx.tile is not None:
+            tile_w, tile_h = tile_extent(ctx.tile, src.tile_size(),
+                                         self.s.max_tile_length)
+            tile_level = level
+            edge = region.width < tile_w or region.height < tile_h
+        telemetry.TILE_RENDERS.count(region.width * region.height,
+                                     tile_level, edge)
+        warm_edges = getattr(self.s.renderer, "warm_edges", None)
+        if (edge and not tiny and warm_edges is not None
+                and ctx.format == "jpeg" and ctx.projection is None):
+            # The image's first edge tile: every edge shape of its
+            # levels, compiled off the loop before a viewer meets it.
+            warm_edges(len(active), src.dtype,
+                       edge_tile_shapes(levels, tile_w, tile_h))
 
         if ctx.projection is not None:
             raw, region = await self._project(ctx, pixels, src, active)

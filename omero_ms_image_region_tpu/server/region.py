@@ -68,6 +68,32 @@ def clamp_region_to_plane(resolution_levels: Sequence[Sequence[int]],
     return region
 
 
+def tile_extent(tile: RegionDef, image_tile_size: Tuple[int, int],
+                max_tile_length: int) -> Tuple[int, int]:
+    """``(width, height)`` of a tile request before truncation: the
+    tile's own, else the image's native tile size, each clamped to
+    ``max_tile_length``.  A region truncated below it at the level's
+    edge is an edge tile."""
+    tile_w = tile.width or image_tile_size[0]
+    tile_h = tile.height or image_tile_size[1]
+    return min(tile_w, max_tile_length), min(tile_h, max_tile_length)
+
+
+def edge_tile_shapes(resolution_levels: Sequence[Sequence[int]],
+                     tile_w: int, tile_h: int) -> List[Tuple[int, int]]:
+    """``(height, width)`` of every tile of ``tile_w x tile_h`` that its
+    level's edge cuts below the tile: each level's right column, bottom
+    row and corner (a level smaller than the tile is one such tile).
+    Fixed by the image's levels, not by its clients."""
+    shapes = set()
+    for size_x, size_y in resolution_levels:
+        widths = {min(tile_w, size_x), size_x % tile_w or tile_w}
+        heights = {min(tile_h, size_y), size_y % tile_h or tile_h}
+        shapes |= {(h, w) for h in heights for w in widths}
+    shapes.discard((tile_h, tile_w))
+    return sorted(shapes)
+
+
 def get_region_def(
     resolution_levels: Sequence[Sequence[int]],
     resolution: Optional[int],
@@ -90,15 +116,8 @@ def get_region_def(
     size_x, size_y = resolution_levels[res][0], resolution_levels[res][1]
     out = RegionDef()
     if tile is not None:
-        tile_w, tile_h = tile.width, tile.height
-        if tile_w == 0:
-            tile_w = image_tile_size[0]
-        if tile_w > max_tile_length:
-            tile_w = max_tile_length
-        if tile_h == 0:
-            tile_h = image_tile_size[1]
-        if tile_h > max_tile_length:
-            tile_h = max_tile_length
+        tile_w, tile_h = tile_extent(tile, image_tile_size,
+                                     max_tile_length)
         out.width = tile_w
         out.height = tile_h
         out.x = tile.x * tile_w

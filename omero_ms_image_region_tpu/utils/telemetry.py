@@ -1265,6 +1265,56 @@ class DuplicateLoadStats:
 DUPLICATE_LOADS = DuplicateLoadStats()
 
 
+class TileRenderStats:
+    """What the handler prepared, counted where it resolves the region:
+    tile requests by the pyramid level read and by whether the region,
+    truncated at the level's edge, is the whole tile asked for
+    (``full``) or smaller (``edge``), whatever bucket it is padded to
+    later; and the unpadded pixels of every region rendered, tile or
+    not.  ``/metrics imageregion_tile_renders_total{level, edge}`` (a
+    series appears once counted; levels are bounded by the budget's
+    16) and ``imageregion_render_pixels_total``."""
+
+    EDGES = ("full", "edge")
+    MAX_LEVELS = 16
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.reset()
+
+    def reset(self) -> None:
+        with self._lock:
+            self.tiles: Dict[tuple, int] = {}
+            self.pixels = 0
+
+    def count(self, pixels: int, level: Optional[int] = None,
+              edge: Optional[bool] = None) -> None:
+        """One render of ``pixels``; a tile's ``level`` and ``edge``
+        where it is one (levels past the bound share the last label)."""
+        with self._lock:
+            self.pixels += pixels
+            if level is not None:
+                key = (min(level, self.MAX_LEVELS - 1),
+                       self.EDGES[bool(edge)])
+                self.tiles[key] = self.tiles.get(key, 0) + 1
+
+    def metric_lines(self, extra_labels: str = "") -> List[str]:
+        extra = extra_labels.lstrip(",")
+        more = f",{extra}" if extra else ""
+        with self._lock:
+            lines = [
+                f"imageregion_tile_renders_total{{level=\"{level}\","
+                f"edge=\"{edge}\"{more}}} {n}"
+                for (level, edge), n in sorted(self.tiles.items())]
+            lines.append("imageregion_render_pixels_total"
+                         + (f"{{{extra}}}" if extra else "")
+                         + f" {self.pixels}")
+        return lines
+
+
+TILE_RENDERS = TileRenderStats()
+
+
 def capture_profile(directory: str, ms: float) -> dict:
     """Wrap ``jax.profiler`` around whatever the device is doing for
     ``ms`` milliseconds; returns the artifact manifest with the
@@ -3573,6 +3623,8 @@ METRIC_TYPES: Dict[str, str] = {
     "imageregion_rawcache_bytes": "gauge",
     "imageregion_rawcache_channel_loads_total": "counter",
     "imageregion_rawcache_duplicate_loads_total": "counter",
+    "imageregion_tile_renders_total": "counter",
+    "imageregion_render_pixels_total": "counter",
     "imageregion_pixel_sources_opened_total": "counter",
     "imageregion_pixel_sources_open": "gauge",
     "imageregion_planecache_hits": "counter",
@@ -3837,6 +3889,12 @@ METRIC_HELP: Dict[str, str] = {
     "imageregion_rawcache_duplicate_loads_total":
         "Channel planes read and uploaded to the HBM raw cache while "
         "another thread loaded the same key, by who lost the race",
+    "imageregion_tile_renders_total":
+        "Tile renders prepared, by pyramid level and by whether the "
+        "region truncated at the level's edge is the whole tile",
+    "imageregion_render_pixels_total":
+        "Unpadded pixels of every region the handler prepared a render "
+        "of",
     "imageregion_pixel_sources_opened_total":
         "Pixel sources constructed: lookups the LRU of open sources "
         "missed",
@@ -4393,6 +4451,8 @@ def device_metric_lines(services, extra_labels: str = "") -> List[str]:
     lines += DEVICE_RENDERS.metric_lines(extra_labels)
     # Loads of a raw-cache key that another thread loaded meanwhile.
     lines += DUPLICATE_LOADS.metric_lines(extra_labels)
+    # Tile renders by level and edge, and the pixels of every render.
+    lines += TILE_RENDERS.metric_lines(extra_labels)
     # Warm-state persistence tier (disk byte cache, snapshot engine,
     # boot rehydrator) — device-side state, merged like the rest.
     lines += PERSIST.metric_lines(extra_labels)
@@ -4443,6 +4503,7 @@ def reset() -> None:
     ROUTES.reset()
     DEVICE_RENDERS.reset()
     DUPLICATE_LOADS.reset()
+    TILE_RENDERS.reset()
     entropypool.reset_counts()
     PERSIST.reset()
     WIRE.reset()

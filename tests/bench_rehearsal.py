@@ -6,7 +6,7 @@ from tier-1 over a rehearsal that holds EVERY configuration of
 ``rehearsal/overrides.json``, which has no entry for the deployments
 added after it (``stock4-u16-t256``, ``cycif40-u16-t1024``,
 ``jump5-u16-p1080``, ``fleet4-wsi4-u16-t1024``,
-``wsi4-u16-t1024x24``): a change that is not one of the harness itself
+``wsi4-u16-t1024x24``, ``wsi4-u16-t1024-24000x16000``): a change that is not one of the harness itself
 adds files under ``benchmark/`` and edits none, so the entries sit
 beside it in ``overrides_<deployment>.json`` and every user
 of the harness's ``rehearsal_root`` fixture errors when
@@ -18,8 +18,9 @@ with the merged rehearsal, so nothing of them is copied
 ``tests/test_benchmark_stock_cell.py``,
 ``tests/test_benchmark_toggle_cell.py``,
 ``tests/test_benchmark_jump_cell.py``,
-``tests/test_benchmark_fleet_cell.py`` and
-``tests/test_benchmark_coldpan_cell.py``: the new ones).  A rehearsal
+``tests/test_benchmark_fleet_cell.py``,
+``tests/test_benchmark_coldpan_cell.py`` and
+``tests/test_benchmark_zoompan_cell.py``: the new ones).  A rehearsal
 gives counts, never speeds.
 """
 
@@ -41,6 +42,8 @@ SINGLE1024_CELL = "wsi4-u16-t1024.single"
 TINY_SINGLE1024_CELL = "tiny4-u16-t64.single"
 COLDPAN_CELL = "wsi4-u16-t1024x24.coldpan"
 TINY_COLDPAN_CELL = "tinywsi4x24-u16-t64.coldpan"
+ZOOMPAN_CELL = "wsi4-u16-t1024-24000x16000.zoompan"
+TINY_ZOOMPAN_CELL = "tinyzoom4-u16-t64.zoompan"
 
 # The harness's rehearsal tests (benchmark/tests/test_rehearsal.py):
 # those it runs once a cell, and those it runs on its first cell only
@@ -96,7 +99,8 @@ def build_rehearsal(tmp_path_factory) -> str:
             over[key].update(more[key])
     # ``conftest`` renames configurations by substring, in the order it
     # meets them: a name that holds another (``wsi4-u16-t1024x24``,
-    # ``fleet4-wsi4-u16-t1024``) has to go before the one it holds.
+    # ``wsi4-u16-t1024-24000x16000``, ``fleet4-wsi4-u16-t1024``) has to
+    # go before the one it holds.
     over["configs"] = dict(sorted(over["configs"].items(),
                                   key=lambda item: -len(item[0])))
     for mix, sets in STEADY.items():

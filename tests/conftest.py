@@ -133,3 +133,18 @@ def coding_pool(monkeypatch):
     monkeypatch.setattr(entropypool, "SPLIT_TILES", 0)
     yield entropypool
     pool._executor.shutdown(wait=True)
+
+
+# ----------------------------------- the renderer's edge-shape warm
+
+@pytest.fixture(autouse=True)
+def _edge_warms_end_with_their_test():
+    """A test that serves an image's edge tile starts the renderer's
+    ``edge-warm`` thread (``BatchingRenderer.warm_edges``); it ends with
+    that test, so that its compiles never land in a later test that
+    counts them."""
+    yield
+    import threading
+    for thread in threading.enumerate():
+        if thread.name == "edge-warm":
+            thread.join(timeout=120)

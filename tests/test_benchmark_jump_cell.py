@@ -60,8 +60,10 @@ def test_the_new_entries_are_the_issues():
         m = by_name[name]
         assert (m["layer"], m["source"], m["moves"]) == (
             layer, source, "renders_per_s")
+        # The deep zoom's edge tiles came after.
         assert sorted(m["workloads"]) == sorted(
-            [JUMP_CELL, "plate3-u16-p2048.scan"])
+            [JUMP_CELL, "plate3-u16-p2048.scan",
+             "wsi4-u16-t1024-24000x16000.zoompan"])
     listed = {cell: {m["name"] for m in bench["per_layer"]
                      if cell in m["workloads"]}
               for cell in (w["name"] for w in bench["workloads"])}

@@ -98,7 +98,8 @@ def test_the_fifteen_entries_are_the_tables_appended_in_its_order():
                                if w not in later])
             for m in bench["per_layer"][31:31 + 15]]
     assert [m["name"] for m in mine] == list(METRICS)
-    assert len(bench["per_layer"]) == 31 + 15 + 1 + 4 + 1 + 4 + 1
+    # The deep zoom's four came last.
+    assert len(bench["per_layer"]) == 31 + 15 + 1 + 4 + 1 + 4 + 1 + 4
     layers = {m["layer"] for m in bench["per_layer"][:31]}
     for entry in mine:
         layer, source, moves, reader, listed = METRICS[entry["name"]]
@@ -212,14 +213,15 @@ def test_entropy_pooled_share_is_appended_and_reads_the_tails_counter():
     the reader ``plane_stack_share`` uses; nothing from a server
     without the family (the parent), 0 where every group is of one."""
     bench = _bench()
-    # The seven cells of its time, and the cold pan appended to them;
-    # later metrics came after it.
+    # The seven cells of its time, and the cold pan and the deep zoom
+    # appended to them; later metrics came after it.
     cells = [w["name"] for w in bench["workloads"]][:7]
     assert bench["per_layer"][31 + 15] == {
         "name": "entropy_pooled_share", "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "host entropy tail",
         "moves": "p50_ms",
-        "workloads": cells + ["wsi4-u16-t1024x24.coldpan"]}
+        "workloads": cells + ["wsi4-u16-t1024x24.coldpan",
+                              "wsi4-u16-t1024-24000x16000.zoompan"]}
     entropy_ms = {m["name"]: m for m in bench["per_layer"]}["entropy_ms"]
     assert (entropy_ms["layer"], entropy_ms["moves"]) == (
         "host entropy tail", "p50_ms")
@@ -260,7 +262,9 @@ def test_entropy_split_share_is_appended_and_reads_the_split_counter():
     split."""
     bench = _bench()
     by_name = {m["name"]: m for m in bench["per_layer"]}
-    assert bench["per_layer"][-1] == {
+    # Last of its time; the deep zoom's four came after it.
+    split_entry = bench["per_layer"][31 + 15 + 1 + 4 + 1 + 4]
+    assert split_entry == {
         "name": "entropy_split_share", "unit": "tiles/tile",
         "better": "higher", "source": "program_counter",
         "layer": "host entropy tail", "moves": "p50_ms",
@@ -268,7 +272,7 @@ def test_entropy_split_share_is_appended_and_reads_the_split_counter():
     for cell in ("wsi4-u16-t1024.single", "plate3-u16-p2048.scan",
                  "jump5-u16-p1080.scan", "wsi4-u16-t1024.rewindow",
                  "stock4-u16-t256.pan", "stock4-u16-t256.single"):
-        assert cell in bench["per_layer"][-1]["workloads"]
+        assert cell in split_entry["workloads"]
     split = "imageregion_entropy_split_tiles_total"
     tiles = "imageregion_entropy_tiles_total"
     assert _spec("entropy_split_share") == {

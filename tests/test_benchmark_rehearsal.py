@@ -104,9 +104,10 @@ def test_plane_stack_share_is_added_at_the_end_and_reads_the_counter():
         "source": "program_counter", "layer": "staging",
         "moves": "renders_per_s",
         # Every cell of its time; the four-chip cell came after and is
-        # not on it, the cold pan after that and is.
+        # not on it, the cold pan and the deep zoom after that and are.
         "workloads": [w["name"] for w in bench["workloads"]][:7]
-        + ["wsi4-u16-t1024x24.coldpan"]}
+        + ["wsi4-u16-t1024x24.coldpan",
+           "wsi4-u16-t1024-24000x16000.zoompan"]}
     with open(os.path.join(REPO, "benchmark", "layer_metrics",
                            "plane_stack_share.json")) as f:
         spec = json.load(f)

@@ -54,7 +54,9 @@ def test_the_new_entries_are_the_issues():
         "fleet_steal_share", "fleet_render_roofline",
         "fleet_queue_wait_ms",                 # the fleet's too
         "prefetch_stage_ms", "prefetch_used_share",   # the cold pan's
-        "rawcache_dup_load_share", "idle_prefetch_share"}
+        "rawcache_dup_load_share", "idle_prefetch_share",
+        "level0_render_share", "edge_tile_share",     # the deep zoom's
+        "request_pad_ms", "zoom_render_roofline"}
     with open(os.path.join(REPO, "benchmark", "configs",
                            "stock4-u16-t256.json")) as f:
         config = json.load(f)

@@ -214,6 +214,34 @@ class TestExpositionBudget:
         assert any(family in f
                    for f in lint.lint_exposition(smuggled, budget))
 
+    def test_tile_render_families_lint_clean(self, lint, budget):
+        """Tile renders by level and edge, and the pixels of every
+        render: budgeted, typed, one HELP each; the level label is held
+        to the budget's bound."""
+        family = "imageregion_tile_renders_total"
+        pixels = "imageregion_render_pixels_total"
+        telemetry.TILE_RENDERS.count(64 * 64, 0, False)
+        telemetry.TILE_RENDERS.count(22 * 64, 99, True)
+        text = telemetry.finalize_exposition([
+            line for line in telemetry.device_metric_lines(None)
+            if line.startswith((family, pixels))])
+        assert f'\n{family}{{level="0",edge="full"}} 1\n' in text
+        assert f'\n{family}{{level="15",edge="edge"}} 1\n' in text
+        assert f"\n{pixels} {64 * 64 + 22 * 64}\n" in text
+        for name in (family, pixels):
+            assert f"# TYPE {name} counter\n" in text
+            assert text.count(f"# HELP {name} ") == 1
+        assert budget["families"][family] == {"labels": ["level", "edge"]}
+        assert budget["label_bounds"]["level"] == \
+            telemetry.TileRenderStats.MAX_LEVELS
+        assert budget["label_bounds"]["edge"] == len(
+            telemetry.TileRenderStats.EDGES)
+        assert lint.lint_exposition(text, budget) == []
+        smuggled = text + f'{pixels}{{level="0"}} 1\n'
+        assert any(pixels in f
+                   for f in lint.lint_exposition(smuggled, budget))
+        telemetry.TILE_RENDERS.reset()
+
     def test_every_idle_class_fits_the_during_bound(self, budget):
         from omero_ms_image_region_tpu.utils import profile_summary as ps
         classes = set(ps.IDLE_ORDER) | {ps.NO_GROUP, ps.UNATTRIBUTED}

@@ -6,6 +6,7 @@ import pytest
 from omero_ms_image_region_tpu.server.region import (
     RegionDef,
     clamp_region_to_plane,
+    edge_tile_shapes,
     flip_region,
     get_region_def,
     truncate_region,
@@ -135,3 +136,26 @@ def test_truncate_region_noop_when_inside():
     rd = RegionDef(0, 0, 100, 100)
     truncate_region(1024, 1024, rd)
     assert rd.as_tuple() == (0, 0, 100, 100)
+
+
+def test_edge_tile_shapes_are_the_truncated_tiles_of_every_level():
+    """The shapes ``get_region_def`` cuts at each level's right column,
+    bottom row and corner; a level smaller than the tile is one tile."""
+    levels = [[24000, 16000], [12000, 8000], [6000, 4000], [3000, 2000],
+              [1500, 1000], [750, 500]]
+    shapes = edge_tile_shapes(levels, 1024, 1024)
+    assert len(shapes) == 15
+    assert {(1024, 448), (640, 1024), (640, 448), (1000, 476),
+            (1000, 1024), (500, 750)} <= set(shapes)
+    cut = set()
+    for res, (sx, sy) in enumerate(levels):
+        for col in range(-(-sx // 1024)):
+            for row in range(-(-sy // 1024)):
+                rd = get_region_def(levels, res, RegionDef(col, row, 1024,
+                                                           1024),
+                                    None, (1024, 1024), MAX_TILE)
+                if (rd.height, rd.width) != (1024, 1024):
+                    cut.add((rd.height, rd.width))
+    assert cut == set(shapes)
+    # A pyramid of whole tiles has none.
+    assert edge_tile_shapes([[2048, 1024], [1024, 512]], 512, 512) == []

@@ -523,6 +523,10 @@ _ALLOWED_LABEL_KEYS = frozenset({
     # Who lost a raw-cache load race: "prefetch" or "request", the two
     # keys of ``telemetry.DUPLICATE_LOADS``.
     "by",
+    # A tile render's pyramid level (held under
+    # ``telemetry.TileRenderStats.MAX_LEVELS``) and whether the level's
+    # edge cut it: "full" or "edge".
+    "level", "edge",
 })
 
 
